@@ -5,8 +5,8 @@ bench result JSON into ``bench_experiments/BASELINE.json`` (NOT the
 repo-root BASELINE.json, which is the immutable seed reference);
 ``bench.py --check-regressions`` compares a fresh result against the
 bank and fails with an attributed report when any metric moved beyond
-its tolerance in the bad direction. Stdlib-only: the gate runs on the
-bench supervisor side, which never imports jax.
+its tolerance in the bad direction. Stdlib-only: the gate never imports
+jax and never touches the chip.
 
 Store schema (``version`` 1)::
 

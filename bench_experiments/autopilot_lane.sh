@@ -20,8 +20,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=cpu
-export PADDLE_TPU_BENCH_CPU=1
-export PADDLE_TPU_BENCH_SKIP_PROBE=1
 export PADDLE_TPU_TELEMETRY=on
 
 WORK_DIR="$(mktemp -d /tmp/paddle_tpu_autopilot_lane.XXXXXX)"

@@ -11,7 +11,7 @@ matches ~0.34, s512 is attention-bandwidth destiny; if not, the gap is
 framework overhead worth chasing.
 
 Self-exiting; banks to bench_experiments/bert_s512_ablate.json after
-every variant (relay-safe).
+every variant.
 """
 import os
 import sys

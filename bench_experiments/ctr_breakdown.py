@@ -12,7 +12,7 @@
                    -> step): what bench.py reports.
   pipeline_b8192 — same, batch 8192: does the sparse path scale?
 
-Self-exiting; banks to ctr_breakdown.json per variant (relay-safe).
+Self-exiting; banks to ctr_breakdown.json per variant.
 """
 import os
 import sys

@@ -31,6 +31,8 @@ echo "== lane 2: continuous batching under 8 concurrent streams =="
 CACHE_DIR="$(mktemp -d /tmp/paddle_tpu_decode_lane.XXXXXX)"
 trap 'rm -rf "$CACHE_DIR"' EXIT
 export PADDLE_TPU_COMPILE_CACHE_DIR="$CACHE_DIR"
+# the XLA tier is placed from outside too: nothing in the program sets it
+export JAX_COMPILATION_CACHE_DIR="$CACHE_DIR/xla"
 
 python - <<'EOF'
 import json

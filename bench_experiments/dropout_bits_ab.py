@@ -4,7 +4,7 @@ b16/s512). Decides whether 8-bit ships as the default: the s512
 ablation showed dropout is ~18% of the step there, but the first mixed
 readings were contended — this run is back-to-back on an idle host.
 
-Self-exiting; banks to dropout_bits_ab.json per variant (relay-safe).
+Self-exiting; banks to dropout_bits_ab.json per variant.
 """
 import os
 import sys

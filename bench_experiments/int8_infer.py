@@ -3,7 +3,7 @@ int8 path (2x bf16 peak on v5e: 394 vs 197 TOPS) show up through the
 framework's real-int8 quantized ops (slim freeze/convert ->
 quantized_mul: int8xint8 -> int32 dot_general)?
 
-Three levels, each banked separately (relay-safe, self-exiting):
+Three levels, each banked separately (self-exiting):
 1. primitive — raw dot_general at BERT shapes, bf16 vs int8
 2. end-to-end BERT-base ENCODER inference: bf16-AMP baseline vs the
    quantized program (every fc weight int8; attention act-act matmuls

@@ -9,14 +9,8 @@
 # zero-dependency CLI round-trip: `--plan --devices 8` must emit a
 # ranked plan (exit 0), write byte-identical JSON across two fresh
 # processes, and the winning plan must load back through
-# DistributedStrategy.from_plan into a runnable fleet step. Lane 3 is
-# the jax version-matrix step (ROADMAP item 6's upgrade lane): the
-# planner slice runs under the current pin always, and — when
-# PADDLE_TPU_JAX_LATEST_PY points at a python with a newer jax
-# installed (the matrix never pip-installs anything itself) — under
-# latest jax too, plus a non-gating pass over the decode/disagg
-# serving slices so upgrade hazards in the serving surface get
-# reported without blocking the lane.
+# DistributedStrategy.from_plan into a runnable fleet step. Both run
+# under the one jax this installation has.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -25,7 +19,7 @@ export JAX_PLATFORMS=cpu
 # measurements have a real dp axis (same trick as tests/conftest.py)
 export XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS:-}"
 
-echo "== lane 1: planner pytest slice (current jax pin) =="
+echo "== lane 1: planner pytest slice =="
 python -c 'import jax; print("jax", jax.__version__)'
 python -m pytest -q -p no:cacheprovider -m planner tests/
 
@@ -82,56 +76,5 @@ assert np.isfinite(float(np.asarray(out[0])))
 print("fleet step under the planned strategy: loss",
       float(np.asarray(out[0])))
 EOF
-
-echo "== lane 3: jax version matrix =="
-# current pin already ran in lane 1; run latest jax when an alternate
-# interpreter is provided (this lane never installs packages)
-if [[ -n "${PADDLE_TPU_JAX_LATEST_PY:-}" ]]; then
-    echo "-- latest jax via $PADDLE_TPU_JAX_LATEST_PY --"
-    "$PADDLE_TPU_JAX_LATEST_PY" -c 'import jax; print("jax", jax.__version__)'
-    "$PADDLE_TPU_JAX_LATEST_PY" -m pytest -q -p no:cacheprovider \
-        -m planner tests/
-    # serving surface under latest jax: decode + disagg slices ride the
-    # matrix non-gating (report-only) until the pin moves — their pass
-    # counts flag upgrade hazards without blocking the planner lane
-    echo "-- latest jax, serving slices (non-gating) --"
-    "$PADDLE_TPU_JAX_LATEST_PY" -m pytest -q -p no:cacheprovider \
-        tests/test_decode_serving.py tests/test_disagg_serving.py \
-        || echo "WARN: serving slices not clean under latest jax" \
-               "(non-gating; see output above)"
-    # analysis slice (verifier/shapes/lint + the concurrency/donation
-    # sanitizers) rides the matrix non-gating the same way: the
-    # dataflow pass reads donation semantics off jax's donate_argnums
-    # contract, so a pin move that shifts it gets flagged here first
-    echo "-- latest jax, analysis slice (non-gating) --"
-    "$PADDLE_TPU_JAX_LATEST_PY" -m pytest -q -p no:cacheprovider \
-        -m analysis tests/ \
-        || echo "WARN: analysis slice not clean under latest jax" \
-               "(non-gating; see output above)"
-    # perf/ledger slice: the executable ledger probes cost_analysis()/
-    # memory_analysis() off compiled executables, APIs that drift with
-    # jax HEAD — run it under the matrix so a shape change degrades to
-    # a WARN here before the pin moves
-    echo "-- latest jax, perf/ledger slice (non-gating) --"
-    "$PADDLE_TPU_JAX_LATEST_PY" -m pytest -q -p no:cacheprovider \
-        tests/test_perf_observatory.py \
-        || echo "WARN: perf/ledger slice not clean under latest jax" \
-               "(non-gating; cost_analysis/memory_analysis probing" \
-               "tracks jax HEAD — see output above)"
-    # retrieval slice: shard_map + bitcast psum + streamed top_k lean
-    # on collective semantics that have shifted across jax releases —
-    # the bit-exactness proofs run under the matrix non-gating so a
-    # pin move that breaks them degrades to a WARN here first
-    echo "-- latest jax, retrieval slice (non-gating) --"
-    "$PADDLE_TPU_JAX_LATEST_PY" -m pytest -q -p no:cacheprovider \
-        -m retrieval tests/ \
-        || echo "WARN: retrieval slice not clean under latest jax" \
-               "(non-gating; shard_map/bitcast-psum/top_k semantics" \
-               "track jax HEAD — see output above)"
-else
-    echo "SKIP latest-jax leg: set PADDLE_TPU_JAX_LATEST_PY to a python"
-    echo "with a newer jax to run the matrix (no packages are installed"
-    echo "by this lane)"
-fi
 
 echo "planner lane OK"

@@ -7,7 +7,7 @@ chrome-trace events from the profile dir and aggregates device-track
 op durations into a top-N table. Banks to profile_b48.json; the trace
 dir itself is left under .bench_runs/profile_b48/ for tensorboard.
 
-Self-exiting; never killed (relay protocol).
+Self-exiting.
 """
 import glob
 import gzip

@@ -10,7 +10,7 @@ ops/nn_ops.py _batch_norm, marked experiment-only):
 - sgd               — Momentum -> SGD (bounds optimizer state traffic)
 
 Self-exiting; banks to bench_experiments/resnet_gap.json after every
-variant (relay-safe). Ship whichever knob wins as the default;
+variant. Ship whichever knob wins as the default;
 document whichever doesn't in BENCHMARKS.md.
 """
 import os

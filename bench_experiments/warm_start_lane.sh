@@ -24,6 +24,8 @@ echo "== lane 2: two-process warm start on a shared cache dir =="
 CACHE_DIR="$(mktemp -d /tmp/paddle_tpu_warm_lane.XXXXXX)"
 trap 'rm -rf "$CACHE_DIR"' EXIT
 export PADDLE_TPU_COMPILE_CACHE_DIR="$CACHE_DIR"
+# the XLA tier is placed from outside too: nothing in the program sets it
+export JAX_COMPILATION_CACHE_DIR="$CACHE_DIR/xla"
 
 run_once() {
 python - <<'EOF'

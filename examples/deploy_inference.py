@@ -4,10 +4,7 @@ import sys
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    import jax
 
-    jax.config.update("jax_platforms", "cpu")
 import tempfile
 
 import numpy as np

@@ -5,17 +5,14 @@ migration path end to end.
 Run with 8 virtual devices to see real sharding on a CPU host:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PADDLE_TPU_FORCE_CPU=1 python examples/retrieval_serving.py
+        JAX_PLATFORMS=cpu python examples/retrieval_serving.py
 """
 import os
 import sys
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    import jax
 
-    jax.config.update("jax_platforms", "cpu")
 import json
 import urllib.request
 

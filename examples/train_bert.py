@@ -1,6 +1,6 @@
 """BERT-base MLM pretraining through the fluid API.
 
-CPU smoke:   python examples/train_bert.py --tiny --steps 5
+CPU smoke:   JAX_PLATFORMS=cpu python examples/train_bert.py --tiny --steps 5
 TPU:         python examples/train_bert.py --steps 100
 """
 import os
@@ -8,10 +8,6 @@ import sys
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 import time

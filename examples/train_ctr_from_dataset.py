@@ -20,10 +20,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-if os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import paddle_tpu.fluid as fluid  # noqa: E402
 from paddle_tpu.fluid.incubate.data_generator import (  # noqa: E402

@@ -33,6 +33,7 @@ import os
 
 __all__ = [
     "DeviceProfile", "DEVICE_TABLE", "device_profile", "peak_flops",
+    "require_device_profile",
     "bert_train_flops_per_token", "OpCost", "op_costs", "jaxpr_flops",
     "CostReport", "analyze_cost", "predict_program",
     "ring_allreduce_seconds", "allreduce_bandwidth",
@@ -189,19 +190,21 @@ class DeviceProfile:
 
 
 # Public per-chip figures, matched by device_kind substring — the
-# LONGEST matching key wins ("v5p" beats "v5" regardless of row order,
-# so adding rows can never shadow existing ones). bf16 peak FLOPs/s,
-# HBM bytes, HBM bytes/s, ICI bytes/s (all links per chip), DCN
-# bytes/s per chip, max chips per ICI slice.
+# LONGEST matching key wins ("v5p" beats a shorter key regardless of
+# row order, so adding rows can never shadow existing ones). A v5e
+# chip reports device_kind "TPU v5 lite" (chip run, PR 21); "v5e" is
+# the marketing name tools print. There is no bare "v5" row: a part
+# this table does not know gets no peaks, never another part's.
+# bf16 peak FLOPs/s, HBM bytes, HBM bytes/s, ICI bytes/s (all links
+# per chip), DCN bytes/s per chip, max chips per ICI slice.
+_V5E = ("v5e", 197e12, 16e9, 819e9, 200e9, 12.5e9, 256)
 DEVICE_TABLE = [
     ("v6", DeviceProfile("v6e", 918e12, 32e9, 1640e9, 448e9,
                          25e9, 256)),
     ("v5p", DeviceProfile("v5p", 459e12, 95e9, 2765e9, 600e9,
                           25e9, 8960)),
-    ("v5e", DeviceProfile("v5e", 197e12, 16e9, 819e9, 200e9,
-                          12.5e9, 256)),
-    ("v5", DeviceProfile("v5e", 197e12, 16e9, 819e9, 200e9,
-                         12.5e9, 256)),
+    ("v5 lite", DeviceProfile(*_V5E)),
+    ("v5e", DeviceProfile(*_V5E)),
     ("v4", DeviceProfile("v4", 275e12, 32e9, 1228e9, 300e9,
                          12.5e9, 4096)),
     ("v3", DeviceProfile("v3", 123e12, 32e9, 900e9, 82e9,
@@ -351,10 +354,23 @@ def device_profile(device_kind=None):
 
 
 def peak_flops(device_kind):
-    """bf16 peak FLOPs/s for a device_kind, or None (bench.py's
-    ``_peak_flops``, now table-backed here)."""
+    """bf16 peak FLOPs/s for a device_kind, or None when the device is
+    unknown (the analyzer then makes no prediction)."""
     p = device_profile(device_kind)
     return p.peak_flops if p is not None else None
+
+
+def require_device_profile(device_kind):
+    """:func:`device_profile` for a measurement path: an unknown device
+    is an error there, never a default and never a silently dropped
+    utilisation figure."""
+    p = device_profile(device_kind)
+    if p is None or not p.peak_flops:
+        raise LookupError(
+            "device_kind %r is not in analysis.costs.DEVICE_TABLE (keys "
+            "%s) — add a row with its published peaks and their source"
+            % (device_kind, [k for k, _ in DEVICE_TABLE]))
+    return p
 
 
 def bert_train_flops_per_token(cfg, seq):

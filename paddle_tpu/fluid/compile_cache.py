@@ -16,11 +16,18 @@ Activation — either of:
 - :func:`activate` (``TrainGuard`` calls it to co-locate the cache with
   its checkpoint directory, see ``parallel.checkpoint.compile_cache_dir``).
 
-Both also point jax's own persistent XLA compilation cache at a
-``<dir>/xla`` subdirectory (best effort), so the *backend* compile of a
-deserialized module is disk-cached too: the export blob skips
-trace+lower, the XLA cache skips codegen, and a warm process pays only
-the deserialize + executable load.
+This AOT tier is off by default. A disk hit runs the deserialized
+``jax.export`` module, which does **not** donate its inputs
+(:class:`_DiskEntry`): it is not the executable a cold process runs, so
+a benchmark leaves this tier off or says which tier served each compile.
+
+jax's own persistent XLA compilation cache is a separate tier placed by
+:func:`configure_xla_cache` — the one function in the repository that
+decides its directory: ``JAX_COMPILATION_CACHE_DIR`` when the
+environment sets it (then nothing is set in code), else the fixed
+``<checkout>/.jax_cache``. Entry points (``chip_smoke.py``, ``bench.py``,
+the experiment scripts and ``TrainGuard(compile_cache=...)``) call it;
+importing the package does not.
 
 Cache entries are content-addressed: the key hashes the program's
 *structural* fingerprint (op types/slots/attrs, var shapes/dtypes —
@@ -73,11 +80,16 @@ from .. import observability as obs
 
 __all__ = [
     "CACHE_DIR_ENV", "Unfingerprintable", "activate", "cache_dir",
-    "enabled", "entry_key", "fingerprint_or_none", "has", "load",
-    "program_fingerprint", "store",
+    "configure_xla_cache", "enabled", "entry_key", "fingerprint_or_none",
+    "has", "load", "program_fingerprint", "store",
 ]
 
 CACHE_DIR_ENV = "PADDLE_TPU_COMPILE_CACHE_DIR"
+# fixed, never built from a temp name, pid or time: the directory is
+# part of the cache key, so one that moves never hits
+_XLA_CACHE_DEFAULT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 # v2: entries are sealed in an integrity envelope (digest-before-
 # deserialize); v1 blobs simply miss under the new keys and re-fill.
 _FORMAT_VERSION = 2
@@ -86,7 +98,6 @@ _ENTRY_KIND = "compile-cache"
 
 _lock = threading.Lock()
 _default_dir = None     # programmatic activation (TrainGuard co-location)
-_xla_cache_set = False
 _warned_store = False
 
 
@@ -106,35 +117,33 @@ def enabled():
     return cache_dir() is not None
 
 
-def activate(path, configure_xla_cache=True):
+def activate(path):
     """Programmatically enable the disk tier at `path` (the env var, when
     set, still wins — an operator override beats code defaults). Returns
-    the previously configured default. Also points jax's persistent XLA
-    compilation cache at ``<path>/xla`` (best effort, once per process)
-    so backend compiles of deserialized modules are cached too."""
+    the previously configured default. jax's own XLA cache is a separate
+    tier: see :func:`configure_xla_cache`."""
     global _default_dir
     with _lock:
         prev, _default_dir = _default_dir, (
             os.path.abspath(path) if path else None)
-    if path and configure_xla_cache:
-        _configure_xla_cache(os.path.join(os.path.abspath(path), "xla"))
     return prev
 
 
-def _configure_xla_cache(path):
-    global _xla_cache_set
-    with _lock:
-        if _xla_cache_set:
-            return
-        _xla_cache_set = True
-    try:
-        import jax
+def configure_xla_cache():
+    """Place jax's persistent XLA compilation cache and return its
+    directory. With ``JAX_COMPILATION_CACHE_DIR`` in the environment jax
+    has already taken the directory from there and nothing is set in
+    code; otherwise the directory is the fixed ``<checkout>/.jax_cache``.
+    The minimum compile time for an entry stays jax's own default (1 s)
+    either way. Idempotent."""
+    import jax
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
-    except Exception:  # noqa: BLE001 — the XLA cache is an optimization only
-        pass
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env   # jax read it at import; nothing is set here
+    if jax.config.jax_compilation_cache_dir != _XLA_CACHE_DEFAULT:
+        jax.config.update("jax_compilation_cache_dir", _XLA_CACHE_DEFAULT)
+    return _XLA_CACHE_DEFAULT
 
 
 # ---------------------------------------------------------------------------

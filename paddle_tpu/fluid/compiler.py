@@ -89,13 +89,41 @@ class CompiledProgram:
             devs = [p.jax_device() if hasattr(p, "jax_device") else p
                     for p in self._places]
         else:
-            backend = getattr(place, "_backend", None)
-            try:
-                devs = jax.devices(backend) if backend else jax.devices()
-            except RuntimeError:
-                devs = jax.devices()
+            # every device of the executor place's backend; a TPUPlace
+            # without a TPU raises here, it never lands on the CPU
+            devs = jax.devices(place._backend)
         self._mesh = Mesh(np.array(devs), axis_names=("dp",))
         return self._mesh
+
+    def _shard_feeds(self, feed, mesh):
+        """Host feeds -> device arrays split on the batch axis over
+        'dp'. A feed with no batch axis (a scalar, or a static leading
+        dim the device count does not divide) is replicated; a batch
+        the device count does not divide is an error, never a silent
+        replication of the whole batch onto every chip."""
+        ndev = mesh.devices.size
+        block = self._program.global_block()
+        out = {}
+        for name, value in (feed or {}).items():
+            value = getattr(value, "_ndarray", value)
+            arr = np.asarray(value)
+            var = block.var(name) if block.has_var(name) else None
+            if var is not None and var.dtype is not None:
+                want = core.np_dtype(var.dtype)
+                if arr.dtype != want:
+                    arr = arr.astype(want)
+            if arr.shape and arr.shape[0] % ndev == 0:
+                spec = P("dp")
+            elif (arr.shape and var is not None and var.shape
+                  and var.shape[0] in (None, -1)):
+                raise ValueError(
+                    "data-parallel feed %r has batch %d, which %d devices "
+                    "do not divide — feed a multiple of the device count"
+                    % (name, arr.shape[0], ndev))
+            else:
+                spec = P()
+            out[name] = jax.device_put(arr, NamedSharding(mesh, spec))
+        return out
 
     # called by Executor.run when program is a CompiledProgram
     def _executor_run(self, executor, feed, fetch_list, scope, return_numpy):
@@ -117,20 +145,7 @@ class CompiledProgram:
         mesh = self._get_mesh(executor.place)
         ndev = mesh.devices.size
         repl = NamedSharding(mesh, P())
-        batch_shard = NamedSharding(mesh, P("dp"))
-        block = program.global_block()
-        feed_arrays = {}
-        for name, value in (feed or {}).items():
-            value = getattr(value, "_ndarray", value)
-            arr = np.asarray(value)
-            if block.has_var(name) and block.var(name).dtype is not None:
-                want = core.np_dtype(block.var(name).dtype)
-                if arr.dtype != want:
-                    arr = arr.astype(want)
-            if arr.shape and arr.shape[0] % ndev == 0:
-                feed_arrays[name] = jax.device_put(arr, batch_shard)
-            else:
-                feed_arrays[name] = jax.device_put(arr, repl)
+        feed_arrays = self._shard_feeds(feed, mesh)
         state = {
             k: (v if hasattr(v, "sharding")
                 and getattr(v.sharding, "mesh", None) is mesh
@@ -148,7 +163,8 @@ class CompiledProgram:
         )
         entry = self._cache.get(sig)
         if entry is None:
-            step = build_step_fn(program, list(feed_arrays), fetch_names)
+            step = build_step_fn(program, list(feed_arrays), fetch_names,
+                                 platform=executor.place._backend)
             # shardings are carried by the committed input arrays (feeds
             # batch-sharded over 'dp', state replicated); XLA partitions the
             # whole step and inserts the ICI collectives for the vjp grads

@@ -108,13 +108,13 @@ def cpu_places(device_count=None):
 
 
 def tpu_places(device_ids=None):
+    """One TPUPlace per accelerator device jax exposes (none on a CPU
+    backend), or per given id."""
     import jax
 
     if device_ids is None:
-        try:
-            device_ids = range(len(jax.devices()))
-        except RuntimeError:
-            device_ids = [0]
+        device_ids = range(
+            sum(d.platform != "cpu" for d in jax.devices()))
     return [core.TPUPlace(i) for i in device_ids]
 
 

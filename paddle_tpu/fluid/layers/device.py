@@ -13,10 +13,10 @@ __all__ = []
 
 def get_places(device_count=None, device_type=None):
     """Return up to ``device_count`` Places of ``device_type``
-    ('CPU'/'TPU'); deprecated — use CompiledProgram.with_data_parallel,
+    ('CPU'/'TPU', default: the kind jax's backend exposes); deprecated — use CompiledProgram.with_data_parallel,
     which shards over the full jax mesh (ref layers/device.py:30)."""
     if device_type is None:
-        device_type = "TPU" if core.is_compiled_with_tpu() else "CPU"
+        device_type = "TPU" if core._default_backend() == "tpu" else "CPU"
     dt = str(device_type).upper()
     if dt == "TPU":
         places = tpu_places()

@@ -48,7 +48,6 @@ stacked-stage pipeline (paddle_tpu.parallel.pipeline.gpipe_composed),
 whose single stage body is executed by EVERY device so tp psums are
 structurally uniform.
 """
-import warnings
 
 import numpy as np
 
@@ -273,17 +272,7 @@ def run_pipeline_program(executor, program, feed, fetch_list, scope,
         # AOT-compile like the main executor path: without this the
         # donated state comes back in compiler-chosen layouts and run 2
         # would retrace+recompile the whole shard_map/scan module
-        try:
-            entry = jitted.lower(state, feed_arrays, rng).compile()
-        except OpLoweringError:
-            raise
-        except Exception as e:
-            warnings.warn(
-                "pipeline AOT compile failed (%s: %s); falling back to "
-                "traced jit — expect one redundant recompile"
-                % (type(e).__name__, e)
-            )
-            entry = jitted
+        entry = jitted.lower(state, feed_arrays, rng).compile()
         executor._cache[sig] = entry
 
     fetches, new_state = entry(state, feed_arrays, rng)

@@ -888,7 +888,10 @@ class TrainGuard:
         # with the checkpoints (parallel.checkpoint.compile_cache_dir):
         # a crash-resumed process then skips the cold recompile the same
         # way it skips completed steps. A string names an explicit cache
-        # dir; PADDLE_TPU_COMPILE_CACHE_DIR in the env always wins.
+        # dir; PADDLE_TPU_COMPILE_CACHE_DIR in the env always wins. jax's
+        # XLA cache goes where compile_cache.configure_xla_cache puts it
+        # (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache), never
+        # under the checkpoint directory.
         if compile_cache:
             from . import compile_cache as _cc
 
@@ -903,7 +906,8 @@ class TrainGuard:
                         "to co-locate the cache (or pass an explicit "
                         "cache path string)")
                 cache_path = _ckpt_mod.compile_cache_dir(ckpt_dir)
-            _cc.activate(cache_path, configure_xla_cache=False)
+            _cc.activate(cache_path)
+            _cc.configure_xla_cache()
         self._stage_to_device = bool(stage_to_device)
         self._fetch_list = fetch_list
         self._feed_fn = feed_fn

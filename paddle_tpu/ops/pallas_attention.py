@@ -652,7 +652,6 @@ def _fused_mha_lowering(ctx, ins, attrs):
     ):
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..parallel.ring_attention import ring_attention
@@ -675,9 +674,9 @@ def _fused_mha_lowering(ctx, ins, attrs):
             ot = ring_attention(qt, kt, vt, axis_name=sp, causal=causal)
             return jnp.moveaxis(ot, 2, 1)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=spec, check_rep=False,
+            out_specs=spec, check_vma=False,
         )(q, k, v)
         return single(out)
 

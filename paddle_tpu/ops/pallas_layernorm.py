@@ -32,8 +32,8 @@ def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
     xhat = xc * rstd
     y = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
     y_ref[...] = y.astype(y_ref.dtype)
-    mean_ref[...] = mean[:, 0]
-    rstd_ref[...] = rstd[:, 0]
+    mean_ref[...] = mean
+    rstd_ref[...] = rstd
 
 
 def _bwd_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref, dx_ref, dg_ref,
@@ -41,24 +41,31 @@ def _bwd_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref, dx_ref, dg_ref,
     x = x_ref[...].astype(jnp.float32)
     dy = dy_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    mean = mean_ref[...][:, None]
-    rstd = rstd_ref[...][:, None]
-    xhat = (x - mean) * rstd
+    rstd = rstd_ref[...]
+    xhat = (x - mean_ref[...]) * rstd
     wdy = dy * g
     c1 = jnp.mean(wdy, axis=1, keepdims=True)
     c2 = jnp.mean(wdy * xhat, axis=1, keepdims=True)
     dx = (wdy - c1 - xhat * c2) * rstd
     dx_ref[...] = dx.astype(dx_ref.dtype)
     # per-block partials; wrapper sums over the grid axis
-    dg_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    dg_ref[0] = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(dy, axis=0, keepdims=True)
+
+
+# Mosaic wants the last two block dims to be multiples of the (8, 128)
+# f32 tile or the whole array dim: rows ride in blocks of >= _SUBLANES
+# (the wrapper pads n up to a multiple), gamma/beta are (1, H), the
+# per-row stats (bm, 1), and the per-block dgamma/dbeta partials
+# (1, 1, H) slices of a (grid, 1, H) array.
+_SUBLANES = 8
 
 
 def _row_block(n):
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
+    for b in (256, 128, 64, 32, 16, _SUBLANES):
         if n % b == 0:
             return b
-    return 1
+    raise ValueError("row count %d is not a multiple of %d" % (n, _SUBLANES))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -73,23 +80,18 @@ def _ln_fwd(x, gamma, beta, eps, interpret):
     n, h = x.shape
     bm = _row_block(n)
     grid = (n // bm,)
+    rows = pl.BlockSpec((bm, h), lambda i: (i, 0))
+    vec = pl.BlockSpec((1, h), lambda i: (0, 0))
+    stat = pl.BlockSpec((bm, 1), lambda i: (i, 0))
     y, mean, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-        ),
+        in_specs=[rows, vec, vec],
+        out_specs=(rows, stat, stat),
         out_shape=(
             jax.ShapeDtypeStruct((n, h), x.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ),
         interpret=interpret,
     )(x, gamma, beta)
@@ -102,31 +104,25 @@ def _ln_bwd(eps, interpret, res, dys):
     n, h = x.shape
     bm = _row_block(n)
     grid = (n // bm,)
+    rows = pl.BlockSpec((bm, h), lambda i: (i, 0))
+    stat = pl.BlockSpec((bm, 1), lambda i: (i, 0))
+    part = pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0))
     dx, dg_part, db_part = pl.pallas_call(
         _bwd_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((bm, h), lambda i: (i, 0)),
-            pl.BlockSpec((1, h), lambda i: (i, 0)),
-            pl.BlockSpec((1, h), lambda i: (i, 0)),
-        ),
+        in_specs=[rows, pl.BlockSpec((1, h), lambda i: (0, 0)), stat, stat,
+                  rows],
+        out_specs=(rows, part, part),
         out_shape=(
             jax.ShapeDtypeStruct((n, h), x.dtype),
-            jax.ShapeDtypeStruct((grid[0], h), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], h), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, h), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0], 1, h), jnp.float32),
         ),
         interpret=interpret,
     )(x, gamma, mean, rstd, dy)
-    dg = jnp.sum(dg_part, axis=0).astype(gamma.dtype)
-    db = jnp.sum(db_part, axis=0).astype(gamma.dtype)
-    return dx, dg, db
+    dg = jnp.sum(dg_part, axis=(0, 1)).reshape(gamma.shape)
+    db = jnp.sum(db_part, axis=(0, 1)).reshape(gamma.shape)
+    return dx, dg.astype(gamma.dtype), db.astype(gamma.dtype)
 
 
 _ln.defvjp(_ln_fwd, _ln_bwd)
@@ -144,17 +140,24 @@ def fused_layer_norm(x, gamma=None, beta=None, eps=1e-5, interpret=False,
     shape = x.shape
     h = shape[-1]
     xf = x.reshape(-1, h)
+    n = xf.shape[0]
+    # pad/slice sit OUTSIDE the custom_vjp so autodiff zeroes the pad
+    # rows' cotangents for free
+    pad = -n % _SUBLANES
+    if pad:
+        xf = jnp.pad(xf, ((0, pad), (0, 0)))
     if gamma is None:
         gamma = jnp.ones((h,), jnp.float32)
     if beta is None:
         beta = jnp.zeros((h,), jnp.float32)
     y, mean, rstd = _ln(
-        xf, gamma.reshape(h), beta.reshape(h), float(eps), interpret
+        xf, gamma.reshape(1, h), beta.reshape(1, h), float(eps), interpret
     )
+    y = y[:n].reshape(shape)
     if return_stats:
         return (
-            y.reshape(shape),
-            mean.reshape(shape[:-1]),
-            rstd.reshape(shape[:-1]),
+            y,
+            mean[:n, 0].reshape(shape[:-1]),
+            rstd[:n, 0].reshape(shape[:-1]),
         )
-    return y.reshape(shape)
+    return y

@@ -105,14 +105,12 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None):
 def ring_attention_sharded(q, k, v, mesh, axis="sp", causal=False):
     """Convenience wrapper: q,k,v are GLOBAL (B, T, H, D) arrays; runs ring
     attention with the sequence dim sharded over `axis`."""
-    from jax.experimental.shard_map import shard_map
-
     spec = P(None, axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -17,29 +17,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def shard_map_manual(f, mesh, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` with replication checking off, portable across the
-    ``jax.shard_map`` (``check_vma``/``axis_names``) and experimental
-    (``check_rep``/``auto``) signatures. ``manual_axes=None`` means
-    every mesh axis is manual; a set selects partially-manual mode
-    (the remaining axes stay GSPMD-auto)."""
+    """``jax.shard_map`` with replication checking off.
+    ``manual_axes=None`` means every mesh axis is manual; a set selects
+    partially-manual mode (the remaining axes stay GSPMD-auto)."""
     kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if getattr(jax, "shard_map", None) is not None:
-        if manual_axes is not None:
-            kw["axis_names"] = frozenset(manual_axes)
-        return jax.shard_map(f, check_vma=False, **kw)
     if manual_axes is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    try:
-        return shard_map(f, check_vma=False, **kw)
-    except TypeError:
-        return shard_map(f, check_rep=False, **kw)
+        kw["axis_names"] = frozenset(manual_axes)
+    return shard_map(f, check_vma=False, **kw)
 
 from ..fluid import core
 from ..fluid.framework import Variable
@@ -606,10 +594,7 @@ class StackedDpProgram(DistributedProgram):
                 in_specs=(state_specs, feed_specs, P(), P()),
                 out_specs=([P("dp")] * len(fetch_names), state_specs),
             )
-            try:  # replication checking: check_vma (new) / check_rep (old)
-                stepper = shard_map(per_shard, check_vma=False, **smap_kw)
-            except TypeError:
-                stepper = shard_map(per_shard, check_rep=False, **smap_kw)
+            stepper = shard_map(per_shard, check_vma=False, **smap_kw)
             entry = jax.jit(stepper, donate_argnums=(0,))
             self._cache[sig] = entry
 

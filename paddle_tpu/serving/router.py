@@ -29,7 +29,11 @@ Replica flavors:
   ServingEngine and writes responses back. SIGKILL the worker and its
   beacons stop; the router's health loop fails the orphaned in-flight
   requests with :class:`ReplicaGoneError`, which the dispatch layer
-  treats as "replay on the next replica".
+  treats as "replay on the next replica". A chip belongs to one
+  process, and every worker process would claim every chip of its
+  host: these workers are for CPU drills (``chaos_serving_lane.sh``)
+  unless each is handed disjoint chips from outside. On a chip host
+  the fleet is the in-process ``local_fleet(per_device=True)``.
 
 Dispatch is least-loaded with shed-aware failover: candidates are the
 live replicas ordered by (straggler?, queue depth), depth ties rotated
@@ -1254,7 +1258,9 @@ def _parse_buckets(text):
 
 
 def worker_main(argv=None):
-    """Process entry point for one FileStore-transport replica::
+    """Process entry point for one FileStore-transport replica (a CPU
+    drill, or one worker per disjoint set of chips given from outside —
+    see the module docstring)::
 
         python -m paddle_tpu.serving.router --store /shared/fleet \\
             --rid 0 --name mnist --model-dir /models/mnist \\

@@ -11,10 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except (ImportError, AttributeError):  # pragma: no cover - jax version
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu import observability as obs
@@ -33,12 +30,8 @@ def _mesh():
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells the flag check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 # -- quantize.py ------------------------------------------------------------

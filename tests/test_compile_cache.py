@@ -70,8 +70,7 @@ def test_unfingerprintable_program_raises():
 
 def test_activate_and_env_precedence(monkeypatch, tmp_path):
     monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
-    prev = compile_cache.activate(str(tmp_path / "prog"),
-                                  configure_xla_cache=False)
+    prev = compile_cache.activate(str(tmp_path / "prog"))
     try:
         assert compile_cache.cache_dir() == str(tmp_path / "prog")
         assert compile_cache.enabled()
@@ -80,7 +79,7 @@ def test_activate_and_env_precedence(monkeypatch, tmp_path):
                            str(tmp_path / "env"))
         assert compile_cache.cache_dir() == str(tmp_path / "env")
     finally:
-        compile_cache.activate(prev, configure_xla_cache=False)
+        compile_cache.activate(prev)
 
 
 def test_checkpoint_colocation_helper(tmp_path):
@@ -172,7 +171,7 @@ def test_trainguard_colocates_compile_cache(monkeypatch, tmp_path):
         assert _entry_files(cache_d), \
             "TrainGuard(compile_cache=True) stored nothing"
     finally:
-        compile_cache.activate(prev, configure_xla_cache=False)
+        compile_cache.activate(prev)
     # without ckpt_dir there is nowhere to co-locate
     with pytest.raises(ValueError):
         TrainGuard(exe, compile_cache=True)

@@ -40,12 +40,22 @@ def _fc_chain(widths=(16, 32, 1), batch=None):
 def test_device_table_lookup_and_precedence():
     p = costs.device_profile("TPU v5e chip")
     assert (p.name, p.peak_flops, p.hbm_bytes) == ("v5e", 197e12, 16e9)
-    # "v5p" must win over the bare "v5" prefix
     assert costs.device_profile("TPU v5p").peak_flops == 459e12
-    assert costs.device_profile("TPU v5 lite").peak_flops == 197e12
+    # the string a v5e chip reports (chip run, PR 21) has its own row
+    p = costs.device_profile("TPU v5 lite")
+    assert (p.name, p.peak_flops, p.hbm_bw) == ("v5e", 197e12, 819e9)
+    # no "v5" catch-all: an unknown v5 part gets no peaks, not v5e's
+    assert costs.device_profile("TPU v5") is None
     assert costs.device_profile("Threadripper") is None
     assert costs.peak_flops("TPU v4") == 275e12
     assert costs.peak_flops("unknown") is None
+
+
+def test_require_device_profile_errors_on_unknown_device():
+    assert costs.require_device_profile("TPU v5 lite").peak_flops == 197e12
+    for dk in ("cpu", "TPU v5", None):
+        with pytest.raises(LookupError, match="DEVICE_TABLE"):
+            costs.require_device_profile(dk)
 
 
 def test_device_profile_env_overrides(monkeypatch):
@@ -65,8 +75,11 @@ def test_bench_helpers_are_table_backed():
     import bench
     from paddle_tpu.models.bert import bert_tiny
 
-    for dk in ("TPU v6e", "TPU v5p", "TPU v5e", "TPU v4", "nope"):
+    for dk in ("TPU v6e", "TPU v5p", "TPU v5 lite", "TPU v4"):
         assert bench._peak_flops(dk) == costs.peak_flops(dk)
+    # a measurement path errors on a device the table does not know
+    with pytest.raises(LookupError):
+        bench._peak_flops("nope")
     cfg = bert_tiny()
     for seq in (64, 512):
         got = bench._flops_per_token_train(cfg, seq)

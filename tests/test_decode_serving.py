@@ -324,8 +324,7 @@ def test_warmup_zero_compile_restart(m, tmp_path):
     the restarted server never sees XLA."""
     from paddle_tpu.fluid import compile_cache, unique_name
 
-    prev = compile_cache.activate(str(tmp_path / "cc"),
-                                  configure_xla_cache=False)
+    prev = compile_cache.activate(str(tmp_path / "cc"))
     try:
         def build():
             # a fresh process numbers program vars from zero — emulated
@@ -342,7 +341,7 @@ def test_warmup_zero_compile_restart(m, tmp_path):
         second = two.warmup(check_hbm=False)
         two.stop()
     finally:
-        compile_cache.activate(prev, configure_xla_cache=False)
+        compile_cache.activate(prev)
     assert {r["source"] for r in first} <= {"compile", "disk", "memory"}
     assert all(r["source"] != "compile" for r in second), second
     assert len(second) == 2  # step + one prefill bucket

@@ -119,11 +119,9 @@ def test_tp_sharded_matmul_matches_replicated():
 
 def test_collective_allreduce_psum_semantics():
     """lax.psum over shard_map mesh axis sums shard contributions."""
-    from jax.experimental.shard_map import shard_map
-
     mesh = build_mesh({"dp": 8})
     x = np.arange(8, dtype=np.float32)
-    f = shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
+    f = jax.shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
                   in_specs=P("dp"), out_specs=P("dp"))
     out = np.asarray(f(x))
     np.testing.assert_allclose(out, np.full(8, x.sum()))

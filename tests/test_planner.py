@@ -172,7 +172,7 @@ class TestCostModelExtensions:
         assert costs_mod.device_profile("tpu-v5p").name == "v5p"
         assert costs_mod.device_profile("TPU v5p chip").peak_flops \
             == 459e12
-        # bare "v5" (older runtime strings) maps to the v5e row
+        # the string a v5e chip reports maps to the v5e row
         assert costs_mod.device_profile("tpu v5 lite").name == "v5e"
 
     def test_device_table_order_independence(self, monkeypatch):

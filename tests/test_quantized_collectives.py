@@ -24,12 +24,8 @@ def _mesh_dp():
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:     # older jax spells it check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def test_pmean_int8_error_within_bound():
