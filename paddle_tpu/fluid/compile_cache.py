@@ -229,14 +229,17 @@ def _device_fingerprint():
 
 
 def entry_key(program, feed_names, fetch_names, feed_sig, state_sig,
-              platform, kind="step"):
+              platform, kind="step", name=None):
     """The content-addressed disk key for one compiled specialization.
     Raises :class:`Unfingerprintable` when the program can't be hashed
-    stably (caller skips the disk tier)."""
+    stably (caller skips the disk tier). ``name`` is the name the module
+    was compiled under (``Predictor(name=)``): an artifact keeps the
+    name it was exported with, so another name is another entry."""
     h = hashlib.sha256()
     h.update(program_fingerprint(program).encode())
     h.update(repr((kind, platform, list(feed_names), list(fetch_names),
-                   feed_sig, state_sig)).encode())
+                   feed_sig, state_sig) + ((name,) if name else ())
+                  ).encode())
     h.update(_device_fingerprint().encode())
     return h.hexdigest()
 

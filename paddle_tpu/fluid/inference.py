@@ -43,11 +43,14 @@ class Predictor:
     """AOT-compiled predictor over a pruned inference Program."""
 
     def __init__(self, program, feed_names, fetch_vars, scope=None,
-                 place=None, dtype_policy=None):
+                 place=None, dtype_policy=None, name=None):
         import jax
 
         self._jax = jax
         self.program = program
+        # names this predictor's XLA module ``jit_fwd_<name>`` in a device
+        # trace (``jit_fwd`` without one); part of the disk tier's key
+        self.name = name
         self.feed_names = list(feed_names)
         self.fetch_names = [
             v.name if hasattr(v, "name") else v for v in fetch_vars
@@ -211,7 +214,7 @@ class Predictor:
                     disk_key = compile_cache.entry_key(
                         self.program, self.feed_names, self.fetch_names,
                         sig, self._state_sig, self._platform,
-                        kind="predict")
+                        kind="predict", name=self.name)
                 except compile_cache.Unfingerprintable:
                     disk_key = None
                 else:
@@ -224,7 +227,7 @@ class Predictor:
                 obs.event("compile_start", source="predictor", count=False,
                           sig=repr(sig))
                 t0 = time.monotonic()
-                jitted = jax.jit(self._fwd)
+                jitted = jax.jit(self._named_fwd())
                 ex = jitted.lower(self._state, prepared).compile()
                 dt = time.monotonic() - t0
                 self.compile_seconds[sig] = dt
@@ -240,6 +243,19 @@ class Predictor:
             with self._lock:
                 self._compiled[sig] = ex
             return ex, source
+
+    def _named_fwd(self):
+        """The forward function under the name its module should carry:
+        jax names a module after the function it is given."""
+        if not self.name:
+            return self._fwd
+        fwd = self._fwd
+
+        def named(state, feeds):
+            return fwd(state, feeds)
+
+        named.__name__ = named.__qualname__ = "fwd_%s" % self.name
+        return named
 
     def warm(self, feeds):
         """Ensure the executable for this feed signature exists without

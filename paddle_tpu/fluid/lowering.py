@@ -91,8 +91,13 @@ def apply_op(op, env, ctx, var_lookup, op_tag=0):
     ctx.set_op_tag(op_tag)
     ctx.current_env = env  # control-flow ops close over the outer env
     ctx.run_ops = run_ops
+    # the HLO metadata of every device operation then says which op of
+    # which block it came from ("mul/encoder_layer_7_ffn_fc_0.tmp_0")
+    scope = "%s/%s" % (op.type, next(
+        (names[0] for names in op.outputs.values() if names), ""))
     try:
-        outs = fn(ctx, ins, op.attrs)
+        with jax.named_scope(scope):
+            outs = fn(ctx, ins, op.attrs)
     except (OpLoweringError, NotImplementedError):
         raise
     except Exception as e:

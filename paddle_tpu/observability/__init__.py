@@ -8,8 +8,10 @@ One import point for every instrumented layer::
     obs.observe("checkpoint.save_seconds", dt)
     obs.set_gauge("reader.queue_depth", q.qsize())
     obs.event("retry", source="guard", attempt=2)
-    with obs.span("executor.run"):
+    with obs.span("executor.run", program=uid) as sp:
         ...
+        sp.note(rows=n)          # fields the block learns on its way
+    obs.record_span("decode.queue", t_submit, now, request=rid)
 
 Every helper here is gated on the live ``PADDLE_TPU_TELEMETRY`` mode
 (``off`` | ``on`` | ``trace``): with ``off`` each call is a single
@@ -18,10 +20,47 @@ instrumentation stays compiled into the hot paths permanently.
 
 Read side: ``snapshot()`` (nested dict), ``render_prom()`` (Prometheus
 text), single-metric probes ``counter(name)`` / ``gauge(name)`` /
-``histogram(name)``, ``get_recorder().dump_jsonl(path)`` (the event
-ring), and crash dumps written automatically on uncaught exceptions
-(see ``recorder.install_excepthook``). ``reset()`` clears the hub AND
-the ring — tests use it to scope assertions to a scripted session.
+``histogram(name)``, ``spans(name=, since=, until=)`` (the span ring),
+``get_recorder().dump_jsonl(path)`` (the event ring), and crash dumps
+written automatically on uncaught exceptions (see
+``recorder.install_excepthook``). ``reset()`` clears the hub AND both
+rings — tests use it to scope assertions to a scripted session.
+
+Spans (``observability.tracing``) — one primitive, three sinks. Every
+``span`` exit observes ``span.<name>.seconds`` in the hub; appends
+``name, t0, t1`` (``time.monotonic()``), thread name, parent span's
+name and fields to a process-wide ring of the last 65,536 finished
+spans (``spans()`` returns copies; a crash dump carries the tail); and
+closes the ``jax.profiler.TraceAnnotation("paddle_tpu.<name>")`` it
+opened on entry, so under a running profiler session
+(``fluid.profiler`` / ``jax.profiler.start_trace``; open the result in
+xprof or Perfetto) the span is written by the profiler on the
+profiler's clock, beside the device's "XLA Ops" and "XLA Modules"
+lines. ``record_span`` records a span that starts on one thread and
+ends on another (ring + histogram). ``off`` records nothing; with no
+profiler session an annotation is a flag test. Span names on the two
+hot paths:
+
+- ``executor.run`` ⊃ ``executor.feed_convert`` / ``executor.
+  device_compute`` (the enqueue) / ``executor.fetch``.
+- the decode engine's dispatch thread, per loop iteration:
+  ``decode.loop.admit`` ⊃ ``decode.prefill`` (one per request filled;
+  ``decode.adopt`` for a remote handoff) ⊃ ``decode.prefill.sync``;
+  ``decode.step.dispatch`` / ``decode.step.sync`` / ``decode.step.
+  emit`` / ``decode.step.release`` (the step's inputs dropped: device
+  buffers are freed with the GIL released, so the thread waits there
+  behind the stream threads the emit woke); ``decode.loop.idle`` (one
+  per idle stretch). Their exits add to ``DecodeEngine.stats()``:
+  ``admit_seconds`` (self time), ``prefill_seconds_total``,
+  ``prefill_sync_seconds`` (a part of it), ``dispatch_seconds``,
+  ``sync_seconds``, ``emit_seconds``, ``release_seconds``,
+  ``idle_seconds`` — the seven phases sum to the thread's wall time.
+- per request, sharing ``request=<DecodeStream.id>``: ``http.generate``
+  (first body byte to terminating chunk; ``status``, ``tokens``,
+  ``first_byte_s``), ``decode.queue`` (submit to the start of its
+  prefill), ``decode.prefill`` (``slot``, ``bucket``, ``plen``,
+  ``path``), ``decode.stream`` (first emit to retire; ``tokens``,
+  ``reason``). No per-token span.
 
 Well-known executor fast-path metrics (PR 4):
 
@@ -94,14 +133,19 @@ Well-known decode-serving metrics (PR 9, ``serving.decode``):
   total slots after each dispatch iteration (continuous batching keeps
   this near 1.0 under load); ``serving.decode.cache_occupancy.<engine>``
   gauge — filled KV rows / (slots × cache_len).
-- ``serving.decode.prefill_seconds`` / ``step_seconds`` /
-  ``ttft_seconds`` / ``request_seconds`` histograms — the two-program
-  loop's dispatch costs plus time-to-first-token and whole-request
-  latency.
+- ``serving.decode.step_seconds`` histogram — one decode step's
+  latency on the host: ``decode.step.dispatch`` + ``decode.step.sync``,
+  enqueue to tokens on the host (also the measured step time the
+  executable ledger's drift score reads; until PR 25 it stopped at the
+  enqueue). ``serving.decode.prefill_seconds`` — one slot fill from
+  the start of its span to its first token on the host (device wait
+  included). ``ttft_seconds`` — submit to first emit inside the
+  engine; ``request_seconds`` — submit to retire.
 - ``serving.decode.tokens`` / ``requests`` / ``prefills`` / ``steps``
   / ``retired`` / ``shed`` / ``deadline_miss`` / ``cancelled``
   counters — every lifecycle edge ``stats()`` reports, mirrored into
-  the hub; rejects and client disconnects also land in the flight
+  the hub (``tokens`` once per step with the step's count, not once
+  per token); rejects and client disconnects also land in the flight
   recorder with ``engine="decode"``.
 
 Well-known gradient-communication metrics (PR 10, ``parallel/comms``):
@@ -230,8 +274,9 @@ Well-known distributed-tracing + fleet metrics (PR 14,
   ``ttft_slo_ms`` / ``per_token_slo_ms`` target) / budget; 1.0 means
   the error budget is being consumed exactly at the allowed rate.
 - ``span.*.seconds`` histograms gain distributed siblings: spans
-  created with ``ctx=`` still observe locally but also export
-  trace records whose names carry the phase
+  created with ``ctx=`` (or joined later with ``span.adopt(ctx)``)
+  still observe and fill the ring locally but also export, from the
+  same exit, trace records whose names carry the phase
   (``serving.http.request``, ``disagg.queue`` / ``.prefill`` /
   ``.handoff`` / ``.adopt``, ``decode.token``), which the collector
   folds into per-phase breakdowns.
@@ -405,11 +450,14 @@ from .telemetry import (  # noqa: F401
     OFF, ON, TRACE, TELEMETRY_ENV, PROM_STYLE_ENV, Histogram,
     Telemetry, get_telemetry, mode,
 )
-from .tracing import active_spans, current_span, span  # noqa: F401
+from .tracing import (  # noqa: F401
+    active_spans, current_span, record_span, span, spans,
+)
 
 __all__ = [
     "Telemetry", "Histogram", "FlightRecorder", "get_telemetry",
-    "get_recorder", "span", "active_spans", "current_span", "mode",
+    "get_recorder", "span", "spans", "record_span", "active_spans",
+    "current_span", "mode",
     "enabled", "trace_enabled", "inc", "observe", "set_gauge", "event",
     "counter", "gauge", "histogram",
     "snapshot", "render_prom", "reset", "install_excepthook",
@@ -500,10 +548,11 @@ def render_prom(style=None):
 
 
 def reset():
-    """Clear the hub, the global event ring, the executable ledger,
-    and the active run-health bundle (testing / session scoping). Does
-    not uninstall the excepthook."""
+    """Clear the hub, the global event ring, the span ring, the
+    executable ledger, and the active run-health bundle (testing /
+    session scoping). Does not uninstall the excepthook."""
     _telemetry._hub.reset()
     _recorder._global.clear()
+    _tracing.clear_spans()
     _ledger_mod._global.clear()
     _runhealth_mod.reset()

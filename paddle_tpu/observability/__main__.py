@@ -4,7 +4,12 @@
 merges the per-process ``trace-*.jsonl`` span files a traced serving
 run left under ``$PADDLE_TPU_TRACE_DIR`` into one Perfetto-loadable
 Chrome trace-event file (load it at https://ui.perfetto.dev or
-``chrome://tracing``) and prints a per-trace phase summary.
+``chrome://tracing``) and prints a per-trace phase summary. Given a
+JSON file with a ``spans`` key in place of the directory — a crash
+dump, or what ``obs.get_recorder().crash_dump(path)`` writes from a
+live replica — it draws the in-memory span ring the same way: the
+engine loop's phases and every request's spans, one track per thread,
+with no trace directory.
 
 ``python -m paddle_tpu.observability perf <dir|snapshot.json>``
 renders the executable ledger's predicted-vs-XLA-vs-measured drift
@@ -28,8 +33,27 @@ from . import perf as _perf
 from . import runhealth as _rh
 
 
+def _ring_spans(path):
+    """The span ring a crash dump carries under ``spans``, as the
+    records ``chrome_trace`` takes: one track per thread of the process
+    (or per ``proc`` field), the parent span's name among the args."""
+    with open(path, encoding="utf-8") as f:
+        doc = json.load(f)
+    out = []
+    for i, s in enumerate(doc.get("spans") or ()):
+        args = dict(s.get("fields") or {}, parent=s.get("parent"))
+        out.append({
+            "span": i, "name": s["name"], "tid": s.get("thread", "main"),
+            "proc": args.pop("proc", None) or "pid%s" % doc.get("pid"),
+            "t0": s["t0"], "dur": s["t1"] - s["t0"], "args": args})
+    return out
+
+
 def _cmd_trace(args):
-    spans = _dist.read_spans(args.dir)
+    import os
+
+    spans = (_dist.read_spans(args.dir) if os.path.isdir(args.dir)
+             else _ring_spans(args.dir))
     if not spans:
         print("no span records under %s" % args.dir, file=sys.stderr)
         return 1
@@ -38,8 +62,6 @@ def _cmd_trace(args):
     tmp = out + ".tmp"
     with open(tmp, "w", encoding="utf-8") as f:
         json.dump(doc, f)
-    import os
-
     os.replace(tmp, out)
     meta = doc["otherData"]
     print("wrote %s: %d spans, %d cross-process flows, %d process "
@@ -130,8 +152,9 @@ def main(argv=None):
     sub = ap.add_subparsers(dest="cmd", required=True)
     tr = sub.add_parser("trace", help="merge JSONL span files into a "
                         "Chrome trace-event JSON")
-    tr.add_argument("dir", help="trace directory "
-                    "(the run's $PADDLE_TPU_TRACE_DIR)")
+    tr.add_argument("dir", help="trace directory (the run's "
+                    "$PADDLE_TPU_TRACE_DIR), or a crash-dump JSON "
+                    "file whose `spans` key holds the span ring")
     tr.add_argument("-o", "--out", default=None,
                     help="output path (default: trace.json)")
     tr.add_argument("--trace-id", default=None,

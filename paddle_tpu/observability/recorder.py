@@ -33,6 +33,8 @@ __all__ = [
 ]
 
 CRASH_DUMP_ENV = "PADDLE_TPU_CRASH_DUMP"
+# finished spans a crash dump carries: the tail of the span ring
+_CRASH_SPANS = 2048
 
 
 def crash_dump_path(per_pid=False):
@@ -156,8 +158,9 @@ class FlightRecorder:
         return path
 
     def crash_dump(self, path=None, exc=None):
-        """Write the black box: last events + active spans + telemetry
-        snapshot + the executable-ledger tail and compile-cache
+        """Write the black box: last events + active spans + the tail
+        of the span ring (what each thread had just finished) +
+        telemetry snapshot + the executable-ledger tail and compile-cache
         hit/miss counters (what was compiled and resident at death) +
         the active run's StepSeries tail and goodput decomposition
         (convergence state at death), plus the exception when given.
@@ -170,6 +173,7 @@ class FlightRecorder:
             "events": [{k: _san(v) for k, v in ev.items()}
                        for ev in self.tail()],
             "active_spans": _tr.active_spans(),
+            "spans": _tr.spans()[-_CRASH_SPANS:],
             "telemetry": _t.get_telemetry().snapshot(),
         }
         try:

@@ -87,6 +87,7 @@ KV-reuse + speculation hooks (``serving.prefix_pool`` /
   concurrent sessions stop being bounded by live slots.
 """
 import collections
+import itertools
 import queue
 import threading
 import time
@@ -101,6 +102,12 @@ from .engine import DeadlineExceededError, EngineClosedError, ShedError
 
 __all__ = ["DecodeEngine", "DecodeStream", "default_prompt_buckets",
            "kv_slot_bytes"]
+
+
+# every stream of the process gets its own id: the ``request`` field the
+# spans of one request share (http.generate, decode.queue, .prefill,
+# .stream)
+_stream_ids = itertools.count(1)
 
 
 def kv_slot_bytes(cfg, cache_len, kv_dtype="fp32"):
@@ -146,6 +153,7 @@ class DecodeStream:
     trace = None
 
     def __init__(self, prompt_len, max_new, stall_timeout_s=60.0):
+        self.id = next(_stream_ids)
         self.prompt_len = int(prompt_len)
         self.max_new = int(max_new)
         self.stall_timeout_s = float(stall_timeout_s)
@@ -225,7 +233,7 @@ class DecodeStream:
 class _Request:
     __slots__ = ("prompt", "plen", "bucket", "max_new", "eos_id",
                  "deadline", "handle", "handoff", "tenant", "priority",
-                 "trace", "t_wall",
+                 "trace",
                  # KV-reuse routing: "session" id (tiering), "base"
                  # (adopted rows: a KVHandoff on resume, a pool entry
                  # on a prefix hit), "start" adopted row count,
@@ -237,7 +245,7 @@ class _Request:
 
 class _Slot:
     __slots__ = ("handle", "remaining", "eos_id", "t_prefill",
-                 "trace", "t_wall", "t_last", "session", "hist")
+                 "trace", "t_last", "session", "hist")
 
     def __init__(self, handle, remaining, eos_id, trace=None,
                  session=None, hist=None):
@@ -248,7 +256,6 @@ class _Slot:
         # sampled TraceContext of the span that filled this slot; the
         # per-token spans and the retire summary parent to it
         self.trace = trace
-        self.t_wall = time.time() if trace is not None else None
         self.t_last = self.t_prefill
         # tiering: session id to hibernate under at retire, plus the
         # token history whose rows the slot held at admission (the
@@ -404,29 +411,35 @@ class DecodeEngine:
                                    snapshot=True)
         self._params = persist
         self._step_vars = step_vars
+        # each program has a module name of its own in a device trace
+        # (jit_fwd_decode_step, jit_fwd_prefill_<bucket>, ...); every
+        # name keeps "fwd"
         self._step_pred = Predictor(
             step_prog, step_vars["feed_names"], step_vars["fetch_vars"],
-            scope=persist)
+            scope=persist, name="decode_step")
         self._step_pred.ledger_tag = "decode.step:%s" % self.name
         self._prefill_preds = {}
         self._prefill_vars = {}
         for b, (prog, pv) in prefill.items():
             self._prefill_preds[b] = Predictor(
-                prog, pv["feed_names"], pv["fetch_vars"], scope=persist)
+                prog, pv["feed_names"], pv["fetch_vars"], scope=persist,
+                name="prefill_%d" % b)
             self._prefill_preds[b].ledger_tag = (
                 "decode.prefill:%s" % self.name)
             self._prefill_vars[b] = pv
         self._delta_preds = {}
         for b, (prog, dv) in delta.items():
             self._delta_preds[b] = Predictor(
-                prog, dv["feed_names"], dv["fetch_vars"], scope=persist)
+                prog, dv["feed_names"], dv["fetch_vars"], scope=persist,
+                name="delta_%d" % b)
             self._delta_preds[b].ledger_tag = (
                 "decode.delta_prefill:%s" % self.name)
         self._verify_pred = None
         if verify is not None:
             prog, vv = verify
             self._verify_pred = Predictor(
-                prog, vv["feed_names"], vv["fetch_vars"], scope=persist)
+                prog, vv["feed_names"], vv["fetch_vars"], scope=persist,
+                name="verify_block")
             self._verify_pred.ledger_tag = "decode.verify:%s" % self.name
 
         # -- the persistent slot buffer pair + host-side slot state ----
@@ -457,6 +470,16 @@ class DecodeEngine:
         self._admit_lock = _conc.named_lock("serving.decode.admit")
         self._stats_lock = _conc.named_lock("serving.decode.stats")
         self._stats = collections.Counter()
+        # where the dispatch thread's time went, in seconds, added from
+        # the exits of its phase spans (one writer; stats() copies).
+        # admit + prefill_total + dispatch + sync + emit + release + idle
+        # is the loop's wall time; prefill_sync is the part of
+        # prefill_total spent waiting for the device
+        self._phase_s = dict.fromkeys(
+            ("admit_seconds", "prefill_seconds_total",
+             "prefill_sync_seconds", "dispatch_seconds", "sync_seconds",
+             "emit_seconds", "release_seconds", "idle_seconds"), 0.0)
+        self._proc = "decode:%s" % self.name  # track of its trace spans
         self._rate = collections.deque(maxlen=64)  # (t_done, 1) retires
         self._thread = None
         self._owner = _conc.owner_token("decode-engine", self.name, self)
@@ -683,7 +706,6 @@ class DecodeEngine:
                         if deadline_ms is not None else None)
         sampled = trace_ctx is not None and trace_ctx.sampled
         req.trace = trace_ctx if sampled else None
-        req.t_wall = time.time() if sampled else None
         req.handle = DecodeStream(
             plen, max_new, stall_timeout_s=self.request_timeout_s)
         req.handle.tenant = tenant
@@ -778,7 +800,6 @@ class DecodeEngine:
             trace_ctx = getattr(handoff, "trace", None)
         sampled = trace_ctx is not None and trace_ctx.sampled
         req.trace = trace_ctx if sampled else None
-        req.t_wall = time.time() if sampled else None
         req.handle = DecodeStream(
             plen, max_new, stall_timeout_s=self.request_timeout_s)
         req.handle.tenant = tenant
@@ -956,9 +977,15 @@ class DecodeEngine:
 
     # -- dispatch loop ---------------------------------------------------
     def _loop(self):
+        phase = self._phase_s
         while True:
-            self._sweep_cancelled()
-            self._admit()
+            filled = phase["prefill_seconds_total"]
+            with obs.span("decode.loop.admit") as sp:
+                self._sweep_cancelled()
+                self._admit()
+            # self time: the slot fills it ran have their own total
+            phase["admit_seconds"] += sp.seconds - (
+                phase["prefill_seconds_total"] - filled)
             live = sum(1 for s in self._slots if s is not None)
             if self._abort:
                 self._fail_all()
@@ -966,14 +993,25 @@ class DecodeEngine:
             if live == 0:
                 if self._stop_event.is_set() and self._q.empty():
                     return
-                if _conc._on:
-                    _conc.note_blocking("time.sleep(idle)")
-                time.sleep(0.002)
+                self._idle()
                 continue
             if self._draft is not None:
                 self._spec_step()
             else:
                 self._step()
+
+    def _idle(self):
+        """No slot is live: poll until a request is queued (or the engine
+        stops). One span per idle stretch, so an idle engine does not
+        fill the span ring; with no live slot and an empty queue a sweep
+        and an admission have nothing to do."""
+        if _conc._on:
+            _conc.note_blocking("time.sleep(idle)")
+        with obs.span("decode.loop.idle") as sp:
+            while (self._q.empty() and not self._abort
+                   and not self._stop_event.is_set()):
+                time.sleep(0.002)
+        self._phase_s["idle_seconds"] += sp.seconds
 
     def _fail_all(self):
         while True:
@@ -1035,24 +1073,51 @@ class DecodeEngine:
                       self._q.qsize())
 
     def _fill_slot(self, slot, req):
-        """Route one admitted request onto its cheapest fill path:
-        remote handoff adopt, session-resume delta, prefix-pool
-        full-hit adopt, prefix-pool delta, or cold prefill."""
+        """Route one admitted request onto its cheapest fill path (remote
+        handoff adopt, session-resume delta, prefix-pool full-hit adopt,
+        prefix-pool delta, or cold prefill) and run it inside the
+        request's fill span. The finished ``decode.queue`` wait
+        (``t_submit`` to now) is recorded where the request leaves the
+        queue; a sampled request's spans also go to its JSONL trace,
+        from the same exits."""
+        name, ctx, bucket = "decode.prefill", None, None
         if req.handoff is not None:
-            return self._adopt(slot, req)
-        if req.base is not None:  # session resume (handoff from tier)
-            return self._delta_prefill(slot, req)
-        if self._prefix_pool is not None:
-            entry = self._prefix_pool.lookup(req.prompt)
+            # the adopt span parents to the PREFILL side's span when the
+            # handoff carries one — that's the cross-process flow arrow
+            fill, path = self._adopt, "handoff"
+            name, ctx = "decode.adopt", getattr(req.handoff, "trace", None)
+        elif req.base is not None:  # session resume (handoff from tier)
+            fill, path, bucket = self._delta_prefill, "resume", req.sbucket
+        else:
+            fill, path, bucket = self._prefill, "cold", req.bucket
+            entry = (self._prefix_pool.lookup(req.prompt)
+                     if self._prefix_pool is not None else None)
             if entry is not None and self._entry_fits(entry, req):
                 req.base = entry
                 req.start = entry.plen
                 if entry.plen == req.plen:
-                    return self._adopt_prefix(slot, req)
-                req.suffix = req.prompt[entry.plen:]
-                req.sbucket = self._bucket_for(len(req.suffix))
-                return self._delta_prefill(slot, req)
-        return self._prefill(slot, req)
+                    fill, path, bucket = self._adopt_prefix, "pool", None
+                else:
+                    req.suffix = req.prompt[entry.plen:]
+                    req.sbucket = self._bucket_for(len(req.suffix))
+                    fill, path, bucket = (self._delta_prefill, "delta",
+                                          req.sbucket)
+        qctx = obs.record_span(
+            "decode.queue", req.handle.t_submit, time.monotonic(),
+            ctx=req.trace, proc=self._proc, request=req.handle.id,
+            tenant=req.tenant)
+        if ctx is None or not ctx.sampled:
+            ctx = qctx
+        fields = {}
+        if ctx is not None and path == "cold":
+            # cost-model annotation of a sampled request's trace only: an
+            # unsampled request never runs the analyzer
+            fields["predicted_s"] = self._predicted_s("prefill", bucket)
+        with obs.span(name, ctx=ctx, proc=self._proc,
+                      request=req.handle.id, slot=slot, bucket=bucket,
+                      plen=req.plen, path=path, **fields) as sp:
+            fill(slot, req, sp)
+        self._phase_s["prefill_seconds_total"] += sp.seconds
 
     def _entry_fits(self, entry, req):
         """A pool entry is adoptable when its geometry matches this
@@ -1081,28 +1146,32 @@ class DecodeEngine:
             self._kscale = self._write(self._kscale, ks, slot_i)
             self._vscale = self._write(self._vscale, vs, slot_i)
 
-    def _trace_queue_span(self, req, now):
-        """Export the (already finished) queue-wait span for a traced
-        request; returns the context its work span should parent to."""
-        ctx = req.trace.child()
-        obs.export_span(
-            "decode.queue", ctx, req.t_wall,
-            now - req.handle.t_submit,
-            {"proc": "decode:%s" % self.name, "tenant": req.tenant})
-        return ctx
+    def _first_token(self, nxt):
+        """Wait on the host for the token a fill program produced."""
+        with obs.span("decode.prefill.sync") as sp:
+            tok = int(np.asarray(nxt)[0, 0])
+        self._phase_s["prefill_sync_seconds"] += sp.seconds
+        return tok
 
-    def _prefill(self, slot, req):
-        t0 = time.monotonic()
-        ctx = (self._trace_queue_span(req, t0)
-               if req.trace is not None else None)
-        sp = None
-        if ctx is not None:
-            sp = obs.span("decode.prefill", ctx=ctx,
-                          proc="decode:%s" % self.name, slot=slot,
-                          bucket=req.bucket, plen=req.plen,
-                          predicted_s=self._predicted_s(
-                              "prefill", req.bucket))
-            sp.__enter__()
+    def _seat(self, slot, req, sp, tok, pos):
+        """The request takes the slot: host-side slot state, and its
+        first token to the stream."""
+        self._tok[slot, 0] = tok
+        self._pos[slot, 0] = pos
+        self._slots[slot] = _Slot(req.handle, req.max_new, req.eos_id,
+                                  trace=sp.ctx, session=req.session,
+                                  hist=req.hist)
+        self._draft_fill(slot, req.hist)
+        self._bump("tokens")
+        self._emit(slot, tok)
+        self._gauges()
+
+    def _observe_prefill(self, req, sp):
+        obs.observe("serving.decode.prefill_seconds", sp.elapsed())
+        obs.observe("serving.decode.ttft_seconds",
+                    time.monotonic() - req.handle.t_submit)
+
+    def _prefill(self, slot, req, sp):
         ids = np.zeros((1, req.bucket), np.int64)
         ids[0, :req.plen] = req.prompt
         plen = np.asarray([[req.plen]], np.int64)
@@ -1113,8 +1182,7 @@ class DecodeEngine:
                 {"gpt_prefill_ids": ids, "gpt_prefill_len": plen},
                 return_numpy=False)
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
-            if sp is not None:
-                sp.__exit__(type(e), e, None)
+            sp.note(error=type(e).__name__)
             self._bump("prefill_errors")
             obs.event("prefill_error", source="serving", model=self.name,
                       error="%s: %s" % (type(e).__name__, str(e)[:200]))
@@ -1131,14 +1199,7 @@ class DecodeEngine:
                                    ks[None], vs[None])
         else:
             self._write_slot_cache(slot, k1, v1)
-        if sp is not None:
-            sp.__exit__(None, None, None)
-        self._tok[slot, 0] = tok = int(np.asarray(nxt)[0, 0])
-        self._pos[slot, 0] = req.plen
-        self._slots[slot] = _Slot(req.handle, req.max_new, req.eos_id,
-                                  trace=sp.ctx if sp is not None
-                                  else None, session=req.session,
-                                  hist=req.hist)
+        tok = self._first_token(nxt)
         self._bump("prefill_rows_computed", req.bucket)
         if self._prefix_pool is not None:
             # bank this prompt's rows (fp32, pre-residency) so the
@@ -1148,23 +1209,15 @@ class DecodeEngine:
                                       np.asarray(v1), next_token=tok)
             except Exception:  # noqa: BLE001 — caching is best-effort
                 self._bump("prefix_insert_errors")
-        self._draft_fill(slot, req.hist)
-        now = time.monotonic()
-        obs.observe("serving.decode.prefill_seconds", now - t0)
-        obs.observe("serving.decode.ttft_seconds",
-                    now - req.handle.t_submit)
+        self._observe_prefill(req, sp)
         self._bump("prefills")
-        self._emit(slot, tok)
-        self._gauges()
+        self._seat(slot, req, sp, tok, req.plen)
 
-    def _adopt_prefix(self, slot, req):
+    def _adopt_prefix(self, slot, req, sp):
         """FULL prefix-pool hit: the pool holds rows for the whole
         prompt AND the greedy token after it — adopt and emit with no
         program dispatch at all (zero prefill FLOPs)."""
-        t0 = time.monotonic()
         entry = req.base
-        if req.trace is not None:
-            self._trace_queue_span(req, t0)
         kd, vd = entry.dense()
         if self.kv_dtype == "int8":
             from .disagg import kv_wire
@@ -1179,31 +1232,19 @@ class DecodeEngine:
                                    ks[None], vs[None])
         else:
             self._write_slot_cache(slot, kd[None], vd[None])
-        self._tok[slot, 0] = tok = int(entry.next_token)
-        self._pos[slot, 0] = req.plen
-        self._slots[slot] = _Slot(req.handle, req.max_new, req.eos_id,
-                                  session=req.session, hist=req.hist)
         self._bump("prefix_full_hits")
         self._bump("prefill_rows_saved", entry.plen)
-        self._draft_fill(slot, req.hist)
-        now = time.monotonic()
-        obs.observe("serving.decode.prefill_seconds", now - t0)
-        obs.observe("serving.decode.ttft_seconds",
-                    now - req.handle.t_submit)
-        self._emit(slot, tok)
-        self._gauges()
+        self._observe_prefill(req, sp)
+        self._seat(slot, req, sp, int(entry.next_token), req.plen)
 
-    def _delta_prefill(self, slot, req):
+    def _delta_prefill(self, slot, req, sp):
         """Adopt ``req.start`` base rows (a prefix-pool entry or a
         hibernated session's handoff) and run the delta-prefill program
         over only the suffix — prefill FLOPs proportional to the
         unshared tail. The base rows feed the program in fp32; int8-
         resident engines requantize the returned cache, which is
         bit-stable on untouched rows (idempotent codec)."""
-        t0 = time.monotonic()
         base = req.base
-        if req.trace is not None:
-            self._trace_queue_span(req, t0)
         suffix = np.asarray(req.suffix, np.int64).reshape(-1)
         slen = int(suffix.size)
         ids = np.zeros((1, req.sbucket), np.int64)
@@ -1226,6 +1267,7 @@ class DecodeEngine:
                  "gpt_dpre_k": kd[None], "gpt_dpre_v": vd[None]},
                 return_numpy=False)
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
+            sp.note(error=type(e).__name__)
             self._bump("delta_errors")
             obs.event("delta_error", source="serving", model=self.name,
                       error="%s: %s" % (type(e).__name__, str(e)[:200]))
@@ -1240,10 +1282,7 @@ class DecodeEngine:
                                    ks[None], vs[None])
         else:
             self._write_slot_cache(slot, k1, v1)
-        self._tok[slot, 0] = tok = int(np.asarray(nxt)[0, 0])
-        self._pos[slot, 0] = req.start + slen
-        self._slots[slot] = _Slot(req.handle, req.max_new, req.eos_id,
-                                  session=req.session, hist=req.hist)
+        tok = self._first_token(nxt)
         self._bump("delta_prefills")
         self._bump("prefill_rows_computed", req.sbucket)
         self._bump("prefill_rows_saved", req.start)
@@ -1255,13 +1294,8 @@ class DecodeEngine:
                                       np.asarray(v1), next_token=tok)
             except Exception:  # noqa: BLE001 — caching is best-effort
                 self._bump("prefix_insert_errors")
-        self._draft_fill(slot, req.hist)
-        now = time.monotonic()
-        obs.observe("serving.decode.prefill_seconds", now - t0)
-        obs.observe("serving.decode.ttft_seconds",
-                    now - req.handle.t_submit)
-        self._emit(slot, tok)
-        self._gauges()
+        self._observe_prefill(req, sp)
+        self._seat(slot, req, sp, tok, req.start + slen)
 
     def _draft_fill(self, slot, hist):
         """Mirror a freshly filled slot into the draft's cache (the
@@ -1279,26 +1313,14 @@ class DecodeEngine:
                       model=self.name,
                       error="%s: %s" % (type(e).__name__, str(e)[:200]))
 
-    def _adopt(self, slot, req):
+    def _adopt(self, slot, req, sp):
         """Install a remote prefill's :class:`KVHandoff` into a slot —
         the decode half of the disaggregated handoff. An int8 handoff
         whose block is the hidden width drops payload+scales straight
         into an int8-resident engine (no requantize); every other
         combination goes through fp32."""
-        t0 = time.monotonic()
         h = req.handoff
-        if req.trace is not None:
-            self._trace_queue_span(req, t0)
-        # the adopt span parents to the PREFILL side's span when the
-        # handoff carries one — that's the cross-process flow arrow
-        actx = getattr(h, "trace", None) or req.trace
-        sp = None
-        if actx is not None and actx.sampled:
-            sp = obs.span("decode.adopt", ctx=actx,
-                          proc="decode:%s" % self.name, slot=slot,
-                          plen=req.plen, wire_dtype=h.wire_dtype,
-                          wire_bytes=h.wire_bytes())
-            sp.__enter__()
+        sp.note(wire_dtype=h.wire_dtype, wire_bytes=h.wire_bytes())
         try:
             # digest check FIRST: a corrupted handoff must fail the
             # inner stream here (the router's migration path then
@@ -1323,8 +1345,7 @@ class DecodeEngine:
                 kd, vd = h.dense()
                 self._write_slot_cache(slot, kd[None], vd[None])
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
-            if sp is not None:
-                sp.__exit__(type(e), e, None)
+            sp.note(error=type(e).__name__)
             self._bump("adopt_errors")
             from ..integrity.digest import IntegrityError
             if isinstance(e, IntegrityError):
@@ -1337,20 +1358,9 @@ class DecodeEngine:
                       error="%s: %s" % (type(e).__name__, str(e)[:200]))
             req.handle._fail(e)
             return
-        if sp is not None:
-            sp.__exit__(None, None, None)
-        self._tok[slot, 0] = tok = int(h.next_token)
-        self._pos[slot, 0] = req.plen
-        self._slots[slot] = _Slot(req.handle, req.max_new, req.eos_id,
-                                  trace=sp.ctx if sp is not None
-                                  else None, session=req.session,
-                                  hist=req.hist)
-        self._draft_fill(slot, req.hist)
-        obs.observe("serving.disagg.adopt_seconds",
-                    time.monotonic() - t0)
+        obs.observe("serving.disagg.adopt_seconds", sp.elapsed())
         self._bump("adopts")
-        self._emit(slot, tok)
-        self._gauges()
+        self._seat(slot, req, sp, int(h.next_token), req.plen)
 
     def _emit(self, slot, tok):
         """Deliver one generated token to a slot's stream; retires the
@@ -1358,8 +1368,6 @@ class DecodeEngine:
         s = self._slots[slot]
         s.handle._emit(tok)
         s.remaining -= 1
-        self._bump("tokens")
-        obs.inc("serving.decode.tokens")
         if s.trace is not None:
             # one tiny span per generated token on a SAMPLED request:
             # dur is the inter-token gap (the per-token-p99 SLO leg)
@@ -1368,7 +1376,7 @@ class DecodeEngine:
             s.t_last = now
             obs.export_span(
                 "decode.token", s.trace.child(), time.time() - gap, gap,
-                {"proc": "decode:%s" % self.name, "slot": slot,
+                {"proc": self._proc, "slot": slot,
                  "index": len(s.handle._tokens),
                  "predicted_s": self._predicted_s("step")})
         if s.eos_id is not None and tok == s.eos_id:
@@ -1401,17 +1409,12 @@ class DecodeEngine:
         now = time.monotonic()
         obs.observe("serving.decode.request_seconds",
                     now - s.handle.t_submit)
-        if s.trace is not None:
-            obs.export_span(
-                "decode.stream", s.trace.child(), s.t_wall,
-                now - s.t_prefill,
-                {"proc": "decode:%s" % self.name, "slot": slot,
-                 "reason": reason, "tokens": len(s.handle._tokens)})
+        obs.record_span(
+            "decode.stream", s.t_prefill, now, ctx=s.trace,
+            proc=self._proc, request=s.handle.id, slot=slot,
+            reason=reason, tokens=len(s.handle._tokens))
         with self._stats_lock:
             self._rate.append((now, 1))
-        obs.event("slot_retired", source="serving", count=False,
-                  model=self.name, slot=slot, reason=reason,
-                  tokens=len(s.handle._tokens))
 
     def _hibernate(self, slot, s):
         """Encode a retiring session slot's live KV rows into the
@@ -1457,35 +1460,51 @@ class DecodeEngine:
             feeds["gpt_step_vscale"] = self._vscale
         return feeds
 
+    def _fail_live(self, error):
+        for i, s in enumerate(self._slots):
+            if s is not None:
+                self._retire(i, "error", error=error)
+
     def _step(self):
+        phase = self._phase_s
         t0 = time.monotonic()
-        # the feed dict is captured BEFORE dispatch: the run reassigns
-        # self._k/_v (and _tok/_pos mutate only at emission, below), so
-        # these references are exactly the step's inputs — what the SDC
-        # sentinel re-dispatches on a sampled replay
-        feeds = self._step_feeds()
+        sp = obs.span("decode.step.dispatch")
         try:
-            # chaos site: a 'slow' clause stalls the step in place (it
-            # shows up in step_seconds + the ledger, the autopilot
-            # drill's seeded degradation); an exception clause flows to
-            # the step_error path below like a real device fault
-            R.fault_check("dispatch")
-            if _conc._on:
-                _conc.note_blocking("device.dispatch")
-            outs = self._step_pred.run(feeds, return_numpy=False)
-            if self.kv_dtype == "int8":
-                (nxt, self._k, self._v, self._kscale,
-                 self._vscale) = outs
-            else:
-                nxt, self._k, self._v = outs
+            with sp:
+                # the feed dict is captured BEFORE dispatch: the run
+                # reassigns self._k/_v (and _tok/_pos mutate only at
+                # emission, below), so these references are exactly the
+                # step's inputs — what the SDC sentinel re-dispatches on
+                # a sampled replay
+                feeds = self._step_feeds()
+                # chaos site: a 'slow' clause stalls the step in place
+                # (it shows up in step_seconds + the ledger, the
+                # autopilot drill's seeded degradation); an exception
+                # clause flows to the step_error path below like a real
+                # device fault
+                R.fault_check("dispatch")
+                if _conc._on:
+                    _conc.note_blocking("device.dispatch")
+                outs = self._step_pred.run(feeds, return_numpy=False)
+                if self.kv_dtype == "int8":
+                    (nxt, self._k, self._v, self._kscale,
+                     self._vscale) = outs
+                else:
+                    nxt, self._k, self._v = outs
         except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
             self._bump("step_errors")
             obs.event("step_error", source="serving", model=self.name,
                       error="%s: %s" % (type(e).__name__, str(e)[:200]))
-            for i, s in enumerate(self._slots):
-                if s is not None:
-                    self._retire(i, "error", error=e)
+            self._fail_live(e)
             return
+        finally:
+            phase["dispatch_seconds"] += sp.seconds
+        with obs.span("decode.step.sync") as sp:
+            nxt_np = np.asarray(nxt)
+        phase["sync_seconds"] += sp.seconds
+        # the step's latency on the host: enqueue + the wait for its
+        # tokens (what the ledger's drift score and the autopilot's
+        # calibration read as the measured step time)
         dt = time.monotonic() - t0
         obs.observe("serving.decode.step_seconds", dt)
         self._note_step_measured(dt)
@@ -1504,23 +1523,31 @@ class DecodeEngine:
                 # cross-replica vote adjudicates this one
                 from ..integrity.digest import IntegrityError
                 self._bump("sdc_disagree")
-                err = IntegrityError(
+                self._fail_live(IntegrityError(
                     "SDC replay disagreement on decode replica %r — "
                     "withholding this step's tokens"
-                    % (self._sentinel_id,))
-                for i, s in enumerate(self._slots):
-                    if s is not None:
-                        self._retire(i, "error", error=err)
+                    % (self._sentinel_id,)))
                 return
-        nxt_np = np.asarray(nxt)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            tok = int(nxt_np[i, 0])
-            self._pos[i, 0] += 1
-            self._tok[i, 0] = tok
-            self._emit(i, tok)
-        self._gauges()
+        with obs.span("decode.step.emit") as sp:
+            n = 0
+            for i, s in enumerate(self._slots):
+                if s is None:
+                    continue
+                tok = int(nxt_np[i, 0])
+                self._pos[i, 0] += 1
+                self._tok[i, 0] = tok
+                self._emit(i, tok)
+                n += 1
+            self._bump("tokens", n)
+            self._gauges()
+        phase["emit_seconds"] += sp.seconds
+        # the step's inputs die here, and with them the last reference to
+        # the previous cache buffers. jaxlib frees device buffers with the
+        # GIL released, so every stream thread the emit just woke runs
+        # before this thread has the GIL back: a phase of its own
+        with obs.span("decode.step.release") as sp:
+            del feeds, outs, nxt, nxt_np
+        phase["release_seconds"] += sp.seconds
 
     def _spec_step(self):
         """One speculative iteration: ``k`` draft proposals per slot,
@@ -1544,52 +1571,53 @@ class DecodeEngine:
                 self._bump("draft_step_errors")
             self._step()
             return
-        t0 = time.monotonic()
-        try:
-            proposals = self._draft.propose(self._tok, self._pos)
-        except Exception as e:  # noqa: BLE001 — draft down ≠ engine down
+        phase = self._phase_s
+        with obs.span("decode.step.dispatch", spec=True) as sp:
+            failed = None
+            try:
+                proposals = self._draft.propose(self._tok, self._pos)
+            except Exception as e:  # noqa: BLE001 — draft down ≠ engine down
+                proposals, failed = None, e
+            if proposals is not None:
+                feeds = {"gpt_vrf_tok": np.concatenate(
+                             [self._tok, proposals], axis=1),
+                         "gpt_vrf_pos": self._pos,
+                         "gpt_vrf_k": self._k, "gpt_vrf_v": self._v}
+                try:
+                    R.fault_check("dispatch")
+                    if _conc._on:
+                        _conc.note_blocking("device.dispatch")
+                    y, self._k, self._v = self._verify_pred.run(
+                        feeds, return_numpy=False)
+                except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
+                    failed = e
+        phase["dispatch_seconds"] += sp.seconds
+        if proposals is None:
             self._bump("draft_step_errors")
             obs.event("draft_step_error", source="serving",
                       model=self.name,
-                      error="%s: %s" % (type(e).__name__, str(e)[:200]))
+                      error="%s: %s" % (type(failed).__name__,
+                                        str(failed)[:200]))
             self._step()
             return
-        feeds = {"gpt_vrf_tok": np.concatenate(
-                     [self._tok, proposals], axis=1),
-                 "gpt_vrf_pos": self._pos,
-                 "gpt_vrf_k": self._k, "gpt_vrf_v": self._v}
-        try:
-            R.fault_check("dispatch")
-            if _conc._on:
-                _conc.note_blocking("device.dispatch")
-            y, self._k, self._v = self._verify_pred.run(
-                feeds, return_numpy=False)
-        except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
+        if failed is not None:
             self._bump("step_errors")
             obs.event("step_error", source="serving", model=self.name,
-                      error="%s: %s" % (type(e).__name__, str(e)[:200]))
-            for i, s in enumerate(self._slots):
-                if s is not None:
-                    self._retire(i, "error", error=e)
+                      error="%s: %s" % (type(failed).__name__,
+                                        str(failed)[:200]))
+            self._fail_live(failed)
             return
-        dt = time.monotonic() - t0
-        obs.observe("serving.spec.round_seconds", dt)
-        y = np.asarray(y)                                 # (S, k+1)
-        accepted = 0
-        for i in live:
-            # longest prefix of the draft's proposals matching the
-            # target's picks; emit those + the correction/bonus token
-            m = 0
-            while m < k and proposals[i, m] == y[i, m]:
-                m += 1
-            accepted += m
-            for j in range(m + 1):
-                if self._slots[i] is None:
-                    break  # EOS/length retired the slot mid-block
-                tok = int(y[i, j])
-                self._pos[i, 0] += 1
-                self._tok[i, 0] = tok
-                self._emit(i, tok)
+        with obs.span("decode.step.sync", spec=True) as sp_sync:
+            y = np.asarray(y)                             # (S, k+1)
+        phase["sync_seconds"] += sp_sync.seconds
+        obs.observe("serving.spec.round_seconds",
+                    sp.seconds + sp_sync.seconds)
+        with obs.span("decode.step.emit", spec=True) as sp:
+            accepted = self._emit_block(live, k, proposals, y)
+        phase["emit_seconds"] += sp.seconds
+        with obs.span("decode.step.release", spec=True) as sp:
+            del feeds  # the previous cache buffers, as in _step
+        phase["release_seconds"] += sp.seconds
         self._bump("spec_rounds")
         self._bump("spec_proposed", k * len(live))
         self._bump("spec_accepted", accepted)
@@ -1601,7 +1629,28 @@ class DecodeEngine:
             obs.set_gauge("serving.spec.accept_rate", rate)
             obs.set_gauge("serving.spec.accept_rate.%s" % self.name,
                           rate)
+
+    def _emit_block(self, live, k, proposals, y):
+        """Emit, per live slot, the longest prefix of the draft's
+        proposals matching the target's picks plus the correction/bonus
+        token; returns the number of accepted proposals."""
+        accepted = n = 0
+        for i in live:
+            m = 0
+            while m < k and proposals[i, m] == y[i, m]:
+                m += 1
+            accepted += m
+            for j in range(m + 1):
+                if self._slots[i] is None:
+                    break  # EOS/length retired the slot mid-block
+                tok = int(y[i, j])
+                self._pos[i, 0] += 1
+                self._tok[i, 0] = tok
+                self._emit(i, tok)
+                n += 1
+        self._bump("tokens", n)
         self._gauges()
+        return accepted
 
     def _note_step_measured(self, dt):
         """Feed the measured step time into the executable ledger
@@ -1676,17 +1725,22 @@ class DecodeEngine:
         with self._stats_lock:
             self._stats[key] += n
         # mirror every lifecycle counter into the hub so /metrics sees
-        # the same numbers stats() reports ("tokens" incs at its own
-        # site to keep the hot emit path one call)
-        if key != "tokens":
-            obs.inc("serving.decode.%s" % key, n)
+        # the same numbers stats() reports
+        obs.inc("serving.decode.%s" % key, n)
 
     def stats(self):
         """Local lifetime counters: requests/tokens/prefills/steps/
         retired/shed/deadline_miss/cancelled/prefill_errors/
-        step_errors."""
+        step_errors; and where the dispatch thread's time went, in
+        seconds: ``admit_seconds`` (self time), ``prefill_seconds_total``
+        (of it ``prefill_sync_seconds`` waiting for the device),
+        ``dispatch_seconds``, ``sync_seconds``, ``emit_seconds``,
+        ``release_seconds`` (the step's inputs dropped; the thread waits
+        for the GIL behind the streams it woke), ``idle_seconds`` — the
+        seven sum to the thread's wall time."""
         with self._stats_lock:
             out = dict(self._stats)
+        out.update(self._phase_s)
         for k in ("requests", "tokens", "prefills", "adopts", "steps",
                   "retired", "shed", "deadline_miss", "cancelled",
                   "prefill_errors", "adopt_errors", "step_errors",

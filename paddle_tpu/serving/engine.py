@@ -87,6 +87,8 @@ class ServingEngine:
         # attribute this engine's executables in the ledger/perf CLI
         try:
             predictor.ledger_tag = "serving:%s" % self.name
+            if getattr(predictor, "name", "") is None:
+                predictor.name = "predict"  # module jit_fwd_predict
         except Exception:  # noqa: BLE001 — duck-typed predictors in tests
             pass
         self.replica_id = replica_id

@@ -250,6 +250,20 @@ class ServingHandler(BaseHTTPRequestHandler):
         if _kind_of(engine) != "decode":
             return self._send_json(
                 400, _wrong_kind_doc(name, engine, "decode"))
+        # one span per request, from the first byte of its body to the
+        # terminating chunk; with decode.queue / .prefill / .stream of
+        # the same ``request`` id it holds everything a client's time to
+        # first token is made of
+        with obs.span("http.generate", proc="http", model=name) as sp:
+            sp.note(status=self._generate(name, engine, sp))
+
+    def _reply(self, code, doc, headers=None):
+        self._send_json(code, doc, headers)
+        return code
+
+    def _generate(self, name, engine, sp):
+        """Serve one ``:generate`` request inside its span `sp`; returns
+        the HTTP status it answered with."""
         try:
             n = int(self.headers.get("Content-Length") or 0)
             body = json.loads(self.rfile.read(n) or b"{}")
@@ -271,34 +285,32 @@ class ServingHandler(BaseHTTPRequestHandler):
             timeout_s = body.get("timeout_s")
             stream = bool(body.get("stream", True))
         except (ValueError, KeyError, TypeError) as e:
-            return self._send_json(
+            return self._reply(
                 400, {"error": "bad request: %s: %s"
                                % (type(e).__name__, e)})
-        tctx = self._trace_ctx(body)
-        t_req = time.time() if tctx is not None else None
+        # a sampled request's span joins its distributed trace here (the
+        # body may be what asks for it); the engine's spans parent to it
+        tctx = sp.adopt(self._trace_ctx(body))
         if tctx is not None:
             kw["trace_ctx"] = tctx
         try:
             handle = engine.submit(prompt, **kw)
         except (ValueError, TypeError) as e:
-            return self._send_json(
+            return self._reply(
                 400, {"error": "bad request: %s: %s"
                                % (type(e).__name__, e)})
         except Exception as e:  # noqa: BLE001 — admission errors -> statuses
-            return self._send_json(*self._generate_errdoc(e, name, engine))
+            return self._reply(*self._generate_errdoc(e, name, engine))
+        sp.note(request=getattr(handle, "id", None))
 
         if not stream:
             try:
                 toks = handle.result(timeout_s)
             except Exception as e:  # noqa: BLE001
-                return self._send_json(
+                return self._reply(
                     *self._generate_errdoc(e, name, engine))
-            if tctx is not None:
-                obs.export_span(
-                    "http.generate", tctx, t_req, time.time() - t_req,
-                    {"proc": "http", "model": name,
-                     "tokens": len(toks)})
-            return self._send_json(200, {
+            sp.note(tokens=len(toks))
+            return self._reply(200, {
                 "tokens": toks, "n_tokens": len(toks),
                 "finish_reason": handle.finish_reason, "model": name,
                 "trace_id": tctx.trace_id if tctx is not None
@@ -312,7 +324,7 @@ class ServingHandler(BaseHTTPRequestHandler):
             first = next(gen, None)
         except Exception as e:  # noqa: BLE001
             handle.cancel()
-            return self._send_json(*self._generate_errdoc(e, name, engine))
+            return self._reply(*self._generate_errdoc(e, name, engine))
         self.send_response(200)
         self.send_header("Content-Type", "application/jsonl")
         self.send_header("Transfer-Encoding", "chunked")
@@ -321,6 +333,8 @@ class ServingHandler(BaseHTTPRequestHandler):
             try:
                 if first is not None:
                     self._chunk({"token": first, "index": 0})
+                    # the first token is on the wire
+                    sp.note(first_byte_s=sp.elapsed())
                     for i, tok in enumerate(gen, start=1):
                         self._chunk({"token": tok, "index": i})
                 toks = handle.so_far()
@@ -337,24 +351,21 @@ class ServingHandler(BaseHTTPRequestHandler):
                 obs.event("client_disconnect", source="serving",
                           model=name, streamed=len(handle.so_far()))
                 self.close_connection = True
-                return
+                return 200
             except Exception as e:  # noqa: BLE001 — mid-stream engine error
                 self._chunk({"error": "%s: %s" % (type(e).__name__, e),
                              "done": True, "finish_reason": "error"})
-                return
+                return 200
         finally:
             if not handle.done:
                 handle.cancel()
-            if tctx is not None:
-                obs.export_span(
-                    "http.generate", tctx, t_req, time.time() - t_req,
-                    {"proc": "http", "model": name,
-                     "tokens": len(handle.so_far())})
+            sp.note(tokens=len(handle.so_far()))
             try:
                 self.wfile.write(b"0\r\n\r\n")
                 self.wfile.flush()
             except (BrokenPipeError, ConnectionResetError):
                 self.close_connection = True
+        return 200
 
     # -- retrieval (:lookup / :search) -----------------------------------
     def _do_retrieval(self, name, engine, op):
