@@ -348,7 +348,8 @@ def phase_server(sz, dev, cache):
             raise errors[0][1]
         assert sorted(results) == list(range(len(prompts)))
         assert_on_device(
-            [("k_cache", eng._k), ("v_cache", eng._v)]
+            [("slot_cache_%d" % i, b)
+             for i, b in enumerate(eng._cache.bufs)]
             + sorted(eng._params.items()), dev["platform"], "engine state")
         stats = eng.stats()
     finally:

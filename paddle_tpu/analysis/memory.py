@@ -151,18 +151,19 @@ def _ceil_div(a, b):
 def estimate(program, env=None, feed_specs=None, state_specs=None,
              fetch_names=(), state_names=None, default_dim=None,
              param_shards=1, act_shards=1, sizes=None,
-             resident_names=()):
+             resident_names=(), alias_names=()):
     """Run the liveness walk; returns a :class:`MemoryReport`.
 
     ``state_names=None`` treats every persistable as state (executor
     semantics). ``param_shards``/``act_shards`` divide parameter and
     activation footprints (see :func:`shard_divisors`).
     ``resident_names`` pins names live across the WHOLE program
-    regardless of their def/use span — e.g. the persistent per-slot KV
-    buffer pair a decode engine round-trips device-to-device every
-    step: def-use liveness would let the fed copy die at its last
-    reader, but the serving process holds both the fed and the fetched
-    buffer for the region's entire lifetime."""
+    regardless of their def/use span — e.g. the persistent slot cache a
+    decode engine feeds to every step: def-use liveness would let a fed
+    buffer die at its last reader, but the serving process holds it for
+    the region's entire lifetime. ``alias_names`` are outputs written
+    into a resident buffer's storage (a donated cache feed updated in
+    place and fetched): they occupy nothing of their own."""
     gb = program.global_block()
     if sizes is None:
         sizes = sizes_from(program, env=env, feed_specs=feed_specs,
@@ -175,6 +176,7 @@ def estimate(program, env=None, feed_specs=None, state_specs=None,
     fetch_names = set(fetch_names or ())
     feed_names = set(feed_specs or ())
     resident_names = set(resident_names or ())
+    alias_names = set(alias_names or ())
 
     param_bytes = sum(
         _ceil_div(sizes[n], param_shards)
@@ -209,7 +211,7 @@ def estimate(program, env=None, feed_specs=None, state_specs=None,
     transient = {}
     seen_unsized = set(unsized)
     for n in set(first_def) | set(last_use) | feed_names | resident_names:
-        if n in state_names:
+        if n in state_names or n in alias_names:
             continue
         if n not in sizes:
             if n not in seen_unsized:

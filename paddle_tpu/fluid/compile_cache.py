@@ -20,6 +20,10 @@ This AOT tier is off by default. A disk hit runs the deserialized
 ``jax.export`` module, which does **not** donate its inputs
 (:class:`_DiskEntry`): it is not the executable a cold process runs, so
 a benchmark leaves this tier off or says which tier served each compile.
+The exception is a ``Predictor`` with ``donate_feeds``: it wraps its
+disk entry in a ``jax.jit`` that donates those feeds again, because a
+decode step that silently ran on a copied K/V cache would double the
+engine's memory.
 
 jax's own persistent XLA compilation cache is a separate tier placed by
 :func:`configure_xla_cache` — the one function in the repository that
@@ -229,17 +233,21 @@ def _device_fingerprint():
 
 
 def entry_key(program, feed_names, fetch_names, feed_sig, state_sig,
-              platform, kind="step", name=None):
+              platform, kind="step", name=None, donated=()):
     """The content-addressed disk key for one compiled specialization.
     Raises :class:`Unfingerprintable` when the program can't be hashed
     stably (caller skips the disk tier). ``name`` is the name the module
     was compiled under (``Predictor(name=)``): an artifact keeps the
-    name it was exported with, so another name is another entry."""
+    name it was exported with, so another name is another entry.
+    ``donated`` is the set of feeds the caller donates
+    (``Predictor(donate_feeds=)``): they are an argument of their own
+    of the exported function, so another set is another entry."""
     h = hashlib.sha256()
     h.update(program_fingerprint(program).encode())
     h.update(repr((kind, platform, list(feed_names), list(fetch_names),
                    feed_sig, state_sig) + ((name,) if name else ())
-                  ).encode())
+                  + ((("donated",) + tuple(sorted(donated)),)
+                     if donated else ())).encode())
     h.update(_device_fingerprint().encode())
     return h.hexdigest()
 

@@ -35,15 +35,28 @@ from .executor import (Executor, Scope, global_scope, _device_kind,
                        _publish_analysis_gauges)
 from .lowering import build_step_fn
 from .. import observability as obs
+from ..analysis import concurrency as _conc, dataflow as _dataflow
 
 __all__ = ["Predictor", "create_paddle_predictor"]
+
+_DTYPE_NAMES = {}
+
+
+def _dtype_name(dtype):
+    """``str(dtype)``, remembered: a decode step names the dtype of every
+    cache feed on every call."""
+    name = _DTYPE_NAMES.get(dtype)
+    if name is None:
+        name = _DTYPE_NAMES[dtype] = str(dtype)
+    return name
 
 
 class Predictor:
     """AOT-compiled predictor over a pruned inference Program."""
 
     def __init__(self, program, feed_names, fetch_vars, scope=None,
-                 place=None, dtype_policy=None, name=None):
+                 place=None, dtype_policy=None, name=None,
+                 donate_feeds=()):
         import jax
 
         self._jax = jax
@@ -52,6 +65,20 @@ class Predictor:
         # trace (``jit_fwd`` without one); part of the disk tier's key
         self.name = name
         self.feed_names = list(feed_names)
+        # feeds whose buffers every run CONSUMES: the executable may
+        # write its fetches into them (a decode step updating its K/V
+        # cache in place), and the caller must not touch one again after
+        # ``run``. An argument of the program's owner, not a user knob;
+        # empty for every predictor that does not hand its fetches back
+        # as the next call's feeds. The ORDER is the owner's: jax pairs
+        # donated inputs with same-shaped outputs in order, so a buffer
+        # is updated in place only if the feeds are listed in the order
+        # of the fetches they come back as.
+        self.donate_feeds = tuple(donate_feeds)
+        unknown = set(self.donate_feeds) - set(self.feed_names)
+        if unknown:
+            raise ValueError("donate_feeds %s are not feeds of this "
+                             "program" % sorted(unknown))
         self.fetch_names = [
             v.name if hasattr(v, "name") else v for v in fetch_vars
         ]
@@ -74,7 +101,15 @@ class Predictor:
             platform=platform,
         )
 
-        def fwd(state, feeds):
+        donate_names = self.donate_feeds
+
+        def fwd(state, feeds, donated=()):
+            # donate_argnums cannot pick entries of one dict, so a
+            # donating predictor passes its donated feeds as an argument
+            # of their own: a tuple in donate_feeds order (a dict would
+            # flatten in the order of its sorted keys)
+            feeds = dict(feeds)
+            feeds.update(zip(donate_names, donated))
             fetches, _ = step(state, feeds, jax.random.PRNGKey(0))
             return fetches
 
@@ -172,7 +207,9 @@ class Predictor:
         for n in self.feed_names:
             v = feeds[n]
             want = self._want_dtypes.get(n)
-            if isinstance(v, jax.Array):
+            if isinstance(v, jax.ShapeDtypeStruct):
+                pass  # a described feed: enough for warm(), which compiles
+            elif isinstance(v, jax.Array):
                 if want is not None and v.dtype != want:
                     v = v.astype(want)
             else:
@@ -181,7 +218,7 @@ class Predictor:
                     v = v.astype(want)
             prepared[n] = v
         sig = tuple(
-            (n, tuple(prepared[n].shape), str(prepared[n].dtype))
+            (n, tuple(prepared[n].shape), _dtype_name(prepared[n].dtype))
             for n in self.feed_names
         )
         return prepared, sig
@@ -214,21 +251,29 @@ class Predictor:
                     disk_key = compile_cache.entry_key(
                         self.program, self.feed_names, self.fetch_names,
                         sig, self._state_sig, self._platform,
-                        kind="predict", name=self.name)
+                        kind="predict", name=self.name,
+                        donated=self.donate_feeds)
                 except compile_cache.Unfingerprintable:
                     disk_key = None
                 else:
                     ex = compile_cache.load(disk_key)
                     if ex is not None:
                         source = "disk"
+                        if self.donate_feeds:
+                            # jax.export drops donation: put it back
+                            # around the exported call, so a disk hit
+                            # never runs this program on a copied cache
+                            ex = self._jit(ex)
                         _ledger_register(self.program, self.ledger_tag,
-                                         ex, "disk")
+                                         ex, "disk",
+                                         donated=self.donate_feeds)
             if ex is None:
                 obs.event("compile_start", source="predictor", count=False,
                           sig=repr(sig))
                 t0 = time.monotonic()
-                jitted = jax.jit(self._named_fwd())
-                ex = jitted.lower(self._state, prepared).compile()
+                jitted = self._jit(self._fwd)
+                args = self._call_args(prepared)
+                ex = jitted.lower(*args).compile()
                 dt = time.monotonic() - t0
                 self.compile_seconds[sig] = dt
                 obs.observe("predictor.compile_seconds", dt)
@@ -236,26 +281,34 @@ class Predictor:
                           sig=repr(sig), seconds=round(dt, 6))
                 _ledger_register(self.program, self.ledger_tag, ex,
                                  "compile", compile_seconds=dt,
-                                 donated=())
+                                 donated=self.donate_feeds)
                 if disk_key is not None:
-                    compile_cache.store(
-                        disk_key, jitted, (self._state, prepared))
+                    compile_cache.store(disk_key, jitted, args)
             with self._lock:
                 self._compiled[sig] = ex
             return ex, source
 
-    def _named_fwd(self):
-        """The forward function under the name its module should carry:
-        jax names a module after the function it is given."""
-        if not self.name:
-            return self._fwd
-        fwd = self._fwd
+    def _jit(self, fn):
+        """``fn`` jitted under the name this predictor's module should
+        carry (jax names a module after the function it is given), its
+        donated feeds (argument 2, see :meth:`_call_args`) donated."""
+        def named(*args):
+            return fn(*args)
 
-        def named(state, feeds):
-            return fwd(state, feeds)
+        named.__name__ = named.__qualname__ = (
+            "fwd_%s" % self.name if self.name else "fwd")
+        return self._jax.jit(
+            named, donate_argnums=(2,) if self.donate_feeds else ())
 
-        named.__name__ = named.__qualname__ = "fwd_%s" % self.name
-        return named
+    def _call_args(self, prepared):
+        """The executable's arguments: ``(state, feeds)``, or ``(state,
+        kept feeds, donated feeds)`` for a donating predictor."""
+        if not self.donate_feeds:
+            return self._state, prepared
+        kept = {n: v for n, v in prepared.items()
+                if n not in self.donate_feeds}
+        return (self._state, kept,
+                tuple(prepared[n] for n in self.donate_feeds))
 
     def warm(self, feeds):
         """Ensure the executable for this feed signature exists without
@@ -266,9 +319,17 @@ class Predictor:
         return self._ensure_exec(sig, prepared)[1]
 
     def run(self, feeds, return_numpy=True):
-        """feeds: dict name -> array (or list aligned with feed_names)."""
+        """feeds: dict name -> array (or list aligned with feed_names).
+        The buffers of ``donate_feeds`` are consumed: a device array
+        passed for one is deleted when this returns (or raises after
+        the dispatch)."""
         prepared, sig = self._prepare(feeds)
-        outs = self._ensure_exec(sig, prepared)[0](self._state, prepared)
+        ex = self._ensure_exec(sig, prepared)[0]
+        if self.donate_feeds and _conc._on:
+            # the registry's "scope" is whoever owns the named buffers:
+            # for donated feeds, this predictor
+            _dataflow.note_donation(self, self.donate_feeds)
+        outs = ex(*self._call_args(prepared))
         if return_numpy:
             outs = [np.asarray(o) for o in outs]
         return list(outs)

@@ -7,9 +7,12 @@ complement, and the <=pos additive visibility mask. Keeping them (and
 the head-split attention) here means a fix to the cache-write or
 masking logic lands in every decoder at once.
 """
+import numpy as np
+
 from paddle_tpu.fluid import layers
 
-__all__ = ["attend", "split_heads", "step_masks", "update_cache"]
+__all__ = ["attend", "attend_cached", "split_heads", "step_masks",
+           "update_cache"]
 
 
 def split_heads(t, heads, dh):
@@ -37,6 +40,43 @@ def attend(q, k, v, mask, heads, hidden):
     ctx = layers.matmul(layers.softmax(scores), split(v))
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     return layers.reshape(ctx, [0, 0, hidden])
+
+
+def attend_cached(q, k, v, mask, heads, hidden):
+    """:func:`attend` for a few query rows over a long cache, reading the
+    caches where they lie: q (S, K, H), k/v (S, T, H) slot caches,
+    additive mask (S, 1, K, T) -> context (S, K, H).
+
+    :func:`attend` splits k and v by head, which makes the head a batch
+    dimension of both products; with one query row per head the TPU
+    compiler then copies each whole cache into a transposed layout
+    before it multiplies. Here the head stays inside the query: every
+    query row is spread over ``heads`` rows that are zero outside their
+    own head's columns, so the scores are ONE batched product
+    ``(S, heads*K, H) x (S, T, H)^T`` contracting the caches' minor
+    dimension, and the context ONE product ``(S, heads*K, T) x (S, T,
+    H)`` of which each head keeps its own columns. The zeros add
+    nothing, so every score and context element is the same sum of the
+    same products as in :func:`attend`; the extra multiplications are
+    ``heads`` times a product that was one row wide."""
+    dh = hidden // heads
+    kq = q.shape[1]
+    head_cols = layers.assign(np.repeat(
+        np.eye(heads, dtype="float32"), dh, axis=1))     # (heads, H)
+    head_cols = layers.unsqueeze(head_cols, [0, 2])      # (1, nh, 1, H)
+    q_heads = layers.elementwise_mul(
+        layers.unsqueeze(q, [1]), head_cols)             # (S, nh, K, H)
+    scores = layers.matmul(
+        layers.reshape(q_heads, [0, heads * kq, hidden]), k,
+        transpose_y=True, alpha=dh ** -0.5)              # (S, nh*K, T)
+    scores = layers.reshape(scores, [0, heads, kq, -1])
+    if mask is not None:
+        scores = layers.elementwise_add(scores, mask)
+    probs = layers.reshape(layers.softmax(scores), [0, heads * kq, -1])
+    ctx = layers.reshape(layers.matmul(probs, v),
+                         [0, heads, kq, hidden])         # (S, nh, K, H)
+    return layers.reduce_sum(
+        layers.elementwise_mul(ctx, head_cols), dim=1)   # (S, K, H)
 
 
 def step_masks(pos, tmax):

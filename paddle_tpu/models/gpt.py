@@ -293,6 +293,12 @@ def _row_coords(col):
     return layers.concat([rows, col], axis=1)
 
 
+def _check_cache_len(cfg, cache_len):
+    if cache_len > cfg.max_len:
+        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
+                         % (cache_len, cfg.max_len))
+
+
 def build_gpt_prefill(cfg, prompt_len, cache_len):
     """Slot-prefill program for continuous-batching decode: one parallel
     pass over a (right-padded) prompt bucket that writes a slot's KV
@@ -316,9 +322,7 @@ def build_gpt_prefill(cfg, prompt_len, cache_len):
         raise ValueError(
             "need 1 <= prompt_len (%d) <= cache_len (%d)"
             % (prompt_len, cache_len))
-    if cache_len > cfg.max_len:
-        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
-                         % (cache_len, cfg.max_len))
+    _check_cache_len(cfg, cache_len)
     ids = fluid.data("gpt_prefill_ids", shape=[None, prompt_len],
                      dtype="int64")
     plen = fluid.data("gpt_prefill_len", shape=[None, 1], dtype="int64")
@@ -395,9 +399,7 @@ def build_gpt_prefill_delta(cfg, suffix_len, cache_len):
         raise ValueError(
             "need 1 <= suffix_len (%d) <= cache_len (%d)"
             % (suffix_len, cache_len))
-    if cache_len > cfg.max_len:
-        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
-                         % (cache_len, cfg.max_len))
+    _check_cache_len(cfg, cache_len)
     h = cfg.hidden
     nl = cfg.num_layers
     ids = fluid.data("gpt_dpre_ids", shape=[None, suffix_len],
@@ -477,6 +479,71 @@ def build_gpt_prefill_delta(cfg, suffix_len, cache_len):
             "fetch_vars": [nxt, k_out, v_out]}
 
 
+def _slot_cache_feeds(prefix, cfg, cache_len, width=None,
+                      dtype="float32"):
+    """The slot cache as a program sees it: ONE feed per layer, named
+    ``<prefix>_<layer>``, each (slots, cache_len, width). The engine
+    donates every one of them (``Predictor(donate_feeds=...)``) and the
+    program returns each updated in place, aliased to its input — no
+    layer is ever sliced out of a stacked array and none is stacked
+    back, so nothing cache-sized is materialised inside a step."""
+    width = cfg.hidden if width is None else width
+    return [fluid.data("%s_%d" % (prefix, i),
+                       shape=[None, cache_len, width], dtype=dtype)
+            for i in range(cfg.num_layers)]
+
+
+def _cached_block(x, cfg, i, pos, mask, k_cache, v_cache):
+    """Transformer block ``i`` over a slot cache layer: project the
+    (S, K, H) input's q/k/v, write the K new rows of every slot at its
+    own ``pos`` (one row scatter per cache), attend over the written
+    caches. Returns ``(x, k_cache, v_cache, k_new, v_new)``."""
+    from .decode_utils import attend_cached, update_cache
+
+    h = cfg.hidden
+    n = "gpt%d" % i
+    q = _proj(x, h, n + ".self.q")
+    k_new = _proj(x, h, n + ".self.k")
+    v_new = _proj(x, h, n + ".self.v")
+    k_cache = update_cache(k_cache, k_new, pos=pos, per_row=True)
+    v_cache = update_cache(v_cache, v_new, pos=pos, per_row=True)
+    attn = _proj(attend_cached(q, k_cache, v_cache, mask, cfg.heads, h),
+                 h, n + ".self.o")
+    x = _ln(layers.elementwise_add(x, attn), n + ".ln1")
+    f = _proj(x, cfg.ffn, n + ".ffn.fc1")
+    f = layers.gelu(f)
+    f = _proj(f, h, n + ".ffn.fc2")
+    x = _ln(layers.elementwise_add(x, f), n + ".ln2")
+    return x, k_cache, v_cache, k_new, v_new
+
+
+def _step_input(cfg, tok, pos, cache_len):
+    """Embedded (S, 1, H) input and per-row visibility mask of a
+    single-token step."""
+    from .decode_utils import step_masks
+
+    emb = layers.reshape(
+        layers.embedding(tok, size=[cfg.vocab, cfg.hidden],
+                         param_attr=_p("gpt_tok_emb")), [-1, cfg.hidden])
+    pos_table = layers.create_parameter(
+        shape=[cfg.max_len, cfg.hidden], dtype="float32",
+        name="gpt_pos_emb")
+    x = layers.elementwise_add(emb, layers.gather_nd(pos_table, pos))
+    _w3, _k3, self_mask = step_masks(pos, cache_len)      # per-row mask
+    return layers.unsqueeze(x, [1]), self_mask
+
+
+def _greedy(x, cfg):
+    logits = _proj(layers.squeeze(x, [1]), cfg.vocab, "gpt_out", nfd=1)
+    nxt = layers.cast(
+        layers.unsqueeze(layers.argmax(logits, axis=-1), [1]), "int64")
+    return logits, nxt
+
+
+def _names(feeds):
+    return [v.name for v in feeds]
+
+
 def build_gpt_verify_block(cfg, block_len, cache_len):
     """Speculative-decoding verify program: score a block of
     ``block_len`` candidate tokens for EVERY slot in one batched pass —
@@ -489,38 +556,33 @@ def build_gpt_verify_block(cfg, block_len, cache_len):
     Feeds: ``gpt_vrf_tok`` (S, block_len) int64 — column 0 is the
     slot's current token (what the non-speculative step would feed),
     columns 1.. are draft proposals — ``gpt_vrf_pos`` (S, 1) int64,
-    and the fp32 caches ``gpt_vrf_k`` / ``gpt_vrf_v``
-    (S, num_layers, cache_len, hidden). The caller must guarantee
-    ``pos + block_len <= cache_len`` for every live row (the engine
-    falls back to the single-token step near the cache edge).
+    and the per-layer fp32 slot caches ``gpt_vrf_k_<i>`` /
+    ``gpt_vrf_v_<i>`` (S, cache_len, hidden), the same buffers the
+    decode step takes (see :func:`build_gpt_decode_step`). The caller
+    must guarantee ``pos + block_len <= cache_len`` for every live row
+    (the engine falls back to the single-token step near the cache
+    edge).
 
     Returns ``next`` (S, block_len) int64 where ``next[s, i]`` is the
     target's greedy pick after consuming block tokens 0..i — column 0
     is bit-identical to the non-speculative step's output by
     construction (same math, same mask at position pos) — plus the
-    updated caches with ALL block rows written. Rows past the accepted
-    prefix are dirty-but-invisible: every consumer masks by position,
-    and the next write at those positions overwrites them, the same
-    contract dead slots already rely on.
+    caches with ALL block rows written, updated in place. Rows past the
+    accepted prefix are dirty-but-invisible: every consumer masks by
+    position, and the next write at those positions overwrites them,
+    the same contract dead slots already rely on.
     """
-    from .decode_utils import update_cache
-
     if not (1 <= block_len <= cache_len):
         raise ValueError(
             "need 1 <= block_len (%d) <= cache_len (%d)"
             % (block_len, cache_len))
-    if cache_len > cfg.max_len:
-        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
-                         % (cache_len, cfg.max_len))
+    _check_cache_len(cfg, cache_len)
     h = cfg.hidden
-    nl = cfg.num_layers
     tok = fluid.data("gpt_vrf_tok", shape=[None, block_len],
                      dtype="int64")
     pos = fluid.data("gpt_vrf_pos", shape=[None, 1], dtype="int64")
-    k_all = fluid.data("gpt_vrf_k", shape=[None, nl, cache_len, h],
-                       dtype="float32")
-    v_all = fluid.data("gpt_vrf_v", shape=[None, nl, cache_len, h],
-                       dtype="float32")
+    k_in = _slot_cache_feeds("gpt_vrf_k", cfg, cache_len)
+    v_in = _slot_cache_feeds("gpt_vrf_v", cfg, cache_len)
     steps = layers.range(0, block_len, 1, "int64")
     steps0 = layers.unsqueeze(steps, [0])                 # (1, K)
     pos_idx = layers.elementwise_add(steps0, pos)         # (S, K) abs pos
@@ -543,39 +605,20 @@ def build_gpt_verify_block(cfg, block_len, cache_len):
         "float32")                                        # (S, K, T)
     mask = layers.unsqueeze(
         layers.scale(seen, scale=1e9, bias=-1e9), [1])    # (S, 1, K, T)
-
-    def layer_cache(t, i):
-        return layers.squeeze(
-            layers.slice(t, axes=[1], starts=[i], ends=[i + 1]), [1])
-
-    new_ks, new_vs = [], []
-    for i in range(nl):
-        n = "gpt%d" % i
-        q = _proj(x, h, n + ".self.q")
-        k_cache = update_cache(layer_cache(k_all, i),
-                               _proj(x, h, n + ".self.k"),
-                               pos=pos, per_row=True)
-        v_cache = update_cache(layer_cache(v_all, i),
-                               _proj(x, h, n + ".self.v"),
-                               pos=pos, per_row=True)
-        new_ks.append(k_cache)
-        new_vs.append(v_cache)
-        attn = _proj(_attend(cfg, q, k_cache, v_cache, mask),
-                     h, n + ".self.o")
-        x = _ln(layers.elementwise_add(x, attn), n + ".ln1")
-        f = _proj(x, cfg.ffn, n + ".ffn.fc1")
-        f = layers.gelu(f)
-        f = _proj(f, h, n + ".ffn.fc2")
-        x = _ln(layers.elementwise_add(x, f), n + ".ln2")
+    ks, vs = [], []
+    for i in range(cfg.num_layers):
+        x, k, v, _kn, _vn = _cached_block(x, cfg, i, pos, mask,
+                                          k_in[i], v_in[i])
+        ks.append(k)
+        vs.append(v)
     logits = _proj(x, cfg.vocab, "gpt_out")               # (S, K, V)
     nxt = layers.cast(layers.argmax(logits, axis=-1), "int64")
-    k_out = layers.stack(new_ks, axis=1)                  # (S, L, T, H)
-    v_out = layers.stack(new_vs, axis=1)
-    return {"tok": tok, "pos": pos, "k_in": k_all, "v_in": v_all,
-            "next": nxt, "logits": logits, "k": k_out, "v": v_out,
-            "feed_names": ["gpt_vrf_tok", "gpt_vrf_pos",
-                           "gpt_vrf_k", "gpt_vrf_v"],
-            "fetch_vars": [nxt, k_out, v_out]}
+    cache_feeds = _names(k_in + v_in)
+    return {"tok": tok, "pos": pos, "k_in": k_in, "v_in": v_in,
+            "next": nxt, "logits": logits, "k": ks, "v": vs,
+            "feed_names": ["gpt_vrf_tok", "gpt_vrf_pos"] + cache_feeds,
+            "cache_feed_names": cache_feeds,
+            "fetch_vars": [nxt] + ks + vs}
 
 
 def build_gpt_decode_step(cfg, cache_len):
@@ -583,84 +626,50 @@ def build_gpt_decode_step(cfg, cache_len):
     the :class:`GPTDecodeCell` math with the batch dim reinterpreted as
     a slot dim — every row carries its OWN position (a freshly
     prefilled slot at ``len`` sits beside one deep into generation), so
-    cache writes use the per-row dynamic-update-slice path and the
-    visibility mask is per-row.
+    cache writes are one row scatter over (slot, position) pairs and
+    the visibility mask is per-row.
 
     Feeds: ``gpt_step_tok`` (S, 1) int64 current token per slot,
-    ``gpt_step_pos`` (S, 1) int64 write position per slot, and the
-    stacked cache pair ``gpt_step_k`` / ``gpt_step_v``
-    (S, num_layers, cache_len, hidden). Returns vars ``next`` (S, 1)
-    int64 greedy tokens and the updated ``k``/``v`` pair (the engine
-    round-trips them device-to-device; dead slots write harmlessly at
-    position 0 and are ignored host-side).
+    ``gpt_step_pos`` (S, 1) int64 write position per slot, and the slot
+    cache as ``2 * num_layers`` fp32 buffers ``gpt_step_k_<i>`` /
+    ``gpt_step_v_<i>`` (S, cache_len, hidden), listed in
+    ``cache_feed_names`` (all K layers, then all V layers). The engine
+    donates them; the program writes S rows into each, attends over it
+    and returns it aliased to its input, so a step moves no cache-sized
+    array. Returns vars ``next`` (S, 1) int64 greedy tokens and the
+    updated ``k``/``v`` lists, fetched in the order of the cache feeds
+    (dead slots write harmlessly at position 0 and are ignored
+    host-side).
     """
-    from .decode_utils import step_masks, update_cache
-
-    if cache_len > cfg.max_len:
-        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
-                         % (cache_len, cfg.max_len))
-    h = cfg.hidden
-    nl = cfg.num_layers
+    _check_cache_len(cfg, cache_len)
     tok = fluid.data("gpt_step_tok", shape=[None, 1], dtype="int64")
     pos = fluid.data("gpt_step_pos", shape=[None, 1], dtype="int64")
-    k_all = fluid.data("gpt_step_k", shape=[None, nl, cache_len, h],
-                       dtype="float32")
-    v_all = fluid.data("gpt_step_v", shape=[None, nl, cache_len, h],
-                       dtype="float32")
-    emb = layers.reshape(
-        layers.embedding(tok, size=[cfg.vocab, h],
-                         param_attr=_p("gpt_tok_emb")), [-1, h])
-    pos_table = layers.create_parameter(
-        shape=[cfg.max_len, h], dtype="float32", name="gpt_pos_emb")
-    x = layers.elementwise_add(emb, layers.gather_nd(pos_table, pos))
-    x = layers.unsqueeze(x, [1])                          # (S, 1, H)
-    _w3, _k3, self_mask = step_masks(pos, cache_len)      # per-row mask
-
-    def layer_cache(t, i):
-        return layers.squeeze(
-            layers.slice(t, axes=[1], starts=[i], ends=[i + 1]), [1])
-
-    new_ks, new_vs = [], []
-    for i in range(nl):
-        n = "gpt%d" % i
-        q = _proj(x, h, n + ".self.q")
-        k_cache = update_cache(layer_cache(k_all, i),
-                               _proj(x, h, n + ".self.k"),
-                               pos=pos, per_row=True)
-        v_cache = update_cache(layer_cache(v_all, i),
-                               _proj(x, h, n + ".self.v"),
-                               pos=pos, per_row=True)
-        new_ks.append(k_cache)
-        new_vs.append(v_cache)
-        attn = _proj(_attend(cfg, q, k_cache, v_cache, self_mask),
-                     h, n + ".self.o")
-        x = _ln(layers.elementwise_add(x, attn), n + ".ln1")
-        f = _proj(x, cfg.ffn, n + ".ffn.fc1")
-        f = layers.gelu(f)
-        f = _proj(f, h, n + ".ffn.fc2")
-        x = _ln(layers.elementwise_add(x, f), n + ".ln2")
-    logits = _proj(layers.squeeze(x, [1]), cfg.vocab, "gpt_out", nfd=1)
-    nxt = layers.cast(
-        layers.unsqueeze(layers.argmax(logits, axis=-1), [1]), "int64")
-    k_out = layers.stack(new_ks, axis=1)                  # (S, L, T, H)
-    v_out = layers.stack(new_vs, axis=1)
-    return {"tok": tok, "pos": pos, "k_in": k_all, "v_in": v_all,
-            "next": nxt, "logits": logits, "k": k_out, "v": v_out,
-            "feed_names": ["gpt_step_tok", "gpt_step_pos",
-                           "gpt_step_k", "gpt_step_v"],
-            "fetch_vars": [nxt, k_out, v_out]}
+    k_in = _slot_cache_feeds("gpt_step_k", cfg, cache_len)
+    v_in = _slot_cache_feeds("gpt_step_v", cfg, cache_len)
+    x, self_mask = _step_input(cfg, tok, pos, cache_len)
+    ks, vs = [], []
+    for i in range(cfg.num_layers):
+        x, k, v, _kn, _vn = _cached_block(x, cfg, i, pos, self_mask,
+                                          k_in[i], v_in[i])
+        ks.append(k)
+        vs.append(v)
+    logits, nxt = _greedy(x, cfg)
+    cache_feeds = _names(k_in + v_in)
+    return {"tok": tok, "pos": pos, "k_in": k_in, "v_in": v_in,
+            "next": nxt, "logits": logits, "k": ks, "v": vs,
+            "feed_names": ["gpt_step_tok", "gpt_step_pos"] + cache_feeds,
+            "cache_feed_names": cache_feeds,
+            "fetch_vars": [nxt] + ks + vs}
 
 
 def _quantize_cache_rows(t):
-    """In-graph per-(slot, layer, row) block-scaled int8 encode of a
-    (S, L, T, H) fp32 cache: block = hidden width, matching
-    serving.disagg.kv_wire. Returns (payload int8, scales fp32 with the
-    hidden axis collapsed to 1). The 1e-30 clamp keeps all-zero rows
-    (unwritten cache positions) at scale 1e-30 / payload 0, and rows
-    decoded from an existing (payload, scale) re-encode identically
-    (max |element| is exactly 127 * scale), so requantizing the whole
-    cache every step does not compound error on unwritten rows."""
-    amax = layers.reduce_max(layers.abs(t), dim=3, keep_dim=True)
+    """In-graph per-row block-scaled int8 encode of fp32 cache rows
+    (..., H): block = hidden width, matching serving.disagg.kv_wire.
+    Returns (payload int8, scales fp32 with the hidden axis collapsed
+    to 1). The 1e-30 clamp keeps all-zero rows at scale 1e-30 /
+    payload 0."""
+    amax = layers.reduce_max(layers.abs(t), dim=len(t.shape) - 1,
+                             keep_dim=True)
     scale = layers.scale(layers.clip(amax, 1e-30, 3.0e38),
                          scale=1.0 / 127.0)
     q = layers.round(layers.elementwise_div(t, scale))
@@ -672,81 +681,60 @@ def build_gpt_decode_step_q(cfg, cache_len):
     """:func:`build_gpt_decode_step` with an int8-**resident** KV
     cache: the engine keeps (payload int8, per-row fp32 scale) buffers
     instead of fp32 caches — ~4x more decode slots per chip at equal
-    HBM — and this program dequantizes on entry and requantizes the
-    updated caches before returning them.
+    HBM. Each layer is dequantized for its attention (an fp32
+    transient of one layer), and only the step's NEW row is quantized
+    and written into the resident payload and scale buffers, in place;
+    rows written earlier are never re-encoded.
 
-    Extra feeds beyond the fp32 step: ``gpt_step_kscale`` /
-    ``gpt_step_vscale`` (S, num_layers, cache_len, 1) fp32, with
-    ``gpt_step_k`` / ``gpt_step_v`` now int8. Fetches next tokens plus
-    the requantized (k, v, k_scale, v_scale) quadruple. Compute after
-    dequantize is identical op-for-op to the fp32 step, so the only
-    numeric delta is the per-row int8 rounding (bounded by scale/2 per
-    element — the round-trip tolerance the kv_wire tests pin).
+    Feeds beyond tok/pos, one per layer as in the fp32 step:
+    ``gpt_step_k_<i>`` / ``gpt_step_v_<i>`` (S, cache_len, hidden) int8
+    and ``gpt_step_kscale_<i>`` / ``gpt_step_vscale_<i>``
+    (S, cache_len, 1) fp32, all donated. Fetches next tokens plus the
+    updated buffers in the order of ``cache_feed_names`` (k, v,
+    k_scale, v_scale). A step attends over its own new row in fp32 and
+    over earlier rows through their int8 rounding (bounded by scale/2
+    per element — the round-trip tolerance the kv_wire tests pin).
     """
-    from .decode_utils import step_masks, update_cache
+    from .decode_utils import update_cache
 
-    if cache_len > cfg.max_len:
-        raise ValueError("cache_len (%d) exceeds cfg.max_len (%d)"
-                         % (cache_len, cfg.max_len))
-    h = cfg.hidden
-    nl = cfg.num_layers
+    _check_cache_len(cfg, cache_len)
     tok = fluid.data("gpt_step_tok", shape=[None, 1], dtype="int64")
     pos = fluid.data("gpt_step_pos", shape=[None, 1], dtype="int64")
-    k_all = fluid.data("gpt_step_k", shape=[None, nl, cache_len, h],
-                       dtype="int8")
-    v_all = fluid.data("gpt_step_v", shape=[None, nl, cache_len, h],
-                       dtype="int8")
-    k_sc = fluid.data("gpt_step_kscale", shape=[None, nl, cache_len, 1],
-                      dtype="float32")
-    v_sc = fluid.data("gpt_step_vscale", shape=[None, nl, cache_len, 1],
-                      dtype="float32")
-    k_f = layers.elementwise_mul(layers.cast(k_all, "float32"), k_sc)
-    v_f = layers.elementwise_mul(layers.cast(v_all, "float32"), v_sc)
-    emb = layers.reshape(
-        layers.embedding(tok, size=[cfg.vocab, h],
-                         param_attr=_p("gpt_tok_emb")), [-1, h])
-    pos_table = layers.create_parameter(
-        shape=[cfg.max_len, h], dtype="float32", name="gpt_pos_emb")
-    x = layers.elementwise_add(emb, layers.gather_nd(pos_table, pos))
-    x = layers.unsqueeze(x, [1])                          # (S, 1, H)
-    _w3, _k3, self_mask = step_masks(pos, cache_len)      # per-row mask
+    k_in = _slot_cache_feeds("gpt_step_k", cfg, cache_len, dtype="int8")
+    v_in = _slot_cache_feeds("gpt_step_v", cfg, cache_len, dtype="int8")
+    ks_in = _slot_cache_feeds("gpt_step_kscale", cfg, cache_len, width=1)
+    vs_in = _slot_cache_feeds("gpt_step_vscale", cfg, cache_len, width=1)
+    x, self_mask = _step_input(cfg, tok, pos, cache_len)
 
-    def layer_cache(t, i):
-        return layers.squeeze(
-            layers.slice(t, axes=[1], starts=[i], ends=[i + 1]), [1])
+    def dequant(payload, scale):
+        return layers.elementwise_mul(layers.cast(payload, "float32"),
+                                      scale)
 
-    new_ks, new_vs = [], []
-    for i in range(nl):
-        n = "gpt%d" % i
-        q = _proj(x, h, n + ".self.q")
-        k_cache = update_cache(layer_cache(k_f, i),
-                               _proj(x, h, n + ".self.k"),
-                               pos=pos, per_row=True)
-        v_cache = update_cache(layer_cache(v_f, i),
-                               _proj(x, h, n + ".self.v"),
-                               pos=pos, per_row=True)
-        new_ks.append(k_cache)
-        new_vs.append(v_cache)
-        attn = _proj(_attend(cfg, q, k_cache, v_cache, self_mask),
-                     h, n + ".self.o")
-        x = _ln(layers.elementwise_add(x, attn), n + ".ln1")
-        f = _proj(x, cfg.ffn, n + ".ffn.fc1")
-        f = layers.gelu(f)
-        f = _proj(f, h, n + ".ffn.fc2")
-        x = _ln(layers.elementwise_add(x, f), n + ".ln2")
-    logits = _proj(layers.squeeze(x, [1]), cfg.vocab, "gpt_out", nfd=1)
-    nxt = layers.cast(
-        layers.unsqueeze(layers.argmax(logits, axis=-1), [1]), "int64")
-    k_q, k_s = _quantize_cache_rows(layers.stack(new_ks, axis=1))
-    v_q, v_s = _quantize_cache_rows(layers.stack(new_vs, axis=1))
-    return {"tok": tok, "pos": pos, "k_in": k_all, "v_in": v_all,
-            "k_scale_in": k_sc, "v_scale_in": v_sc,
-            "next": nxt, "logits": logits, "k": k_q, "v": v_q,
-            "k_scale": k_s, "v_scale": v_s,
-            "feed_names": ["gpt_step_tok", "gpt_step_pos",
-                           "gpt_step_k", "gpt_step_v",
-                           "gpt_step_kscale", "gpt_step_vscale"],
-            "fetch_vars": [nxt, k_q, v_q, k_s, v_s]}
+    def write_q(payload, scale, new_t):
+        q, s = _quantize_cache_rows(new_t)
+        return (update_cache(payload, q, pos=pos, per_row=True),
+                update_cache(scale, s, pos=pos, per_row=True))
+
+    kq, vq, ksc, vsc = [], [], [], []
+    for i in range(cfg.num_layers):
+        x, _k, _v, k_new, v_new = _cached_block(
+            x, cfg, i, pos, self_mask, dequant(k_in[i], ks_in[i]),
+            dequant(v_in[i], vs_in[i]))
+        q, s = write_q(k_in[i], ks_in[i], k_new)
+        kq.append(q)
+        ksc.append(s)
+        q, s = write_q(v_in[i], vs_in[i], v_new)
+        vq.append(q)
+        vsc.append(s)
+    logits, nxt = _greedy(x, cfg)
+    cache_feeds = _names(k_in + v_in + ks_in + vs_in)
+    return {"tok": tok, "pos": pos, "k_in": k_in, "v_in": v_in,
+            "k_scale_in": ks_in, "v_scale_in": vs_in,
+            "next": nxt, "logits": logits, "k": kq, "v": vq,
+            "k_scale": ksc, "v_scale": vsc,
+            "feed_names": ["gpt_step_tok", "gpt_step_pos"] + cache_feeds,
+            "cache_feed_names": cache_feeds,
+            "fetch_vars": [nxt] + kq + vq + ksc + vsc}
 
 
 def tp_rules():

@@ -448,22 +448,32 @@ def _decode_cache_write(ctx, ins, attrs):
     re-reads and re-writes the entire cache every step — the decode
     equivalent of the reference's in-place beam-search cache kernels
     (ref: paddle/fluid/operators/math/beam_search.cc writes rows in
-    place rather than rebuilding the tensor)."""
+    place rather than rebuilding the tensor). With ``per_row`` every
+    batch row writes its (K, H) block of Value at its own Pos."""
     cache, val, pos = ins["Cache"][0], ins["Value"][0], ins["Pos"][0]
     if attrs.get("per_row"):
         # continuous-batching slot semantics: every row is its OWN
         # sequence at its own position (freed slots restart at 0 while
         # neighbours keep decoding), so the write index varies per row.
-        # vmap the row write — still O(B·H), no one-hot rewrite.
-        import jax as _jax
-
-        starts = pos.reshape(-1).astype(jnp.int32)
-
-        def _row(c, v, s):
-            return lax.dynamic_update_slice(
-                c, v.astype(c.dtype), (s, jnp.int32(0)))
-
-        return single(_jax.vmap(_row)(cache, val, starts))
+        # ONE scatter of B*K rows of width H over (row, position) index
+        # pairs: the TPU compiler emits that as a single in-place
+        # operation, where a vmap of dynamic_update_slice (a scatter of
+        # B windows) is expanded into a loop of B iterations. Rows that
+        # would land past the cache's end are dropped (callers keep
+        # pos + K <= T).
+        b, k, h = val.shape
+        rows = jnp.broadcast_to(
+            jnp.arange(b, dtype=jnp.int32)[:, None], (b, k))
+        cols = (pos.reshape(-1, 1).astype(jnp.int32)
+                + jnp.arange(k, dtype=jnp.int32)[None, :])
+        dnums = lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1))
+        return single(lax.scatter(
+            cache, jnp.stack([rows, cols], -1).reshape(b * k, 2),
+            val.astype(cache.dtype).reshape(b * k, h), dnums,
+            indices_are_sorted=True, unique_indices=True,
+            mode=lax.GatherScatterMode.FILL_OR_DROP))
     start = pos.reshape(-1)[0].astype(jnp.int32)
     zero = jnp.int32(0)
     return single(lax.dynamic_update_slice(

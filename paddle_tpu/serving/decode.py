@@ -7,10 +7,13 @@ is autoregressive *decode*, where a full-batch ``lax.scan`` generator
 wait for the slowest sequence in its batch and admits nothing
 mid-generation. This engine removes the full-batch barrier:
 
-- **Slotted KV cache** — ONE pre-allocated device buffer pair
-  ``(slots, layers, cache_len, heads*dh)`` holds every live sequence's
-  keys/values. A slot is a sequence's home for its whole generation;
-  retiring frees the slot the same step.
+- **Slotted KV cache** — ONE pre-allocated set of device buffers, per
+  layer a ``(slots, cache_len, heads*dh)`` K and V (:class:`SlotCache`),
+  holds every live sequence's keys/values. Every program that takes the
+  buffers is given them **donated** and updates them in place: a step
+  writes one row per slot and layer and copies nothing. A slot is a
+  sequence's home for its whole generation; retiring frees the slot the
+  same step.
 - **Two programs, both AOT** — a *prefill* program per declared prompt
   bucket (parallel pass over the right-padded prompt writes a slot's
   cache and emits the first token) and ONE *step* program (one token
@@ -100,8 +103,8 @@ from ..analysis import dataflow as _dataflow
 from ..fluid import resilience as R
 from .engine import DeadlineExceededError, EngineClosedError, ShedError
 
-__all__ = ["DecodeEngine", "DecodeStream", "default_prompt_buckets",
-           "kv_slot_bytes"]
+__all__ = ["DecodeEngine", "DecodeStream", "SlotCache",
+           "default_prompt_buckets", "kv_slot_bytes"]
 
 
 # every stream of the process gets its own id: the ``request`` field the
@@ -135,6 +138,112 @@ def default_prompt_buckets(cache_len, smallest=8):
         b *= 2
     buckets.append(int(cache_len))
     return tuple(sorted(set(buckets)))
+
+
+class SlotCache:
+    """The slot KV cache in the form the step programs take it, and its
+    ONE owner: per layer a ``(slots, cache_len, hidden)`` device buffer
+    of K and one of V (int8 residency: int8 payloads plus a ``(slots,
+    cache_len, 1)`` fp32 scale buffer for each), kept as the flat list
+    ``bufs`` in the order of a step program's ``cache_feed_names`` (all
+    K layers, all V layers, then the K and V scales).
+
+    Every program that takes the buffers consumes them
+    (``Predictor(donate_feeds=...)``) and hands them back updated in
+    place (:meth:`run`). A reference to a buffer held anywhere else is
+    dead after the next step, so nothing outside this class keeps one:
+    a sequence goes in through :meth:`write_slot`
+    (one donated dispatch for all layers) and comes out, as host
+    arrays in the ``(layers, cache_len, hidden)`` geometry of the wire
+    format and the prefix pool, through :meth:`read_slot`."""
+
+    def __init__(self, jax, cfg, slots, cache_len, kv_dtype="fp32"):
+        self._jax = jax
+        self.layers = int(cfg.num_layers)
+        payload = ((int(slots), int(cache_len), int(cfg.hidden)),
+                   np.int8 if kv_dtype == "int8" else np.float32)
+        groups = [payload, payload]
+        if kv_dtype == "int8":
+            scale = ((int(slots), int(cache_len), 1), np.float32)
+            groups += [scale, scale]
+        self.specs = [jax.ShapeDtypeStruct(shape, dtype)
+                      for shape, dtype in groups
+                      for _ in range(self.layers)]
+        nl = self.layers
+
+        def write(bufs, vals, slot):
+            # vals: per group one (1, layers, cache_len, width) array
+            return [jax.lax.dynamic_update_slice(
+                        b, vals[j // nl][:, j % nl], (slot, 0, 0))
+                    for j, b in enumerate(bufs)]
+
+        def read(bufs, slot):
+            rows = [jax.lax.dynamic_index_in_dim(b, slot, 0, False)
+                    for b in bufs]
+            return [jax.numpy.stack(rows[g:g + nl])
+                    for g in range(0, len(rows), nl)]
+
+        # the slot index is a traced scalar: each compiles once
+        self._write = jax.jit(write, donate_argnums=(0,))
+        self._read = jax.jit(read)
+        self.reallocs = 0   # times a failed dispatch cost the cache
+        self.bufs = None
+        self.allocate()
+
+    def allocate(self):
+        """Fresh zeroed buffers, filled on the device (the old ones are
+        dropped first)."""
+        self.bufs = None
+        zeros = self._jax.numpy.zeros
+        self.bufs = [zeros(sp.shape, sp.dtype) for sp in self.specs]
+
+    def feeds(self, names):
+        """The buffers under a program's cache feed names."""
+        return dict(zip(names, self.bufs))
+
+    def run(self, pred, names, feeds):
+        """Dispatch a program that takes the whole cache: the buffers go
+        in, donated, under its cache feed ``names`` beside ``feeds``, and
+        the updated ones come back as its fetches after the first.
+        Returns ``(fetches, in_place)``; ``in_place`` is False when the
+        run left its inputs alive, i.e. worked on a copy. A dispatch
+        that raises after consuming the buffers leaves no valid cache:
+        it is replaced by zeroed buffers (as ``Executor.run`` evicts a
+        poisoned donated state) before the error goes on to the caller,
+        whose sequences are lost either way."""
+        fed = self.bufs
+        feeds = dict(feeds)
+        feeds.update(zip(names, fed))
+        try:
+            outs = pred.run(feeds, return_numpy=False)
+        except Exception:
+            if any(b.is_deleted() for b in fed):
+                del fed, feeds
+                self.allocate()
+                self.reallocs += 1
+            raise
+        self.bufs = list(outs[1:])
+        return outs, fed[0].is_deleted()
+
+    def write_slot(self, slot, *vals):
+        """Install one sequence: per group (k, v[, k_scale, v_scale]) a
+        ``(1, layers, cache_len, width)`` array in the residency dtype."""
+        self.bufs = self._write(self.bufs, list(vals), np.int32(slot))
+
+    def read_slot(self, slot):
+        """One slot's rows as host arrays ``(layers, cache_len, width)``,
+        one per group."""
+        return [np.asarray(a)
+                for a in self._read(self.bufs, np.int32(slot))]
+
+    def snapshot(self):
+        """Device copies of every buffer (what a replay of a step needs,
+        since the step consumes the originals)."""
+        return [self._jax.numpy.copy(b) for b in self.bufs]
+
+    def nbytes(self):
+        return sum(int(np.prod(sp.shape)) * np.dtype(sp.dtype).itemsize
+                   for sp in self.specs)
 
 
 class DecodeStream:
@@ -416,7 +525,8 @@ class DecodeEngine:
         # name keeps "fwd"
         self._step_pred = Predictor(
             step_prog, step_vars["feed_names"], step_vars["fetch_vars"],
-            scope=persist, name="decode_step")
+            scope=persist, name="decode_step",
+            donate_feeds=step_vars["cache_feed_names"])
         self._step_pred.ledger_tag = "decode.step:%s" % self.name
         self._prefill_preds = {}
         self._prefill_vars = {}
@@ -434,34 +544,22 @@ class DecodeEngine:
                 name="delta_%d" % b)
             self._delta_preds[b].ledger_tag = (
                 "decode.delta_prefill:%s" % self.name)
-        self._verify_pred = None
+        self._verify_pred = self._verify_vars = None
         if verify is not None:
             prog, vv = verify
+            self._verify_vars = vv
             self._verify_pred = Predictor(
                 prog, vv["feed_names"], vv["fetch_vars"], scope=persist,
-                name="verify_block")
+                name="verify_block",
+                donate_feeds=vv["cache_feed_names"])
             self._verify_pred.ledger_tag = "decode.verify:%s" % self.name
 
-        # -- the persistent slot buffer pair + host-side slot state ----
-        shape = (self.slots, cfg.num_layers, self.cache_len, cfg.hidden)
-        self._cache_np_dtype = (np.int8 if self.kv_dtype == "int8"
-                                else np.float32)
-        self._k = jax.device_put(np.zeros(shape, self._cache_np_dtype))
-        self._v = jax.device_put(np.zeros(shape, self._cache_np_dtype))
-        self._kscale = self._vscale = None
-        if self.kv_dtype == "int8":
-            sshape = shape[:-1] + (1,)
-            self._kscale = jax.device_put(np.zeros(sshape, np.float32))
-            self._vscale = jax.device_put(np.zeros(sshape, np.float32))
+        # -- the persistent slot cache + host-side slot state ----------
+        self._cache = SlotCache(jax, cfg, self.slots, self.cache_len,
+                                self.kv_dtype)
         self._tok = np.zeros((self.slots, 1), np.int64)
         self._pos = np.zeros((self.slots, 1), np.int64)
         self._slots = [None] * self.slots
-        # slot writes trace once (slot index is a traced scalar); the
-        # old buffer is donated so the pair never triples up in HBM
-        self._write = jax.jit(
-            lambda buf, val, slot: jax.lax.dynamic_update_slice(
-                buf, val, (slot, 0, 0, 0)),
-            donate_argnums=(0,))
 
         self._q = queue.Queue(maxsize=int(queue_capacity))
         self._stop_event = threading.Event()
@@ -517,8 +615,13 @@ class DecodeEngine:
     def sentinel_replay(self, feeds):
         """Re-dispatch the step program on arbitrary feeds (the
         cross-replica vote path — peers re-run a suspect's feeds).
-        Stateless: the jitted step is functional, so this never
+        The step consumes its cache feeds, so it runs on copies of
+        them: ``feeds`` stay valid for the next peer, and this never
         touches this engine's resident cache."""
+        jnp = self._jax.numpy
+        feeds = dict(feeds)
+        for n in self._step_pred.donate_feeds:
+            feeds[n] = jnp.copy(feeds[n])
         return self._step_pred.run(feeds, return_numpy=False)
 
     # -- construction helpers -------------------------------------------
@@ -827,12 +930,17 @@ class DecodeEngine:
 
     # -- admission checks before warmup ----------------------------------
     def check_hbm_budget(self, budget_bytes=None):
-        """Price params + the persistent KV buffer pair + the step
+        """Price params + the persistent slot cache + the step
         program's transient peak with the static liveness analyzer,
-        BEFORE any warmup compile. The cache feeds/fetches are passed
-        as ``resident_names`` so the analyzer holds them live across
-        the whole decode region instead of letting them die like
-        ordinary activations. ``budget_bytes=None`` resolves the device
+        BEFORE any warmup compile. The cache is priced ONCE: its feeds
+        are ``resident_names`` (live across the whole decode region,
+        where ordinary activations die at their last reader) and the
+        fetches, which the donated feeds' buffers are updated into, are
+        ``alias_names`` and cost nothing. The dtype of the feeds drives
+        the bytes: int8 residency costs 1 byte/element where fp32 cost
+        4, plus the per-row fp32 scale planes and the dequantized fp32
+        layer the program holds while it attends — the slot multiplier
+        disagg banks on. ``budget_bytes=None`` resolves the device
         capacity from the analyzer's device table; unknown capacity is
         a no-op. Raises ``ProgramVerifyError`` when the engine cannot
         fit."""
@@ -854,36 +962,13 @@ class DecodeEngine:
         if self._draft is not None:
             overhead += self._draft.resident_bytes()
         budget_bytes = budget_bytes - overhead
-        jax = self._jax
         pred = self._step_pred
-        sv = self._step_vars
-        cache_names = [sv["k_in"].name, sv["v_in"].name,
-                       sv["k"].name, sv["v"].name]
-        # the cache feed dtype drives the byte pricing: int8 residency
-        # costs 1 byte/element where fp32 cost 4, plus the per-row fp32
-        # scale planes — exactly the slot multiplier disagg banks on
-        feed_specs = {
-            sv["tok"].name: jax.ShapeDtypeStruct(
-                (self.slots, 1), np.int64),
-            sv["pos"].name: jax.ShapeDtypeStruct(
-                (self.slots, 1), np.int64),
-            sv["k_in"].name: jax.ShapeDtypeStruct(
-                tuple(self._k.shape), self._cache_np_dtype),
-            sv["v_in"].name: jax.ShapeDtypeStruct(
-                tuple(self._v.shape), self._cache_np_dtype),
-        }
-        if self.kv_dtype == "int8":
-            cache_names += [sv["k_scale_in"].name, sv["v_scale_in"].name,
-                            sv["k_scale"].name, sv["v_scale"].name]
-            feed_specs[sv["k_scale_in"].name] = jax.ShapeDtypeStruct(
-                tuple(self._kscale.shape), np.float32)
-            feed_specs[sv["v_scale_in"].name] = jax.ShapeDtypeStruct(
-                tuple(self._vscale.shape), np.float32)
         est = _memory.estimate(
-            pred.program, feed_specs=feed_specs,
+            pred.program, feed_specs=self._step_specs(),
             state_specs=pred._state, fetch_names=pred.fetch_names,
             state_names=set(pred._state), default_dim=self.slots,
-            resident_names=cache_names)
+            resident_names=pred.donate_feeds,
+            alias_names=pred.fetch_names[1:])
         obs.set_gauge(
             "serving.predicted_peak_hbm.%s" % self.name, est.peak_bytes)
         if est.peak_bytes > budget_bytes:
@@ -892,7 +977,7 @@ class DecodeEngine:
                       budget_bytes=int(budget_bytes))
             raise ProgramVerifyError(
                 "predicted-oom: decode engine %r needs %.2f MB "
-                "(params %.2f MB + resident KV pair + step peak at op "
+                "(params %.2f MB + resident slot cache + step peak at op "
                 "%s '%s') but the HBM budget is %.2f MB — shrink "
                 "slots/cache_len or shard the model"
                 % (self.name, est.peak_bytes / 1e6,
@@ -928,16 +1013,8 @@ class DecodeEngine:
             self.check_hbm_budget()
         self.check_ladder()
         report = []
-        warm_feeds = {
-            "gpt_step_tok": self._tok, "gpt_step_pos": self._pos,
-            "gpt_step_k": np.zeros(self._k.shape, self._cache_np_dtype),
-            "gpt_step_v": np.zeros(self._v.shape, self._cache_np_dtype)}
-        if self.kv_dtype == "int8":
-            warm_feeds["gpt_step_kscale"] = np.zeros(
-                self._kscale.shape, np.float32)
-            warm_feeds["gpt_step_vscale"] = np.zeros(
-                self._vscale.shape, np.float32)
-        source = self._step_pred.warm(warm_feeds)
+        # warm() only compiles: the signature is described, not fed
+        source = self._step_pred.warm(self._step_specs())
         report.append({"program": "step", "slots": self.slots,
                        "cache_len": self.cache_len,
                        "kv_dtype": self.kv_dtype, "source": source})
@@ -960,11 +1037,10 @@ class DecodeEngine:
                            "source": source})
         if self._verify_pred is not None:
             blk = self._draft.k + 1
-            source = self._verify_pred.warm({
-                "gpt_vrf_tok": np.zeros((self.slots, blk), np.int64),
-                "gpt_vrf_pos": np.zeros((self.slots, 1), np.int64),
-                "gpt_vrf_k": np.zeros(self._k.shape, np.float32),
-                "gpt_vrf_v": np.zeros(self._v.shape, np.float32)})
+            source = self._verify_pred.warm(dict(
+                self._cache.feeds(self._verify_vars["cache_feed_names"]),
+                gpt_vrf_tok=np.zeros((self.slots, blk), np.int64),
+                gpt_vrf_pos=self._pos))
             report.append({"program": "verify", "block": blk,
                            "source": source})
             report.extend(self._draft.warmup())
@@ -1135,17 +1211,6 @@ class DecodeEngine:
         return (sbucket is not None
                 and entry.plen + sbucket <= self.cache_len)
 
-    def _write_slot_cache(self, slot, k1, v1, ks=None, vs=None):
-        """Install one sequence's cache pair into slot ``slot``.
-        ``k1``/``v1`` are (1, L, T, H) in the engine's residency dtype;
-        int8 engines also take the (1, L, T, 1) fp32 scale pair."""
-        slot_i = np.int32(slot)
-        self._k = self._write(self._k, k1, slot_i)
-        self._v = self._write(self._v, v1, slot_i)
-        if self.kv_dtype == "int8":
-            self._kscale = self._write(self._kscale, ks, slot_i)
-            self._vscale = self._write(self._vscale, vs, slot_i)
-
     def _first_token(self, nxt):
         """Wait on the host for the token a fill program produced."""
         with obs.span("decode.prefill.sync") as sp:
@@ -1195,10 +1260,10 @@ class DecodeEngine:
 
             kq, ks = kv_wire.quantize_rows(np.asarray(k1)[0])
             vq, vs = kv_wire.quantize_rows(np.asarray(v1)[0])
-            self._write_slot_cache(slot, kq[None], vq[None],
+            self._cache.write_slot(slot, kq[None], vq[None],
                                    ks[None], vs[None])
         else:
-            self._write_slot_cache(slot, k1, v1)
+            self._cache.write_slot(slot, k1, v1)
         tok = self._first_token(nxt)
         self._bump("prefill_rows_computed", req.bucket)
         if self._prefix_pool is not None:
@@ -1228,10 +1293,10 @@ class DecodeEngine:
             else:
                 kq, ks = kv_wire.quantize_rows(kd)
                 vq, vs = kv_wire.quantize_rows(vd)
-            self._write_slot_cache(slot, kq[None], vq[None],
+            self._cache.write_slot(slot, kq[None], vq[None],
                                    ks[None], vs[None])
         else:
-            self._write_slot_cache(slot, kd[None], vd[None])
+            self._cache.write_slot(slot, kd[None], vd[None])
         self._bump("prefix_full_hits")
         self._bump("prefill_rows_saved", entry.plen)
         self._observe_prefill(req, sp)
@@ -1278,10 +1343,10 @@ class DecodeEngine:
 
             kq, ks = kv_wire.quantize_rows(np.asarray(k1)[0])
             vq, vs = kv_wire.quantize_rows(np.asarray(v1)[0])
-            self._write_slot_cache(slot, kq[None], vq[None],
+            self._cache.write_slot(slot, kq[None], vq[None],
                                    ks[None], vs[None])
         else:
-            self._write_slot_cache(slot, k1, v1)
+            self._cache.write_slot(slot, k1, v1)
         tok = self._first_token(nxt)
         self._bump("delta_prefills")
         self._bump("prefill_rows_computed", req.sbucket)
@@ -1337,13 +1402,12 @@ class DecodeEngine:
                     kd, vd = h.dense()
                     kq, ks = kv_wire.quantize_rows(kd)
                     vq, vs = kv_wire.quantize_rows(vd)
-                self._write_slot_cache(
-                    slot, kq[None], vq[None],
+                self._cache.write_slot(slot, kq[None], vq[None],
                     np.asarray(ks, np.float32)[None],
                     np.asarray(vs, np.float32)[None])
             else:
                 kd, vd = h.dense()
-                self._write_slot_cache(slot, kd[None], vd[None])
+                self._cache.write_slot(slot, kd[None], vd[None])
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
             sp.note(error=type(e).__name__)
             self._bump("adopt_errors")
@@ -1438,45 +1502,67 @@ class DecodeEngine:
                 "slot %d history %d rows != pos %d — refusing to "
                 "hibernate a misaligned session"
                 % (slot, hist.size, pos))
+        rows = self._cache.read_slot(slot)  # (L, T, H) per group
         if self.kv_dtype == "int8":
-            h = kv_wire.encode_kv_q(
-                np.asarray(self._k[slot]), np.asarray(self._v[slot]),
-                np.asarray(self._kscale[slot]),
-                np.asarray(self._vscale[slot]),
-                int(emitted[-1]), pos, hist)
+            h = kv_wire.encode_kv_q(*rows, int(emitted[-1]), pos, hist)
         else:
             h = kv_wire.encode_kv(
-                np.asarray(self._k[slot]), np.asarray(self._v[slot]),
-                int(emitted[-1]), pos, hist,
+                *rows, int(emitted[-1]), pos, hist,
                 wire_dtype=self._session_tier.wire_dtype)
         self._session_tier.hibernate(s.session, h)
         self._bump("hibernated")
 
-    def _step_feeds(self):
-        feeds = {"gpt_step_tok": self._tok, "gpt_step_pos": self._pos,
-                 "gpt_step_k": self._k, "gpt_step_v": self._v}
-        if self.kv_dtype == "int8":
-            feeds["gpt_step_kscale"] = self._kscale
-            feeds["gpt_step_vscale"] = self._vscale
-        return feeds
+    def _step_specs(self):
+        """The step program's feed signature, described (for a compile
+        or a cost estimate: nothing is allocated or read)."""
+        sds = self._jax.ShapeDtypeStruct
+        specs = {"gpt_step_tok": sds(self._tok.shape, self._tok.dtype),
+                 "gpt_step_pos": sds(self._pos.shape, self._pos.dtype)}
+        specs.update(zip(self._step_vars["cache_feed_names"],
+                         self._cache.specs))
+        return specs
 
     def _fail_live(self, error):
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._retire(i, "error", error=error)
 
+    def _dispatch_failed(self, error):
+        """A step or verify dispatch raised: fail every live stream and
+        keep serving (if the dispatch had consumed the donated cache,
+        :meth:`SlotCache.run` has already replaced it)."""
+        self._bump("step_errors")
+        obs.event("step_error", source="serving", model=self.name,
+                  error="%s: %s" % (type(error).__name__,
+                                    str(error)[:200]))
+        self._fail_live(error)
+
+    def _run_on_cache(self, pred, names, feeds):
+        """:meth:`SlotCache.run`, counting a run that worked on a copy
+        of the cache."""
+        outs, in_place = self._cache.run(pred, names, feeds)
+        if not in_place:
+            self._bump("cache_copy_steps")
+        return outs
+
     def _step(self):
         phase = self._phase_s
+        names = self._step_vars["cache_feed_names"]
+        # the SDC sample is decided BEFORE dispatch: the step consumes
+        # its cache feeds, so what the sentinel re-dispatches on a
+        # sampled replay is a device copy taken now (_tok/_pos mutate at
+        # emission, so they are copied too). Sampled steps only.
+        replay = None
+        if (self._sentinel is not None
+                and self._sentinel.sample(self._sentinel_id)):
+            replay = dict(zip(names, self._cache.snapshot()),
+                          gpt_step_tok=self._tok.copy(),
+                          gpt_step_pos=self._pos.copy())
+            self._bump("cache_copy_steps")
         t0 = time.monotonic()
         sp = obs.span("decode.step.dispatch")
         try:
             with sp:
-                # the feed dict is captured BEFORE dispatch: the run
-                # reassigns self._k/_v (and _tok/_pos mutate only at
-                # emission, below), so these references are exactly the
-                # step's inputs — what the SDC sentinel re-dispatches on
-                # a sampled replay
-                feeds = self._step_feeds()
                 # chaos site: a 'slow' clause stalls the step in place
                 # (it shows up in step_seconds + the ledger, the
                 # autopilot drill's seeded degradation); an exception
@@ -1485,17 +1571,13 @@ class DecodeEngine:
                 R.fault_check("dispatch")
                 if _conc._on:
                     _conc.note_blocking("device.dispatch")
-                outs = self._step_pred.run(feeds, return_numpy=False)
-                if self.kv_dtype == "int8":
-                    (nxt, self._k, self._v, self._kscale,
-                     self._vscale) = outs
-                else:
-                    nxt, self._k, self._v = outs
+                outs = self._run_on_cache(
+                    self._step_pred, names,
+                    {"gpt_step_tok": self._tok,
+                     "gpt_step_pos": self._pos})
+                nxt = outs[0]
         except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
-            self._bump("step_errors")
-            obs.event("step_error", source="serving", model=self.name,
-                      error="%s: %s" % (type(e).__name__, str(e)[:200]))
-            self._fail_live(e)
+            self._dispatch_failed(e)
             return
         finally:
             phase["dispatch_seconds"] += sp.seconds
@@ -1509,12 +1591,10 @@ class DecodeEngine:
         obs.observe("serving.decode.step_seconds", dt)
         self._note_step_measured(dt)
         self._bump("steps")
-        if (self._sentinel is not None
-                and self._sentinel.sample(self._sentinel_id)):
+        if replay is not None:
             ok = self._sentinel.replay_check(
                 self._sentinel_id,
-                lambda: self._step_pred.run(feeds, return_numpy=False),
-                outs, feeds=feeds)
+                lambda: self.sentinel_replay(replay), outs, feeds=replay)
             if not ok:
                 # the step disagreed with its own replay: retire every
                 # live slot BEFORE emission so a possibly-corrupted
@@ -1541,12 +1621,13 @@ class DecodeEngine:
             self._bump("tokens", n)
             self._gauges()
         phase["emit_seconds"] += sp.seconds
-        # the step's inputs die here, and with them the last reference to
-        # the previous cache buffers. jaxlib frees device buffers with the
-        # GIL released, so every stream thread the emit just woke runs
-        # before this thread has the GIL back: a phase of its own
+        # what is left of the step dies here. Until the cache was donated
+        # this dropped the last reference to the previous K/V buffers,
+        # and jaxlib frees device buffers with the GIL released, so every
+        # stream thread the emit just woke ran before this thread had the
+        # GIL back; the span stays so the phase is read as it is now
         with obs.span("decode.step.release") as sp:
-            del feeds, outs, nxt, nxt_np
+            del outs, nxt, nxt_np, replay
         phase["release_seconds"] += sp.seconds
 
     def _spec_step(self):
@@ -1579,16 +1660,16 @@ class DecodeEngine:
             except Exception as e:  # noqa: BLE001 — draft down ≠ engine down
                 proposals, failed = None, e
             if proposals is not None:
-                feeds = {"gpt_vrf_tok": np.concatenate(
-                             [self._tok, proposals], axis=1),
-                         "gpt_vrf_pos": self._pos,
-                         "gpt_vrf_k": self._k, "gpt_vrf_v": self._v}
                 try:
                     R.fault_check("dispatch")
                     if _conc._on:
                         _conc.note_blocking("device.dispatch")
-                    y, self._k, self._v = self._verify_pred.run(
-                        feeds, return_numpy=False)
+                    y = self._run_on_cache(
+                        self._verify_pred,
+                        self._verify_vars["cache_feed_names"],
+                        {"gpt_vrf_tok": np.concatenate(
+                            [self._tok, proposals], axis=1),
+                         "gpt_vrf_pos": self._pos})[0]
                 except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
                     failed = e
         phase["dispatch_seconds"] += sp.seconds
@@ -1601,11 +1682,7 @@ class DecodeEngine:
             self._step()
             return
         if failed is not None:
-            self._bump("step_errors")
-            obs.event("step_error", source="serving", model=self.name,
-                      error="%s: %s" % (type(failed).__name__,
-                                        str(failed)[:200]))
-            self._fail_live(failed)
+            self._dispatch_failed(failed)
             return
         with obs.span("decode.step.sync", spec=True) as sp_sync:
             y = np.asarray(y)                             # (S, k+1)
@@ -1615,9 +1692,6 @@ class DecodeEngine:
         with obs.span("decode.step.emit", spec=True) as sp:
             accepted = self._emit_block(live, k, proposals, y)
         phase["emit_seconds"] += sp.seconds
-        with obs.span("decode.step.release", spec=True) as sp:
-            del feeds  # the previous cache buffers, as in _step
-        phase["release_seconds"] += sp.seconds
         self._bump("spec_rounds")
         self._bump("spec_proposed", k * len(live))
         self._bump("spec_accepted", accepted)
@@ -1693,8 +1767,7 @@ class DecodeEngine:
                                None)
             if kind == "step":
                 prog = self._step_pred.program
-                feeds = {k: np.asarray(v) for k, v in
-                         self._step_feeds().items()}
+                feeds = self._step_specs()
             else:
                 prog = self._prefill_preds[bucket].program
                 feeds = {"gpt_prefill_ids": np.zeros((1, bucket),
@@ -1731,7 +1804,11 @@ class DecodeEngine:
     def stats(self):
         """Local lifetime counters: requests/tokens/prefills/steps/
         retired/shed/deadline_miss/cancelled/prefill_errors/
-        step_errors; and where the dispatch thread's time went, in
+        step_errors; ``cache_copy_steps``, the steps that ran on a copy
+        of the slot cache (an SDC-sampled step keeps one for its replay;
+        any other count says donation did not engage);
+        ``cache_reallocs``, the times a failed dispatch cost the cache;
+        and where the dispatch thread's time went, in
         seconds: ``admit_seconds`` (self time), ``prefill_seconds_total``
         (of it ``prefill_sync_seconds`` waiting for the device),
         ``dispatch_seconds``, ``sync_seconds``, ``emit_seconds``,
@@ -1744,6 +1821,7 @@ class DecodeEngine:
         for k in ("requests", "tokens", "prefills", "adopts", "steps",
                   "retired", "shed", "deadline_miss", "cancelled",
                   "prefill_errors", "adopt_errors", "step_errors",
+                  "cache_copy_steps",
                   "prefill_rows_computed", "prefill_rows_saved",
                   "prefix_full_hits", "delta_prefills", "delta_errors",
                   "spec_rounds", "spec_proposed", "spec_accepted",
@@ -1752,6 +1830,7 @@ class DecodeEngine:
         out["spec_accept_rate"] = (
             out["spec_accepted"] / float(out["spec_proposed"])
             if out["spec_proposed"] else None)
+        out["cache_reallocs"] = self._cache.reallocs
         out["live_slots"] = sum(1 for s in self._slots if s is not None)
         out["slots"] = self.slots
         out["kv_dtype"] = self.kv_dtype

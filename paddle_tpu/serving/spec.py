@@ -58,8 +58,7 @@ class DraftModel:
         self._step_pred = None
         self._prefill_preds = {}
         self._buckets = ()
-        self._k_buf = self._v_buf = None
-        self._write = None
+        self._cache = None
         self.slots = 0
         self.cache_len = 0
 
@@ -73,7 +72,7 @@ class DraftModel:
         import paddle_tpu.fluid as fluid
         from ..fluid.inference import Predictor
         from ..models.gpt import build_gpt_decode_step, build_gpt_prefill
-        from .decode import default_prompt_buckets
+        from .decode import SlotCache, default_prompt_buckets
 
         if self._engine is engine:
             return self
@@ -123,32 +122,26 @@ class DraftModel:
         self._params = persist
         self._step_vars = sv
         self._step_pred = Predictor(
-            step_prog, sv["feed_names"], sv["fetch_vars"], scope=persist)
+            step_prog, sv["feed_names"], sv["fetch_vars"], scope=persist,
+            donate_feeds=sv["cache_feed_names"])
         self._step_pred.ledger_tag = "spec.draft_step:%s" % self.name
         for b, (prog, pv) in prefill.items():
             self._prefill_preds[b] = Predictor(
                 prog, pv["feed_names"], pv["fetch_vars"], scope=persist)
             self._prefill_preds[b].ledger_tag = (
                 "spec.draft_prefill:%s" % self.name)
-        shape = (self.slots, self.cfg.num_layers, self.cache_len,
-                 self.cfg.hidden)
-        self._k_buf = jax.device_put(np.zeros(shape, np.float32))
-        self._v_buf = jax.device_put(np.zeros(shape, np.float32))
-        self._write = jax.jit(
-            lambda buf, val, slot: jax.lax.dynamic_update_slice(
-                buf, val, (slot, 0, 0, 0)),
-            donate_argnums=(0,))
+        # the draft's own slot cache, in the form its step takes it
+        self._cache = SlotCache(jax, self.cfg, self.slots, self.cache_len)
         return self
 
     def warmup(self):
         """Warm every draft program through the compile-cache tier;
         returns the per-program report rows."""
         report = []
-        source = self._step_pred.warm({
-            "gpt_step_tok": np.zeros((self.slots, 1), np.int64),
-            "gpt_step_pos": np.zeros((self.slots, 1), np.int64),
-            "gpt_step_k": np.zeros(self._k_buf.shape, np.float32),
-            "gpt_step_v": np.zeros(self._v_buf.shape, np.float32)})
+        source = self._step_pred.warm(dict(
+            self._cache.feeds(self._step_vars["cache_feed_names"]),
+            gpt_step_tok=np.zeros((self.slots, 1), np.int64),
+            gpt_step_pos=np.zeros((self.slots, 1), np.int64)))
         report.append({"program": "draft_step", "k": self.k,
                        "source": source})
         for b in sorted(self._prefill_preds):
@@ -179,18 +172,17 @@ class DraftModel:
             {"gpt_prefill_ids": ids,
              "gpt_prefill_len": np.asarray([[n]], np.int64)},
             return_numpy=False)
-        slot_i = np.int32(slot)
-        self._k_buf = self._write(self._k_buf, k1, slot_i)
-        self._v_buf = self._write(self._v_buf, v1, slot_i)
+        self._cache.write_slot(slot, k1, v1)
 
     def _step(self, tok, pos):
         if _conc._on:
             _conc.note_blocking("device.dispatch")
-        nxt, self._k_buf, self._v_buf = self._step_pred.run(
-            {"gpt_step_tok": tok, "gpt_step_pos": pos,
-             "gpt_step_k": self._k_buf, "gpt_step_v": self._v_buf},
-            return_numpy=False)
-        return np.asarray(nxt)
+        # a dispatch that fails with the buffers consumed leaves zeroed
+        # rows behind: stale draft rows cost acceptance only
+        outs, _ = self._cache.run(
+            self._step_pred, self._step_vars["cache_feed_names"],
+            {"gpt_step_tok": tok, "gpt_step_pos": pos})
+        return np.asarray(outs[0])
 
     def propose(self, tok, pos):
         """One speculation round from the target's ``(tok, pos)`` slot
@@ -224,8 +216,8 @@ class DraftModel:
         if self._params:
             n += sum(int(np.prod(a.shape)) * a.dtype.itemsize
                      for a in self._params.values())
-        if self._k_buf is not None:
-            n += 2 * int(np.prod(self._k_buf.shape)) * 4
+        if self._cache is not None:
+            n += self._cache.nbytes()
         return n
 
     def info(self):

@@ -144,12 +144,18 @@ def test_gpt_prefill_step_bit_identical_to_generate():
         pf_prog, feed={"gpt_prefill_ids": ids, "gpt_prefill_len": plen},
         fetch_list=[pf["next"], pf["k"], pf["v"]]))
     assert k.shape == (len(lens), cfg.num_layers, cache_len, cfg.hidden)
+    # the step takes the slot cache per layer: (S, T, H) K then V
+    # buffers under st["cache_feed_names"], fetched in the same order
+    cache = [k[:, i] for i in range(cfg.num_layers)] + [
+        v[:, i] for i in range(cfg.num_layers)]
+    assert len(st["cache_feed_names"]) == 2 * cfg.num_layers
     toks, pos = [tok], plen.copy()
     for _ in range(n_new - 1):
-        tok, k, v = map(np.asarray, exe.run(
-            st_prog, feed={"gpt_step_tok": tok, "gpt_step_pos": pos,
-                           "gpt_step_k": k, "gpt_step_v": v},
-            fetch_list=[st["next"], st["k"], st["v"]]))
+        feed = dict(zip(st["cache_feed_names"], cache),
+                    gpt_step_tok=tok, gpt_step_pos=pos)
+        tok, *cache = map(np.asarray, exe.run(
+            st_prog, feed=feed,
+            fetch_list=[st["next"]] + st["k"] + st["v"]))
         toks.append(tok)
         pos = pos + 1
     got = np.concatenate(toks, axis=1)
