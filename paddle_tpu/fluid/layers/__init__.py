@@ -27,6 +27,8 @@ from .detection import *  # noqa: F401,F403
 from . import distributions
 from .distributions import *  # noqa: F401,F403
 from . import device  # noqa: F401
+from . import hybrid
+from .hybrid import *  # noqa: F401,F403
 from . import math_op_patch
 
 math_op_patch.monkey_patch_variable()
@@ -44,3 +46,4 @@ __all__ += sequence_lod.__all__
 __all__ += _rnn_module.__all__
 __all__ += detection.__all__
 __all__ += distributions.__all__
+__all__ += hybrid.__all__

@@ -7,12 +7,84 @@ complement, and the <=pos additive visibility mask. Keeping them (and
 the head-split attention) here means a fix to the cache-write or
 masking logic lands in every decoder at once.
 """
+import collections
+
 import numpy as np
 
 from paddle_tpu.fluid import layers
 
 __all__ = ["attend", "attend_cached", "split_heads", "step_masks",
-           "update_cache"]
+           "update_cache", "StateEntry", "DecodeModel", "require_rows_only"]
+
+
+class StateEntry(collections.namedtuple(
+        "StateEntry", ["name", "shape", "dtype", "kind"])):
+    """One piece of the state a served model carries per sequence: a
+    ``name``, its per-slot ``shape``, its ``dtype`` and its ``kind``:
+
+    - ``"rows"``: one row per position (K or V of an attention layer);
+      the leading axis is the cache length, a prefix of the sequence is
+      the first rows, and cutting at a prefix is zeroing the rest;
+    - ``"fixed"``: a whole value per sequence (a convolution window, a
+      state-space state); it has no rows to cut or to share.
+    """
+
+    __slots__ = ()
+
+    @property
+    def nbytes(self):
+        return int(np.prod(self.shape)) * np.dtype(self.dtype).itemsize
+
+
+class DecodeModel:
+    """What a served model hands ``serving.DecodeEngine``: its program
+    builders and the declaration of the state they carry.
+
+    ``state`` lists the :class:`StateEntry` of every buffer, in the order
+    of the step program's ``cache_feed_names`` (and of its fetches after
+    the first). ``build_prefill(cfg, bucket, cache_len)`` and
+    ``build_step(cfg, cache_len)`` build the two programs into the
+    current default program; ``build_delta`` / ``build_verify`` are the
+    suffix-prefill and block-verify builders of a model that has them
+    (rows-only models). A prefill fetches the first token and then the
+    sequence's state; ``unpack(*fetched)`` turns those fetches into one
+    ``(1,) + entry.shape`` array per entry and ``pack(rows)`` turns one
+    slot's per-entry arrays into the stacked groups of the wire format
+    (both are traced inside one jitted call; identity by default).
+    ``step_counters(aux, live)`` maps the step program's trailing fetch
+    (after the state) to lifetime counters of ``DecodeEngine.stats()``.
+    """
+
+    def __init__(self, cfg, state, build_prefill, build_step,
+                 build_delta=None, build_verify=None, unpack=None,
+                 pack=None, step_counters=None):
+        self.cfg = cfg
+        self.state = list(state)
+        self.build_prefill = build_prefill
+        self.build_step = build_step
+        self.build_delta = build_delta
+        self.build_verify = build_verify
+        self.unpack = unpack or (lambda *vals: list(vals))
+        self.pack = pack or (lambda rows: list(rows))
+        self.step_counters = step_counters
+
+    def slot_bytes(self, kind=None):
+        """Device bytes one slot's state occupies (of one ``kind``)."""
+        return sum(e.nbytes for e in self.state
+                   if kind is None or e.kind == kind)
+
+
+def require_rows_only(model, feature):
+    """Features that cut, share, quantise or ship a sequence's state row
+    by row cannot hold a ``fixed`` entry: refuse, do not emulate."""
+    fixed = [e.name for e in model.state if e.kind == "fixed"]
+    if fixed:
+        raise ValueError(
+            "%s needs state with one row per position; this model also "
+            "carries a fixed-size state per sequence (%s%s), which has no "
+            "prefix to cut, share or roll back"
+            % (feature, ", ".join(fixed[:3]),
+               ", ..." if len(fixed) > 3 else ""))
 
 
 def split_heads(t, heads, dh):

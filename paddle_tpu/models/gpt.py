@@ -37,6 +37,43 @@ class GPTConfig:
         self.max_len = max_len
         self.dropout = dropout
 
+    def decode_model(self, cache_len, kv_dtype="fp32"):
+        """This model as ``serving.DecodeEngine`` takes it: per layer one
+        K and one V of ``(cache_len, hidden)`` rows (all K layers, then
+        all V layers; int8 residency adds a float32 scale per row and
+        buffer), with the builders of this file."""
+        from .decode_utils import DecodeModel, StateEntry
+
+        if kv_dtype not in ("fp32", "int8"):
+            raise ValueError("kv_dtype must be 'fp32' or 'int8', got %r"
+                             % (kv_dtype,))
+        nl = int(self.num_layers)
+        groups = [("k", int(self.hidden), np.float32),
+                  ("v", int(self.hidden), np.float32)]
+        if kv_dtype == "int8":
+            groups = [("k", int(self.hidden), np.int8),
+                      ("v", int(self.hidden), np.int8),
+                      ("k_scale", 1, np.float32), ("v_scale", 1, np.float32)]
+        state = [StateEntry("%s_%d" % (g, i), (int(cache_len), w), dt, "rows")
+                 for g, w, dt in groups for i in range(nl)]
+
+        def unpack(*stacked):
+            # per group one (1, layers, cache_len, width) array
+            return [g[:, i] for g in stacked for i in range(nl)]
+
+        def pack(rows):
+            import jax.numpy as jnp
+
+            return [jnp.stack(rows[g:g + nl])
+                    for g in range(0, len(rows), nl)]
+
+        return DecodeModel(
+            self, state, build_gpt_prefill,
+            build_gpt_decode_step_q if kv_dtype == "int8"
+            else build_gpt_decode_step,
+            build_delta=build_gpt_prefill_delta,
+            build_verify=build_gpt_verify_block, unpack=unpack, pack=pack)
+
 
 def gpt_tiny(vocab=211, max_len=64):
     return GPTConfig(vocab=vocab, hidden=32, num_layers=2, heads=2,

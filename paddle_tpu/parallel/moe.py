@@ -22,10 +22,16 @@ module path:
 
 ``moe_ep_rules(name)`` gives the ShardingRule patterns for the expert
 dim; on a mesh without 'ep' the same program runs replicated.
+
+:func:`held_experts_ffn` is the other way to spread experts: one chip's
+share of an expert-parallel layer as a program of its own. The router
+(``layers.moe_route_topk``) keeps its published width and its experts per
+token; the layer is told the contiguous range of experts it holds and
+computes their part of the result, with no capacity and no dropped token.
 """
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["switch_ffn", "moe_ep_rules"]
+__all__ = ["switch_ffn", "moe_ep_rules", "held_experts_ffn"]
 
 
 def switch_ffn(x, num_experts, d_ff, capacity_factor=1.25, act="gelu",
@@ -109,6 +115,50 @@ def switch_ffn(x, num_experts, d_ff, capacity_factor=1.25, act="gelu",
         layers.reduce_sum(layers.elementwise_mul(frac, mprob)),
         scale=float(E))
     return y, aux
+
+
+def held_experts_ffn(x, index, weight, held, d_ff, name, live=None):
+    """The held experts' part of a routed layer (squared-ReLU experts, no
+    gate, no bias): ``sum over the chosen experts e in [held[0], held[0]
+    + held[1]) of weight_e * W2_e relu(W1_e x)^2``.
+
+    ``x`` (T, D); ``index``/``weight`` (T, k) from a router over ALL the
+    layer's experts, the weights already normalised over all k chosen, so
+    a token whose experts lie on other chips adds nothing from them and
+    the shares of all chips sum to the whole layer. ``live`` (T, 1) masks
+    rows that carry no token (a dead decode slot). The two products are
+    grouped by expert (``ops.hybrid_ops.grouped_dot``: the Pallas kernel
+    ``gmm`` on the TPU, ``lax.ragged_dot`` elsewhere): their work grows
+    with the assignments that land here. On one chip the layer runs
+    without its exchange; nothing stands in for the absent chips.
+
+    Parameters ``<name>.w1`` (held, D, d_ff), ``<name>.w2`` (held, d_ff,
+    D). Returns ``(out (T, D), counts)``: ``counts`` is int32
+    ``[assignments that landed on held experts, the largest count on one
+    held expert, held experts that got any]``."""
+    from ..fluid.layer_helper import LayerHelper
+    from ..fluid.param_attr import ParamAttr
+
+    first, count = int(held[0]), int(held[1])
+    d = int(x.shape[-1])
+    helper = LayerHelper("held_experts_ffn")
+    inputs = {"X": [x], "Index": [index], "Weight": [weight],
+              "W1": [helper.create_parameter(
+                  ParamAttr(name=name + ".w1"), [count, d, int(d_ff)],
+                  x.dtype)],
+              "W2": [helper.create_parameter(
+                  ParamAttr(name=name + ".w2"), [count, int(d_ff), d],
+                  x.dtype)]}
+    if live is not None:
+        inputs["Live"] = [live]
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = tuple(x.shape)
+    counts = helper.create_variable_for_type_inference("int32")
+    counts.shape = (3,)
+    helper.append_op(type="held_experts_ffn", inputs=inputs,
+                     outputs={"Out": [out], "Counts": [counts]},
+                     attrs={"first_expert": first})
+    return out, counts
 
 
 def moe_ep_rules(name="moe"):

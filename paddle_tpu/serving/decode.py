@@ -7,13 +7,17 @@ is autoregressive *decode*, where a full-batch ``lax.scan`` generator
 wait for the slowest sequence in its batch and admits nothing
 mid-generation. This engine removes the full-batch barrier:
 
-- **Slotted KV cache** — ONE pre-allocated set of device buffers, per
-  layer a ``(slots, cache_len, heads*dh)`` K and V (:class:`SlotCache`),
-  holds every live sequence's keys/values. Every program that takes the
-  buffers is given them **donated** and updates them in place: a step
-  writes one row per slot and layer and copies nothing. A slot is a
-  sequence's home for its whole generation; retiring frees the slot the
-  same step.
+- **Slotted state** — ONE pre-allocated set of device buffers
+  (:class:`SlotCache`) holds every live sequence's state, as the served
+  model declares it (``models.decode_utils.DecodeModel.state``): for an
+  attention layer a ``(slots, cache_len, width)`` K and V (``rows``, one
+  per position), for a state-space layer a convolution window and a
+  recurrent state per slot (``fixed``, a whole value per sequence), for
+  an expert layer nothing. Every program that takes the buffers is given
+  them **donated** and updates them in place: a step writes one row per
+  slot and attention layer, overwrites each fixed state, and copies
+  nothing. A slot is a sequence's home for its whole generation;
+  retiring frees the slot the same step.
 - **Two programs, both AOT** — a *prefill* program per declared prompt
   bucket (parallel pass over the right-padded prompt writes a slot's
   cache and emits the first token) and ONE *step* program (one token
@@ -101,6 +105,7 @@ from .. import observability as obs
 from ..analysis import concurrency as _conc
 from ..analysis import dataflow as _dataflow
 from ..fluid import resilience as R
+from ..models.decode_utils import require_rows_only
 from .engine import DeadlineExceededError, EngineClosedError, ShedError
 
 __all__ = ["DecodeEngine", "DecodeStream", "SlotCache",
@@ -114,18 +119,13 @@ _stream_ids = itertools.count(1)
 
 
 def kv_slot_bytes(cfg, cache_len, kv_dtype="fp32"):
-    """HBM bytes ONE decode slot's KV cache pair occupies — the slot
-    economics `disagg` trades on: int8 residency pays 1 byte/element
-    plus one fp32 scale per (layer, row) instead of 4 bytes/element,
-    so slots-per-budget multiplies by ~4 (3.9x at hidden 32+)."""
-    if kv_dtype not in ("fp32", "int8"):
-        raise ValueError("kv_dtype must be 'fp32' or 'int8', got %r"
-                         % (kv_dtype,))
-    n = int(cfg.num_layers) * int(cache_len) * int(cfg.hidden)
-    if kv_dtype == "int8":
-        rows = int(cfg.num_layers) * int(cache_len)
-        return 2 * (n + rows * 4)
-    return 2 * n * 4
+    """HBM bytes ONE decode slot's state occupies: the sum of the model's
+    own declaration (``cfg.decode_model(cache_len, kv_dtype).state``),
+    whatever its entries are. For a model of K/V rows alone this is the
+    slot economics `disagg` trades on: int8 residency pays 1 byte/element
+    plus one fp32 scale per (layer, row) instead of 4 bytes/element, so
+    slots-per-budget multiplies by ~4 (3.9x at hidden 32+)."""
+    return cfg.decode_model(int(cache_len), kv_dtype).slot_bytes()
 
 
 def default_prompt_buckets(cache_len, smallest=8):
@@ -141,47 +141,44 @@ def default_prompt_buckets(cache_len, smallest=8):
 
 
 class SlotCache:
-    """The slot KV cache in the form the step programs take it, and its
-    ONE owner: per layer a ``(slots, cache_len, hidden)`` device buffer
-    of K and one of V (int8 residency: int8 payloads plus a ``(slots,
-    cache_len, 1)`` fp32 scale buffer for each), kept as the flat list
-    ``bufs`` in the order of a step program's ``cache_feed_names`` (all
-    K layers, all V layers, then the K and V scales).
+    """The slots' state in the form the step programs take it, and its
+    ONE owner: for every :class:`~paddle_tpu.models.decode_utils.
+    StateEntry` the served model declares, a ``(slots,) + entry.shape``
+    device buffer of ``entry.dtype``, kept as the flat list ``bufs`` in
+    the declaration's order, which is the order of a step program's
+    ``cache_feed_names``. ``rows`` entries (K and V of an attention
+    layer) and ``fixed`` entries (a convolution window, a state-space
+    state) live side by side; allocation, donation, :meth:`write_slot`
+    and :meth:`read_slot` treat them alike.
 
     Every program that takes the buffers consumes them
     (``Predictor(donate_feeds=...)``) and hands them back updated in
     place (:meth:`run`). A reference to a buffer held anywhere else is
     dead after the next step, so nothing outside this class keeps one:
-    a sequence goes in through :meth:`write_slot`
-    (one donated dispatch for all layers) and comes out, as host
-    arrays in the ``(layers, cache_len, hidden)`` geometry of the wire
-    format and the prefix pool, through :meth:`read_slot`."""
+    a sequence goes in through :meth:`write_slot` (one donated dispatch
+    for all entries, every entry written whole, so nothing of the slot's
+    last sequence is left behind) and comes out as host arrays, in the
+    model's packed form (``DecodeModel.pack``: for K/V rows the
+    ``(layers, cache_len, width)`` geometry of the wire format and the
+    prefix pool), through :meth:`read_slot`."""
 
-    def __init__(self, jax, cfg, slots, cache_len, kv_dtype="fp32"):
+    def __init__(self, jax, model, slots):
         self._jax = jax
-        self.layers = int(cfg.num_layers)
-        payload = ((int(slots), int(cache_len), int(cfg.hidden)),
-                   np.int8 if kv_dtype == "int8" else np.float32)
-        groups = [payload, payload]
-        if kv_dtype == "int8":
-            scale = ((int(slots), int(cache_len), 1), np.float32)
-            groups += [scale, scale]
-        self.specs = [jax.ShapeDtypeStruct(shape, dtype)
-                      for shape, dtype in groups
-                      for _ in range(self.layers)]
-        nl = self.layers
+        self._model, self.slots = model, int(slots)
+        self.specs = [jax.ShapeDtypeStruct((self.slots,) + tuple(e.shape),
+                                           e.dtype) for e in model.state]
+        unpack, pack = model.unpack, model.pack
 
         def write(bufs, vals, slot):
-            # vals: per group one (1, layers, cache_len, width) array
+            # vals: what a prefill fetched after its token (or the same
+            # form from the host); unpack -> one (1,) + shape per entry
             return [jax.lax.dynamic_update_slice(
-                        b, vals[j // nl][:, j % nl], (slot, 0, 0))
-                    for j, b in enumerate(bufs)]
+                        b, v.astype(b.dtype), (slot,) + (0,) * (b.ndim - 1))
+                    for b, v in zip(bufs, unpack(*vals))]
 
         def read(bufs, slot):
-            rows = [jax.lax.dynamic_index_in_dim(b, slot, 0, False)
-                    for b in bufs]
-            return [jax.numpy.stack(rows[g:g + nl])
-                    for g in range(0, len(rows), nl)]
+            return pack([jax.lax.dynamic_index_in_dim(b, slot, 0, False)
+                         for b in bufs])
 
         # the slot index is a traced scalar: each compiles once
         self._write = jax.jit(write, donate_argnums=(0,))
@@ -222,17 +219,19 @@ class SlotCache:
                 self.allocate()
                 self.reallocs += 1
             raise
-        self.bufs = list(outs[1:])
+        self.bufs = list(outs[1:1 + len(fed)])
         return outs, fed[0].is_deleted()
 
     def write_slot(self, slot, *vals):
-        """Install one sequence: per group (k, v[, k_scale, v_scale]) a
-        ``(1, layers, cache_len, width)`` array in the residency dtype."""
+        """Install one sequence's whole state: ``vals`` as a prefill
+        fetched them after its token (for K/V rows alone: per group
+        (k, v[, k_scale, v_scale]) a ``(1, layers, cache_len, width)``
+        array), in the residency dtype."""
         self.bufs = self._write(self.bufs, list(vals), np.int32(slot))
 
     def read_slot(self, slot):
-        """One slot's rows as host arrays ``(layers, cache_len, width)``,
-        one per group."""
+        """One slot's state as host arrays in the model's packed form
+        (K/V rows: ``(layers, cache_len, width)``, one per group)."""
         return [np.asarray(a)
                 for a in self._read(self.bufs, np.int32(slot))]
 
@@ -241,9 +240,9 @@ class SlotCache:
         since the step consumes the originals)."""
         return [self._jax.numpy.copy(b) for b in self.bufs]
 
-    def nbytes(self):
-        return sum(int(np.prod(sp.shape)) * np.dtype(sp.dtype).itemsize
-                   for sp in self.specs)
+    def nbytes(self, kind=None):
+        """Device bytes of all buffers (of entries of one ``kind``)."""
+        return self.slots * self._model.slot_bytes(kind)
 
 
 class DecodeStream:
@@ -375,8 +374,11 @@ class _Slot:
 
 class DecodeEngine:
     """Continuous-batching decode engine over a prefill/step program
-    pair (GPT-family by default; any builder pair with the same feed/
-    fetch contract plugs in via ``build_prefill``/``build_step``).
+    pair. The model hands over its builders and the declaration of the
+    state it carries (``cfg.decode_model(cache_len, kv_dtype)``, a
+    :class:`~paddle_tpu.models.decode_utils.DecodeModel`); another
+    builder pair with the same feed/fetch contract plugs in via
+    ``build_prefill``/``build_step``.
 
     ::
 
@@ -391,7 +393,17 @@ class DecodeEngine:
     dict); :meth:`from_dir` loads a ``save_persistables`` /
     ``save_inference_model`` directory. Params are device_put ONCE and
     shared by every program (prefill buckets + step), not duplicated
-    per predictor."""
+    per predictor. ``adopt_params=True`` takes the scope's device arrays
+    as the engine's own instead of copying each through the host: for a
+    caller that made them on the device and hands them over (it must not
+    donate or change them afterwards).
+
+    A model that carries ``fixed`` state (a state-space layer) cannot be
+    combined with what cuts, shares, quantises or ships state row by
+    row: ``prefix_pool``, ``session_tier``, ``kv_dtype="int8"``, a
+    ``draft`` (block verify with roll-back), ``role="decode"`` /
+    :meth:`submit_prefilled` (the KV wire). Each is refused at
+    construction."""
 
     engine_kind = "decode"
 
@@ -402,7 +414,8 @@ class DecodeEngine:
                  barrier=False, auto_start=True,
                  build_prefill=None, build_step=None,
                  kv_dtype="fp32", role="colocated",
-                 draft=None, prefix_pool=None, session_tier=None):
+                 draft=None, prefix_pool=None, session_tier=None,
+                 adopt_params=False):
         import jax
 
         import paddle_tpu.fluid as fluid
@@ -425,15 +438,22 @@ class DecodeEngine:
                 "prefix_pool/session_tier need the delta-prefill "
                 "program a pure decode-role replica does not build — "
                 "attach them to the router's prefill side instead")
-        if build_prefill is None or build_step is None:
-            from ..models.gpt import (build_gpt_decode_step,
-                                      build_gpt_decode_step_q,
-                                      build_gpt_prefill)
-
-            build_prefill = build_prefill or build_gpt_prefill
-            build_step = build_step or (
-                build_gpt_decode_step_q if kv_dtype == "int8"
-                else build_gpt_decode_step)
+        # the model's own declaration: builders and per-slot state
+        model = cfg.decode_model(int(cache_len), kv_dtype)
+        for feature, on in (
+                ("prefix_pool (prefix reuse)", prefix_pool is not None),
+                ("session_tier (hibernation + delta prefill)",
+                 session_tier is not None),
+                ("kv_dtype='int8'", kv_dtype == "int8"),
+                ("draft (speculative verify with roll-back)",
+                 draft is not None),
+                ("role='decode' (KV hand-over on the wire)",
+                 role == "decode")):
+            if on:
+                require_rows_only(model, feature)
+        build_prefill = build_prefill or model.build_prefill
+        build_step = build_step or model.build_step
+        self._model = model
         self._jax = jax
         self.cfg = cfg
         self.name = str(name)
@@ -475,21 +495,16 @@ class DecodeEngine:
         # same bucket widths as cold prefill, suffix-sized at use
         delta = {}
         if prefix_pool is not None or session_tier is not None:
-            from ..models.gpt import build_gpt_prefill_delta
-
             for b in self.prompt_buckets:
                 with fluid.program_guard(fluid.Program(), fluid.Program()):
-                    dv = build_gpt_prefill_delta(cfg, b, self.cache_len)
+                    dv = model.build_delta(cfg, b, self.cache_len)
                     delta[b] = (fluid.default_main_program(), dv)
         # block-verify program (speculative decoding): k proposals +
         # the slot's current token = a k+1 wide block per dispatch
         verify = None
         if draft is not None:
-            from ..models.gpt import build_gpt_verify_block
-
             with fluid.program_guard(fluid.Program(), fluid.Program()):
-                vv = build_gpt_verify_block(cfg, draft.k + 1,
-                                            self.cache_len)
+                vv = model.build_verify(cfg, draft.k + 1, self.cache_len)
                 verify = (fluid.default_main_program(), vv)
         persist = {}
         all_progs = ([step_prog] + [p for p, _ in prefill.values()]
@@ -506,12 +521,18 @@ class DecodeEngine:
                         "param %r required by the decode programs is "
                         "missing from the given scope — train the model "
                         "or load its persistables first" % v.name)
+                val = scope[v.name]
+                if adopt_params and isinstance(val, jax.Array):
+                    # handed over as owned: no second copy of weights
+                    # that were made on the device
+                    persist[v.name] = val
+                    continue
                 # snapshot through the host: device_put on a committed
                 # jax array is a no-op, and sharing the training
                 # executor's buffers would let its donating step
                 # invalidate them under this engine mid-serve
-                persist[v.name] = jax.device_put(np.asarray(scope[v.name]))
-        if _conc._on:
+                persist[v.name] = jax.device_put(np.asarray(val))
+        if _conc._on and not adopt_params:
             # the copy above breaks aliasing with the training executor's
             # donated buffers — register it so the donation registry can
             # prove (not assume) no cross-program alias survives
@@ -520,6 +541,8 @@ class DecodeEngine:
                                    snapshot=True)
         self._params = persist
         self._step_vars = step_vars
+        # the feeds every step and prefill program has beside the state
+        self._tok_name, self._pos_name = step_vars["feed_names"][:2]
         # each program has a module name of its own in a device trace
         # (jit_fwd_decode_step, jit_fwd_prefill_<bucket>, ...); every
         # name keeps "fwd"
@@ -555,8 +578,10 @@ class DecodeEngine:
             self._verify_pred.ledger_tag = "decode.verify:%s" % self.name
 
         # -- the persistent slot cache + host-side slot state ----------
-        self._cache = SlotCache(jax, cfg, self.slots, self.cache_len,
-                                self.kv_dtype)
+        self._cache = SlotCache(jax, model, self.slots)
+        for kind in ("rows", "fixed"):
+            obs.set_gauge("serving.decode.state_bytes_%s.%s"
+                          % (kind, self.name), self._cache.nbytes(kind))
         self._tok = np.zeros((self.slots, 1), np.int64)
         self._pos = np.zeros((self.slots, 1), np.int64)
         self._slots = [None] * self.slots
@@ -862,6 +887,8 @@ class DecodeEngine:
         if self._closed:
             raise EngineClosedError(
                 "engine %r is draining/stopped" % self.name)
+        require_rows_only(self._model, "submit_prefilled (KV hand-over on "
+                          "the wire)")
         expect = (self.cfg.num_layers, self.cache_len, self.cfg.hidden)
         if tuple(handoff.shape) != expect:
             raise ValueError(
@@ -1019,14 +1046,13 @@ class DecodeEngine:
                        "cache_len": self.cache_len,
                        "kv_dtype": self.kv_dtype, "source": source})
         for b in sorted(self._prefill_preds):
-            source = self._prefill_preds[b].warm({
-                "gpt_prefill_ids": np.zeros((1, b), np.int64),
-                "gpt_prefill_len": np.ones((1, 1), np.int64)})
+            source = self._prefill_preds[b].warm(self._prefill_feeds(
+                np.zeros((1, b), np.int64), 1, b))
             report.append({"program": "prefill", "bucket": b,
                            "source": source})
-        cache1 = (1, self.cfg.num_layers, self.cache_len,
-                  self.cfg.hidden)
         for b in sorted(self._delta_preds):
+            cache1 = (1, self.cfg.num_layers, self.cache_len,
+                      self.cfg.hidden)
             source = self._delta_preds[b].warm({
                 "gpt_dpre_ids": np.zeros((1, b), np.int64),
                 "gpt_dpre_len": np.ones((1, 1), np.int64),
@@ -1236,15 +1262,19 @@ class DecodeEngine:
         obs.observe("serving.decode.ttft_seconds",
                     time.monotonic() - req.handle.t_submit)
 
+    def _prefill_feeds(self, ids, plen, bucket):
+        """A prefill program's two feeds under its own names."""
+        names = self._prefill_vars[bucket]["feed_names"]
+        return {names[0]: ids, names[1]: np.asarray([[plen]], np.int64)}
+
     def _prefill(self, slot, req, sp):
         ids = np.zeros((1, req.bucket), np.int64)
         ids[0, :req.plen] = req.prompt
-        plen = np.asarray([[req.plen]], np.int64)
         try:
             if _conc._on:
                 _conc.note_blocking("device.dispatch")
-            nxt, k1, v1 = self._prefill_preds[req.bucket].run(
-                {"gpt_prefill_ids": ids, "gpt_prefill_len": plen},
+            nxt, *state = self._prefill_preds[req.bucket].run(
+                self._prefill_feeds(ids, req.plen, req.bucket),
                 return_numpy=False)
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
             sp.note(error=type(e).__name__)
@@ -1258,17 +1288,19 @@ class DecodeEngine:
             # way into the resident buffers (same codec as the wire)
             from .disagg import kv_wire
 
+            k1, v1 = state
             kq, ks = kv_wire.quantize_rows(np.asarray(k1)[0])
             vq, vs = kv_wire.quantize_rows(np.asarray(v1)[0])
             self._cache.write_slot(slot, kq[None], vq[None],
                                    ks[None], vs[None])
         else:
-            self._cache.write_slot(slot, k1, v1)
+            self._cache.write_slot(slot, *state)
         tok = self._first_token(nxt)
         self._bump("prefill_rows_computed", req.bucket)
         if self._prefix_pool is not None:
             # bank this prompt's rows (fp32, pre-residency) so the
             # next shared-prefix request adopts instead of recomputing
+            k1, v1 = state
             try:
                 self._prefix_pool.put(req.prompt, np.asarray(k1),
                                       np.asarray(v1), next_token=tok)
@@ -1516,8 +1548,8 @@ class DecodeEngine:
         """The step program's feed signature, described (for a compile
         or a cost estimate: nothing is allocated or read)."""
         sds = self._jax.ShapeDtypeStruct
-        specs = {"gpt_step_tok": sds(self._tok.shape, self._tok.dtype),
-                 "gpt_step_pos": sds(self._pos.shape, self._pos.dtype)}
+        specs = {self._tok_name: sds(self._tok.shape, self._tok.dtype),
+                 self._pos_name: sds(self._pos.shape, self._pos.dtype)}
         specs.update(zip(self._step_vars["cache_feed_names"],
                          self._cache.specs))
         return specs
@@ -1555,9 +1587,9 @@ class DecodeEngine:
         replay = None
         if (self._sentinel is not None
                 and self._sentinel.sample(self._sentinel_id)):
-            replay = dict(zip(names, self._cache.snapshot()),
-                          gpt_step_tok=self._tok.copy(),
-                          gpt_step_pos=self._pos.copy())
+            replay = dict(zip(names, self._cache.snapshot()))
+            replay[self._tok_name] = self._tok.copy()
+            replay[self._pos_name] = self._pos.copy()
             self._bump("cache_copy_steps")
         t0 = time.monotonic()
         sp = obs.span("decode.step.dispatch")
@@ -1573,8 +1605,8 @@ class DecodeEngine:
                     _conc.note_blocking("device.dispatch")
                 outs = self._run_on_cache(
                     self._step_pred, names,
-                    {"gpt_step_tok": self._tok,
-                     "gpt_step_pos": self._pos})
+                    {self._tok_name: self._tok,
+                     self._pos_name: self._pos})
                 nxt = outs[0]
         except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
             self._dispatch_failed(e)
@@ -1584,6 +1616,13 @@ class DecodeEngine:
         with obs.span("decode.step.sync") as sp:
             nxt_np = np.asarray(nxt)
         phase["sync_seconds"] += sp.seconds
+        if self._model.step_counters is not None:
+            # what the step program counted on the device (its fetch
+            # after the state), into the lifetime counters
+            live = sum(1 for s in self._slots if s is not None)
+            for key, n in self._model.step_counters(
+                    np.asarray(outs[1 + len(names)]), live).items():
+                self._bump(key, int(n))
         # the step's latency on the host: enqueue + the wait for its
         # tokens (what the ledger's drift score and the autopilot's
         # calibration read as the measured step time)
@@ -1770,9 +1809,8 @@ class DecodeEngine:
                 feeds = self._step_specs()
             else:
                 prog = self._prefill_preds[bucket].program
-                feeds = {"gpt_prefill_ids": np.zeros((1, bucket),
-                                                     np.int64),
-                         "gpt_prefill_len": np.ones((1, 1), np.int64)}
+                feeds = self._prefill_feeds(
+                    np.zeros((1, bucket), np.int64), 1, bucket)
             pred = _costs.predict_program(
                 prog, feed_specs=feeds, is_test=True,
                 device_kind=kind_dev)
@@ -1832,6 +1870,8 @@ class DecodeEngine:
             if out["spec_proposed"] else None)
         out["cache_reallocs"] = self._cache.reallocs
         out["live_slots"] = sum(1 for s in self._slots if s is not None)
+        out["state_bytes_rows"] = self._cache.nbytes("rows")
+        out["state_bytes_fixed"] = self._cache.nbytes("fixed")
         out["slots"] = self.slots
         out["kv_dtype"] = self.kv_dtype
         out["role"] = self.role
@@ -1866,9 +1906,9 @@ class DecodeEngine:
         }
 
     def slot_bytes(self):
-        """HBM bytes one slot's resident KV pair occupies (see
-        :func:`kv_slot_bytes`)."""
-        return kv_slot_bytes(self.cfg, self.cache_len, self.kv_dtype)
+        """HBM bytes one slot's resident state occupies: the sum of the
+        model's declaration (see :func:`kv_slot_bytes`)."""
+        return self._model.slot_bytes()
 
     def queue_depth(self):
         return self._q.qsize()

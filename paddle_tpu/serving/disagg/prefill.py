@@ -114,6 +114,13 @@ class PrefillEngine:
             from ...models.gpt import build_gpt_prefill
 
             build_prefill = build_gpt_prefill
+        if hasattr(cfg, "decode_model"):
+            # the hand-over on the wire is K/V rows: a model that also
+            # carries a fixed-size state per sequence cannot ship it
+            from ...models.decode_utils import require_rows_only
+
+            require_rows_only(cfg.decode_model(int(cache_len)),
+                              "PrefillEngine (KV hand-over on the wire)")
         self.cfg = cfg
         self.name = str(name)
         self.cache_len = int(cache_len)

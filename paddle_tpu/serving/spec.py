@@ -131,7 +131,8 @@ class DraftModel:
             self._prefill_preds[b].ledger_tag = (
                 "spec.draft_prefill:%s" % self.name)
         # the draft's own slot cache, in the form its step takes it
-        self._cache = SlotCache(jax, self.cfg, self.slots, self.cache_len)
+        self._cache = SlotCache(
+            jax, self.cfg.decode_model(self.cache_len), self.slots)
         return self
 
     def warmup(self):

@@ -1,0 +1,82 @@
+"""The hybrid decode step at its published widths, compiled for a described
+TPU v5e (no chip attached, nothing runs): the chip's compiler accepts it,
+every declared state buffer is aliased to its fetch (updated in place, no
+second copy of 2.86 GB), and the arguments fit one chip's memory.
+
+The topology is described inside a fixture: only the worker that runs this
+file loads the TPU's library. A compile that passes is not a chip run."""
+import json
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_step_at_published_widths_aliases_all_its_state(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import nemotron_h as nh
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "nemotron_3_super_120b_a12b.json")))
+    cfg = nh.NemotronHConfig.from_hf(
+        doc, router_experts=doc["reduced_from"]["n_routed_experts"])
+    slots, cache_len = doc["serving"]["slots"], doc["serving"]["cache_len"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = nh.build_step(cfg, cache_len)
+        prog = fluid.default_main_program()
+    step = build_step_fn(prog, v["feed_names"],
+                         [x.name for x in v["fetch_vars"]], is_test=True,
+                         platform="tpu")
+    names = v["cache_feed_names"]
+    decl = cfg.decode_model(cache_len).state
+
+    def fwd(state, feeds, donated):
+        feeds = dict(feeds)
+        feeds.update(zip(names, donated))
+        return step(state, feeds, jax.random.PRNGKey(0))[0]
+
+    params = {k: sds(s, d) for k, (s, d) in nh.param_shapes(cfg).items()}
+    feeds = {"nh_step_tok": sds((slots, 1), "int32"),
+             "nh_step_pos": sds((slots, 1), "int32")}
+    donated = tuple(sds((slots,) + tuple(e.shape), e.dtype) for e in decl)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(fwd, donate_argnums=(2,)).lower(
+            params, feeds, donated).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    mem = compiled.memory_analysis()
+    state_bytes = slots * sum(e.nbytes for e in decl)
+    assert mem.alias_size_in_bytes >= state_bytes       # all 12, in place
+    assert mem.temp_size_in_bytes < 256e6               # nothing state-sized
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 11e9 < held < 14e9                           # of the chip's 16 GB
+    weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in params.values())
+    assert round(weights / 1e9, 1) == 9.3
