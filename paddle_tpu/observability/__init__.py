@@ -43,18 +43,29 @@ hot paths:
 
 - ``executor.run`` ⊃ ``executor.feed_convert`` / ``executor.
   device_compute`` (the enqueue) / ``executor.fetch``.
-- the decode engine's dispatch thread, per loop iteration:
-  ``decode.loop.admit`` ⊃ ``decode.prefill`` (one per request filled;
-  ``decode.adopt`` for a remote handoff) ⊃ ``decode.prefill.sync``;
-  ``decode.step.dispatch`` / ``decode.step.sync`` / ``decode.step.
-  emit`` / ``decode.step.release`` (the step's inputs dropped: device
+- the decode engine's dispatch thread, per loop iteration, in the
+  loop's order (it is pipelined by one step: step n+1 is dispatched
+  before step n's tokens are delivered, so the stream threads run while
+  the device does): ``decode.loop.admit`` ⊃ ``decode.prefill`` (one
+  per request filled; ``decode.adopt`` for a remote handoff) ⊃
+  ``decode.prefill.sync``; ``decode.step.dispatch`` (step n+1);
+  ``decode.step.emit`` (step n's tokens and ends handed to their
+  streams: the first wake-up of a stream thread since the sync);
+  ``decode.step.release`` (what is left of step n dropped: device
   buffers are freed with the GIL released, so the thread waits there
-  behind the stream threads the emit woke); ``decode.loop.idle`` (one
-  per idle stretch). Their exits add to ``DecodeEngine.stats()``:
+  behind the stream threads the emit woke, while the device runs step
+  n+1); ``decode.step.sync`` (the wait for step n+1's tokens);
+  ``decode.step.decide`` (from those tokens and without touching a
+  stream: the next feeds, which slots finish and are freed, what a
+  retirement reads of the cache); ``decode.loop.idle`` (one per idle
+  stretch). Their exits add to ``DecodeEngine.stats()``:
   ``admit_seconds`` (self time), ``prefill_seconds_total``,
   ``prefill_sync_seconds`` (a part of it), ``dispatch_seconds``,
-  ``sync_seconds``, ``emit_seconds``, ``release_seconds``,
-  ``idle_seconds`` — the seven phases sum to the thread's wall time.
+  ``emit_seconds`` (``emit`` and ``decide``), ``release_seconds``,
+  ``sync_seconds``, ``idle_seconds`` — the seven phases sum to the
+  thread's wall time. ``steps_ahead`` in ``stats()`` (counter
+  ``serving.decode.steps_ahead``) counts the steps dispatched while the
+  step before's tokens were still undelivered.
 - per request, sharing ``request=<DecodeStream.id>``: ``http.generate``
   (first body byte to terminating chunk; ``status``, ``tokens``,
   ``first_byte_s``), ``decode.queue`` (submit to the start of its
@@ -134,10 +145,11 @@ Well-known decode-serving metrics (PR 9, ``serving.decode``):
   this near 1.0 under load); ``serving.decode.cache_occupancy.<engine>``
   gauge — filled KV rows / (slots × cache_len).
 - ``serving.decode.step_seconds`` histogram — one decode step's
-  latency on the host: ``decode.step.dispatch`` + ``decode.step.sync``,
-  enqueue to tokens on the host (also the measured step time the
-  executable ledger's drift score reads; until PR 25 it stopped at the
-  enqueue). ``serving.decode.prefill_seconds`` — one slot fill from
+  latency on the host, from the start of its ``decode.step.dispatch``
+  to the end of its ``decode.step.sync``, enqueue to tokens on the host;
+  the delivery of the step before (``emit``, ``release``) lies between
+  the two (also the measured step time the executable ledger's drift
+  score reads; until PR 25 it stopped at the enqueue). ``serving.decode.prefill_seconds`` — one slot fill from
   the start of its span to its first token on the host (device wait
   included). ``ttft_seconds`` — submit to first emit inside the
   engine; ``request_seconds`` — submit to retire.

@@ -28,9 +28,12 @@ mid-generation. This engine removes the full-batch barrier:
 - **Continuous batching** — a single dispatch thread interleaves the
   two: finished sequences (EOS or max-new) retire in-flight and queued
   requests are prefilled into freed slots between steps; the other
-  slots never stall on a barrier. Per-request tokens are bit-identical
-  to a solo ``build_gpt_generate`` run (row independence + per-slot
-  masks), which the tests assert token-for-token.
+  slots never stall on a barrier. The loop is pipelined by one step:
+  step n+1 is dispatched before step n's tokens are delivered, so the
+  stream threads' work per token overlaps the device's step.
+  Per-request tokens are bit-identical to a solo
+  ``build_gpt_generate`` run (row independence + per-slot masks),
+  which the tests assert token-for-token.
 - **Streaming** — ``submit()`` returns a :class:`DecodeStream` whose
   ``tokens()`` generator yields each token as the step loop produces
   it; ``serving.http`` exposes it as a chunked-transfer ``:generate``
@@ -585,6 +588,11 @@ class DecodeEngine:
         self._tok = np.zeros((self.slots, 1), np.int64)
         self._pos = np.zeros((self.slots, 1), np.int64)
         self._slots = [None] * self.slots
+        # deliveries decided and not yet handed to their streams, in
+        # order: (slot state, slot, token or None, end reason or None,
+        # error); and what is left of the step that produced them
+        self._outbox = []
+        self._spent = None
 
         self._q = queue.Queue(maxsize=int(queue_capacity))
         self._stop_event = threading.Event()
@@ -595,7 +603,7 @@ class DecodeEngine:
         self._stats = collections.Counter()
         # where the dispatch thread's time went, in seconds, added from
         # the exits of its phase spans (one writer; stats() copies).
-        # admit + prefill_total + dispatch + sync + emit + release + idle
+        # admit + prefill_total + dispatch + emit + release + sync + idle
         # is the loop's wall time; prefill_sync is the part of
         # prefill_total spent waiting for the device
         self._phase_s = dict.fromkeys(
@@ -692,6 +700,8 @@ class DecodeEngine:
         self._stop_event.set()
         if self._thread is not None and self._thread.is_alive():
             self._thread.join(timeout=max(0.1, float(timeout)))
+        if self._thread is None or not self._thread.is_alive():
+            self._flush()  # what a thread that died still owed
         while True:  # no thread (or it died): fail leftovers loudly
             try:
                 req = self._q.get_nowait()
@@ -1079,6 +1089,16 @@ class DecodeEngine:
 
     # -- dispatch loop ---------------------------------------------------
     def _loop(self):
+        """The dispatch thread. The loop is software-pipelined by one
+        step: after the sync of step n its tokens are on the host and
+        are *decided* (next ``_tok`` / ``_pos``, which slots finish; the
+        deliveries wait in ``_outbox``), freed slots are refilled
+        (admit), step n+1 is dispatched, and only then is step n
+        *delivered* to its streams (:meth:`_flush`), so the threads that
+        delivery wakes run while the device runs step n+1. Whatever
+        fails, retires or abandons streams, and the loop before it idles
+        or returns, flushes the outbox first: a stream sees its tokens in
+        order, then exactly one end."""
         phase = self._phase_s
         while True:
             filled = phase["prefill_seconds_total"]
@@ -1093,12 +1113,16 @@ class DecodeEngine:
                 self._fail_all()
                 return
             if live == 0:
+                self._flush()
                 if self._stop_event.is_set() and self._q.empty():
                     return
                 self._idle()
                 continue
             if self._draft is not None:
+                # the draft + verify block keeps its own order: every
+                # token is delivered before the next dispatch
                 self._spec_step()
+                self._flush()
             else:
                 self._step()
 
@@ -1123,10 +1147,8 @@ class DecodeEngine:
                 break
             req.handle._fail(EngineClosedError(
                 "engine %r stopped before prefill" % self.name))
-        for i, s in enumerate(self._slots):
-            if s is not None:
-                self._retire(i, "error", error=EngineClosedError(
-                    "engine %r stopped mid-generation" % self.name))
+        self._fail_live(EngineClosedError(
+            "engine %r stopped mid-generation" % self.name))
 
     def _sweep_cancelled(self):
         for i, s in enumerate(self._slots):
@@ -1458,43 +1480,45 @@ class DecodeEngine:
         self._bump("adopts")
         self._seat(slot, req, sp, int(h.next_token), req.plen)
 
-    def _emit(self, slot, tok):
-        """Deliver one generated token to a slot's stream; retires the
-        slot the SAME step when the sequence finishes (EOS or length)."""
+    def _decide(self, slot, tok):
+        """What the token ``tok`` means for ``slot``, settled on the
+        slot's side alone: no stream handle is touched and no thread is
+        woken. A sequence that finishes (EOS or length) leaves its slot
+        HERE, so the slot is refilled before the next step goes out, and
+        whatever its retirement reads of the cache is read here too,
+        before that step consumes the buffers. Returns the delivery
+        :meth:`_deliver` owes the stream."""
         s = self._slots[slot]
-        s.handle._emit(tok)
         s.remaining -= 1
-        if s.trace is not None:
-            # one tiny span per generated token on a SAMPLED request:
-            # dur is the inter-token gap (the per-token-p99 SLO leg)
-            now = time.monotonic()
-            gap = now - s.t_last
-            s.t_last = now
-            obs.export_span(
-                "decode.token", s.trace.child(), time.time() - gap, gap,
-                {"proc": self._proc, "slot": slot,
-                 "index": len(s.handle._tokens),
-                 "predicted_s": self._predicted_s("step")})
+        reason = None
         if s.eos_id is not None and tok == s.eos_id:
-            self._retire(slot, "eos")
+            reason = "eos"
         elif s.remaining <= 0:
-            self._retire(slot, "length")
+            reason = "length"
+        if reason is not None:
+            self._vacate(slot, last=tok)
+        return (s, slot, tok, reason, None)
 
-    def _retire(self, slot, reason, error=None):
-        s = self._slots[slot]
-        if (self._session_tier is not None and s.session is not None
-                and reason in ("eos", "length") and error is None):
-            try:
-                self._hibernate(slot, s)
-            except Exception as e:  # noqa: BLE001 — tiering is best-effort
-                self._bump("hibernate_errors")
-                obs.event("hibernate_error", source="serving",
-                          model=self.name, session=s.session,
-                          error="%s: %s" % (type(e).__name__,
-                                            str(e)[:200]))
-        self._slots[slot] = None
-        self._tok[slot, 0] = 0
-        self._pos[slot, 0] = 0
+    def _deliver(self, delivery):
+        """Hand one delivery to its stream: the token (which wakes the
+        stream's reader), then, where the sequence ended, its one
+        ``done`` or ``err`` with the request's span and totals."""
+        s, slot, tok, reason, error = delivery
+        if tok is not None:
+            s.handle._emit(tok)
+            if s.trace is not None:
+                # one tiny span per generated token on a SAMPLED request:
+                # dur is the inter-token gap (the per-token-p99 SLO leg)
+                now = time.monotonic()
+                gap = now - s.t_last
+                s.t_last = now
+                obs.export_span(
+                    "decode.token", s.trace.child(), time.time() - gap,
+                    gap, {"proc": self._proc, "slot": slot,
+                          "index": len(s.handle._tokens),
+                          "predicted_s": self._predicted_s("step")})
+        if reason is None:
+            return
         if error is not None:
             s.handle._fail(error)
         else:
@@ -1512,20 +1536,80 @@ class DecodeEngine:
         with self._stats_lock:
             self._rate.append((now, 1))
 
-    def _hibernate(self, slot, s):
+    def _emit(self, slot, tok):
+        """Decide and deliver one token at once (a fill's first token,
+        the verify block's tokens); retires the slot the SAME step when
+        the sequence finishes (EOS or length)."""
+        self._deliver(self._decide(slot, tok))
+
+    def _flush(self):
+        """Deliver the outbox in order, then drop what is left of the
+        step whose tokens it held. From the loop this runs right after
+        the NEXT step's dispatch: it is the first point at which a
+        stream thread is woken, and the device is busy while they run."""
+        if not self._outbox and self._spent is None:
+            return
+        phase = self._phase_s
+        with obs.span("decode.step.emit") as sp:
+            out, self._outbox = self._outbox, []
+            for delivery in out:
+                self._deliver(delivery)
+            n = sum(tok is not None for _, _, tok, _, _ in out)
+            if n:
+                self._bump("tokens", n)
+            self._gauges()
+        phase["emit_seconds"] += sp.seconds
+        if self._spent is None:
+            return
+        # what is left of the step dies here (its token array on the
+        # device, the sentinel's replay copy): jaxlib frees device
+        # buffers with the GIL released, so every stream thread the
+        # delivery just woke runs before this thread has the GIL back.
+        # The span stays so the phase is read as it is
+        with obs.span("decode.step.release") as sp:
+            self._spent = None
+        phase["release_seconds"] += sp.seconds
+
+    def _vacate(self, slot, last=None):
+        """The slot's side of a retirement: the slot is free. ``last`` is
+        the token that ended the sequence (EOS or length), decided but
+        not yet in the stream's history: a session's rows then go to the
+        tier, while the cache still holds them."""
+        s = self._slots[slot]
+        if (last is not None and self._session_tier is not None
+                and s.session is not None):
+            try:
+                self._hibernate(slot, s, last)
+            except Exception as e:  # noqa: BLE001 — tiering is best-effort
+                self._bump("hibernate_errors")
+                obs.event("hibernate_error", source="serving",
+                          model=self.name, session=s.session,
+                          error="%s: %s" % (type(e).__name__,
+                                            str(e)[:200]))
+        self._slots[slot] = None
+        self._tok[slot, 0] = 0
+        self._pos[slot, 0] = 0
+        return s
+
+    def _retire(self, slot, reason, error=None):
+        """End a sequence that no token of its own ended (cancelled,
+        failed, shut down): the slot is free now, the stream's end waits
+        in the outbox behind the tokens it is still owed."""
+        self._outbox.append((self._vacate(slot), slot, None, reason, error))
+
+    def _hibernate(self, slot, s, last):
         """Encode a retiring session slot's live KV rows into the
         KVHandoff wire format and park them in the session tier.
         ``prompt`` carries the token-per-row history (admission history
         + every emitted token but the last), ``next_token`` the last
         emitted token — exactly what the resume delta-prefill consumes
-        first — and ``plen`` the written row count. int8-resident
-        engines ship payload + scales verbatim (no requantize), fp32
-        engines encode at the tier's wire dtype."""
+        first — and ``plen`` the written row count. The emitted tokens
+        are the stream's own plus ``last``, the one decided and not yet
+        delivered. int8-resident engines ship payload + scales verbatim
+        (no requantize), fp32 engines encode at the tier's wire dtype."""
         from .disagg import kv_wire
 
-        emitted = np.asarray(s.handle._tokens, np.int64)
-        if emitted.size == 0:
-            return
+        emitted = np.asarray(s.handle._tokens + [last], np.int64)
         pos = int(self._pos[slot, 0])
         hist = np.concatenate([np.asarray(s.hist, np.int64),
                                emitted[:-1]])
@@ -1555,14 +1639,18 @@ class DecodeEngine:
         return specs
 
     def _fail_live(self, error):
+        """Fail every live stream, each after the tokens it is still
+        owed: nothing stays undelivered behind a failure."""
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._retire(i, "error", error=error)
+        self._flush()
 
     def _dispatch_failed(self, error):
-        """A step or verify dispatch raised: fail every live stream and
-        keep serving (if the dispatch had consumed the donated cache,
-        :meth:`SlotCache.run` has already replaced it)."""
+        """A step or verify dispatch raised: deliver the previous step's
+        tokens, fail every live stream and keep serving (if the dispatch
+        had consumed the donated cache, :meth:`SlotCache.run` has
+        already replaced it)."""
         self._bump("step_errors")
         obs.event("step_error", source="serving", model=self.name,
                   error="%s: %s" % (type(error).__name__,
@@ -1578,12 +1666,15 @@ class DecodeEngine:
         return outs
 
     def _step(self):
+        """One turn of the pipelined loop: dispatch this step, deliver
+        the step before it (its tokens wait in the outbox since their
+        decide) while the device runs this one, sync, decide."""
         phase = self._phase_s
         names = self._step_vars["cache_feed_names"]
         # the SDC sample is decided BEFORE dispatch: the step consumes
         # its cache feeds, so what the sentinel re-dispatches on a
         # sampled replay is a device copy taken now (_tok/_pos mutate at
-        # emission, so they are copied too). Sampled steps only.
+        # the decide, so they are copied too). Sampled steps only.
         replay = None
         if (self._sentinel is not None
                 and self._sentinel.sample(self._sentinel_id)):
@@ -1607,67 +1698,60 @@ class DecodeEngine:
                     self._step_pred, names,
                     {self._tok_name: self._tok,
                      self._pos_name: self._pos})
-                nxt = outs[0]
         except Exception as e:  # noqa: BLE001 — fail the slots, not the loop
             self._dispatch_failed(e)
             return
         finally:
             phase["dispatch_seconds"] += sp.seconds
+        if self._outbox:
+            # the step went out with the last one's tokens undelivered
+            self._bump("steps_ahead")
+        # the first wake-up of a stream thread since the last sync: the
+        # other threads' work per token now overlaps the device's step
+        self._flush()
         with obs.span("decode.step.sync") as sp:
-            nxt_np = np.asarray(nxt)
+            nxt_np = np.asarray(outs[0])
         phase["sync_seconds"] += sp.seconds
-        if self._model.step_counters is not None:
-            # what the step program counted on the device (its fetch
-            # after the state), into the lifetime counters
-            live = sum(1 for s in self._slots if s is not None)
-            for key, n in self._model.step_counters(
-                    np.asarray(outs[1 + len(names)]), live).items():
-                self._bump(key, int(n))
-        # the step's latency on the host: enqueue + the wait for its
-        # tokens (what the ledger's drift score and the autopilot's
-        # calibration read as the measured step time)
-        dt = time.monotonic() - t0
-        obs.observe("serving.decode.step_seconds", dt)
-        self._note_step_measured(dt)
-        self._bump("steps")
-        if replay is not None:
-            ok = self._sentinel.replay_check(
+        with obs.span("decode.step.decide") as sp:
+            # the step's latency on the host, from its enqueue to its
+            # tokens (the delivery of the step before lies inside it;
+            # what the ledger's drift score and the autopilot's
+            # calibration read as the measured step time)
+            dt = time.monotonic() - t0
+            obs.observe("serving.decode.step_seconds", dt)
+            self._note_step_measured(dt)
+            self._bump("steps")
+            live = [i for i, s in enumerate(self._slots) if s is not None]
+            if self._model.step_counters is not None:
+                # what the step program counted on the device (its fetch
+                # after the state), into the lifetime counters
+                for key, n in self._model.step_counters(
+                        np.asarray(outs[1 + len(names)]),
+                        len(live)).items():
+                    self._bump(key, int(n))
+            sound = replay is None or self._sentinel.replay_check(
                 self._sentinel_id,
                 lambda: self.sentinel_replay(replay), outs, feeds=replay)
-            if not ok:
-                # the step disagreed with its own replay: retire every
-                # live slot BEFORE emission so a possibly-corrupted
-                # token is never delivered; the streams migrate and
-                # regenerate on a healthy replica while the sentinel's
-                # cross-replica vote adjudicates this one
-                from ..integrity.digest import IntegrityError
-                self._bump("sdc_disagree")
-                self._fail_live(IntegrityError(
-                    "SDC replay disagreement on decode replica %r — "
-                    "withholding this step's tokens"
-                    % (self._sentinel_id,)))
-                return
-        with obs.span("decode.step.emit") as sp:
-            n = 0
-            for i, s in enumerate(self._slots):
-                if s is None:
-                    continue
-                tok = int(nxt_np[i, 0])
-                self._pos[i, 0] += 1
-                self._tok[i, 0] = tok
-                self._emit(i, tok)
-                n += 1
-            self._bump("tokens", n)
-            self._gauges()
+            if sound:
+                toks = nxt_np[live, 0]
+                self._pos[live, 0] += 1
+                self._tok[live, 0] = toks
+                self._outbox.extend(map(self._decide, live, toks.tolist()))
         phase["emit_seconds"] += sp.seconds
-        # what is left of the step dies here. Until the cache was donated
-        # this dropped the last reference to the previous K/V buffers,
-        # and jaxlib frees device buffers with the GIL released, so every
-        # stream thread the emit just woke ran before this thread had the
-        # GIL back; the span stays so the phase is read as it is now
-        with obs.span("decode.step.release") as sp:
-            del outs, nxt, nxt_np, replay
-        phase["release_seconds"] += sp.seconds
+        if not sound:
+            # the step disagreed with its own replay: retire every live
+            # slot BEFORE its tokens are decided so a possibly-corrupted
+            # token is never delivered; the streams migrate and
+            # regenerate on a healthy replica while the sentinel's
+            # cross-replica vote adjudicates this one
+            from ..integrity.digest import IntegrityError
+            self._bump("sdc_disagree")
+            self._fail_live(IntegrityError(
+                "SDC replay disagreement on decode replica %r — "
+                "withholding this step's tokens" % (self._sentinel_id,)))
+            return
+        # kept until the flush after the next dispatch drops it
+        self._spent = (outs, nxt_np, replay)
 
     def _spec_step(self):
         """One speculative iteration: ``k`` draft proposals per slot,
@@ -1846,20 +1930,27 @@ class DecodeEngine:
         of the slot cache (an SDC-sampled step keeps one for its replay;
         any other count says donation did not engage);
         ``cache_reallocs``, the times a failed dispatch cost the cache;
-        and where the dispatch thread's time went, in
-        seconds: ``admit_seconds`` (self time), ``prefill_seconds_total``
-        (of it ``prefill_sync_seconds`` waiting for the device),
-        ``dispatch_seconds``, ``sync_seconds``, ``emit_seconds``,
-        ``release_seconds`` (the step's inputs dropped; the thread waits
-        for the GIL behind the streams it woke), ``idle_seconds`` — the
-        seven sum to the thread's wall time."""
+        ``steps_ahead``, the steps dispatched while the step before's
+        tokens were still undelivered (all but the first after an idle
+        stretch or a failure: the loop is pipelined by one step);
+        and where the dispatch thread's time went, in seconds, in the
+        loop's order: ``admit_seconds`` (self time),
+        ``prefill_seconds_total`` (of it ``prefill_sync_seconds`` waiting
+        for the device), ``dispatch_seconds`` (step n+1 goes out),
+        ``emit_seconds`` (step n's tokens handed to their streams, and
+        the decide after each sync: next feeds, which slots finish),
+        ``release_seconds`` (what is left of step n dropped; the thread
+        waits for the GIL behind the streams it just woke, while the
+        device runs step n+1), ``sync_seconds`` (the wait for step n+1's
+        tokens), ``idle_seconds`` — the seven sum to the thread's wall
+        time."""
         with self._stats_lock:
             out = dict(self._stats)
         out.update(self._phase_s)
         for k in ("requests", "tokens", "prefills", "adopts", "steps",
                   "retired", "shed", "deadline_miss", "cancelled",
                   "prefill_errors", "adopt_errors", "step_errors",
-                  "cache_copy_steps",
+                  "cache_copy_steps", "steps_ahead",
                   "prefill_rows_computed", "prefill_rows_saved",
                   "prefix_full_hits", "delta_prefills", "delta_errors",
                   "spec_rounds", "spec_proposed", "spec_accepted",

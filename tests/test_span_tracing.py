@@ -265,6 +265,7 @@ def test_every_request_has_queue_prefill_and_stream_under_one_id(tiny):
     assert obs.counter("serving.decode.tokens") == st["tokens"]
     steps = obs.spans("decode.step.dispatch")
     assert len(steps) == st["steps"] == len(obs.spans("decode.step.sync")) \
+        == len(obs.spans("decode.step.decide")) \
         == len(obs.spans("decode.step.emit")) \
         == len(obs.spans("decode.step.release"))
 
@@ -295,7 +296,9 @@ def test_phase_totals_account_for_the_loop_threads_time(tiny):
     assert delta["idle_seconds"] >= 0.3
 
 
-def test_step_seconds_is_dispatch_plus_sync_and_feeds_the_ledger(tiny):
+def test_step_seconds_runs_from_dispatch_to_sync_and_feeds_the_ledger(tiny):
+    """A step's latency on the host: its dispatch, the delivery and release
+    of the step before it (the loop is pipelined by one step), its sync."""
     eng = make_engine(tiny)
     eng.start()
     try:
@@ -305,7 +308,8 @@ def test_step_seconds_is_dispatch_plus_sync_and_feeds_the_ledger(tiny):
     st, h = eng.stats(), obs.histogram("serving.decode.step_seconds")
     assert h["count"] == st["steps"]
     both = st["dispatch_seconds"] + st["sync_seconds"]
-    assert both <= h["sum"] <= both + 0.002 * st["steps"]
+    between = st["emit_seconds"] + st["release_seconds"]
+    assert both <= h["sum"] <= both + between + 0.002 * st["steps"]
     from paddle_tpu.fluid import compile_cache
 
     fp = compile_cache.program_fingerprint(eng._step_pred.program)
