@@ -188,8 +188,7 @@ def phase_device(rehearse):
 
 # ---------------------------------------------------------------------------
 def build_bert_trainer(full, seq):
-    """BERT pretrain program + bf16-AMP Adam, the construction of
-    bench.py `_measure` (variant b48)."""
+    """BERT pretrain program + bf16-AMP Adam."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid.contrib.mixed_precision import decorate
     from paddle_tpu.models import bert

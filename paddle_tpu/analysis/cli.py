@@ -47,18 +47,17 @@ plan mode:
   against the --device profile (compute roofline + pipeline bubble +
   ICI/DCN comm legs), drops predicted-OOM candidates with
   op-attributed diagnostics, and ranks the rest by predicted step
-  seconds. TARGET may be omitted: the bench BERT pretrain program is
+  seconds. TARGET may be omitted: a BERT-tiny pretrain program is
   built in-process. --json-out writes a plan document that
-  DistributedStrategy.from_plan and bench.py's auto-tuned lane apply
-  directly; with --mesh the given composition is also priced against
-  the winner (suboptimal-parallel-plan finding at >=1.25x).
+  DistributedStrategy.from_plan applies directly; with --mesh the
+  given composition is also priced against the winner
+  (suboptimal-parallel-plan finding at >=1.25x).
 """
 
 
 def _bench_bert_program(batch=8, seq=64):
-    """The default --plan target: the bench BERT-tiny pretrain step
-    (same construction as bench.py's CPU lane), built in-process so
-    ``--plan --devices N`` needs no saved model."""
+    """The default --plan target: a BERT-tiny pretrain step, built
+    in-process so ``--plan --devices N`` needs no saved model."""
     from .. import fluid
     from ..fluid import framework
     from ..models import bert

@@ -12,11 +12,10 @@ roofline's memory leg. The symbolic ``backward`` op is costed
 analytically as 2x its forward region (the classic fwd:bwd ratio; the
 vjp replay's duplicated forward is CSE'd by XLA, see lowering.run_ops).
 
-The device table below is the ONE shared peak-FLOPs/HBM table —
-``bench.py`` imports :func:`peak_flops` and
-:func:`bert_train_flops_per_token` from here so the bench and the
-analyzer can never drift. Env overrides (all optional) calibrate or
-pin a profile where no table entry matches (CPU smoke lanes, tests):
+The device table below is the ONE peak-FLOPs/HBM table the analyzer,
+the planner and ``check_hbm_budget`` read. Env overrides (all optional)
+calibrate or pin a profile where no table entry matches (CPU runs,
+tests):
 
 - ``PADDLE_TPU_PEAK_FLOPS`` — peak FLOPs/s
 - ``PADDLE_TPU_HBM_BYTES``  — memory capacity in bytes
@@ -33,8 +32,7 @@ import os
 
 __all__ = [
     "DeviceProfile", "DEVICE_TABLE", "device_profile", "peak_flops",
-    "require_device_profile",
-    "bert_train_flops_per_token", "OpCost", "op_costs", "jaxpr_flops",
+    "require_device_profile", "OpCost", "op_costs", "jaxpr_flops",
     "CostReport", "analyze_cost", "predict_program",
     "ring_allreduce_seconds", "allreduce_bandwidth",
     "pipeline_bubble_fraction", "dp_grad_bytes", "ICI_BW_ENV",
@@ -371,17 +369,6 @@ def require_device_profile(device_kind):
             "%s) — add a row with its published peaks and their source"
             % (device_kind, [k for k, _ in DEVICE_TABLE]))
     return p
-
-
-def bert_train_flops_per_token(cfg, seq):
-    """Analytic matmul FLOPs per trained token (fwd + bwd ~= 3x fwd) —
-    bench.py's ``_flops_per_token_train``, shared so the bench MFU and
-    the analyzer's roofline use one formula."""
-    d, L, V = cfg.hidden, cfg.num_layers, cfg.vocab_size
-    per_layer = 12 * d * d          # qkv (3d^2) + proj (d^2) + mlp (8d^2)
-    attn = 4 * seq * d              # QK^T and AV rows for one token
-    fwd = 2 * (L * (per_layer + attn) + d * V)
-    return 3 * fwd
 
 
 def ring_allreduce_seconds(n_bytes, n_shards, ici_bw):

@@ -18,10 +18,8 @@ A control loop over a noisy signal flaps without three dampers, and
   until an operator (or :meth:`release`) pardons it.
 
 :func:`verify_measurement` is the regression verdict the apply path
-runs after every fleet mutation — the same direction-aware tolerance
-framing as the PR-15 bench baseline gate (``bench_experiments/
-_baseline.py``), inlined here so a serving process needs no bench
-checkout to self-gate.
+runs after every fleet mutation: a direction-aware tolerance per
+metric.
 """
 import threading
 import time

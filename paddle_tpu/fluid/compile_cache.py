@@ -1,10 +1,10 @@
 """Persistent AOT compile cache: a disk tier under the Executor's
 in-memory executable LRU.
 
-Cold compiles dominate short runs (BENCH_r05: the transformer b64
-variant spends 24.4 s compiling vs 61.5 ms/step), and every new process
-— a TrainGuard crash-resume, a repeat bench round, a re-queued job —
-pays them again. This module makes the compile a one-time cost per
+Cold compiles dominate short runs (tens of seconds against a step of
+tens of milliseconds), and every new process — a TrainGuard
+crash-resume, a repeated benchmark run, a re-queued job — pays them
+again. This module makes the compile a one-time cost per
 *machine*: after the in-memory LRU misses, the executor asks the disk
 tier for the program's AOT artifact (the StableHLO module serialized
 via ``jax.export``) before tracing anything; a hit deserializes in
@@ -29,8 +29,8 @@ jax's own persistent XLA compilation cache is a separate tier placed by
 :func:`configure_xla_cache` — the one function in the repository that
 decides its directory: ``JAX_COMPILATION_CACHE_DIR`` when the
 environment sets it (then nothing is set in code), else the fixed
-``<checkout>/.jax_cache``. Entry points (``chip_smoke.py``, ``bench.py``,
-the experiment scripts and ``TrainGuard(compile_cache=...)``) call it;
+``<checkout>/.jax_cache``. Entry points (``chip_smoke.py``,
+``benchmark/run.py`` and ``TrainGuard(compile_cache=...)``) call it;
 importing the package does not.
 
 Cache entries are content-addressed: the key hashes the program's

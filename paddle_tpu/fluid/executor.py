@@ -758,9 +758,9 @@ class Executor:
         # Reuse one loader (and its native C++ pipe: mlock'd arena +
         # worker pool) per (dataset, feed signature, place) across
         # train_from_dataset calls — the pipe setup measured ~0.4s, and
-        # a small dataset's epoch is shorter than that
-        # (bench_experiments/ctr_breakdown.py). The cache lives ON the
-        # dataset so its lifetime tracks the data, not the executor.
+        # a small dataset's epoch is shorter than that. The cache lives
+        # ON the dataset so its lifetime tracks the data, not the
+        # executor.
         cache_key = (
             tuple(v.name for v in dataset.use_vars),
             type(self.place).__name__,

@@ -63,10 +63,9 @@ def _encoder_layer(x, cfg, i, attn_mask, is_test):
         bias_attr=ParamAttr(name=_attn_name(i, "qkv.b")),
     )
     # (B, T, 3H): split by CONTIGUOUS last-axis slices, then head-split
-    # each (B, T, H) piece. The earlier reshape-to-(B,T,3,nh,dh) +
-    # mid-axis slice + squeeze chain cost 27% more HLO copy traffic and
-    # worse attention-region fusion (BENCHMARKS round 5: b48 +2%, s512
-    # +5.6% from this change).
+    # each (B, T, H) piece: a reshape-to-(B,T,3,nh,dh) + mid-axis
+    # slice + squeeze chain costs 27% more HLO copy traffic and fuses
+    # the attention region worse.
     from .decode_utils import split_heads
 
     def _split(part, idx):

@@ -90,9 +90,8 @@ def require_rows_only(model, feature):
 def split_heads(t, heads, dh):
     """(B, T, heads*dh) -> (B, heads, T, dh). Reshape + transpose on a
     contiguous input — XLA folds the permutation into the consuming
-    dot_general. Replacing BERT's mid-axis slice+squeeze formulation
-    with this cut HLO copy traffic 27% per step and measured +2-6%
-    (BENCHMARKS round 5)."""
+    dot_general (a mid-axis slice+squeeze formulation moves 27% more
+    HLO copy traffic per BERT step)."""
     t = layers.reshape(t, [0, 0, heads, dh])
     return layers.transpose(t, [0, 2, 1, 3])
 

@@ -220,7 +220,7 @@ Well-known KV-reuse + speculation metrics (``serving.spec`` /
   vs suffix-only delta prefills, and
   ``serving.decode.prefill_rows_computed`` / ``prefill_rows_saved``
   counters are the redundant-prefill FLOPs ledger (saved/(saved+
-  computed) is the bench lane's headline).
+  computed) is the share of prefill rows reuse avoided).
 - ``serving.tier.hibernated`` / ``resumed`` / ``evictions`` counters
   and ``serving.tier.sessions`` / ``bytes`` gauges — hibernated
   sessions parked in host RAM (sessions-per-chip = live slots + what
@@ -320,8 +320,8 @@ Well-known perf-ledger metrics (PR 15, ``observability.ledger`` /
   was compiled and resident at death.
 - Render the predicted-vs-XLA-vs-measured drift per executable with
   ``python -m paddle_tpu.observability perf <dir|snapshot.json>``
-  (bench ``--telemetry-out`` files embed the ledger snapshot under
-  their ``"ledger"`` key).
+  (an ``ExecutableLedger.snapshot()`` JSON, bare or under a
+  ``"ledger"`` key).
 
 Well-known autopilot metrics (PR 16, ``paddle_tpu.autopilot`` — the
 self-healing control loop over the ledger/SLO/planner signals above):
@@ -400,8 +400,8 @@ Well-known run-health metrics (PR 18, ``observability.runhealth``):
   run wall-clock at the last ``GoodputAccount.stop()``; the full
   decomposition (``productive_step`` / ``compile`` / ``data_stall``
   / ``checkpoint`` / ``retry_backoff`` / ``restart_rework``) rides
-  ``TrainGuard.train()``'s summary, crash dumps, and bench
-  ``--telemetry-out`` docs (under ``"runhealth"``).
+  ``TrainGuard.train()``'s summary and crash dumps (under
+  ``"runhealth"``).
 - ``amp.loss_scale`` gauge / ``amp.skipped_steps`` counter — the AMP
   decorator's dynamic loss scale and in-graph overflow skips,
   published once per guarded step (``GuardedExecutor`` with

@@ -13,16 +13,14 @@ with no trace directory.
 
 ``python -m paddle_tpu.observability perf <dir|snapshot.json>``
 renders the executable ledger's predicted-vs-XLA-vs-measured drift
-table from a bench ``--telemetry-out`` file (the ledger rides under
-its ``"ledger"`` key), a bare ``ExecutableLedger.snapshot()`` JSON, or
-a directory of either.
+table from an ``ExecutableLedger.snapshot()`` JSON (bare, or under a
+``"ledger"`` key) or a directory of them.
 
 ``python -m paddle_tpu.observability run <dir|snapshot.json> [B]``
 renders a training run-health report — goodput decomposition, loss
 trajectory, anomaly counts — from a ``RunHealth.dump()`` snapshot, a
-StepSeries JSONL, a crash dump, a bench ``--telemetry-out`` file, or
-a directory of any. With a second path it renders the A/B comparison
-table instead.
+StepSeries JSONL, a crash dump, or a directory of any. With a second
+path it renders the A/B comparison table instead.
 """
 import argparse
 import json
@@ -86,9 +84,9 @@ def _cmd_perf(args):
     snap = _perf.load_snapshot(args.path)
     rows = _perf.drift_rows(snap)
     if not rows:
-        print("no ledger entries under %s (want a bench "
-              "--telemetry-out JSON or an ExecutableLedger.snapshot() "
-              "file)" % args.path, file=sys.stderr)
+        print("no ledger entries under %s (want an "
+              "ExecutableLedger.snapshot() file)" % args.path,
+              file=sys.stderr)
         return 1
     print(_perf.render_drift_table(rows))
     s = _perf.drift_summary(rows)
@@ -117,9 +115,8 @@ def _cmd_run(args):
     run_a = _rh.load_run(args.path)
     if run_a["series"] is None and run_a["goodput"] is None:
         print("no run-health records under %s (want a RunHealth "
-              "snapshot JSON, a StepSeries JSONL, a crash dump, a "
-              "bench --telemetry-out file, or a directory of any)"
-              % args.path, file=sys.stderr)
+              "snapshot JSON, a StepSeries JSONL, a crash dump, or a "
+              "directory of any)" % args.path, file=sys.stderr)
         return 1
     if args.path_b:
         run_b = _rh.load_run(args.path_b)
@@ -162,8 +159,8 @@ def main(argv=None):
     tr.set_defaults(fn=_cmd_trace)
     pf = sub.add_parser("perf", help="render the executable ledger's "
                         "predicted-vs-XLA-vs-measured drift table")
-    pf.add_argument("path", help="bench --telemetry-out JSON, a ledger "
-                    "snapshot JSON, or a directory of either")
+    pf.add_argument("path", help="a ledger snapshot JSON or a "
+                    "directory of them")
     pf.add_argument("-o", "--out", default=None,
                     help="also write the rows+summary as JSON here")
     pf.set_defaults(fn=_cmd_perf)
@@ -171,8 +168,7 @@ def main(argv=None):
                         "report (goodput + anomalies), or an A/B "
                         "comparison of two runs")
     rn.add_argument("path", help="RunHealth snapshot JSON, StepSeries "
-                    "JSONL, crash dump, bench --telemetry-out file, "
-                    "or a directory of any")
+                    "JSONL, crash dump, or a directory of any")
     rn.add_argument("path_b", nargs="?", default=None,
                     help="optional second run for an A/B comparison")
     rn.add_argument("-o", "--out", default=None,

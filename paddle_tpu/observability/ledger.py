@@ -46,7 +46,7 @@ from . import telemetry as _t
 __all__ = ["ExecutableLedger", "get_ledger"]
 
 # snapshot()/tail() field caps — entries ride in crash dumps and
-# telemetry-out JSON, so every free-form field is bounded
+# telemetry JSON, so every free-form field is bounded
 _MAX_DONATED = 32
 _MAX_PREDICTIONS = 256
 
@@ -279,8 +279,8 @@ class ExecutableLedger:
 
     def snapshot(self):
         """JSON-safe view: {"entries": [...], "predictions": {...},
-        "measured": {...}} — what bench's ``--telemetry-out`` embeds
-        under the ``"ledger"`` key and the perf CLI reads back."""
+        "measured": {...}} — what the perf CLI reads back, bare or
+        under a ``"ledger"`` key."""
         with self._lock:
             return {
                 "entries": [dict(e) for e in self._entries],

@@ -8,14 +8,14 @@ Three columns per executable, one source each:
 - **XLA** — what ``compiled.cost_analysis()`` /
   ``memory_analysis()`` reported at registration (absent on partial
   entries: deserialized disk artifacts, backends without the APIs),
-- **measured** — steady-state step seconds a bench/serving loop
+- **measured** — steady-state step seconds a timed or serving loop
   attached via ``ExecutableLedger.note_measured``.
 
 ``drift_rows`` flattens a ledger (live object or ``snapshot()`` dict)
 into comparable rows; ``render_drift_table`` prints them as an aligned
-text table; ``load_snapshot`` reads them back from a bench
-``--telemetry-out`` JSON (the ledger rides under its ``"ledger"``
-key), a bare ledger-snapshot JSON, or a directory of either. The
+text table; ``load_snapshot`` reads them back from a telemetry JSON
+that embeds the snapshot under a ``"ledger"`` key, a bare
+ledger-snapshot JSON, or a directory of either. The
 ``python -m paddle_tpu.observability perf <dir|snapshot.json>`` CLI
 wraps the three.
 
@@ -152,9 +152,8 @@ def drift_summary(rows):
 
 
 def _snapshot_of_doc(doc):
-    """A ledger snapshot out of one loaded JSON document: either a
-    bench telemetry-out file ({"ledger": {...}}) or a bare snapshot
-    ({"entries": [...]})."""
+    """A ledger snapshot out of one loaded JSON document: either
+    embedded ({"ledger": {...}}) or bare ({"entries": [...]})."""
     if not isinstance(doc, dict):
         return None
     if isinstance(doc.get("ledger"), dict):

@@ -693,9 +693,8 @@ def _series_from_records(records):
 def _run_of_doc(doc):
     """Normalize one loaded JSON doc into a run dict
     ``{"series":..., "goodput":...}`` or None when not run-shaped.
-    Accepts a ``RunHealth.snapshot()``/``dump()`` doc, a bench
-    ``--telemetry-out`` file (rides under ``"runhealth"``), or a
-    crash dump (same key)."""
+    Accepts a ``RunHealth.snapshot()``/``dump()`` doc, bare or under
+    a ``"runhealth"`` key (a crash dump)."""
     if not isinstance(doc, dict):
         return None
     if isinstance(doc.get("runhealth"), dict):
@@ -713,10 +712,9 @@ def _run_of_doc(doc):
 
 def load_run(path):
     """Load a run-health doc from `path`: a snapshot JSON
-    (``RunHealth.dump()``, a bench ``--telemetry-out`` file, or a
-    crash dump), a StepSeries JSONL, or a directory scanned for both
-    (first run-shaped ``*.json`` wins; every ``*.jsonl`` merges into
-    the series). Returns ``{"path", "series", "goodput"}`` — either
+    (``RunHealth.dump()`` or a crash dump), a StepSeries JSONL, or a
+    directory scanned for both (first run-shaped ``*.json`` wins; every
+    ``*.jsonl`` merges into the series). Returns ``{"path", "series", "goodput"}`` — either
     side may be None when that evidence wasn't found."""
     run = {"path": str(path), "series": None, "goodput": None}
     if os.path.isdir(path):

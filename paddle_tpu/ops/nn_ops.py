@@ -7,7 +7,6 @@ lookup_table_op, interpolate_op (ref: paddle/fluid/operators/...). Convs and
 matmuls lower to lax.conv_general_dilated / dot_general so XLA tiles them on
 the MXU; norms/activations are elementwise chains XLA fuses around them.
 """
-import math
 import os
 
 import numpy as np
@@ -169,49 +168,19 @@ def _log_softmax(ctx, ins, attrs):
 # ---------------------------------------------------------------------------
 # dropout (ref: paddle/fluid/operators/dropout_op.cc)
 # ---------------------------------------------------------------------------
-def _dropout_keep_mask(ctx, p, shape, allow_quantized=True):
-    """Bernoulli keep-mask for dropout; returns ``(mask, keep_prob)``
-    where keep_prob is the EXACT probability the mask was drawn with.
+def _dropout_keep_mask(ctx, p, shape):
+    """Bernoulli keep-mask for dropout; returns ``(mask, keep_prob)``.
 
-    Default path rides XLA's native RngBitGenerator (rbg): threefry
-    mask generation measured ~31% of a BERT-base train step on TPU v5e
-    (82ms -> 40ms with dropout ablated); rbg recovers nearly all of it.
-    PADDLE_TPU_DROPOUT_BITS=8 opts into quantized masks (only honored
-    when ``allow_quantized``, i.e. the upscale_in_train caller): 8
-    random bits per element, keep threshold quantized to t/256 (e.g.
-    p=0.1 -> 230/256) with the RETURNED keep_prob that exact value so
-    upscaling stays unbiased. Measured on v5e it is NOT the default:
-    despite 4x fewer random bits it ties at T=128 and loses 4-6% at
-    T=512 (bench_experiments/dropout_bits_ab.json) — the separate
-    bits/bitcast/compare chain denies XLA the bernoulli-into-consumer
-    fusion and the bool mask round-trips HBM. The rbg key derives from
-    the same deterministic per-(op, draw) step key, so masks stay
-    reproducible and identical between the forward pass and its vjp
-    replay. PADDLE_TPU_DROPOUT_RBG=0 restores threefry."""
-    key = ctx.next_rng()
+    The mask rides XLA's native RngBitGenerator (rbg): threefry mask
+    generation cost about a third of a BERT-base train step on the v5e.
+    The rbg key derives from the deterministic per-(op, draw) step key,
+    so masks are reproducible and identical between the forward pass
+    and its vjp replay."""
+    kd = jax.random.key_data(ctx.next_rng()).astype(jnp.uint32).reshape(-1)
+    if kd.size < 4:
+        kd = jnp.concatenate([kd, kd])
+    key = jax.random.wrap_key_data(kd[:4], impl="rbg")
     keep_prob = 1.0 - p
-    if os.environ.get("PADDLE_TPU_DROPOUT_RBG", "1") != "0":
-        kd = jax.random.key_data(key).astype(jnp.uint32).reshape(-1)
-        if kd.size < 4:
-            kd = jnp.concatenate([kd, kd])
-        key = jax.random.wrap_key_data(kd[:4], impl="rbg")
-        t = int(round(keep_prob * 256.0))
-        # quantization gate: only take the 8-bit path when the implied
-        # DROP rate (1 - t/256) is within 5% relative of the requested
-        # p — tiny rates like p=0.002 would otherwise silently double
-        # their regularization strength (quantum is 1/256)
-        quantize_ok = (
-            allow_quantized and 0 < t < 256 and p > 0
-            and abs((1.0 - t / 256.0) - p) <= 0.05 * p
-        )
-        if quantize_ok and os.environ.get(
-                "PADDLE_TPU_DROPOUT_BITS", "32") == "8":
-            n = math.prod(shape)
-            bits32 = jax.random.bits(key, ((n + 3) // 4,),
-                                     dtype=jnp.uint32)
-            bits8 = jax.lax.bitcast_convert_type(bits32, jnp.uint8)
-            keep = (bits8.reshape(-1)[:n] < jnp.uint8(t)).reshape(shape)
-            return keep, t / 256.0
     return jax.random.bernoulli(key, keep_prob, shape), keep_prob
 
 
@@ -227,11 +196,7 @@ def _dropout(ctx, ins, attrs):
         else:
             out = x
         return {"Out": [out], "Mask": [jnp.ones_like(x)]}
-    # downgrade_in_infer scales by (1-p) at INFER time, so its train
-    # mask must be drawn at exactly 1-p (no quantized threshold);
-    # upscale_in_train rescales by whatever exact prob the mask used
-    keep, keep_prob = _dropout_keep_mask(
-        ctx, p, x.shape, allow_quantized=(impl == "upscale_in_train"))
+    keep, keep_prob = _dropout_keep_mask(ctx, p, x.shape)
     if impl == "upscale_in_train":
         out = jnp.where(keep, x / max(keep_prob, 1e-8), 0.0)
     else:
@@ -249,14 +214,7 @@ def _lookup_table(ctx, ins, attrs):
     squeeze_last = False
     if ids.ndim >= 2 and ids.shape[-1] == 1 and attrs.get("_squeeze", True):
         ids = ids[..., 0]
-    if os.environ.get("PADDLE_TPU_EMBED_ONEHOT", "0") not in ("", "0"):
-        # one-hot matmul path: the VJP is a dense (V, N)@(N, D) matmul on
-        # the MXU instead of a scatter-add, which XLA serializes on TPU.
-        # Worth it when N·V·D matmul time < scatter time (large batches).
-        oh = jax.nn.one_hot(ids.astype(jnp.int32), w.shape[0], dtype=w.dtype)
-        out = oh @ w
-    else:
-        out = jnp.take(w, ids.astype(jnp.int32), axis=0)
+    out = jnp.take(w, ids.astype(jnp.int32), axis=0)
     if padding_idx is not None and padding_idx >= 0:
         mask = (ids != padding_idx)[..., None]
         out = out * mask.astype(out.dtype)
@@ -494,30 +452,15 @@ def _batch_norm(ctx, ins, attrs):
         bm = jnp.mean(xf, axis=axes)
         bv = jnp.var(xf, axis=axes)
         use_mean, use_var = bm, bv
-        if os.environ.get("PADDLE_TPU_BN_FREEZE_STATS"):
-            # experiment knob (bench_experiments/resnet_gap.py):
-            # isolate the moving-stat update's cost; NOT for training
-            new_mean, new_var = mean, var
-        else:
-            new_mean = momentum * mean + (1 - momentum) * bm
-            new_var = momentum * var + (1 - momentum) * bv
+        new_mean = momentum * mean + (1 - momentum) * bm
+        new_var = momentum * var + (1 - momentum) * bv
         saved_mean = bm
         saved_var = 1.0 / jnp.sqrt(bv + eps)
     inv = lax.rsqrt(use_var.astype(jnp.float32) + eps)
-    if os.environ.get("PADDLE_TPU_BN_BF16_APPLY") and \
-            x.dtype == jnp.bfloat16:
-        # experiment knob: per-channel scalars stay f32, the elementwise
-        # normalize runs in the activation dtype (halves the fused
-        # loop's working set on bf16 activations)
-        g16 = (inv * scale.astype(jnp.float32)).astype(x.dtype)
-        out = (x - use_mean.astype(x.dtype).reshape(bshape)) \
-            * g16.reshape(bshape) \
-            + bias.astype(x.dtype).reshape(bshape)
-    else:
-        out = (x.astype(jnp.float32) - use_mean.reshape(bshape)) * (
-            inv * scale.astype(jnp.float32)
-        ).reshape(bshape) + bias.astype(jnp.float32).reshape(bshape)
-        out = out.astype(x.dtype)
+    out = (x.astype(jnp.float32) - use_mean.reshape(bshape)) * (
+        inv * scale.astype(jnp.float32)
+    ).reshape(bshape) + bias.astype(jnp.float32).reshape(bshape)
+    out = out.astype(x.dtype)
     return {
         "Y": [out],
         "MeanOut": [new_mean.astype(mean.dtype)],

@@ -12,8 +12,7 @@ step seconds.
 
 CLI: ``python -m paddle_tpu.analysis --plan --devices 256 --device
 v5e`` prints the ranked table; ``--json-out`` writes a plan document
-``DistributedStrategy.from_plan`` / ``bench.py``'s auto-tuned lane can
-apply directly.
+``DistributedStrategy.from_plan`` applies directly.
 """
 from .plan import ParallelPlan, MESH_AXIS_ORDER
 from .candidates import enumerate_plans, tp_compatible

@@ -55,10 +55,6 @@ Telemetry: ``serving.decode.slot_utilization`` /
 ``serving.decode.tokens`` / ``requests`` / ``retired`` / ``shed`` /
 ``deadline_miss`` / ``cancelled`` counters.
 
-``barrier=True`` is the ablation mode benches compare against: slots
-are only refilled once EVERY slot has retired — the classic full-batch
-generation schedule, identical programs, no in-flight admission.
-
 Disaggregation hooks (PR 12, ``serving.disagg``): ``kv_dtype="int8"``
 keeps the slot cache **resident in int8** with per-(slot, layer, row)
 fp32 scales — ~4x the decode slots at equal HBM, priced honestly by
@@ -379,9 +375,7 @@ class DecodeEngine:
     """Continuous-batching decode engine over a prefill/step program
     pair. The model hands over its builders and the declaration of the
     state it carries (``cfg.decode_model(cache_len, kv_dtype)``, a
-    :class:`~paddle_tpu.models.decode_utils.DecodeModel`); another
-    builder pair with the same feed/fetch contract plugs in via
-    ``build_prefill``/``build_step``.
+    :class:`~paddle_tpu.models.decode_utils.DecodeModel`).
 
     ::
 
@@ -414,8 +408,7 @@ class DecodeEngine:
                  prompt_buckets=None, eos_id=None, queue_capacity=64,
                  default_max_new=32, default_deadline_ms=None,
                  request_timeout_s=60.0, name="default",
-                 barrier=False, auto_start=True,
-                 build_prefill=None, build_step=None,
+                 auto_start=True,
                  kv_dtype="fp32", role="colocated",
                  draft=None, prefix_pool=None, session_tier=None,
                  adopt_params=False):
@@ -454,8 +447,6 @@ class DecodeEngine:
                  role == "decode")):
             if on:
                 require_rows_only(model, feature)
-        build_prefill = build_prefill or model.build_prefill
-        build_step = build_step or model.build_step
         self._model = model
         self._jax = jax
         self.cfg = cfg
@@ -468,7 +459,6 @@ class DecodeEngine:
         self.default_max_new = int(default_max_new)
         self._default_deadline_ms = default_deadline_ms
         self.request_timeout_s = float(request_timeout_s)
-        self.barrier = bool(barrier)
         if prompt_buckets is None:
             prompt_buckets = default_prompt_buckets(self.cache_len)
         self.prompt_buckets = tuple(sorted({int(b) for b in prompt_buckets}))
@@ -486,13 +476,13 @@ class DecodeEngine:
         # -- build the program pair (never touching the caller's
         # default_main_program) and share ONE device param set ---------
         with fluid.program_guard(fluid.Program(), fluid.Program()):
-            step_vars = build_step(cfg, self.cache_len)
+            step_vars = model.build_step(cfg, self.cache_len)
             step_prog = fluid.default_main_program()
         prefill = {}
         if self.role != "decode":  # a pure decode replica never prefills
             for b in self.prompt_buckets:
                 with fluid.program_guard(fluid.Program(), fluid.Program()):
-                    pv = build_prefill(cfg, b, self.cache_len)
+                    pv = model.build_prefill(cfg, b, self.cache_len)
                     prefill[b] = (fluid.default_main_program(), pv)
         # delta-prefill ladder (prefix-pool hits + session resumes):
         # same bucket widths as cold prefill, suffix-sized at use
@@ -1156,11 +1146,7 @@ class DecodeEngine:
                 self._retire(i, "cancelled")
 
     def _admit(self):
-        """Prefill queued requests into free slots. In ``barrier`` mode
-        (the full-batch baseline) admission waits until EVERY slot has
-        retired."""
-        if self.barrier and any(s is not None for s in self._slots):
-            return
+        """Prefill queued requests into free slots."""
         for i in range(self.slots):
             if self._slots[i] is not None:
                 continue

@@ -103,17 +103,14 @@ class PrefillEngine:
     def __init__(self, cfg, scope, cache_len=64, prompt_buckets=None,
                  queue_capacity=64, name="prefill", wire_dtype="int8",
                  ttft_slo_ms=None, request_timeout_s=60.0,
-                 auto_start=True, build_prefill=None, prefix_pool=None):
+                 auto_start=True, prefix_pool=None):
         import jax
 
         import paddle_tpu.fluid as fluid
         from ..decode import default_prompt_buckets
         from ...fluid.inference import Predictor
+        from ...models.gpt import build_gpt_prefill
 
-        if build_prefill is None:
-            from ...models.gpt import build_gpt_prefill
-
-            build_prefill = build_gpt_prefill
         if hasattr(cfg, "decode_model"):
             # the hand-over on the wire is K/V rows: a model that also
             # carries a fixed-size state per sequence cannot ship it
@@ -142,7 +139,7 @@ class PrefillEngine:
         prefill = {}
         for b in self.prompt_buckets:
             with fluid.program_guard(fluid.Program(), fluid.Program()):
-                pv = build_prefill(cfg, b, self.cache_len)
+                pv = build_gpt_prefill(cfg, b, self.cache_len)
                 prefill[b] = (fluid.default_main_program(), pv)
         # a prefix pool turns this replica into a delta-prefill source:
         # pooled base rows + the suffix program cost only the unshared

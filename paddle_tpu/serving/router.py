@@ -31,9 +31,10 @@ Replica flavors:
   requests with :class:`ReplicaGoneError`, which the dispatch layer
   treats as "replay on the next replica". A chip belongs to one
   process, and every worker process would claim every chip of its
-  host: these workers are for CPU drills (``chaos_serving_lane.sh``)
-  unless each is handed disjoint chips from outside. On a chip host
-  the fleet is the in-process ``local_fleet(per_device=True)``.
+  host: these workers are for CPU drills
+  (``tests/test_serving_router.py``) unless each is handed disjoint
+  chips from outside. On a chip host the fleet is the in-process
+  ``local_fleet(per_device=True)``.
 
 Dispatch is least-loaded with shed-aware failover: candidates are the
 live replicas ordered by (straggler?, queue depth), depth ties rotated

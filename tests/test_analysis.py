@@ -299,12 +299,18 @@ def test_executor_verify_memoized_per_signature(monkeypatch):
     out = fluid.layers.fc(x, size=2)
     exe = _exe()
     exe.run(fluid.default_startup_program())
-    n0 = len(calls)
+    from paddle_tpu import observability as obs
+
+    def timed():  # the gate records what each verify cost
+        return (obs.histogram("analysis.verify_seconds") or {"count": 0}
+                )["count"]
+
+    n0, t0 = len(calls), timed()
     feed = {"x": np.ones((2, 4), np.float32)}
     exe.run(feed=feed, fetch_list=[out])
-    assert len(calls) == n0 + 1
+    assert (len(calls), timed()) == (n0 + 1, t0 + 1)
     exe.run(feed=feed, fetch_list=[out])  # cached signature: no re-verify
-    assert len(calls) == n0 + 1
+    assert (len(calls), timed()) == (n0 + 1, t0 + 1)
 
 
 def test_executor_analysis_off(monkeypatch):

@@ -354,6 +354,27 @@ def test_cost_report_scaling_efficiency(monkeypatch):
     assert eff > rep0.scaling_efficiency
 
 
+def test_cli_cost_mesh_dp8_reports_comm_leg(tmp_path, capsys):
+    """`--cost --mesh dp=8` over a saved TRAINING program prints the
+    interconnect leg: predicted allreduce seconds and scaling
+    efficiency."""
+    import json
+
+    from paddle_tpu.analysis import cli
+
+    loss = _build_loss()
+    fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    path = tmp_path / "train.json"
+    path.write_text(fluid.default_main_program().to_json())
+    rc = cli.main([str(path), "--cost", "--device", "v5e", "--mesh",
+                   "dp=8", "--batch", "8", "--fail-on", "never"])
+    assert rc == 0
+    comm = json.loads(capsys.readouterr().out)["cost"]["comm"]
+    assert comm["dp_shards"] == 8
+    assert comm["predicted_allreduce_seconds"] > 0
+    assert 0.0 < comm["scaling_efficiency"] <= 1.0
+
+
 def test_device_table_carries_ici_bw(monkeypatch):
     from paddle_tpu.analysis.costs import (DEVICE_TABLE, ICI_BW_ENV,
                                            device_profile)

@@ -71,22 +71,15 @@ def test_device_profile_env_overrides(monkeypatch):
     assert p.hbm_bw == 819e9  # un-overridden field keeps the table value
 
 
-def test_bench_helpers_are_table_backed():
-    import bench
-    from paddle_tpu.models.bert import bert_tiny
-
+def test_peak_flops_is_table_backed():
     for dk in ("TPU v6e", "TPU v5p", "TPU v5 lite", "TPU v4"):
-        assert bench._peak_flops(dk) == costs.peak_flops(dk)
-    # a measurement path errors on a device the table does not know
+        assert costs.peak_flops(dk) \
+            == costs.require_device_profile(dk).peak_flops > 0
+    # the analyzer makes no prediction for a device the table does not
+    # know; a measurement path errors on it
+    assert costs.peak_flops("nope") is None
     with pytest.raises(LookupError):
-        bench._peak_flops("nope")
-    cfg = bert_tiny()
-    for seq in (64, 512):
-        got = bench._flops_per_token_train(cfg, seq)
-        assert got == costs.bert_train_flops_per_token(cfg, seq)
-        # the formula itself: 3 * 2 * (L*(12d^2 + 4*seq*d) + d*V)
-        d, L, V = cfg.hidden, cfg.num_layers, cfg.vocab_size
-        assert got == 3 * 2 * (L * (12 * d * d + 4 * seq * d) + d * V)
+        costs.require_device_profile("nope")
 
 
 # ---------------------------------------------------------------------------

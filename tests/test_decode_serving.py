@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu.fluid as fluid
+from paddle_tpu import observability as obs
 from paddle_tpu import serving
 from paddle_tpu.models import gpt
 from paddle_tpu.serving import (
@@ -119,10 +120,17 @@ def test_mixed_concurrent_streams_bit_identical_to_solo(m):
                for c in range(6)]
     for t in threads:
         t.start()
+    util_peak = 0.0
+    while any(t.is_alive() for t in threads):
+        util_peak = max(util_peak, obs.gauge(
+            "serving.decode.slot_utilization.gpt-dec") or 0.0)
+        time.sleep(0.002)
     for t in threads:
         t.join()
     assert not errors, errors
     assert len(results) == 6
+    # freed slots were refilled mid-flight: both were live at once
+    assert util_peak >= 0.75, util_peak
     ref = {plen: _solo(m, _prompt(plen), n_new) for plen in lens}
     for cid, (plen, toks) in results.items():
         assert toks == ref[plen], (cid, plen)

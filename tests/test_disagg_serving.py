@@ -577,6 +577,89 @@ def test_http_generate_disagg_statuses_and_tenancy(m):
         router.stop(drain=False, timeout=5.0)
 
 
+def test_fleet_metrics_federate_and_each_request_is_one_timeline(
+        m, tmp_path, monkeypatch):
+    """A 2-prefill x 2-decode fleet behind HTTP with every request
+    sampled: the federated counter totals (`/metrics?scope=fleet`) are
+    the sums of the replicas' own `stats()`, and each request's spans
+    merge into one timeline across >= 3 logical processes with a flow
+    arrow, every phase present and the cost model's prediction beside
+    the measured time."""
+    import urllib.request
+
+    from paddle_tpu import observability as obs
+
+    monkeypatch.setenv(obs.TRACE_DIR_ENV, str(tmp_path))
+    monkeypatch.setenv(obs.TRACE_SAMPLE_ENV, "1.0")
+    # the CPU has no row in the device table: pin one so that spans
+    # carry predicted-vs-measured annotations
+    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "1e12")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BYTES", "16e9")
+    monkeypatch.setenv("PADDLE_TPU_HBM_BW", "6e11")
+    router = disagg_fleet(
+        m["cfg"], m["scope"], n_prefill=2, n_decode=2, slots=2,
+        cache_len=64, prompt_buckets=(8,), kv_dtype="fp32",
+        wire_dtype="fp32", name="fed-fleet")
+    reg = ModelRegistry()
+    reg.publish("fed-fleet", router)
+    srv = ServingServer(reg).start()
+    trace_ids = []
+    try:
+        for i in range(6):
+            body = json.dumps({"prompt": _prompt(3 + i % 5).tolist(),
+                               "max_new_tokens": 8,
+                               "stream": False}).encode()
+            req = urllib.request.Request(
+                srv.url + "/v1/models/fed-fleet:generate", data=body,
+                headers={"Content-Type": "application/json"})
+            doc = json.load(urllib.request.urlopen(req, timeout=120))
+            assert len(doc["tokens"]) == 8
+            trace_ids.append(doc["trace_id"])
+        assert all(trace_ids)
+
+        # one beat later every beacon's metrics document is current
+        deadline = time.monotonic() + 10
+        while True:
+            expected = {}
+            for rep in (list(router._prefill.values())
+                        + list(router._decode.values())):
+                for k, v in rep.engine.stats().items():
+                    if isinstance(v, (int, float)) \
+                            and not isinstance(v, bool):
+                        expected[k] = expected.get(k, 0) + v
+            totals = router.fleet_metrics().counter_totals()
+            if all(totals.get(k) == v for k, v in expected.items()) \
+                    or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
+        assert {k: totals.get(k) for k in expected} == expected
+        assert expected["adopts"] == expected["prefills"] == 6
+        page = urllib.request.urlopen(
+            srv.url + "/metrics?scope=fleet", timeout=30).read().decode()
+        assert "paddle_tpu_fleet_replicas 4" in page
+        for k in ("adopts", "prefills"):
+            assert "paddle_tpu_fleet_%s %g" % (k, expected[k]) in page
+    finally:
+        srv.stop(close_registry=False)
+        router.stop(drain=False, timeout=10.0)
+        reg.close()
+
+    merged = obs.collect_trace(str(tmp_path),
+                               out=str(tmp_path / "merged.json"))
+    assert json.load(open(tmp_path / "merged.json")) == merged
+    assert set(trace_ids) <= set(merged["otherData"]["traces"])
+    spans = obs.read_spans(str(tmp_path))
+    for tid in trace_ids:
+        per = obs.chrome_trace(spans, trace_id=tid)["otherData"]
+        assert per["spans"] >= 4 and per["flows"] >= 1, per
+        assert len(per["processes"]) >= 3, per
+    assert [e for e in merged["traceEvents"] if e["ph"] == "X"
+            and "predicted_ms" in e.get("args", {})]
+    phases = obs.phase_breakdown(spans)
+    for phase in ("queue", "prefill", "handoff", "adopt", "decode"):
+        assert phases.get(phase, {}).get("count", 0) >= 1, (phase, phases)
+
+
 def test_serving_package_exports():
     for name in ("DisaggRouter", "DisaggReplica", "DisaggStream",
                  "PrefillEngine", "PrefillTicket", "KVHandoff",

@@ -222,54 +222,36 @@ def test_dropout_rbg_mask_consistent_between_fwd_and_grad():
     assert 0.3 < kept_fwd.mean() < 0.7
 
 
-def test_dropout_8bit_masks_unbiased(monkeypatch):
-    """The opt-in 8-bit rbg mask path (PADDLE_TPU_DROPOUT_BITS=8):
-    keep rate matches the QUANTIZED threshold t/256 and upscale uses
-    that exact probability, so E[dropout(x)] == x. The default (32)
-    produces a float-threshold mask."""
+def test_dropout_masks_unbiased():
+    """The keep rate is 1-p and upscale_in_train divides by exactly
+    that, so E[dropout(x)] == x."""
     import numpy as np
     import paddle_tpu.fluid as fluid
 
-    def run(bits, p=0.1, n=(64, 1024)):
-        monkeypatch.setenv("PADDLE_TPU_DROPOUT_BITS", bits)
-        prog, startup = fluid.Program(), fluid.Program()
-        prog.random_seed = 11
-        with fluid.program_guard(prog, startup):
-            x = fluid.data("d8x", (None, n[1]), "float32")
-            y = fluid.layers.dropout(
-                x, dropout_prob=p,
-                dropout_implementation="upscale_in_train")
-        exe = fluid.Executor()
-        exe.run(startup)
-        xv = np.ones(n, np.float32)
-        return np.asarray(exe.run(prog, feed={"d8x": xv},
-                                  fetch_list=[y])[0])
-
-    y8 = run("8")
-    kept = y8 != 0
-    # threshold for p=0.1: t = round(0.9*256) = 230 -> keep 230/256
-    t_keep = 230.0 / 256.0
-    assert abs(kept.mean() - t_keep) < 0.01
-    # kept values upscaled by the EXACT quantized keep prob
-    np.testing.assert_allclose(y8[kept], 256.0 / 230.0, rtol=1e-6)
-    # unbiased: E[y] == 1
-    assert abs(y8.mean() - 1.0) < 0.02
-
-    y32 = run("32")
-    kept32 = y32 != 0
-    assert abs(kept32.mean() - 0.9) < 0.01
-    np.testing.assert_allclose(y32[kept32], 1.0 / 0.9, rtol=1e-6)
+    prog, startup = fluid.Program(), fluid.Program()
+    prog.random_seed = 11
+    with fluid.program_guard(prog, startup):
+        x = fluid.data("d8x", (None, 1024), "float32")
+        y = fluid.layers.dropout(
+            x, dropout_prob=0.1,
+            dropout_implementation="upscale_in_train")
+    exe = fluid.Executor()
+    exe.run(startup)
+    xv = np.ones((64, 1024), np.float32)
+    yv = np.asarray(exe.run(prog, feed={"d8x": xv}, fetch_list=[y])[0])
+    kept = yv != 0
+    assert abs(kept.mean() - 0.9) < 0.01
+    np.testing.assert_allclose(yv[kept], 1.0 / 0.9, rtol=1e-6)
+    assert abs(yv.mean() - 1.0) < 0.02
 
 
-def test_dropout_8bit_quantization_gate(monkeypatch):
-    """Tiny drop rates fall back to the float-threshold path even with
-    8-bit masks opted in: p=0.002 quantized to 1/256 would nearly
-    double the drop rate, so the gate must reject it (drop rate stays
-    ~0.002, not ~0.0039)."""
+def test_dropout_tiny_rate_is_honoured_exactly():
+    """A drop rate of 0.002 is drawn at 0.002 (not rounded to a coarser
+    threshold such as 1/256) and kept values are scaled by exactly
+    1/(1-0.002)."""
     import numpy as np
     import paddle_tpu.fluid as fluid
 
-    monkeypatch.setenv("PADDLE_TPU_DROPOUT_BITS", "8")
     prog, startup = fluid.Program(), fluid.Program()
     prog.random_seed = 13
     with fluid.program_guard(prog, startup):
@@ -283,5 +265,4 @@ def test_dropout_8bit_quantization_gate(monkeypatch):
     yv = np.asarray(exe.run(prog, feed={"dqx": xv}, fetch_list=[y])[0])
     drop_rate = (yv == 0).mean()
     assert abs(drop_rate - 0.002) < 0.0008, drop_rate
-    # kept values scaled by exactly 1/(1-0.002) -> float path was used
     np.testing.assert_allclose(yv[yv != 0], 1.0 / 0.998, rtol=1e-6)

@@ -276,19 +276,16 @@ def test_gpt_declares_its_rows_and_serves_through_the_same_door():
                  for v in fluid.default_main_program().list_vars()
                  if getattr(v, "persistable", False)}
     del prog_vars
-    # the default door and the builders handed in by hand: the same tokens
-    prompt = np.arange(1, 7)
-    toks = []
-    for kw in ({}, {"build_prefill": gpt.build_gpt_prefill,
-                    "build_step": gpt.build_gpt_decode_step}):
-        eng = serving.DecodeEngine(cfg, scope, slots=2, cache_len=32,
-                                   prompt_buckets=[8], name="gpt-door", **kw)
-        try:
-            toks.append(eng.generate(prompt, max_new=6))
-            st = eng.stats()
-            assert st["cache_copy_steps"] == 0
-            assert st["state_bytes_fixed"] == 0
-            assert st["state_bytes_rows"] == 2 * kv_slot_bytes(cfg, 32)
-        finally:
-            eng.stop(drain=False, timeout=5)
-    assert toks[0] == toks[1]
+    # the one door: the engine builds from the declaration's builders
+    assert model_.build_prefill is gpt.build_gpt_prefill
+    assert model_.build_step is gpt.build_gpt_decode_step
+    eng = serving.DecodeEngine(cfg, scope, slots=2, cache_len=32,
+                               prompt_buckets=[8], name="gpt-door")
+    try:
+        assert len(eng.generate(np.arange(1, 7), max_new=6)) == 6
+        st = eng.stats()
+        assert st["cache_copy_steps"] == 0
+        assert st["state_bytes_fixed"] == 0
+        assert st["state_bytes_rows"] == 2 * kv_slot_bytes(cfg, 32)
+    finally:
+        eng.stop(drain=False, timeout=5)

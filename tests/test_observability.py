@@ -276,6 +276,41 @@ class TestRecorder:
         assert spans[0][0] == "executor.run"
         assert doc["telemetry"]["counters"]["executor.cache_miss"] == 1
 
+    def test_uncaught_fault_leaves_crash_dump(self, tmp_path):
+        """A process killed by an uncaught (injected) fault leaves the
+        black box behind through the auto-installed excepthook: the
+        exception, the events before it, the telemetry snapshot."""
+        import subprocess
+        import sys
+
+        dump = str(tmp_path / "crash.json")
+        src = (
+            "import numpy as np\n"
+            "import paddle_tpu.fluid as fluid\n"
+            "x = fluid.data('dx', shape=[None, 4], dtype='float32')\n"
+            "loss = fluid.layers.reduce_mean(fluid.layers.fc(x, 1))\n"
+            "fluid.optimizer.SGD(0.05).minimize(loss)\n"
+            "exe = fluid.Executor()\n"
+            "exe.run(fluid.default_startup_program())\n"
+            "feed = {'dx': np.ones((4, 4), 'float32')}\n"
+            "for _ in range(2):\n"
+            "    exe.run(feed=feed, fetch_list=[loss])\n")
+        # run-site checks: 1 = startup, 2 = first step, 3 = the fault
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PADDLE_TPU_FAULT_SPEC="run:at=3:RuntimeError",
+                   PADDLE_TPU_CRASH_DUMP=dump)
+        env.pop("PADDLE_TPU_TELEMETRY", None)
+        r = subprocess.run([sys.executable, "-c", src], env=env,
+                           capture_output=True, text=True, timeout=240,
+                           cwd=os.path.dirname(os.path.dirname(
+                               os.path.abspath(__file__))))
+        assert r.returncode != 0, r.stdout[-500:]
+        doc = json.load(open(dump))
+        assert doc["exception"]["type"] == "RuntimeError"
+        assert "injected fault" in doc["exception"]["message"]
+        assert "compile_done" in [e["kind"] for e in doc["events"]]
+        assert "counters" in doc["telemetry"]
+
     def test_crash_dump_env_path(self, monkeypatch, tmp_path):
         target = str(tmp_path / "env_crash.json")
         monkeypatch.setenv(obs.CRASH_DUMP_ENV, target)

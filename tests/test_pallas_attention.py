@@ -3,7 +3,7 @@
 the fused_multihead_attention op against the unfused matmul/softmax graph.
 
 Kernels run in pallas interpret mode on the CPU test mesh; on real TPU the
-same code path compiles via Mosaic (exercised by bench.py)."""
+same code path compiles via Mosaic (exercised by chip_smoke.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -1,9 +1,8 @@
 """Executable performance observatory (ISSUE 15): the process-wide
-ExecutableLedger, the perf drift CLI, device-profile auto-calibration,
-and the persistent perf-baseline regression gate."""
+ExecutableLedger, the perf drift CLI and device-profile
+auto-calibration."""
 import json
 import os
-import sys
 import warnings
 
 import numpy as np
@@ -15,11 +14,6 @@ from paddle_tpu.analysis import costs
 from paddle_tpu.fluid import compile_cache
 from paddle_tpu.observability import __main__ as obs_cli
 from paddle_tpu.observability import perf
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "bench_experiments"))
-from _baseline import DEFAULT_TOLERANCES, BaselineStore, extract_lanes  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -370,79 +364,6 @@ class TestCalibration:
             feed_specs={"cx": np.zeros((8, 16), "float32")},
             fetch_names=[y.name], device_kind="cpu")
         assert out["device"]["peak_flops"] == pytest.approx(1e13)
-
-
-# ---------------------------------------------------------------------------
-# baseline store / regression gate
-# ---------------------------------------------------------------------------
-
-
-def _result(tps=1000.0, step_ms=50.0, compile_s=5.0, errors=(),
-            serving=None):
-    detail = {"step_ms": step_ms, "compile_s": compile_s,
-              "errors": list(errors)}
-    if serving is not None:
-        detail["serving"] = serving
-    return {"metric": "bert_tiny_pretrain_throughput_cpu", "value": tps,
-            "detail": detail}
-
-
-class TestBaselineStore:
-    def test_extract_lanes(self):
-        lanes = extract_lanes(_result(
-            serving={"ttft_ms_p99": 12.0,
-                     "nested": {"per_token_ms_p99": 3.0}}))
-        head = lanes["bert_tiny_pretrain_throughput_cpu"]
-        assert head["tokens_per_sec"] == 1000.0
-        assert head["predicted_oom"] == 0
-        assert lanes["serving"]["ttft_ms_p99"] == 12.0
-        assert lanes["serving"]["per_token_ms_p99"] == 3.0
-
-    def test_update_keeps_best(self, tmp_path):
-        store = BaselineStore(str(tmp_path / "B.json"))
-        store.update(_result(tps=1000.0, step_ms=50.0))
-        store.update(_result(tps=900.0, step_ms=40.0))  # tps worse, step better
-        doc = store.load()
-        m = doc["lanes"]["bert_tiny_pretrain_throughput_cpu"]["metrics"]
-        assert m["tokens_per_sec"] == 1000.0
-        assert m["step_ms"] == 40.0
-
-    def test_check_passes_within_tolerance(self, tmp_path):
-        store = BaselineStore(str(tmp_path / "B.json"))
-        store.update(_result())
-        rep = store.check(_result(tps=950.0, step_ms=55.0))
-        assert rep["regressions"] == []
-        assert len(rep["checked"]) >= 3
-
-    def test_check_flags_and_attributes(self, tmp_path):
-        store = BaselineStore(str(tmp_path / "B.json"))
-        store.update(_result())
-        rep = store.check(_result(tps=600.0, step_ms=80.0))
-        names = {(r["lane"], r["metric"]) for r in rep["regressions"]}
-        assert ("bert_tiny_pretrain_throughput_cpu",
-                "tokens_per_sec") in names
-        assert ("bert_tiny_pretrain_throughput_cpu", "step_ms") in names
-        txt = store.render_report(rep)
-        assert "PERF REGRESSIONS" in txt and "tokens_per_sec" in txt
-        assert "tolerance" in txt
-
-    def test_predicted_oom_zero_tolerance(self, tmp_path):
-        store = BaselineStore(str(tmp_path / "B.json"))
-        store.update(_result())
-        rep = store.check(_result(
-            errors=["serving: predicted-oom 1 of 2 ladders"]))
-        assert any(r["metric"] == "predicted_oom"
-                   for r in rep["regressions"])
-
-    def test_empty_baseline_is_clean(self, tmp_path):
-        store = BaselineStore(str(tmp_path / "none.json"))
-        rep = store.check(_result())
-        assert rep["regressions"] == [] and rep["missing_lanes"]
-        assert "no baseline yet" in store.render_report(rep)
-
-    def test_default_tolerances_shape(self):
-        for d, t in DEFAULT_TOLERANCES.values():
-            assert d in ("higher", "lower") and t >= 0
 
 
 # ---------------------------------------------------------------------------

@@ -323,6 +323,35 @@ class TestFromPlan:
         s = DistributedStrategy.from_plan(doc)
         assert s.grad_sync_mode == bert_search.best.plan.grad_sync_mode
 
+    def test_winning_plan_runs_a_fleet_step(self, bert_search):
+        """The emitted winner applies end to end: plan document ->
+        from_plan -> distributed_optimizer -> one step on the 8-device
+        mesh with a finite loss."""
+        import numpy as np
+        from paddle_tpu.parallel import fleet as fleet_mod
+
+        doc = json.loads(json.dumps(bert_search.to_dict()))
+        best = next(p for p in doc["ranked"]
+                    if p["plan"]["fleet_runnable"])
+        strategy = fleet_mod.DistributedStrategy.from_plan(best)
+        x = fluid.data("x", [None, 64], dtype="float32")
+        y = fluid.data("y", [None, 1], dtype="float32")
+        h = fluid.layers.fc(x, size=64, act="relu")
+        loss = fluid.layers.reduce_mean(fluid.layers.square_error_cost(
+            fluid.layers.fc(h, size=1), y))
+        fl = fleet_mod.Fleet().init()
+        fl.distributed_optimizer(
+            fluid.optimizer.Adam(learning_rate=1e-3),
+            strategy).minimize(loss)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(fluid.default_startup_program())
+        rng = np.random.default_rng(0)
+        out = exe.run(fl.main_program, feed={
+            "x": rng.normal(size=(16, 64)).astype(np.float32),
+            "y": rng.normal(size=(16, 1)).astype(np.float32)},
+            fetch_list=[loss])
+        assert np.isfinite(float(np.asarray(out[0])))
+
     def test_pp_mesh_refused(self):
         from paddle_tpu.parallel.fleet import DistributedStrategy
 

@@ -3,10 +3,12 @@ loaded dispatch, shed-aware failover, heartbeat-driven death + standby
 backfill, drain-vs-kill preemption, autoscale, rolling version rollout
 with auto-rollback, and the FileStore per-process transport.
 
-Bit-identity note: same contract as test_serving.py — fleet results
-must equal direct ``Predictor.run`` bit-for-bit for >= 2-row requests,
-across failovers, kills, and the JSON wire format (float32 JSON
-round-trips are exact).
+Identity note: same contract as test_serving.py. A request the fleet
+serves alone at its own batch shape equals direct ``Predictor.run``
+bit for bit, across failovers, kills and the JSON wire format (float32
+JSON round-trips are exact); one served from a coalesced or padded
+micro-batch equals it to the last few units of float32
+(``served_equal`` in conftest.py).
 """
 import os
 import subprocess
@@ -58,7 +60,7 @@ def model_dir(tmp_path):
 # dispatch: balance + bit identity
 # ---------------------------------------------------------------------------
 
-def test_fleet_bit_identity_and_balance(model_dir):
+def test_fleet_bit_identity_and_balance(model_dir, served_equal):
     obs.reset()
     base = Predictor.from_model(str(model_dir))
     router = _fleet(model_dir, n_replicas=2)
@@ -70,7 +72,7 @@ def test_fleet_bit_identity_and_balance(model_dir):
         futs = [router.submit({"x": v}) for v in reqs]
         for f, ref in zip(futs, refs):
             out, = f.result(timeout=30)
-            np.testing.assert_array_equal(out, ref)
+            assert served_equal(out, ref)
         stats = router.stats()
         assert stats["requests"] == 16
         assert stats["router_requests"] == 16
@@ -168,7 +170,7 @@ def test_all_replicas_shedding_exhausts_to_shed_error(model_dir):
         router.stop()
 
 
-def test_kill_replays_queued_requests_on_survivor(model_dir):
+def test_kill_replays_queued_requests_on_survivor(model_dir, served_equal):
     """The drain-then-kill contract, kill side: a dead replica's queued
     requests fail internally with EngineClosedError and the router
     replays every one on a survivor — zero client-visible failures."""
@@ -191,7 +193,7 @@ def test_kill_replays_queued_requests_on_survivor(model_dir):
         victim.kill()
         for f, ref in zip(futs, refs):
             out, = f.result(timeout=30)
-            np.testing.assert_array_equal(out, ref)
+            assert served_equal(out, ref)
         assert obs.counter("serving.failovers") >= 1
     finally:
         router.stop()
@@ -214,7 +216,7 @@ def test_dead_replica_detected_and_standby_backfills(model_dir):
         router.stop()
 
 
-def test_remove_replica_drains_queued_work(model_dir):
+def test_remove_replica_drains_queued_work(model_dir, served_equal):
     """Drain side of the preemption contract: planned removal finishes
     the replica's queue instead of replaying it."""
     obs.reset()
@@ -240,9 +242,10 @@ def test_remove_replica_drains_queued_work(model_dir):
         threading.Thread(target=remove, daemon=True).start()
         for f in futs:
             out, = f.result(timeout=30)
-            np.testing.assert_array_equal(out, base.run({"x": x})[0])
+            assert served_equal(out, base.run({"x": x})[0])
         assert done.wait(timeout=30)
         assert router.replicas_live() == [1]
+        assert obs.gauge("serving.replicas_live") == 1
         # clean departure: the survivor never declared it dead
         assert obs.counter("serving.replica_dead") == 0
         with pytest.raises(KeyError):
@@ -349,7 +352,7 @@ def _hammer(router, base, stop_evt, errors, results):
         results.append((x, out))
 
 
-def test_rolling_reload_zero_downtime(model_dir, tmp_path):
+def test_rolling_reload_zero_downtime(model_dir, tmp_path, served_equal):
     obs.reset()
     d2 = tmp_path / "v2"
     _build_and_save(d2, seed=11)  # genuinely different weights
@@ -375,13 +378,13 @@ def test_rolling_reload_zero_downtime(model_dir, tmp_path):
         assert sorted(done) == [0, 1]
         assert router.dirname == str(d2)
         assert all(r.version == 2 for r in router._live.values())
-        # every mid-rollout answer matches ONE of the two versions
-        # bit-for-bit (old engine finishing vs new engine) — never a blend
+        # every mid-rollout answer is ONE of the two versions' (old
+        # engine finishing vs new engine) — never a blend
         mismatched = 0
         for x, out in results:
             v1 = base_v1.run({"x": x})[0]
             v2 = base_v2.run({"x": x})[0]
-            if not (np.array_equal(out, v1) or np.array_equal(out, v2)):
+            if not (served_equal(out, v1) or served_equal(out, v2)):
                 mismatched += 1
         assert mismatched == 0
         # steady state after the rollout: v2 answers only
@@ -460,7 +463,8 @@ def test_rolling_reload_corrupt_dir_leaves_v1_serving(model_dir, tmp_path):
 # FileStore transport (per-process replicas)
 # ---------------------------------------------------------------------------
 
-def test_store_replica_roundtrip_and_ctl_reload(model_dir, tmp_path):
+def test_store_replica_roundtrip_and_ctl_reload(
+        model_dir, tmp_path, served_equal):
     base = Predictor.from_model(str(model_dir))
     store = FileStore(tmp_path / "store")
     cfg = _cfg()
@@ -476,13 +480,14 @@ def test_store_replica_roundtrip_and_ctl_reload(model_dir, tmp_path):
         x = np.random.default_rng(13).normal(size=(3, 6)) \
             .astype(np.float32)
         out, = router.predict({"x": x}, timeout=30)
-        # float32 JSON round-trip is exact: wire == in-process
-        np.testing.assert_array_equal(out, base.run({"x": x})[0])
+        # the float32 JSON round-trip is exact; the worker pads the 3
+        # rows to the next power of two
+        assert served_equal(out, base.run({"x": x})[0])
 
         assert proxy.reload(model_dir, timeout=30) == 2
         assert worker.version == 2
-        out, = router.predict({"x": x}, timeout=30)
-        np.testing.assert_array_equal(out, base.run({"x": x})[0])
+        out2, = router.predict({"x": x}, timeout=30)
+        np.testing.assert_array_equal(out2, out)  # same shape, same bits
     finally:
         router.stop()
         wt.join(timeout=10)
@@ -508,7 +513,7 @@ def test_store_replica_ctl_reload_failure_acks_error(model_dir, tmp_path):
 
 
 def test_silent_store_replica_requests_replay_on_survivor(
-        model_dir, tmp_path):
+        model_dir, tmp_path, served_equal):
     """A store replica whose worker never comes up: its in-flight
     requests are orphaned until the health loop declares it dead
     (startup grace), fails them with ReplicaGoneError, and the router
@@ -532,7 +537,7 @@ def test_silent_store_replica_requests_replay_on_survivor(
         futs = [router.submit({"x": v}) for v in reqs]
         for f, ref in zip(futs, refs):
             out, = f.result(timeout=30)
-            np.testing.assert_array_equal(out, ref)
+            assert served_equal(out, ref)
         assert router.replicas_live() == [1]
         assert obs.counter("serving.replica_dead") == 1
     finally:

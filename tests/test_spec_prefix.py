@@ -8,8 +8,7 @@ inside a block and a saboteur draft rejected at position 0 every
 round), prefix-pool adopt-then-delta vs cold prefill, pool LRU
 eviction, session hibernate/resume through the tier (bit-exact on the
 fp32 wire, functional on int8), and the ladder-lint + registry
-surfaces. ``pytest -m spec`` is the slice
-``bench_experiments/spec_lane.sh`` runs.
+surfaces. ``pytest -m spec`` runs the slice alone.
 """
 import numpy as np
 import pytest
@@ -187,6 +186,46 @@ def test_prefix_adopt_then_delta_matches_cold(m, armed_sanitizers):
         eng.stop(drain=False)
 
 
+def test_shared_prefix_load_saves_most_prefill_rows(m):
+    """12 requests behind one 24-token system prompt (unique tails of
+    4..8 tokens) through a 2-slot engine with a PrefixPool and a k=4
+    draft: every stream equals the plain engine's, more than half of
+    the prefill rows are adopted instead of computed, and speculation
+    ran with some acceptance."""
+    rng = np.random.default_rng(0)
+    shared = rng.integers(1, 97, 24).astype("int64")
+    prompts = [np.concatenate(
+        [shared, rng.integers(1, 97, 4 + c % 5).astype("int64")])
+        for c in range(12)]
+
+    def drive(eng):
+        handles = [eng.submit(p, max_new=12) for p in prompts]
+        return [h.result(180.0) for h in handles]
+
+    plain = _engine(m, prompt_buckets=(8, 32), queue_capacity=64,
+                    name="spec-plain")
+    try:
+        ref = drive(plain)
+        plain_rows = plain.stats()["prefill_rows_computed"]
+    finally:
+        plain.stop(drain=False)
+    reuse = _engine(
+        m, prompt_buckets=(8, 32), queue_capacity=64, name="spec-reuse",
+        draft=DraftModel(m["dcfg"], m["dscope"], k=4, name="d-reuse"),
+        prefix_pool=PrefixPool(prefix_lens=(24,), name="t-shared"))
+    try:
+        assert drive(reuse) == ref
+        st, info = reuse.stats(), reuse.reuse_info()
+        assert info["prefill_rows_saved_pct"] > 50.0, info
+        assert info["prefill_rows_computed"] < plain_rows, (info, plain_rows)
+        assert st["delta_prefills"] >= 1, st
+        assert st["spec_rounds"] >= 1 and st["spec_accept_rate"] > 0.0, st
+        assert drive(reuse) == ref     # the pool is warm: same streams
+        assert reuse.stats()["prefix_full_hits"] >= 1
+    finally:
+        reuse.stop(drain=False)
+
+
 def test_prefix_pool_lru_eviction_and_min_tokens():
     """Byte-budget LRU: inserting past capacity evicts the coldest
     entry; trivially short prefixes are never cached."""
@@ -250,6 +289,41 @@ def test_session_resume_int8_wire_functional(m, armed_sanitizers):
         assert tier.stats()["wire_dtype"] == "int8"
     finally:
         eng.stop(drain=False)
+
+
+def test_more_sessions_than_slots_all_resume_for_fewer_rows(m):
+    """6 conversations on 2 slots: every one hibernates and resumes, a
+    second turn equals the cold replay of its whole transcript, and the
+    tier computes fewer prefill rows than replaying transcripts does."""
+    rng = np.random.default_rng(1)
+    turn1 = {c: rng.integers(1, 97, 6 + c % 3).astype("int64")
+             for c in range(6)}
+    turn2 = {c: rng.integers(1, 97, 4).astype("int64") for c in range(6)}
+    tier = SessionTier(wire_dtype="fp32", name="t-many")
+    eng = _engine(m, prompt_buckets=(8, 32), session_tier=tier,
+                  name="spec-many")
+    try:
+        first = {c: eng.submit(turn1[c], max_new=6,
+                               session="conv%d" % c).result(180.0)
+                 for c in range(6)}
+        second = {c: eng.submit(turn2[c], max_new=6,
+                                session="conv%d" % c).result(180.0)
+                  for c in range(6)}
+        st = eng.stats()
+        assert st["resumed"] == 6 > eng.slots, st
+        assert tier.stats()["resumed"] == 6
+    finally:
+        eng.stop(drain=False)
+    cold = _engine(m, prompt_buckets=(8, 32), name="spec-replay")
+    try:
+        for c in range(6):
+            transcript = np.concatenate(
+                [turn1[c], np.asarray(first[c], np.int64), turn2[c]])
+            assert cold.generate(transcript, max_new=6) == second[c], c
+        assert (st["prefill_rows_computed"]
+                < cold.stats()["prefill_rows_computed"])
+    finally:
+        cold.stop(drain=False)
 
 
 # ---------------------------------------------------------------------------
