@@ -202,24 +202,32 @@ def build_bert_trainer(full, seq):
     return cfg, vs
 
 
-def run_steps(exe, program, feed, loss, n_steps):
+def run_steps(exe, program, feed, vs, n_steps):
     """Step 1 (compile) alone, then the rest timed as one window that ends
-    in a host read. Returns (losses first/last, compile_s, step_ms)."""
+    in a host read. Every step fetches the loss and the head's two device
+    counters (one fetch list, one compile). Returns (losses first/last,
+    compile_s, step_ms, {head_rows, head_chunks} of the last step)."""
     import numpy as np
 
+    fetch = [vs["loss"], vs["head_rows"], vs["head_chunks"]]
     t0 = time.monotonic()
     first = float(np.asarray(
-        exe.run(program, feed=feed, fetch_list=[loss])[0]))
+        exe.run(program, feed=feed, fetch_list=fetch)[0]))
     compile_s = time.monotonic() - t0
     t0 = time.monotonic()
     for _ in range(n_steps - 1):
-        out = exe.run(program, feed=feed, fetch_list=[loss],
+        out = exe.run(program, feed=feed, fetch_list=fetch,
                       return_numpy=False)
     last = float(np.asarray(out[0]))
     step_ms = 1000 * (time.monotonic() - t0) / (n_steps - 1)
+    head = {"head_rows": int(np.asarray(out[1])),
+            "head_chunks": int(np.asarray(out[2]))}
     assert np.isfinite([first, last]).all(), (first, last)
     assert last < first, "loss did not fall: %s -> %s" % (first, last)
-    return first, last, compile_s, step_ms
+    labelled = int((np.asarray(feed["mlm_labels"]) >= 0).sum())
+    assert head["head_rows"] == labelled and head["head_chunks"] >= 1, (
+        head, labelled)
+    return first, last, compile_s, step_ms, head
 
 
 def phase_trainer(sz, dev, cache):
@@ -242,9 +250,8 @@ def phase_trainer(sz, dev, cache):
         return len(obs.get_recorder().of("compile_start"))
 
     before = compiles()
-    first, last, compile_s, step_ms = run_steps(
-        exe, fluid.default_main_program(), feed, vs["loss"],
-        sz["bert_steps"])
+    first, last, compile_s, step_ms, head = run_steps(
+        exe, fluid.default_main_program(), feed, vs, sz["bert_steps"])
     # run_steps compiled once, in step 1; nothing may compile after it
     assert compiles() == before + 1, (
         "%d compile_start events in %d steps, expected 1 (step 1)"
@@ -260,7 +267,8 @@ def phase_trainer(sz, dev, cache):
     note("trainer", model="bert_base" if full else "bert_tiny",
          layers=cfg.num_layers, hidden=cfg.hidden, batch=sz["bert_batch"],
          seq=sz["bert_seq"], steps=sz["bert_steps"],
-         loss_first=round(first, 4), loss_last=round(last, 4), startup_s=round(startup_s, 1),
+         loss_first=round(first, 4), loss_last=round(last, 4), **head,
+         startup_s=round(startup_s, 1),
          step1_compile_s=round(compile_s, 1), step_ms=round(step_ms, 2),
          persistables_on_device=len(persist),
          peak_bytes_in_use=stats.get("peak_bytes_in_use"),
@@ -579,8 +587,8 @@ def phase_multichip(sz, dev, one_chip, cache):
         assert shard_devices(arr) == devices and (
             arr.addressable_shards[0].data.shape[0] * n == arr.shape[0]), (
             "dp feed %r is not split over %d devices" % (name, n))
-    first, last, compile_s, step_ms = run_steps(
-        exe, cp, feed, vs["loss"], steps)
+    first, last, compile_s, step_ms, head = run_steps(
+        exe, cp, feed, vs, steps)
     placed = state_devices(fluid.global_scope(), prog)
     assert placed and all(d == devices for d in placed), (
         "dp state is not on all %d devices" % n)
@@ -590,7 +598,7 @@ def phase_multichip(sz, dev, one_chip, cache):
             "dp loss %s vs one-chip %s (rtol %s)" % (got, want, DP_LOSS_RTOL))
     note("multichip", mode="data_parallel", devices=n,
          global_batch=n * sz["bert_batch"], loss_first=round(first, 4),
-         loss_last=round(last, 4),
+         loss_last=round(last, 4), **head,
          one_chip_loss_first=round(one_chip["loss_first"], 4),
          one_chip_loss_last=round(one_chip["loss_last"], 4),
          loss_rtol=DP_LOSS_RTOL, step1_compile_s=round(compile_s, 1),
@@ -613,8 +621,8 @@ def phase_multichip(sz, dev, one_chip, cache):
         placed = jax.device_put(arr, dist.feed_sharding(name, arr.shape))
         assert shard_devices(placed) == devices, (
             "dp x tp feed %r is not on all %d devices" % (name, n))
-    first, last, compile_s, step_ms = run_steps(
-        exe, dist, feed, vs["loss"], steps)
+    first, last, compile_s, step_ms, head = run_steps(
+        exe, dist, feed, vs, steps)
     scope = fluid.global_scope()
     placed = state_devices(scope, prog)
     assert placed and all(d == devices for d in placed), (
@@ -625,7 +633,8 @@ def phase_multichip(sz, dev, one_chip, cache):
             "enc_l0_qkv.w is not column-sharded over tp: %s" % qkv.sharding)
     note("multichip", mode="dp_x_tp", dp=dp, tp=tp,
          global_batch=dp * sz["bert_batch"], loss_first=round(first, 4),
-         loss_last=round(last, 4), step1_compile_s=round(compile_s, 1),
+         loss_last=round(last, 4), **head,
+         step1_compile_s=round(compile_s, 1),
          step_ms=round(step_ms, 2), **cache.take())
 
 

@@ -43,9 +43,15 @@ def main():
     feed = {"input_ids": ids, "mlm_labels": labels}
     t0 = time.time()
     for step in range(args.steps):
-        loss = exe.run(feed=feed, fetch_list=[vs["loss"]])[0]
+        # head_rows / head_chunks: the labelled positions of the step and
+        # the chunks of them the fused vocabulary head ran, counted on the
+        # device
+        loss, rows, chunks = exe.run(
+            feed=feed,
+            fetch_list=[vs["loss"], vs["head_rows"], vs["head_chunks"]])
         if step % 10 == 0 or step == args.steps - 1:
-            print("step %d loss %.4f" % (step, float(np.asarray(loss))))
+            print("step %d loss %.4f head_rows %d head_chunks %d"
+                  % (step, float(np.asarray(loss)), int(rows), int(chunks)))
     dt = time.time() - t0
     print("%.0f tokens/sec" % (args.steps * args.batch * seq / dt))
 

@@ -517,6 +517,22 @@ def _prim_flops(eqn, prim):
     return float(out_size)
 
 
+def _head_flops(op, env):
+    """The fused vocabulary head at its most: its loop runs once per chunk
+    of LABELLED rows, a count no shape gives, so the bound is every row
+    labelled: one product of all rows with W and the softmax's passes
+    over rows x vocabulary (the count of the pair it replaces)."""
+    x, w = env[op.input("X")[0]], env[op.input("W")[0]]
+    rows = _aval_size(x) // int(x.shape[-1])
+    vocab, hidden = (int(d) for d in w.shape)
+    return 2.0 * rows * hidden * vocab + 6.0 * rows * vocab
+
+
+# ops whose lowering loops a value-dependent number of times (jaxpr_flops
+# counts one trip of a `while`): costed by rule, at their upper bound
+_FLOP_RULES = {"linear_softmax_with_cross_entropy": _head_flops}
+
+
 # -- per-op costing over a Program ------------------------------------------
 
 class OpCost:
@@ -589,7 +605,8 @@ def op_costs(program, env, is_test=False, platform="cpu"):
             closed = jax.make_jaxpr(f)(sub_env)
         except Exception:  # noqa: BLE001 — shapes.propagate reports these
             continue
-        flops = jaxpr_flops(closed.jaxpr)
+        rule = _FLOP_RULES.get(op.type)
+        flops = rule(op, env) if rule else jaxpr_flops(closed.jaxpr)
         nbytes = (sum(_spec_nbytes(env[n]) for n in reads)
                   + sum(_spec_nbytes(env[n])
                         for ns in op.outputs.values() for n in ns

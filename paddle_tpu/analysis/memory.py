@@ -144,6 +144,30 @@ class MemoryReport:
         return d
 
 
+def _head_workspace(program, op, sizes):
+    """Scratch of the fused vocabulary head (its forward and, under the
+    backward op, its gradient loop): two float32 arrays of one chunk of
+    rows by the vocabulary (logits, softmax gradient) and the float32
+    accumulator of W's gradient. The labelled rows are unknown before the
+    labels are fed; the chunk is what the rows' bound allows."""
+    from ..ops.loss_ops import head_chunk_rows
+
+    gb = program.global_block()
+    x, w = op.input("X")[0], op.input("W")[0]
+    if x not in sizes or not gb.has_var(w) or not gb.has_var(x):
+        return 0
+    shape = gb.var(w).shape
+    if not shape or len(shape) != 2 or min(shape) <= 0:
+        return 0
+    vocab, hidden = int(shape[0]), int(shape[1])
+    rows = sizes[x] // var_nbytes((hidden,), gb.var(x).dtype)
+    return 4 * (2 * head_chunk_rows(rows, vocab) * vocab + vocab * hidden)
+
+
+# ops that hold scratch of their own beyond their named outputs
+_WORKSPACE = {"linear_softmax_with_cross_entropy": _head_workspace}
+
+
 def _ceil_div(a, b):
     return -(-int(a) // int(b))
 
@@ -229,6 +253,18 @@ def estimate(program, env=None, feed_specs=None, state_specs=None,
 
     # sweep: +size at def, -size after last use
     delta = [0] * (n_ops + 1)
+    held = 0   # scratch of the forward ops, held again by a backward op
+    for i, op in enumerate(gb.ops):
+        if op.type in _WORKSPACE:
+            b = _ceil_div(_WORKSPACE[op.type](program, op, sizes),
+                          act_shards)
+            held = max(held, b)
+        elif op.type == "backward":
+            b = held
+        else:
+            continue
+        delta[i] += b
+        delta[i + 1] -= b
     for _n, (start, end, b) in transient.items():
         delta[start] += b
         delta[end + 1] -= b

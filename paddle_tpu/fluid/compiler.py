@@ -163,8 +163,13 @@ class CompiledProgram:
         )
         entry = self._cache.get(sig)
         if entry is None:
+            # the mesh marks the lowering as partitioned over 'dp': ops
+            # with a per-shard formulation (the fused vocabulary head's
+            # row lists) take it instead of what the partitioner would
+            # make of their single-device one
             step = build_step_fn(program, list(feed_arrays), fetch_names,
-                                 platform=executor.place._backend)
+                                 platform=executor.place._backend,
+                                 mesh_axes={"dp": "dp"}, mesh=mesh)
             # shardings are carried by the committed input arrays (feeds
             # batch-sharded over 'dp', state replicated); XLA partitions the
             # whole step and inserts the ICI collectives for the vjp grads

@@ -16,7 +16,8 @@ from ...layer_helper import LayerHelper
 __all__ = ["decorate", "AutoMixedPrecisionLists", "bf16_compute_guard"]
 
 # ops whose inputs are worth computing in bf16 (MXU ops)
-WHITE_LIST = {"mul", "matmul", "conv2d", "conv3d", "depthwise_conv2d"}
+WHITE_LIST = {"mul", "matmul", "conv2d", "conv3d", "depthwise_conv2d",
+              "linear_softmax_with_cross_entropy"}
 # ops that must stay fp32
 BLACK_LIST = {
     "softmax_with_cross_entropy", "cross_entropy", "cross_entropy2",

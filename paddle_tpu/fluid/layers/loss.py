@@ -6,7 +6,8 @@ __all__ = [
     "center_loss", "bpr_loss", "cross_entropy", "cross_entropy2",
     "square_error_cost", "edit_distance",
     "warpctc", "nce", "hsigmoid", "sampled_softmax_with_cross_entropy",
-    "softmax_with_cross_entropy", "rank_loss", "margin_rank_loss",
+    "softmax_with_cross_entropy", "linear_softmax_with_cross_entropy",
+    "rank_loss", "margin_rank_loss",
     "sigmoid_cross_entropy_with_logits", "teacher_student_sigmoid_loss",
     "huber_loss", "kldiv_loss", "npair_loss", "mse_loss",
 ]
@@ -44,6 +45,38 @@ def softmax_with_cross_entropy(
     )
     if return_softmax:
         return loss, softmax
+    return loss
+
+
+def linear_softmax_with_cross_entropy(
+    x, weight, label, ignore_index=-100, return_counts=False
+):
+    """A vocabulary head and its loss in one op:
+    ``softmax_with_cross_entropy(matmul(x, weight, transpose_y=True),
+    label, ignore_index=ignore_index)`` for ``x`` [..., H], ``weight``
+    [V, H] and ``label`` [...] or [..., 1], computed for the positions
+    whose label is not ``ignore_index`` only, a chunk of rows at a time,
+    so no [..., V] array exists in the forward or the backward pass. The
+    loss is [..., 1], float32, zero at ignored positions. With
+    ``return_counts`` also two int32 scalars counted on the device: the
+    labelled positions of the step and the chunks the loop ran."""
+    helper = LayerHelper("linear_softmax_with_cross_entropy", **locals())
+    loss = helper.create_variable_for_type_inference("float32")
+    if x.shape is not None:
+        loss.shape = tuple(x.shape[:-1]) + (1,)
+    rows = helper.create_variable_for_type_inference("int32")
+    chunks = helper.create_variable_for_type_inference("int32")
+    rows.shape = chunks.shape = ()
+    for v in (rows, chunks):
+        v.stop_gradient = True
+    helper.append_op(
+        type="linear_softmax_with_cross_entropy",
+        inputs={"X": [x], "W": [weight], "Label": [label]},
+        outputs={"Loss": [loss], "Rows": [rows], "Chunks": [chunks]},
+        attrs={"ignore_index": ignore_index},
+    )
+    if return_counts:
+        return loss, rows, chunks
     return loss
 
 

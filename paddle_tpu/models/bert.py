@@ -166,20 +166,25 @@ def build_bert_pretrain(cfg, seq_len, is_test=False):
         )
     for i in range(cfg.num_layers):
         x = _encoder_layer(x, cfg, i, None, is_test)
-    # MLM head: tied output embedding
+    # MLM head: tied output embedding. Head and loss are one op over the
+    # labelled positions only; the [B, T, V] logits exist where a caller
+    # wants them (is_test) and nowhere in the training graph.
     word_emb_var = fluid.default_main_program().global_block().var("word_emb")
-    logits = layers.matmul(x, word_emb_var, transpose_y=True)
-    loss = layers.softmax_with_cross_entropy(
-        logits, layers.unsqueeze(mlm_labels, [2]), ignore_index=-1
+    loss, head_rows, head_chunks = layers.linear_softmax_with_cross_entropy(
+        x, word_emb_var, mlm_labels, ignore_index=-1, return_counts=True
     )
     mean_loss = layers.mean(loss)
-    return {
+    vs = {
         "input_ids": ids,
         "mlm_labels": mlm_labels,
         "encoder_out": x,
-        "logits": logits,
         "loss": mean_loss,
+        "head_rows": head_rows,
+        "head_chunks": head_chunks,
     }
+    if is_test:
+        vs["logits"] = layers.matmul(x, word_emb_var, transpose_y=True)
+    return vs
 
 
 def tp_rules():

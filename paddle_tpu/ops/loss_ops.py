@@ -1,6 +1,8 @@
 """Loss op lowerings (ref: paddle/fluid/operators/cross_entropy_op.cc,
 softmax_with_cross_entropy_op.cc, squared_l2_distance, bce ops, hinge,
 huber, margin_rank, etc.)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -59,6 +61,202 @@ def _softmax_with_ce(ctx, ins, attrs):
         loss = jnp.where(lab == ignore, 0.0, loss)
         loss = loss[..., None]
     return {"Softmax": [softmax], "Loss": [loss]}
+
+
+def head_chunk_rows(n_rows, vocab):
+    """Rows of one chunk of the fused vocabulary head, from shapes alone:
+    float32 logits of about a quarter of a GB (2,048 rows at a vocabulary
+    of 30,522), a multiple of 256, and no more than the rows there are."""
+    r = max(256, (1 << 26) // max(int(vocab), 1) // 256 * 256)
+    return min(r, -(-int(n_rows) // 8) * 8)
+
+
+def _head_rows(x, lab, vocab, ignore, dp):
+    """What both loops of the fused head start from: x and lab flattened
+    to rows; the chunk size r; the labelled rows first, in their order (a
+    sort of the row indices alone), padded to whole chunks; each row's
+    place in that list; the trips of the loop, the same on every shard of
+    `dp`."""
+    x, lab = x.reshape(-1, x.shape[-1]), lab.reshape(-1).astype(jnp.int32)
+    n = lab.shape[0]
+    r = head_chunk_rows(n, vocab)
+    keep = lab != ignore
+    count = jnp.sum(keep, dtype=jnp.int32)
+    iota = lax.iota(jnp.int32, n)
+    order = jnp.sort(jnp.where(keep, iota, iota + n))
+    rows = jnp.pad(jnp.where(order >= n, order - n, order),
+                   (0, -(-n // r) * r - n))
+    pos = jnp.maximum(jnp.cumsum(keep, dtype=jnp.int32) - 1, 0)
+    trips = (count + (r - 1)) // r
+    trips = lax.pmax(trips, dp) if dp else trips
+    return x, lab, r, keep, count, rows, pos, trips
+
+
+def _head_chunk(x, w, lab, rows, c, r, tp):
+    """Chunk `c` of the compacted rows: their hidden states, their float32
+    logits over the vocabulary this device holds, and their labels as
+    columns of it (out of range where another device holds the column)."""
+    idx = lax.dynamic_slice(rows, (c * r,), (r,))
+    xr = jnp.take(x, idx, axis=0)
+    logits = lax.dot_general(xr, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    v = w.shape[0]
+    col = jnp.take(lab, idx)
+    if tp:
+        col = (jnp.clip(col, 0, v * lax.axis_size(tp) - 1)
+               - lax.axis_index(tp) * v)
+    else:
+        col = jnp.clip(col, 0, v - 1)
+    return idx, xr, logits, col
+
+
+def _head_fwd_local(x, w, lab, ignore, dp, tp):
+    """Losses of the rows one device holds, a chunk of labelled rows at a
+    time; `tp` names the axis the vocabulary is split over, `dp` the axis
+    whose shards must make the same number of trips."""
+    shape = lab.shape
+    x, lab, r, keep, count, rows, pos, trips = _head_rows(
+        x, lab, w.shape[0], ignore, dp)
+
+    def body(state):
+        c, loss_c, lse_c = state
+        _, _, logits, col = _head_chunk(x, w, lab, rows, c, r, tp)
+        m = jnp.max(logits, axis=-1)
+        m = lax.pmax(m, tp) if tp else m
+        s = jnp.sum(jnp.exp(logits - m[:, None]), axis=-1)
+        lse = m + jnp.log(lax.psum(s, tp) if tp else s)
+        held = (col >= 0) & (col < w.shape[0])
+        picked = jnp.where(held, jnp.take_along_axis(
+            logits, jnp.clip(col, 0, w.shape[0] - 1)[:, None], axis=-1
+        )[:, 0], 0.0)
+        picked = lax.psum(picked, tp) if tp else picked
+        at = (c * r,)
+        return (c + 1, lax.dynamic_update_slice(loss_c, lse - picked, at),
+                lax.dynamic_update_slice(lse_c, lse, at))
+
+    zeros = jnp.zeros(rows.shape, jnp.float32)
+    _, loss_c, lse_c = lax.while_loop(
+        lambda state: state[0] < trips, body, (jnp.int32(0), zeros, zeros))
+    loss = jnp.where(keep, jnp.take(loss_c, pos), 0.0)
+    n_rows = lax.psum(count, dp) if dp else count
+    return loss.reshape(shape + (1,)), lse_c, n_rows, trips
+
+
+def _head_bwd_local(x, w, lab, lse_c, g, ignore, dp, tp):
+    """The same loop for the gradients: the chunk's logits again,
+    `softmax - onehot` scaled by each row's cotangent, one product into
+    the rows' dX and one into a float32 dW."""
+    x_shape = x.shape
+    x, lab, r, keep, count, rows, pos, trips = _head_rows(
+        x, lab, w.shape[0], ignore, dp)
+    g = g.reshape(-1).astype(jnp.float32)
+    cols = lax.iota(jnp.int32, w.shape[0])
+
+    def body(state):
+        c, dx_c, dw = state
+        idx, xr, logits, col = _head_chunk(x, w, lab, rows, c, r, tp)
+        lse = lax.dynamic_slice(lse_c, (c * r,), (r,))
+        live = c * r + lax.iota(jnp.int32, r) < count
+        scale = jnp.where(live, jnp.take(g, idx), 0.0)
+        d = ((jnp.exp(logits - lse[:, None])
+              - (cols[None, :] == col[:, None])) * scale[:, None]
+             ).astype(x.dtype)
+        dxr = lax.dot_general(d, w, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dxr = lax.psum(dxr, tp) if tp else dxr
+        dw = dw + lax.dot_general(d, xr, (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        return (c + 1, lax.dynamic_update_slice(
+            dx_c, dxr.astype(x.dtype), (c * r, 0)), dw)
+
+    _, dx_c, dw = lax.while_loop(
+        lambda state: state[0] < trips, body,
+        (jnp.int32(0), jnp.zeros((rows.shape[0], x.shape[1]), x.dtype),
+         jnp.zeros(w.shape, jnp.float32)))
+    dx = jnp.where(keep[:, None], jnp.take(dx_c, pos, axis=0), 0)
+    dw = lax.psum(dw, dp) if dp else dw
+    return dx.reshape(x_shape), dw.astype(w.dtype)
+
+
+def _head_sharded(local, spmd, args, outs):
+    """`local` over the rows and the vocabulary each device holds: one
+    list of labelled rows per batch shard, so no hidden state crosses
+    devices. `args` / `outs` name what each argument and result is split
+    along: "b" the batch, "v" the vocabulary, "-" nothing."""
+    if spmd is None:
+        return functools.partial(local, dp=None, tp=None)
+    from jax.sharding import PartitionSpec as P
+
+    mesh, dp, tp = spmd
+    spec = {"b": P(dp), "v": P(tp), "-": P()}
+    return jax.shard_map(
+        functools.partial(local, dp=dp, tp=tp), mesh=mesh,
+        in_specs=tuple(spec[k] for k in args),
+        out_specs=tuple(spec[k] for k in outs), check_vma=False)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def linear_softmax_ce(x, w, lab, ignore, spmd):
+    """Softmax cross-entropy of `x @ w.T` against `lab`, computed for the
+    rows whose label is not `ignore` only, a chunk of rows at a time: no
+    array of rows x vocabulary is ever built. x [B, T, H], w [V, H],
+    lab [B, T]; returns (loss [B, T, 1] float32, zero at ignored rows; the
+    count of labelled rows; the trips of the loop). `spmd` is None or
+    (mesh, batch axis or None, vocabulary axis or None)."""
+    return _linear_softmax_ce_fwd(x, w, lab, ignore, spmd)[0]
+
+
+def _linear_softmax_ce_fwd(x, w, lab, ignore, spmd):
+    loss, lse_c, n_rows, trips = _head_sharded(
+        functools.partial(_head_fwd_local, ignore=ignore), spmd,
+        "bvb", "bb--")(x, w, lab)
+    return (loss, n_rows, trips), (x, w, lab, lse_c)
+
+
+def _linear_softmax_ce_bwd(ignore, spmd, res, cts):
+    dx, dw = _head_sharded(
+        functools.partial(_head_bwd_local, ignore=ignore), spmd,
+        "bvbbb", "bv")(*res, cts[0])
+    return dx, dw, None
+
+
+linear_softmax_ce.defvjp(_linear_softmax_ce_fwd, _linear_softmax_ce_bwd)
+
+
+def _head_spmd(ctx, x, w):
+    """How the rows and the vocabulary are split when the lowering is
+    partitioned over a mesh: (mesh, 'dp' axis, 'tp' axis), an axis None
+    where it does not divide; None on one device, and inside a shard_map
+    (the arrays are one shard's already)."""
+    mesh = ctx.mesh
+    if mesh is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+
+    def axis(name, dim):
+        a = ctx.mesh_axes.get(name)
+        if a in mesh.shape and mesh.shape[a] > 1 and dim % mesh.shape[a] == 0:
+            return a
+        return None
+
+    dp, tp = axis("dp", x.shape[0]), axis("tp", w.shape[0])
+    return (mesh, dp, tp) if dp or tp else None
+
+
+@register_op("linear_softmax_with_cross_entropy")
+def _linear_softmax_with_ce(ctx, ins, attrs):
+    """The vocabulary head and its loss in one op: Loss = softmax cross-
+    entropy of X @ W^T against Label, over the rows whose label is not
+    `ignore_index` only (ops above). Rows / Chunks count, on the device,
+    the labelled rows of the step and the trips the loop made."""
+    x, w, label = ins["X"][0], ins["W"][0], ins["Label"][0]
+    lab = label[..., 0] if label.ndim == x.ndim else label
+    lead = x.shape[:-1]
+    x3 = x.reshape(lead[0], -1, x.shape[-1])
+    loss, n_rows, trips = linear_softmax_ce(
+        x3, w, lab.reshape(x3.shape[:2]),
+        int(attrs.get("ignore_index", -100)), _head_spmd(ctx, x3, w))
+    return {"Loss": [loss.reshape(lead + (1,))], "Rows": [n_rows],
+            "Chunks": [trips]}
 
 
 @register_op("sigmoid_cross_entropy_with_logits")

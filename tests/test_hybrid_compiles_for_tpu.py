@@ -80,3 +80,46 @@ def test_the_step_at_published_widths_aliases_all_its_state(one_chip):
     weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
                   for s in params.values())
     assert round(weights / 1e9, 1) == 9.3
+
+
+def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
+    """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
+    vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
+    described chip: the loop's scratch is chunks of 2,048 rows, and no
+    array of rows x vocabulary exists (in bfloat16 alone it would be 2 GB).
+    It lives in this file because only one test file may load the TPU's
+    library (see the fixture)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import loss_ops
+
+    rows, hidden, vocab = (256, 128), 768, 30522
+    assert loss_ops.head_chunk_rows(rows[0] * rows[1], vocab) == 2048
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss_and_grads(x, w, lab):
+        def mean_loss(x_, w_):
+            loss, n_rows, trips = loss_ops.linear_softmax_ce(
+                x_, w_, lab, -1, None)
+            return jnp.mean(loss), (n_rows, trips)
+        return jax.value_and_grad(mean_loss, (0, 1), has_aux=True)(x, w)
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(loss_and_grads).lower(
+            sds(rows + (hidden,), "bfloat16"), sds((vocab, hidden), "bfloat16"),
+            sds(rows, "int32")).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    sizes = [int(np.prod([int(d) for d in m.group(1).split(",")]))
+             for m in re.finditer(r"\b(?:bf16|f32|s32|pred)\[([0-9,]+)\]",
+                                  compiled.as_text())]
+    assert max(sizes) == 2048 * vocab         # one chunk of float32 logits
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
