@@ -17,7 +17,10 @@ __all__ = ["decorate", "AutoMixedPrecisionLists", "bf16_compute_guard"]
 
 # ops whose inputs are worth computing in bf16 (MXU ops)
 WHITE_LIST = {"mul", "matmul", "conv2d", "conv3d", "depthwise_conv2d",
-              "linear_softmax_with_cross_entropy"}
+              "linear_softmax_with_cross_entropy", "held_experts_ffn"}
+# white-list ops of which only these slots are MXU operands: the others
+# (a router's float32 weights on each assignment) stay as they are
+WHITE_SLOTS = {"held_experts_ffn": ("X", "W1", "W2", "W3")}
 # ops that must stay fp32
 BLACK_LIST = {
     "softmax_with_cross_entropy", "cross_entropy", "cross_entropy2",
@@ -45,8 +48,9 @@ def _rewrite_program_bf16(program, amp_lists):
     cast_cache = {}
     for op in list(block.ops):
         if op.type in amp_lists.white_list:
+            only = WHITE_SLOTS.get(op.type)
             for slot, names in op.inputs.items():
-                if slot in ("Param",):
+                if slot in ("Param",) or (only and slot not in only):
                     continue
                 casted = []
                 for n in names:

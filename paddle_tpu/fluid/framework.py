@@ -156,13 +156,18 @@ class NameScope:
 _name_scope = NameScope()
 
 
+_scope_prefixes = []  # the open scopes' prefixes as the caller wrote them
+
+
 @contextlib.contextmanager
 def name_scope(prefix=None):
     global _name_scope
     _name_scope = _name_scope.child(prefix or "")
+    _scope_prefixes.append(prefix or "")
     try:
         yield
     finally:
+        _scope_prefixes.pop()
         _name_scope = _name_scope.parent()
 
 
@@ -288,6 +293,14 @@ class Operator:
         self.block = block
         self.type = type
         self.attrs = dict(attrs or {})
+        # ops appended inside `fluid.name_scope(...)` remember it (ref
+        # framework.py's op_namescope attr; here the prefixes as written,
+        # without the suffix that tells two scopes of one name apart): the
+        # lowering opens it as a jax.named_scope, so a device trace groups
+        # the ops of every block of a kind under one name
+        if _scope_prefixes:
+            self.attrs.setdefault(
+                "op_namescope", "/%s/" % "/".join(_scope_prefixes))
         self.inputs = self._canonicalize(inputs)
         self.outputs = self._canonicalize(outputs)
         # op provenance for failure diagnosis (ref records op_callstack

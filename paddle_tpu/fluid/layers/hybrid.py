@@ -1,8 +1,8 @@
 """Layers of hybrid state-space / attention / sparse-expert decoders: RMS
 norm (plain, grouped, gated), squared ReLU, a causal depthwise convolution
 with carried window, the Mamba-2 recurrence (chunked scan and one step),
-grouped-query attention, sigmoid top-k routing and a product that keeps
-its float32 accumulator. The lowerings are ``paddle_tpu/ops/hybrid_ops.py``;
+a rotary position term, grouped-query attention, sigmoid top-k routing and
+a product that keeps its float32 accumulator. The lowerings are ``paddle_tpu/ops/hybrid_ops.py``;
 the held-experts layer built on the router is
 ``paddle_tpu.parallel.moe.held_experts_ffn``.
 """
@@ -10,7 +10,8 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "relu_squared", "dense_acc32", "causal_conv1d",
-           "mamba2_scan", "mamba2_step", "gqa_attention", "moe_route_topk"]
+           "mamba2_scan", "mamba2_step", "rotary_embedding", "gqa_attention",
+           "moe_route_topk"]
 
 
 def _out(helper, dtype, shape):
@@ -19,8 +20,10 @@ def _out(helper, dtype, shape):
     return v
 
 
-def _param(helper, name, shape, dtype):
-    return helper.create_parameter(ParamAttr(name=name), list(shape), dtype)
+def _param(helper, name, shape, dtype, trainable=True, learning_rate=1.0):
+    return helper.create_parameter(
+        ParamAttr(name=name, trainable=trainable,
+                  learning_rate=learning_rate), list(shape), dtype)
 
 
 def rms_norm(x, name, epsilon=1e-5, groups=1, gate=None):
@@ -124,38 +127,72 @@ def mamba2_step(xbc, dt, state, name, heads, head_dim, groups, state_size):
                 (xbc.shape[0], heads * head_dim), state.shape, {})
 
 
+def rotary_embedding(x, theta):
+    """Rotary position term over ``x`` (B, T, heads, head_dim): position t
+    along axis 1 turns pair ``(x[i], x[i + head_dim/2])`` of every head by
+    ``t * theta^(-2i/head_dim)``. No parameter."""
+    helper = LayerHelper("rotary_embedding")
+    out = _out(helper, x.dtype, x.shape)
+    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+    return out
+
+
 def gqa_attention(q, k, v, heads, kv_heads, pos=None):
     """Softmax attention of ``heads`` query heads over ``kv_heads``
-    key/value heads, no position term. Causal over (B, T, .) inputs, or
-    with ``pos`` (B, 1) over a slot cache of which row b sees positions
-    <= pos[b]."""
+    key/value heads, no position term of its own. Causal over (B, T, .)
+    inputs, or with ``pos`` (B, 1) over a slot cache of which row b sees
+    positions <= pos[b]. A causal call of 1,024 positions or more runs as
+    the Pallas flash kernels on an unsharded TPU program
+    (``ops.hybrid_ops.FLASH_MIN_SEQ``), so no (T, T) scores are held for
+    the backward pass."""
     helper = LayerHelper("gqa_attention")
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if pos is not None:
         inputs["Pos"] = [pos]
+    attrs = {"heads": int(heads), "kv_heads": int(kv_heads)}
     out = _out(helper, q.dtype, q.shape)
     helper.append_op(type="gqa_attention", inputs=inputs,
-                     outputs={"Out": [out]},
-                     attrs={"heads": int(heads), "kv_heads": int(kv_heads)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
-def moe_route_topk(x, num_experts, k, name, scale=1.0):
+def moe_route_topk(x, num_experts, k, name, scale=1.0, norm_eps=None,
+                   bias_update_rate=0.0, apply_gradient=True):
     """Sigmoid top-k router over ``num_experts`` in float32: ``x`` (T, H)
     -> ``(index (T, k) int32, weight (T, k) float32)``, the weights
-    normalised over the k chosen and multiplied by ``scale``. Parameters
-    ``<name>.w`` (H, experts) and ``<name>.bias`` (experts, float32, the
-    score correction added for the choice only)."""
+    normalised over the k chosen (``norm_eps``, default 1e-20, added to
+    their sum) and multiplied by ``scale``. Parameters ``<name>.w`` (H,
+    experts) and ``<name>.bias`` (experts, float32, the score correction
+    added for the choice only: a buffer no optimizer trains). With
+    ``bias_update_rate`` > 0 every call also moves that buffer by the
+    auxiliary-loss-free balancing rule, from the call's own counts: up by
+    the rate for an expert under its even share of the assignments, down
+    for one over it; the call itself chooses with the buffer as it was.
+    ``apply_gradient`` false is for one chip's share of an expert-parallel
+    layer, where the gradient through ``weight`` is a partial sum (only the
+    held experts' terms): it is still computed down to ``<name>.w``, whose
+    learning rate is 0 (the optimizer's state holds it, nothing moves), and
+    goes no further into ``x``."""
     helper = LayerHelper("moe_route_topk")
     t = x.shape[0]
     inputs = {"X": [x],
               "Gate": [_param(helper, name + ".w",
-                              [x.shape[-1], num_experts], x.dtype)],
+                              [x.shape[-1], num_experts], x.dtype,
+                              learning_rate=float(bool(apply_gradient)))],
               "Bias": [_param(helper, name + ".bias", [num_experts],
-                              "float32")]}
+                              "float32", trainable=False)]}
     idx = _out(helper, "int32", (t, k))
     wt = _out(helper, "float32", (t, k))
-    helper.append_op(type="moe_route_topk", inputs=inputs,
-                     outputs={"Index": [idx], "Weight": [wt]},
-                     attrs={"k": int(k), "scale": float(scale)})
+    attrs = {"k": int(k), "scale": float(scale)}
+    if norm_eps is not None:
+        attrs["norm_eps"] = float(norm_eps)
+    outputs = {"Index": [idx], "Weight": [wt]}
+    if not apply_gradient:
+        attrs["detach_input"] = True
+    if bias_update_rate:
+        attrs["bias_update_rate"] = float(bias_update_rate)
+        outputs["BiasOut"] = inputs["Bias"]
+    helper.append_op(type="moe_route_topk", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
     return idx, wt

@@ -93,8 +93,9 @@ def apply_op(op, env, ctx, var_lookup, op_tag=0):
     ctx.run_ops = run_ops
     # the HLO metadata of every device operation then says which op of
     # which block it came from ("mul/encoder_layer_7_ffn_fc_0.tmp_0")
-    scope = "%s/%s" % (op.type, next(
-        (names[0] for names in op.outputs.values() if names), ""))
+    scope = "%s%s/%s" % (
+        op.attrs.get("op_namescope", "/")[1:], op.type, next(
+            (names[0] for names in op.outputs.values() if names), ""))
     try:
         with jax.named_scope(scope):
             outs = fn(ctx, ins, op.attrs)

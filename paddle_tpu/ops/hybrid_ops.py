@@ -7,6 +7,8 @@ float32; norms, the router, ``dt``, ``exp(dt A)`` and the state-space state
 are float32; what goes back onto the residual stream is rounded to the
 input's dtype.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -16,10 +18,10 @@ from .registry import register_op, single
 F32 = jnp.float32
 
 
-def _dot_f32(x, w):
+def _dot_f32(x, w, precision=None):
     """x (..., K) @ w (K, N) -> float32 (..., N), operands as stored."""
     return lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())),
-                           preferred_element_type=F32)
+                           precision=precision, preferred_element_type=F32)
 
 
 @register_op("dense_acc32")
@@ -182,17 +184,71 @@ def _mamba2_scan(ctx, ins, attrs):
             "StateOut": [last.reshape(b, g * hg, p, n)]}
 
 
+@register_op("rotary_embedding")
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary position term over whole heads, half-split pairs: X (B, T,
+    heads, dh), position t = 0..T-1 along axis 1, pair i of a head is
+    ``(x[i], x[i + dh/2])`` turned by ``t * theta^(-2i/dh)``. Computed in
+    float32, returned in X's dtype."""
+    x = ins["X"][0]
+    t, dh = x.shape[1], x.shape[-1]
+    half = dh // 2
+    freq = float(attrs["theta"]) ** (
+        -jnp.arange(half, dtype=F32) * 2.0 / dh)
+    ang = jnp.arange(t, dtype=F32)[:, None] * freq[None, :]   # (T, dh/2)
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(F32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return single(out.astype(x.dtype))
+
+
+FLASH_MIN_SEQ = 1024    # a causal call this long takes the flash kernels
+FLASH_BLOCK = 512       # query and key tile of the flash kernels here:
+# 23.6 ms forward + backward at 4 x 32 heads x 4,096 x 64 where 256 reads
+# 41.7 and 128 reads 81.5; 1,024 runs out of fast memory (PERF.md)
+
+
+def _flash_gqa(q, k, v, nh, nkv):
+    """Causal attention through the Pallas flash kernels (forward, dq,
+    dk/dv; ``ops/pallas_attention.py``): no (T, T) score array exists in
+    either pass. The kernels take one key/value head per query head, so
+    the key/value heads are repeated; the sum over a group in the
+    backward pass is the repeat's own transpose."""
+    from .pallas_attention import flash_attention
+
+    b, t, _ = q.shape
+    dh = q.shape[-1] // nh
+
+    def heads_first(x, n):
+        return jnp.swapaxes(x.reshape(b, t, n, dh), 1, 2)   # (B, n, T, dh)
+
+    kh = jnp.repeat(heads_first(k, nkv), nh // nkv, axis=1)
+    vh = jnp.repeat(heads_first(v, nkv), nh // nkv, axis=1)
+    out = flash_attention(heads_first(q, nh), kh, vh, causal=True,
+                          block_q=FLASH_BLOCK, block_k=FLASH_BLOCK)
+    return jnp.swapaxes(out, 1, 2).reshape(b, t, nh * dh)
+
+
 @register_op("gqa_attention")
 def _gqa_attention(ctx, ins, attrs):
     """Softmax attention with fewer key/value heads than query heads; no
-    position term. Q (B, Tq, heads * dh), K/V (B, Tk, kv_heads * dh).
-    With ``Pos`` (B, 1) the keys are a slot cache and row b sees
-    positions <= pos[b]; without it Tq == Tk and the mask is causal."""
+    position term of its own. Q (B, Tq, heads * dh), K/V (B, Tk,
+    kv_heads * dh). With ``Pos`` (B, 1) the keys are a slot cache and row
+    b sees positions <= pos[b]; without it Tq == Tk and the mask is
+    causal. A causal call of at least FLASH_MIN_SEQ positions runs through
+    the flash kernels on the TPU (a training sequence of 4,096 would
+    otherwise hold (B, heads, T, T) float32 scores); shorter calls, other
+    platforms and a sharded program take the products below."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     nh, nkv = int(attrs["heads"]), int(attrs["kv_heads"])
     b, tq, _ = q.shape
     tk = k.shape[1]
     dh = q.shape[-1] // nh
+    if (not ins.get("Pos") and tq >= FLASH_MIN_SEQ
+            and getattr(ctx, "platform", None) == "tpu"
+            and not getattr(ctx, "mesh_axes", None)):
+        return single(_flash_gqa(q, k, v, nh, nkv))
     qg = q.reshape(b, tq, nkv, nh // nkv, dh)
     kg = k.reshape(b, tk, nkv, dh)
     vg = v.reshape(b, tk, nkv, dh)
@@ -214,48 +270,213 @@ def _gqa_attention(ctx, ins, attrs):
 def _moe_route_topk(ctx, ins, attrs):
     """Sigmoid top-k routing in float32 over ALL experts: scores
     ``sigmoid(x W_g)``, the k largest of ``score + bias`` chosen, their
-    scores normalised over the k chosen and scaled. -> Index (T, k) int32,
-    Weight (T, k) float32."""
-    s = jax.nn.sigmoid(_dot_f32(ins["X"][0], ins["Gate"][0]))
+    scores normalised over the k chosen (``norm_eps`` added to their sum)
+    and scaled. -> Index (T, k) int32, Weight (T, k) float32. Operands
+    stored in float32 (a trained router's master weights) are multiplied
+    at full precision: the TPU's default would round them to bfloat16.
+    The weights carry the gradient to ``Gate`` and to ``X`` (with
+    ``detach_input`` to ``Gate`` alone); the choice carries none.
+
+    With ``bias_update_rate`` > 0 the op also runs the auxiliary-loss-free
+    balancing rule on its score correction (Wang et al. 2024,
+    arXiv:2408.15664): ``BiasOut`` = ``Bias`` raised by the rate for every
+    expert that got fewer than the even share ``T k / experts`` of this
+    call's assignments and lowered by it for every one that got more. The
+    layer binds it to the ``Bias`` variable itself, so the next step
+    chooses with it; this step's choice used the old one."""
+    x, gate = ins["X"][0], ins["Gate"][0]
+    if attrs.get("detach_input"):
+        x = lax.stop_gradient(x)
+    full = x.dtype == F32 and gate.dtype == F32
+    s = jax.nn.sigmoid(_dot_f32(x, gate,
+                                lax.Precision.HIGHEST if full else None))
     _, idx = lax.top_k(s + ins["Bias"][0].astype(F32), int(attrs["k"]))
     w = jnp.take_along_axis(s, idx, axis=-1)
-    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
-    return {"Index": [idx.astype(jnp.int32)],
-            "Weight": [w * float(attrs.get("scale", 1.0))]}
+    w = w / (jnp.sum(w, -1, keepdims=True)
+             + float(attrs.get("norm_eps", 1e-20)))
+    out = {"Index": [idx.astype(jnp.int32)],
+           "Weight": [w * float(attrs.get("scale", 1.0))]}
+    rate = float(attrs.get("bias_update_rate", 0.0))
+    if rate:
+        bias = ins["Bias"][0]
+        # one comparison a (assignment, expert) pair: no scatter of 65,536
+        # rows into 32 counters
+        got = jnp.sum(idx.reshape(-1, 1) == jnp.arange(bias.shape[0]),
+                      axis=0).astype(F32)
+        even = idx.size / float(bias.shape[0])
+        out["BiasOut"] = [(bias.astype(F32) + rate * jnp.sign(even - got))
+                          .astype(bias.dtype)]
+    return out
 
 
-GMM_ROWS = 128          # row tile of the grouped kernel
+GMM_ROWS = 128          # row tile where an expert sees a handful of rows
 GMM_TILE_ELEMENTS = 3 << 20   # one expert's matrix held whole in fast memory
+GMM_TRAIN_ROWS = 256    # row tile where an expert sees hundreds of rows
+GMM_TRAIN_WIDTH = 1024  # widest tile of the output's columns there
+GMM_TRAIN_DEPTH = 2048  # widest tile of the contracted width there
 
 
-def grouped_dot(xs, w, sizes, platform=None):
+def _width_tile(d, cap):
+    """The largest multiple of 128 that divides ``d`` and is at most
+    ``cap``."""
+    return max(t for t in range(128, min(d, cap) + 1, 128) if d % t == 0)
+
+
+def gmm_tiling(m, k, n, groups):
+    """(rows, k, n) of one tile of the grouped kernel for ``m`` sorted rows
+    over ``groups`` matrices of (k, n): the tile follows the shape. With a
+    handful of rows a group (a decode step, a prompt) the kernel is bound
+    by reading the matrices: 128 rows and one expert's whole matrix a
+    tile, so each touched matrix streams once, as long as it fits
+    (GMM_TILE_ELEMENTS). With hundreds of rows a group (a training step
+    puts about 2,048 on each) it is bound by arithmetic: 256 rows (a
+    taller tile is revisited across more group boundaries), the contracted
+    width whole where it is at most GMM_TRAIN_DEPTH (one pass, no
+    accumulator carried between tiles: 0.76 ms against 1.03 at half of it,
+    PERF.md), output columns of at most GMM_TRAIN_WIDTH, so that a tile's
+    operands and its float32 accumulator fit fast memory whatever the
+    matrix. Widths that are no multiple of 128 are refused, not sent down
+    a slower kernel."""
+    if k % 128 or n % 128:
+        raise ValueError(
+            "grouped_dot on the TPU tiles both widths of an expert's "
+            "(%d, %d) matrix by multiples of 128" % (k, n))
+    many = m >= GMM_TRAIN_ROWS * 2 * groups
+    if not many and k * n <= GMM_TILE_ELEMENTS:
+        return GMM_ROWS, k, n
+    return (GMM_TRAIN_ROWS if many else GMM_ROWS,
+            _width_tile(k, GMM_TRAIN_DEPTH), _width_tile(n, GMM_TRAIN_WIDTH))
+
+
+def _pad_rows(x, multiple):
+    pad = (-x.shape[0]) % multiple
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+
+
+def _megablox():
+    """(the package, its kernel module): looked up when a program is
+    traced, so a test can hand both an interpreted kernel."""
+    import importlib
+
+    name = "jax.experimental.pallas.ops.tpu.megablox"
+    return importlib.import_module(name), importlib.import_module(
+        name + ".gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(xs, w, sizes, out_dtype):
+    return _gmm_fwd(xs, w, sizes, out_dtype)[0]
+
+
+def _gmm_fwd(xs, w, sizes, out_dtype):
+    m, k = xs.shape
+    tiles = gmm_tiling(m, k, w.shape[2], w.shape[0])
+    out = _megablox()[0].gmm(_pad_rows(xs, tiles[0]), w, sizes, out_dtype,
+                             tiles)[:m]
+    return out, (xs, w, sizes)
+
+
+def _gmm_bwd(out_dtype, res, g):
+    """The two products of the backward pass, each tiled for its own
+    shape: ``dX = g W^T`` by the same kernel with the matrices read
+    transposed, ``dW_e = X_e^T g_e`` by its transposed sibling (``tgmm``
+    in a device trace), which zeroes an expert no row chose."""
+    kernels = _megablox()[1]
+    xs, w, sizes = res
+    m, k = xs.shape
+    groups, _, n = w.shape
+    g = g.astype(xs.dtype)
+    rows, tn, tk = gmm_tiling(m, n, k, groups)        # contracts over n
+    dxs = kernels.gmm(_pad_rows(g, rows), w, sizes, xs.dtype, (rows, tn, tk),
+                      transpose_rhs=True)[:m]
+    # tgmm's accumulator is a (k, n) tile: both at most GMM_TRAIN_WIDTH
+    tiles = (rows, _width_tile(k, GMM_TRAIN_WIDTH),
+             _width_tile(n, GMM_TRAIN_WIDTH))
+    dw = kernels.tgmm(_pad_rows(xs, rows).swapaxes(0, 1), _pad_rows(g, rows),
+                      sizes, w.dtype, tiles)
+    return dxs, dw, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_dot(xs, w, sizes, platform=None, out_dtype=F32):
     """Rows sorted by group times their group's matrix: xs (m, k), w
-    (groups, k, n), sizes (groups,) -> float32 (m, n); rows past
-    ``sum(sizes)`` come back undefined.
+    (groups, k, n), sizes (groups,) -> ``out_dtype`` (m, n), accumulated
+    in float32; rows past ``sum(sizes)`` come back undefined.
 
     On the TPU this is the Pallas grouped matrix product that ships with
-    jax (``pallas.ops.tpu.megablox.gmm``, the kernel ``gmm`` in a device
-    trace) with 128-row tiles and one expert's whole matrix per tile: with a
-    handful of rows per expert it streams every touched expert's weights
-    once, where the compiler's own ragged-dot kernel spends a 256- or
-    512-row tile of arithmetic on every expert (2.5-2.8 x its time at these
-    sizes, PERF.md). Widths that tile cannot take are refused there, not
-    sent down the slower kernel. Off the TPU, ``lax.ragged_dot``."""
+    jax (``pallas.ops.tpu.megablox``, the kernels ``gmm`` and, for the
+    weight gradient, ``tgmm`` in a device trace), tiled by
+    :func:`gmm_tiling`: with a handful of rows per expert it streams every
+    touched expert's weights once, where the compiler's own ragged-dot
+    kernel spends a 256- or 512-row tile of arithmetic on every expert
+    (2.5-2.8 x its time at these sizes, PERF.md). Off the TPU,
+    ``lax.ragged_dot``. Differentiable either way."""
     if platform != "tpu":
-        return lax.ragged_dot(xs, w, sizes, preferred_element_type=F32)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+        return lax.ragged_dot(xs, w, sizes,
+                              preferred_element_type=F32).astype(out_dtype)
+    return _gmm(xs, w, sizes, jnp.dtype(out_dtype))
 
-    m, k = xs.shape
-    n = w.shape[2]
-    if k % 128 or n % 128 or k * n > GMM_TILE_ELEMENTS:
-        raise ValueError(
-            "grouped_dot on the TPU holds one expert's (%d, %d) matrix "
-            "whole in fast memory: both widths must be multiples of 128 "
-            "and their product at most %d" % (k, n, GMM_TILE_ELEMENTS))
-    pad = (-m) % GMM_ROWS
-    if pad:
-        xs = jnp.pad(xs, ((0, pad), (0, 0)))
-    return gmm(xs, w, sizes, F32, (GMM_ROWS, k, n))[:m]
+
+def _sort_by_held_expert(idx, first, held_n, live):
+    """The (T, k) assignments in the order the grouped product wants them:
+    those that land on the held experts [first, first + held_n) sorted by
+    expert, all others after them. -> (order, sizes (held_n,), here
+    (T*k,) bool before the sort)."""
+    k = idx.shape[1]
+    e = idx.reshape(-1) - jnp.int32(first)
+    here = (e >= 0) & (e < held_n)
+    if live is not None:
+        here = here & jnp.repeat(live.reshape(-1).astype(bool), k)
+    key = jnp.where(here, e, held_n)                       # elsewhere: last
+    order = jnp.argsort(key)                               # stable
+    sizes = jnp.zeros((held_n + 1,), jnp.int32).at[key].add(1)[:held_n]
+    return order, sizes, here
+
+
+def _counts(here, sizes):
+    return jnp.stack([jnp.sum(here.astype(jnp.int32)), jnp.max(sizes),
+                      jnp.sum((sizes > 0).astype(jnp.int32))]).astype(
+                          jnp.int32)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is at hand: the
+    backward pass is the gather ``g[inverse]`` and not the scatter-add a
+    gather's transpose would be."""
+    return jnp.take(x, perm, axis=0)
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (jnp.take(x, perm, axis=0), (perm, inverse)),
+    lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
+
+
+def gated_experts_sum(x, idx, wt, w1, w3, w2, first, platform=None):
+    """sum over a token's chosen experts that lie in [first, first + held)
+    of ``wt * W2_e (silu(W1_e x) * W3_e x)`` (SwiGLU experts); experts
+    elsewhere add nothing. Sorted and grouped as :func:`held_experts_sum`,
+    and differentiable in ``x``, the three matrices and ``wt`` (through
+    which the router learns): every gather of rows runs as a gather in the
+    backward pass too, and rows that belong to no held expert are masked
+    on both sides of the kernels, which leave them undefined. The hidden
+    activations and each expert's output are kept in ``x``'s dtype.
+    -> (out (T, D) float32, counts as :func:`held_experts_sum`)."""
+    t, k = idx.shape
+    order, sizes, here = _sort_by_held_expert(idx, first, w1.shape[0], None)
+    back = jnp.argsort(order)
+    keep = jnp.take(here, order)[:, None]
+    xs = _permute_rows(jnp.repeat(x, k, axis=0), order, back)
+    xs = jnp.where(keep, xs, 0)
+    hid = (jax.nn.silu(grouped_dot(xs, w1, sizes, platform, x.dtype)
+                       .astype(F32))
+           * grouped_dot(xs, w3, sizes, platform, x.dtype).astype(F32))
+    out = grouped_dot(hid.astype(x.dtype), w2, sizes, platform, x.dtype)
+    out = _permute_rows(jnp.where(keep, out, 0), back, order)
+    out = jnp.sum(out.reshape(t, k, -1).astype(F32) * wt[:, :, None], 1)
+    return out, _counts(here, sizes)
 
 
 def held_experts_sum(x, idx, wt, w1, w2, first, live=None, platform=None):
@@ -268,14 +489,7 @@ def held_experts_sum(x, idx, wt, w1, w2, first, live=None, platform=None):
     ``[assignments held, largest count on one held expert, held experts
     that got any]``."""
     t, k = idx.shape
-    held_n = w1.shape[0]
-    e = idx.reshape(-1) - jnp.int32(first)
-    here = (e >= 0) & (e < held_n)
-    if live is not None:
-        here = here & jnp.repeat(live.reshape(-1).astype(bool), k)
-    key = jnp.where(here, e, held_n)                       # elsewhere: last
-    order = jnp.argsort(key)                               # stable
-    sizes = jnp.zeros((held_n + 1,), jnp.int32).at[key].add(1)[:held_n]
+    order, sizes, here = _sort_by_held_expert(idx, first, w1.shape[0], live)
     xs = jnp.take(x, order // k, axis=0)                   # (T*k, D)
     hid = grouped_dot(xs, w1, sizes, platform)
     hid = jnp.square(jnp.maximum(hid, 0)).astype(x.dtype)
@@ -286,23 +500,30 @@ def held_experts_sum(x, idx, wt, w1, w2, first, live=None, platform=None):
                     out * jnp.take(wt.reshape(-1), order)[:, None], 0.0)
     back = jnp.argsort(order)                              # undo the sort
     out = jnp.take(out, back, axis=0).reshape(t, k, -1).sum(1)
-    counts = jnp.stack([jnp.sum(here.astype(jnp.int32)), jnp.max(sizes),
-                        jnp.sum((sizes > 0).astype(jnp.int32))])
-    return out, counts.astype(jnp.int32)
+    return out, _counts(here, sizes)
 
 
 @register_op("held_experts_ffn")
 def _held_experts_ffn(ctx, ins, attrs):
-    """A chip's share of a routed expert layer (see
-    :func:`held_experts_sum`). X (T, D), Index/Weight (T, k) from the
-    router over all experts, W1 (held, D, F), W2 (held, F, D); ``Live``
-    (T, 1) masks rows that carry no token. Counts is int32
-    ``[assignments held, largest count on one held expert, held experts
-    that got any]``."""
+    """A chip's share of a routed expert layer. X (T, D), Index/Weight
+    (T, k) from the router over all experts, W1 (held, D, F), W2 (held, F,
+    D): squared-ReLU experts (:func:`held_experts_sum`), where ``Live``
+    (T, 1) masks rows that carry no token; with ``W3`` (held, D, F) gated
+    ones, ``W2 (silu(W1 x) * W3 x)`` (:func:`gated_experts_sum`), which
+    train. Counts is int32 ``[assignments held, largest count on one held
+    expert, held experts that got any]``."""
     x = ins["X"][0]
     live = ins["Live"][0] if ins.get("Live") else None
-    out, counts = held_experts_sum(
-        x, ins["Index"][0], ins["Weight"][0], ins["W1"][0], ins["W2"][0],
-        int(attrs["first_expert"]), live,
-        platform=getattr(ctx, "platform", None))
+    platform = getattr(ctx, "platform", None)
+    first = int(attrs["first_expert"])
+    if ins.get("W3"):
+        if live is not None:
+            raise ValueError("gated held experts take no Live mask")
+        out, counts = gated_experts_sum(
+            x, ins["Index"][0], ins["Weight"][0], ins["W1"][0],
+            ins["W3"][0], ins["W2"][0], first, platform)
+    else:
+        out, counts = held_experts_sum(
+            x, ins["Index"][0], ins["Weight"][0], ins["W1"][0],
+            ins["W2"][0], first, live, platform)
     return {"Out": [out.astype(x.dtype)], "Counts": [counts]}

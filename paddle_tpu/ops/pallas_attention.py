@@ -326,6 +326,7 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return out, lse
 
@@ -381,6 +382,7 @@ def _bwd_call(q, k, v, kpm, seed, do, lse, delta, sm_scale, causal,
         out_specs=qb,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(*(args + [q, k, v, do, lse, delta]))
 
     # dk/dv: grid over k tiles
@@ -419,6 +421,7 @@ def _bwd_call(q, k, v, kpm, seed, do, lse, delta, sm_scale, causal,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         interpret=interpret,
+        name="flash_dkdv",
     )(*(args + [q, k, v, do, lse, delta]))
     if kpm is not None:
         dk, dv, dkpm_bh = outs

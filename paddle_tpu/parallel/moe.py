@@ -117,38 +117,46 @@ def switch_ffn(x, num_experts, d_ff, capacity_factor=1.25, act="gelu",
     return y, aux
 
 
-def held_experts_ffn(x, index, weight, held, d_ff, name, live=None):
-    """The held experts' part of a routed layer (squared-ReLU experts, no
-    gate, no bias): ``sum over the chosen experts e in [held[0], held[0]
-    + held[1]) of weight_e * W2_e relu(W1_e x)^2``.
+def held_experts_ffn(x, index, weight, held, d_ff, name, live=None,
+                     gated=False):
+    """The held experts' part of a routed layer (no bias): ``sum over the
+    chosen experts e in [held[0], held[0] + held[1]) of weight_e * W2_e
+    relu(W1_e x)^2``, or with ``gated`` of ``weight_e * W2_e (silu(W1_e x)
+    * W3_e x)`` (SwiGLU experts).
 
     ``x`` (T, D); ``index``/``weight`` (T, k) from a router over ALL the
     layer's experts, the weights already normalised over all k chosen, so
     a token whose experts lie on other chips adds nothing from them and
     the shares of all chips sum to the whole layer. ``live`` (T, 1) masks
-    rows that carry no token (a dead decode slot). The two products are
+    rows that carry no token (a dead decode slot). The products are
     grouped by expert (``ops.hybrid_ops.grouped_dot``: the Pallas kernel
     ``gmm`` on the TPU, ``lax.ragged_dot`` elsewhere): their work grows
     with the assignments that land here. On one chip the layer runs
-    without its exchange; nothing stands in for the absent chips.
+    without its exchange; nothing stands in for the absent chips. The
+    gated layer trains: gradients reach ``x``, the matrices and, through
+    ``weight``, the router.
 
     Parameters ``<name>.w1`` (held, D, d_ff), ``<name>.w2`` (held, d_ff,
-    D). Returns ``(out (T, D), counts)``: ``counts`` is int32
-    ``[assignments that landed on held experts, the largest count on one
-    held expert, held experts that got any]``."""
+    D), gated also ``<name>.w3`` (held, D, d_ff). Returns ``(out (T, D),
+    counts)``: ``counts`` is int32 ``[assignments that landed on held
+    experts, the largest count on one held expert, held experts that got
+    any]``."""
     from ..fluid.layer_helper import LayerHelper
     from ..fluid.param_attr import ParamAttr
 
     first, count = int(held[0]), int(held[1])
     d = int(x.shape[-1])
     helper = LayerHelper("held_experts_ffn")
+
+    def matrix(part, shape):
+        return [helper.create_parameter(ParamAttr(name=name + part), shape,
+                                        x.dtype)]
+
     inputs = {"X": [x], "Index": [index], "Weight": [weight],
-              "W1": [helper.create_parameter(
-                  ParamAttr(name=name + ".w1"), [count, d, int(d_ff)],
-                  x.dtype)],
-              "W2": [helper.create_parameter(
-                  ParamAttr(name=name + ".w2"), [count, int(d_ff), d],
-                  x.dtype)]}
+              "W1": matrix(".w1", [count, d, int(d_ff)]),
+              "W2": matrix(".w2", [count, int(d_ff), d])}
+    if gated:
+        inputs["W3"] = matrix(".w3", [count, d, int(d_ff)])
     if live is not None:
         inputs["Live"] = [live]
     out = helper.create_variable_for_type_inference(x.dtype)

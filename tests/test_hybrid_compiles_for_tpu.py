@@ -7,6 +7,7 @@ The topology is described inside a fixture: only the worker that runs this
 file loads the TPU's library. A compile that passes is not a chip run."""
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -123,3 +124,107 @@ def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
                                   compiled.as_text())]
     assert max(sizes) == 2048 * vocab         # one chunk of float32 logits
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+def _no_cache_compile(lowered):
+    import jax
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)])
+def test_grouped_dot_takes_the_lfm2_expert_matrix_both_ways(one_chip, k, n):
+    """LFM2-8B-A1B's expert matrices (2048 x 1792 and back; 3.67 M
+    elements, more than one whole-matrix tile holds) at a training step's
+    rows, forward and backward (`gmm`, `gmm` transposed, `tgmm`), compiled
+    for the described chip: the chip's compiler takes the tiles
+    `gmm_tiling` chooses, and a decode step's few rows too."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import hybrid_ops
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss_and_grads(xs, w, sizes):
+        return jax.value_and_grad(lambda a, b: jnp.sum(hybrid_ops.grouped_dot(
+            a, b, sizes, "tpu", jnp.bfloat16).astype(jnp.float32)),
+            (0, 1))(xs, w)
+
+    for rows in (4 * 4096 * 4, 64):
+        compiled = _no_cache_compile(jax.jit(loss_and_grads).lower(
+            sds((rows, k), "bfloat16"), sds((8, k, n), "bfloat16"),
+            sds((8,), "int32")))
+        assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_the_lfm2_training_step_at_published_widths_fits_one_chip(one_chip):
+    """The cell's step (LFM2-8B-A1B's cut: 507.8 M parameters, 4 x 4,096
+    tokens, bf16 AMP, Adam) as `Executor.run` would build it, compiled for
+    the described chip: the Pallas kernels are in it (grouped products
+    forward and backward, the three flash kernels), no (T, T) score array
+    is, the state is donated and updated in place, and arguments + scratch
+    fit 16 GiB less what the runtime keeps."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.contrib.mixed_precision import decorate
+    from paddle_tpu.fluid.lowering import build_step_fn, persistable_names
+    from paddle_tpu.models import lfm2
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "lfm2_8b_a1b.json")))
+    mix = json.load(open(os.path.join(
+        ROOT, "benchmark", "traffic", "pretrain_lm_s4096.json")))
+    rows, seq = mix["rows_per_chip"], mix["seq_len"]
+    opt = doc["optimizer"]
+    cfg = lfm2.Lfm2Config.from_hf(
+        doc, router_experts=doc["reduced_from"]["num_experts"],
+        first_expert=doc["share"]["first_expert"],
+        bias_update_rate=opt["expert_bias_update_rate"])
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        vs = lfm2.build_lfm2_pretrain(cfg, seq)
+        adam = fluid.optimizer.Adam(
+            learning_rate=opt["learning_rate"], beta1=opt["beta1"],
+            beta2=opt["beta2"], epsilon=opt["epsilon"])
+        decorate(adam, use_bf16=True).minimize(vs["loss"])
+        prog = fluid.default_main_program()
+    step = build_step_fn(prog, ["input_ids", "labels"],
+                         [vs["loss"].name, vs["moe_counts"].name],
+                         platform="tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    block = prog.global_block()
+    state = {n: sds(block.vars[n].shape, block.vars[n].dtype)
+             for n in persistable_names(prog)}
+    feeds = {"input_ids": sds((rows, seq), "int32"),
+             "labels": sds((rows, seq), "int32")}
+    compiled = _no_cache_compile(jax.jit(step, donate_argnums=(0,)).lower(
+        state, feeds, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                           sharding=one_chip)))
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkdv"):
+        assert kernel in text, kernel
+    # per expert layer 3 + 3 grouped products and 3 transposed ones
+    assert text.count("tpu_custom_call") >= 4 * 9 + 3
+    assert not re.search(r"\[4,32,4096,4096\]|\[128,4096,4096\]", text)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                      for s in state.values())
+    assert round(state_bytes / 1e9, 1) == 6.1      # weights + two moments
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    # the balancing rule's score corrections are state the step writes
+    assert sum(n.endswith(".moe.gate.bias") for n in state) == 4
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 14e9 < held < 16.5e9, held      # read on the chip: 15.98 GB

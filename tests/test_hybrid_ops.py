@@ -314,14 +314,32 @@ def test_the_kernel_of_the_tpu_branch_equals_the_ragged_dot(monkeypatch):
     assert n_got.tolist() == n_want.tolist() and int(n_want[0]) > 0
 
 
-@pytest.mark.parametrize("k,n", [(96, 256), (128, 200), (2048, 2048)])
-def test_the_tpu_branch_refuses_widths_its_tile_cannot_take(k, n):
-    """No silent second kernel on the chip: a width the one-expert tile
-    cannot hold raises where the program is lowered."""
+@pytest.mark.parametrize("k,n,refused", [
+    (96, 256, True), (128, 200, True), (2048, 2048, False)])
+def test_the_tpu_branch_refuses_widths_its_tile_cannot_take(k, n, refused):
+    """No silent second kernel on the chip: a width that is no multiple of
+    the kernel's 128 raises where the program is lowered. A matrix too
+    large for one whole-matrix tile is no longer refused (PR 32): the tile
+    follows the shape, and the kernel, run in interpret mode here, still
+    equals the ragged dot."""
+    import functools
+
+    from jax.experimental.pallas.ops.tpu import megablox
+
     from paddle_tpu.ops import hybrid_ops
 
-    xs = jnp.zeros((8, k), jnp.bfloat16)
-    w = jnp.zeros((2, k, n), jnp.bfloat16)
-    with pytest.raises(ValueError, match="multiples of 128"):
-        hybrid_ops.grouped_dot(xs, w, jnp.asarray([4, 4]), "tpu")
-    assert hybrid_ops.grouped_dot(xs, w, jnp.asarray([4, 4])).shape == (8, n)
+    xs = jnp.asarray(RNG.normal(size=(8, k)), jnp.bfloat16)
+    w = jnp.asarray(0.1 * RNG.normal(size=(2, k, n)), jnp.bfloat16)
+    sizes = jnp.asarray([4, 4], jnp.int32)
+    want = hybrid_ops.grouped_dot(xs, w, sizes)
+    assert want.shape == (8, n)
+    if refused:
+        with pytest.raises(ValueError, match="multiples of 128"):
+            hybrid_ops.grouped_dot(xs, w, sizes, "tpu")
+        return
+    assert k * n > hybrid_ops.GMM_TILE_ELEMENTS
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(megablox, "gmm",
+                   functools.partial(megablox.gmm, interpret=True))
+        got = hybrid_ops.grouped_dot(xs, w, sizes, "tpu")
+    close(np.asarray(got), np.asarray(want), 1e-5)
