@@ -159,7 +159,7 @@ def _mlp(h, cfg, n):
 
 
 def _experts(h, cfg, n, seq_len):
-    """-> (the held experts' part (B, T, H), its counts (3,) int32)."""
+    """-> (the held experts' part (B, T, H), its counts (4,) int32)."""
     from ..parallel.moe import held_experts_ffn
 
     flat = layers.reshape(h, [-1, cfg.hidden])
@@ -181,9 +181,10 @@ def build_lfm2_pretrain(cfg, seq_len):
     label of position t is the id at t + 1, and -1 (ignored) at the last
     position of a row, so the loss is the mean cross-entropy over the
     B * (seq_len - 1) labelled positions. Returns the interface variables:
-    ``loss``; ``moe_counts`` (expert layers, 3) int32, per expert layer the
+    ``loss``; ``moe_counts`` (expert layers, 4) int32, per expert layer the
     assignments that landed on held experts, the largest count on one held
-    expert and the held experts that got any; ``head_rows`` and
+    expert, the held experts that got any and the sorted rows the layer's
+    loops covered; ``head_rows`` and
     ``head_chunks`` as the fused head counts them; ``block_outputs``, the
     residual stream after each block, which a ``RecomputeOptimizer`` takes
     as checkpoints where the activations of a step do not fit."""
@@ -221,7 +222,7 @@ def build_lfm2_pretrain(cfg, seq_len):
         loss = layers.scale(layers.mean(loss),
                             scale=seq_len / float(seq_len - 1))
     moe_counts = (layers.stack(counts, axis=0) if counts else
-                  layers.fill_constant([0, 3], "int32", 0))
+                  layers.fill_constant([0, 4], "int32", 0))
     moe_counts.stop_gradient = True
     return {"input_ids": ids, "labels": labels, "loss": loss,
             "moe_counts": moe_counts, "head_rows": head_rows,
@@ -265,16 +266,21 @@ def param_shapes(cfg):
 def step_counters(moe_counts, head_rows=None, head_chunks=None, steps=1):
     """The fetched counts of one or more steps -> the counters a trainer
     publishes, under the names the decode engine's counters have:
-    ``moe_counts`` (..., expert layers, 3) summed over whatever leads;
-    adds them to the telemetry hub (``lfm2.<name>``) and returns them."""
+    ``moe_counts`` (..., expert layers, 3 or 4) summed over whatever
+    leads, the fourth column (``moe_rows_covered``: sorted rows the gated
+    experts' loops went over) where the program counts it; adds them to
+    the telemetry hub (``lfm2.<name>``) and returns them."""
     import numpy as np
 
     from .. import observability as obs
 
-    c = np.asarray(moe_counts).reshape(-1, 3).sum(0)
+    c = np.asarray(moe_counts)
+    c = c.reshape(-1, c.shape[-1]).sum(0)
     out = {"steps": int(steps), "moe_assignments_held": int(c[0]),
            "moe_expert_load_max_sum": int(c[1]),
            "moe_experts_touched_sum": int(c[2])}
+    if len(c) > 3:
+        out["moe_rows_covered"] = int(c[3])
     if head_rows is not None:
         out["head_rows"] = int(np.asarray(head_rows).sum())
     if head_chunks is not None:

@@ -441,42 +441,152 @@ def _counts(here, sizes):
                           jnp.int32)
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation whose inverse is at hand: the
-    backward pass is the gather ``g[inverse]`` and not the scatter-add a
-    gather's transpose would be."""
-    return jnp.take(x, perm, axis=0)
+GATED_CHUNK_ROWS = 2048    # sorted rows a trip of the gated experts' loops
 
 
-_permute_rows.defvjp(
-    lambda x, perm, inverse: (jnp.take(x, perm, axis=0), (perm, inverse)),
-    lambda res, g: (jnp.take(g, res[1], axis=0), None, None))
+def _chunk(v, c, r):
+    """Rows [c r, (c + 1) r) of v."""
+    return lax.dynamic_slice_in_dim(v, c * r, r, axis=0)
 
 
+def _put(buf, c, r, rows):
+    """buf with its rows [c r, (c + 1) r) replaced: in place, in a loop's
+    carry."""
+    return lax.dynamic_update_slice_in_dim(buf, rows, c * r, axis=0)
+
+
+def _live(c, r, n):
+    """(r, 1) bool: the rows of chunk c that are held rows."""
+    return (c * r + lax.iota(jnp.int32, r) < n)[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def gated_experts_sum(x, idx, wt, w1, w3, w2, first, platform=None):
     """sum over a token's chosen experts that lie in [first, first + held)
     of ``wt * W2_e (silu(W1_e x) * W3_e x)`` (SwiGLU experts); experts
     elsewhere add nothing. Sorted and grouped as :func:`held_experts_sum`,
     and differentiable in ``x``, the three matrices and ``wt`` (through
-    which the router learns): every gather of rows runs as a gather in the
-    backward pass too, and rows that belong to no held expert are masked
-    on both sides of the kernels, which leave them undefined. The hidden
-    activations and each expert's output are kept in ``x``'s dtype.
-    -> (out (T, D) float32, counts as :func:`held_experts_sum`)."""
+    which the router learns). The sort lists the n assignments that landed
+    here first, and nothing outside the grouped kernels touches a sorted
+    row past them: the gather of the tokens' rows, the gate and the
+    weighted sum back into the tokens are loops over chunks of
+    GATED_CHUNK_ROWS sorted rows whose trip count ``ceil(n / rows)`` the
+    device computes, and so are their gradients (a hand-written backward
+    pass of the same trips). The buffers keep the static bound of tokens x
+    experts per token, so any load is exact: nothing has a capacity. The
+    hidden activations and each expert's output are kept in ``x``'s dtype.
+    -> (out (T, D) float32, counts as :func:`held_experts_sum` and fourth
+    the sorted rows the loops covered, trips x GATED_CHUNK_ROWS)."""
+    return _gated_fwd(x, idx, wt, w1, w3, w2, first, platform)[0]
+
+
+def _gather_rows(x, tok, trips, r, into):
+    """``into`` with its first trips x r rows replaced by the rows of x
+    that ``tok`` names."""
+    return lax.fori_loop(
+        0, trips, lambda c, xs: _put(xs, c, r, jnp.take(
+            x, _chunk(tok, c, r), axis=0).astype(xs.dtype)), into)
+
+
+def _gate(a, b, trips, r, weight=None):
+    """``silu(a) * b`` (times each row's weight) over the first trips x r
+    rows, in float32, rounded to a's dtype."""
+    def body(c, hid):
+        h = (jax.nn.silu(_chunk(a, c, r).astype(F32))
+             * _chunk(b, c, r).astype(F32))
+        if weight is not None:
+            h = h * _chunk(weight, c, r)[:, None]
+        return _put(hid, c, r, h.astype(hid.dtype))
+
+    return lax.fori_loop(0, trips, body, lax.empty(a.shape, a.dtype))
+
+
+def _add_into_tokens(terms, tok, n, trips, r, t, weight=None):
+    """(t, D) float32: the sum of ``terms`` at each of the first n sorted
+    rows (times the row's weight) added into the row's token; a token that
+    two held experts serve gets both."""
+    def body(c, out):
+        add = sum(_chunk(v, c, r).astype(F32) for v in terms)
+        if weight is not None:
+            add = add * _chunk(weight, c, r)[:, None]
+        return out.at[_chunk(tok, c, r)].add(
+            jnp.where(_live(c, r, n), add, 0.0))
+
+    return lax.fori_loop(0, trips, body,
+                         jnp.zeros((t, terms[0].shape[1]), F32))
+
+
+def _gated_fwd(x, idx, wt, w1, w3, w2, first, platform):
     t, k = idx.shape
     order, sizes, here = _sort_by_held_expert(idx, first, w1.shape[0], None)
-    back = jnp.argsort(order)
-    keep = jnp.take(here, order)[:, None]
-    xs = _permute_rows(jnp.repeat(x, k, axis=0), order, back)
-    xs = jnp.where(keep, xs, 0)
-    hid = (jax.nn.silu(grouped_dot(xs, w1, sizes, platform, x.dtype)
-                       .astype(F32))
-           * grouped_dot(xs, w3, sizes, platform, x.dtype).astype(F32))
-    out = grouped_dot(hid.astype(x.dtype), w2, sizes, platform, x.dtype)
-    out = _permute_rows(jnp.where(keep, out, 0), back, order)
-    out = jnp.sum(out.reshape(t, k, -1).astype(F32) * wt[:, :, None], 1)
-    return out, _counts(here, sizes)
+    r, n = min(GATED_CHUNK_ROWS, t * k), jnp.sum(sizes)
+    trips = (n + (r - 1)) // r
+    pad = (-t * k) % r                      # buffers hold whole chunks
+    tok = jnp.pad(order // k, (0, pad))     # a sorted row's token
+    wts = jnp.pad(jnp.take(wt.reshape(-1), order), (0, pad))
+    dot = functools.partial(grouped_dot, sizes=sizes, platform=platform,
+                            out_dtype=x.dtype)
+    xs = _gather_rows(x, tok, trips, r,
+                      lax.empty((t * k + pad, x.shape[1]), x.dtype))
+    a, b = dot(xs, w1), dot(xs, w3)
+    outs = dot(_gate(a, b, trips, r), w2)
+    out = _add_into_tokens([outs], tok, n, trips, r, t, wts)
+    counts = jnp.concatenate([_counts(here, sizes), (trips * r)[None]])
+    return (out, counts), (x, order, sizes, n, trips, tok, wts, a, b,
+                           w1, w3, w2)
+
+
+def _gated_bwd(first, platform, res, cts):
+    """The forward pass's loops mirrored, over the trips it made. Kept from
+    it are the two products under the gate alone: the gathered rows and the
+    gate are made again (a quarter of a pass each, where keeping them is
+    0.5 GB an expert layer), and the experts' outputs are not needed: the
+    weight on a row goes into the gate's side of the last product, so that
+    ``d wt = <g W2^T, hid>`` falls out of the gate's own loop. Loops write
+    over a buffer of their shape that is dead by then where there is one.
+    Rows of the last chunk past the held ones are undefined on the
+    kernels' side: masked wherever they would be summed."""
+    x, order, sizes, n, trips, tok, wts, a, b, w1, w3, w2 = res
+    g = cts[0]
+    r = min(GATED_CHUNK_ROWS, order.shape[0])
+
+    def pull(v, w, ct):
+        """(d v, d w) of ``grouped_dot(v, w)``: on the TPU its own backward
+        rule called as the rule, so that a device trace names the kernels
+        `gmm` and `tgmm` as in every other program."""
+        if platform == "tpu":
+            return _gmm_bwd(v.dtype, (v, w, sizes), ct)[:2]
+        return jax.vjp(lambda v_, w_: grouped_dot(
+            v_, w_, sizes, platform, v.dtype), v, w)[1](ct)
+
+    gs = _gather_rows(g, tok, trips, r,
+                      lax.empty((tok.shape[0], g.shape[1]), x.dtype))
+    dhid, dw2 = pull(_gate(a, b, trips, r, wts), w2, gs)
+    # gs is written over below: only once both products have read it
+    gs, dhid, dw2 = lax.optimization_barrier((gs, dhid, dw2))
+
+    def gate(c, carry):
+        da, db, dwt = carry                 # a and b so far
+        av, bv, dh = (_chunk(v, c, r).astype(F32) for v in (da, db, dhid))
+        sig, live = jax.nn.sigmoid(av), _live(c, r, n)
+        dot = jnp.sum(dh * (av * sig * bv), -1, keepdims=True)
+        dh = dh * _chunk(wts, c, r)[:, None]
+        d_silu = sig * (1.0 + av * (1.0 - sig))
+        return (_put(da, c, r, jnp.where(live, dh * bv * d_silu, 0.0)
+                     .astype(da.dtype)),
+                _put(db, c, r, jnp.where(live, dh * av * sig, 0.0)
+                     .astype(db.dtype)),
+                _put(dwt, c, r, jnp.where(live, dot, 0.0)[:, 0]))
+
+    da, db, dwt = lax.fori_loop(0, trips, gate, (a, b, jnp.zeros_like(wts)))
+    xs = _gather_rows(x, tok, trips, r, gs)
+    (dxa, dw1), (dxb, dw3) = pull(xs, w1, da), pull(xs, w3, db)
+    dx = _add_into_tokens([dxa, dxb], tok, n, trips, r, x.shape[0])
+    dwt = jnp.take(dwt, jnp.argsort(order)).reshape(x.shape[0], -1)
+    return dx.astype(x.dtype), None, dwt, dw1, dw3, dw2
+
+
+gated_experts_sum.defvjp(_gated_fwd, _gated_bwd)
 
 
 def held_experts_sum(x, idx, wt, w1, w2, first, live=None, platform=None):
@@ -511,7 +621,8 @@ def _held_experts_ffn(ctx, ins, attrs):
     (T, 1) masks rows that carry no token; with ``W3`` (held, D, F) gated
     ones, ``W2 (silu(W1 x) * W3 x)`` (:func:`gated_experts_sum`), which
     train. Counts is int32 ``[assignments held, largest count on one held
-    expert, held experts that got any]``."""
+    expert, held experts that got any]``, gated also the sorted rows the
+    loops covered."""
     x = ins["X"][0]
     live = ins["Live"][0] if ins.get("Live") else None
     platform = getattr(ctx, "platform", None)

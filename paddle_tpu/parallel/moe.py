@@ -140,7 +140,7 @@ def held_experts_ffn(x, index, weight, held, d_ff, name, live=None,
     D), gated also ``<name>.w3`` (held, D, d_ff). Returns ``(out (T, D),
     counts)``: ``counts`` is int32 ``[assignments that landed on held
     experts, the largest count on one held expert, held experts that got
-    any]``."""
+    any]``, gated also the sorted rows its loops covered."""
     from ..fluid.layer_helper import LayerHelper
     from ..fluid.param_attr import ParamAttr
 
@@ -162,7 +162,7 @@ def held_experts_ffn(x, index, weight, held, d_ff, name, live=None,
     out = helper.create_variable_for_type_inference(x.dtype)
     out.shape = tuple(x.shape)
     counts = helper.create_variable_for_type_inference("int32")
-    counts.shape = (3,)
+    counts.shape = (4 if gated else 3,)
     helper.append_op(type="held_experts_ffn", inputs=inputs,
                      outputs={"Out": [out], "Counts": [counts]},
                      attrs={"first_expert": first})

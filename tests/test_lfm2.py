@@ -246,7 +246,71 @@ def test_gated_held_experts_and_their_gradients_against_the_reference(share):
     counts = np.asarray(op_experts(x, bw, first)[1])
     per_expert = (weights > 0).sum(0)
     assert counts.tolist() == [per_expert.sum(), per_expert.max(),
-                               (per_expert > 0).sum()]
+                               (per_expert > 0).sum(), 40 * 2]
+
+
+def _gated_by_the_book(x, idx, wt, w1, w3, w2, first):
+    """Every assignment on its own, no sort: the expert's matrices gathered
+    per (token, slot), zero weight where the expert is held elsewhere."""
+    e = idx - first
+    here = (e >= 0) & (e < w1.shape[0])
+    e = jnp.clip(e, 0, w1.shape[0] - 1)
+    a = jnp.einsum("td,tkdf->tkf", x, w1[e])
+    b = jnp.einsum("td,tkdf->tkf", x, w3[e])
+    out = jnp.einsum("tkf,tkfd->tkd", jax.nn.silu(a) * b, w2[e])
+    return jnp.sum(jnp.where(here, wt, 0.0)[:, :, None] * out, 1), None
+
+
+CHUNK, TOKENS, SLOTS = 8, 12, 2     # 24 assignments: up to three trips
+
+
+@pytest.mark.parametrize("held_rows", [0, 1, CHUNK - 1, CHUNK, CHUNK + 1,
+                                       TOKENS * SLOTS])
+def test_the_gated_loops_follow_the_held_rows_at_every_load(monkeypatch,
+                                                            held_rows):
+    """The loops of `gated_experts_sum` with a chunk of 8 sorted rows, at
+    loads that end before, on and after a chunk's edge, none and ALL (every
+    chosen expert held: three full trips): output and the gradients in x,
+    wt and the three matrices against the unsorted sum; the rows covered
+    are whole chunks over the held rows and no more; a token whose two
+    experts are both held gets both."""
+    monkeypatch.setattr(hybrid_ops, "GATED_CHUNK_ROWS", CHUNK)
+    first, held, d, f = 2, 4, 64, 48
+    rng = np.random.default_rng(held_rows)
+    # `held_rows` of the 24 assignments on experts 2..5, the rest elsewhere;
+    # the first tokens hold both of theirs
+    idx = np.empty((TOKENS * SLOTS,), np.int64)
+    idx[:held_rows] = first + np.arange(held_rows) % held
+    idx[held_rows:] = np.where(np.arange(TOKENS * SLOTS - held_rows) % 2,
+                               0, 7)
+    idx = jnp.asarray(idx.reshape(TOKENS, SLOTS), jnp.int32)
+    x = jnp.asarray(rng.normal(size=(TOKENS, d)), jnp.float32)
+    wt = jnp.asarray(0.2 + rng.random((TOKENS, SLOTS)), jnp.float32)
+    w1, w3 = (jnp.asarray(0.1 * rng.normal(size=(held, d, f)), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(0.1 * rng.normal(size=(held, f, d)), jnp.float32)
+
+    def loss(fn):
+        def f_(x, wt, w1, w3, w2):
+            out, counts = fn(x, idx, wt, w1, w3, w2, first)
+            return jnp.sum(jnp.sin(out)), (out, counts)
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(f_, (0, 1, 2, 3, 4), has_aux=True)(
+                x, wt, w1, w3, w2)
+
+    (got, (out, counts)), got_g = loss(hybrid_ops.gated_experts_sum)
+    (want, (ref_out, _)), want_g = loss(_gated_by_the_book)
+    close(got, want)
+    close(out, ref_out)
+    for a, e in zip(got_g, want_g):
+        close(a, e)
+    assert int(counts[0]) == held_rows
+    assert int(counts[3]) == -(-held_rows // CHUNK) * CHUNK
+    if held_rows >= 2:      # token 0 holds experts 2 and 3: both are in it
+        alone = [_gated_by_the_book(x[:1], idx[:1, j:j + 1], wt[:1, j:j + 1],
+                                    w1, w3, w2, first)[0] for j in range(2)]
+        close(out[0], (alone[0] + alone[1])[0])
+        assert float(jnp.abs(alone[1]).max()) > 1e-4
 
 
 def test_four_shares_of_two_experts_add_up_to_the_uncut_layer():
@@ -516,7 +580,7 @@ def test_three_steps_of_the_program_against_the_reference(
                       fetch_list=[vs["loss"], vs["moe_counts"],
                                   vs["head_rows"]])
         losses.append(float(out[0]))
-        assert out[1].shape == (2, 3) and int(out[2]) == 4 * 31
+        assert out[1].shape == (2, 4) and int(out[2]) == 4 * 31
         if grad is None:
             grad = {n: float(jnp.linalg.norm(scope.find_value(
                 n + "_moment1_0"))) / (1 - OPT["beta1"])
@@ -621,16 +685,24 @@ def test_blocks_carry_their_name_scope_into_the_lowered_program(
     assert re.search(r'"[^"]*jit\(step\)/rms_norm/', text)
 
 
-def test_step_counters_carry_the_engines_names_to_the_hub():
+@pytest.mark.parametrize("columns", [3, 4])
+def test_step_counters_carry_the_engines_names_to_the_hub(columns):
+    """Three columns are what the relu² layer counts (and a program from
+    before the gated loops); the gated layer's fourth is the sorted rows
+    its loops covered."""
     from paddle_tpu import observability as obs
 
     obs.reset()
-    moe = np.array([[[10, 4, 3], [12, 5, 4]], [[11, 6, 4], [9, 3, 3]]])
+    moe = np.array([[[10, 4, 3, 16], [12, 5, 4, 16]],
+                    [[11, 6, 4, 16], [9, 3, 3, 8]]])[:, :, :columns]
     out = lfm2.step_counters(moe, np.array([124, 124]), np.array([1, 1]),
                              steps=2)
-    assert out == {"steps": 2, "moe_assignments_held": 42,
-                   "moe_expert_load_max_sum": 18,
-                   "moe_experts_touched_sum": 14, "head_rows": 248,
-                   "head_chunks": 2}
+    want = {"steps": 2, "moe_assignments_held": 42,
+            "moe_expert_load_max_sum": 18, "moe_experts_touched_sum": 14,
+            "head_rows": 248, "head_chunks": 2}
+    if columns == 4:
+        want["moe_rows_covered"] = 56
+    assert out == want
     assert obs.counter("lfm2.moe_assignments_held") == 42
     assert obs.counter("lfm2.steps") == 2
+    assert obs.counter("lfm2.moe_rows_covered") == (56 if columns == 4 else 0)
