@@ -1,9 +1,11 @@
 """Layers of hybrid state-space / attention / sparse-expert decoders: RMS
 norm (plain, grouped, gated), squared ReLU, a causal depthwise convolution
 with carried window, the Mamba-2 recurrence (chunked scan and one step),
-a rotary position term, grouped-query attention, sigmoid top-k routing and
-a product that keeps its float32 accumulator. The lowerings are ``paddle_tpu/ops/hybrid_ops.py``;
-the held-experts layer built on the router is
+a rotary position term (whole or part of a head, plain or YaRN, from the row
+index or from a slot's position), grouped-query attention (causal, windowed,
+over a slot cache or ring), top-k routing (sigmoid or softmax scores) and a
+product that keeps its float32 accumulator. The lowerings are
+``paddle_tpu/ops/hybrid_ops.py``; the held-experts layer built on the router is
 ``paddle_tpu.parallel.moe.held_experts_ffn``.
 """
 from ..layer_helper import LayerHelper
@@ -11,7 +13,7 @@ from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "relu_squared", "dense_acc32", "causal_conv1d",
            "mamba2_scan", "mamba2_step", "rotary_embedding", "gqa_attention",
-           "moe_route_topk"]
+           "kv_ring_gather", "moe_route_topk"]
 
 
 def _out(helper, dtype, shape):
@@ -127,43 +129,77 @@ def mamba2_step(xbc, dt, state, name, heads, head_dim, groups, state_size):
                 (xbc.shape[0], heads * head_dim), state.shape, {})
 
 
-def rotary_embedding(x, theta):
-    """Rotary position term over ``x`` (B, T, heads, head_dim): position t
-    along axis 1 turns pair ``(x[i], x[i + head_dim/2])`` of every head by
-    ``t * theta^(-2i/head_dim)``. No parameter."""
+def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None):
+    """Rotary position term over ``x`` (B, T, heads, head_dim): the row at
+    t along axis 1 stands at position t, or with ``pos`` (B, 1) at
+    ``pos[b] + t`` (a decode step's one row at its slot's position). Pair
+    ``(x[i], x[i + rotary_dim/2])`` of every head is turned by ``position
+    * theta^(-2i/rotary_dim)``; dimensions past ``rotary_dim`` (default:
+    the whole head) pass unturned. ``yarn`` = ``(factor, original
+    positions, beta_fast, beta_slow, attention_factor)`` blends the rates
+    and multiplies cos and sin by the last
+    (``ops.hybrid_ops.rotary_inv_freq``). No parameter."""
     helper = LayerHelper("rotary_embedding")
     out = _out(helper, x.dtype, x.shape)
-    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]}, attrs={"theta": float(theta)})
+    inputs = {"X": [x]}
+    if pos is not None:
+        inputs["Pos"] = [pos]
+    attrs = {"theta": float(theta)}
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
+    if yarn is not None:
+        attrs["yarn"] = [float(v) for v in yarn]
+    helper.append_op(type="rotary_embedding", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
-def gqa_attention(q, k, v, heads, kv_heads, pos=None):
+def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None):
     """Softmax attention of ``heads`` query heads over ``kv_heads``
     key/value heads, no position term of its own. Causal over (B, T, .)
-    inputs, or with ``pos`` (B, 1) over a slot cache of which row b sees
-    positions <= pos[b]. A causal call of 1,024 positions or more runs as
-    the Pallas flash kernels on an unsharded TPU program
-    (``ops.hybrid_ops.FLASH_MIN_SEQ``), so no (T, T) scores are held for
-    the backward pass."""
+    inputs, with ``window`` each query sees the last ``window`` positions
+    only (banded blocks: neither (T, T) scores nor a full causal call's
+    cost); or with ``pos`` (B, 1) over a slot cache of which row b sees
+    columns <= pos[b], be it a sequence's rows or a window layer's ring.
+    A plain causal call of 1,024 positions or more runs as the Pallas flash
+    kernels on an unsharded TPU program (``ops.hybrid_ops.FLASH_MIN_SEQ``),
+    so no (T, T) scores are held for the backward pass."""
     helper = LayerHelper("gqa_attention")
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if pos is not None:
         inputs["Pos"] = [pos]
     attrs = {"heads": int(heads), "kv_heads": int(kv_heads)}
+    if window:
+        attrs["window"] = int(window)
     out = _out(helper, q.dtype, q.shape)
     helper.append_op(type="gqa_attention", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
+def kv_ring_gather(x, length, window):
+    """A window layer's ring as a prefill hands it over: ``x`` (B, T, C)
+    one row a position, ``length`` (B, 1) -> (B, window, C), column j the
+    row of the last real position p with ``p mod window == j`` (zeros
+    where the sequence has none yet)."""
+    helper = LayerHelper("kv_ring_gather")
+    out = _out(helper, x.dtype, (x.shape[0], int(window), x.shape[-1]))
+    helper.append_op(type="kv_ring_gather",
+                     inputs={"X": [x], "Len": [length]},
+                     outputs={"Out": [out]}, attrs={"window": int(window)})
+    return out
+
+
 def moe_route_topk(x, num_experts, k, name, scale=1.0, norm_eps=None,
-                   bias_update_rate=0.0, apply_gradient=True):
-    """Sigmoid top-k router over ``num_experts`` in float32: ``x`` (T, H)
+                   bias_update_rate=0.0, apply_gradient=True,
+                   score_func="sigmoid", bias=True):
+    """Top-k router over ``num_experts`` in float32, scores a sigmoid of
+    the logits or (``score_func="softmax"``) their softmax: ``x`` (T, H)
     -> ``(index (T, k) int32, weight (T, k) float32)``, the weights
     normalised over the k chosen (``norm_eps``, default 1e-20, added to
     their sum) and multiplied by ``scale``. Parameters ``<name>.w`` (H,
-    experts) and ``<name>.bias`` (experts, float32, the score correction
+    experts) and, unless ``bias`` is false, ``<name>.bias`` (experts,
+    float32, the score correction
     added for the choice only: a buffer no optimizer trains). With
     ``bias_update_rate`` > 0 every call also moves that buffer by the
     auxiliary-loss-free balancing rule, from the call's own counts: up by
@@ -179,12 +215,18 @@ def moe_route_topk(x, num_experts, k, name, scale=1.0, norm_eps=None,
     inputs = {"X": [x],
               "Gate": [_param(helper, name + ".w",
                               [x.shape[-1], num_experts], x.dtype,
-                              learning_rate=float(bool(apply_gradient)))],
-              "Bias": [_param(helper, name + ".bias", [num_experts],
-                              "float32", trainable=False)]}
+                              learning_rate=float(bool(apply_gradient)))]}
+    if bias:
+        inputs["Bias"] = [_param(helper, name + ".bias", [num_experts],
+                                 "float32", trainable=False)]
+    elif bias_update_rate:
+        raise ValueError("bias_update_rate moves the score correction; "
+                         "this router has none (bias=False)")
     idx = _out(helper, "int32", (t, k))
     wt = _out(helper, "float32", (t, k))
     attrs = {"k": int(k), "scale": float(scale)}
+    if score_func != "sigmoid":
+        attrs["score_func"] = str(score_func)
     if norm_eps is not None:
         attrs["norm_eps"] = float(norm_eps)
     outputs = {"Index": [idx], "Weight": [wt]}

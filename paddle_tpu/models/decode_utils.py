@@ -26,7 +26,11 @@ class StateEntry(collections.namedtuple(
       the leading axis is the cache length, a prefix of the sequence is
       the first rows, and cutting at a prefix is zeroing the rest;
     - ``"fixed"``: a whole value per sequence (a convolution window, a
-      state-space state); it has no rows to cut or to share.
+      state-space state); it has no rows to cut or to share;
+    - ``"ring"``: the last ``shape[0]`` positions' rows of a window
+      attention layer, position p at row ``p mod shape[0]``: it does not
+      grow with the sequence, and what it held of an earlier position is
+      written over, so it has no prefix to cut or to share either.
     """
 
     __slots__ = ()
@@ -76,15 +80,20 @@ class DecodeModel:
 
 def require_rows_only(model, feature):
     """Features that cut, share, quantise or ship a sequence's state row
-    by row cannot hold a ``fixed`` entry: refuse, do not emulate."""
-    fixed = [e.name for e in model.state if e.kind == "fixed"]
-    if fixed:
+    by row cannot hold a ``fixed`` or a ``ring`` entry: refuse, do not
+    emulate."""
+    other = [e for e in model.state if e.kind != "rows"]
+    if other:
+        names = [e.name for e in other]
+        kinds = sorted({e.kind for e in other})
+        what = {"fixed": "a fixed-size state per sequence",
+                "ring": "a ring of a window layer's last positions"}
         raise ValueError(
             "%s needs state with one row per position; this model also "
-            "carries a fixed-size state per sequence (%s%s), which has no "
-            "prefix to cut, share or roll back"
-            % (feature, ", ".join(fixed[:3]),
-               ", ..." if len(fixed) > 3 else ""))
+            "carries %s (%s%s), which has no prefix to cut, share or roll "
+            "back" % (feature, " and ".join(what.get(k, k) for k in kinds),
+                      ", ".join(names[:3]),
+                      ", ..." if len(names) > 3 else ""))
 
 
 def split_heads(t, heads, dh):

@@ -39,6 +39,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
+from . import decoder_blocks as blocks
+
 __all__ = ["Lfm2Config", "build_lfm2_pretrain", "param_shapes",
            "step_counters"]
 
@@ -123,8 +125,7 @@ class Lfm2Config:
 
 
 def _fc(x, size, name):
-    return layers.fc(x, size, num_flatten_dims=2,
-                     param_attr=ParamAttr(name=name + ".w"), bias_attr=False)
+    return blocks.fc(x, size, name, nfd=2)
 
 
 def _conv(h, cfg, n):
@@ -152,26 +153,13 @@ def _attention(h, cfg, n, seq_len):
     return _fc(a, cfg.hidden, n + ".o")
 
 
-def _mlp(h, cfg, n):
-    gate = layers.swish(_fc(h, cfg.ffn, n + ".w1"))
-    return _fc(layers.elementwise_mul(gate, _fc(h, cfg.ffn, n + ".w3")),
-               cfg.hidden, n + ".w2")
-
-
 def _experts(h, cfg, n, seq_len):
     """-> (the held experts' part (B, T, H), its counts (4,) int32)."""
-    from ..parallel.moe import held_experts_ffn
-
-    flat = layers.reshape(h, [-1, cfg.hidden])
-    with fluid.name_scope("lfm2.moe.route"):
-        idx, wt = layers.moe_route_topk(
-            flat, cfg.num_experts, cfg.top_k, n + ".gate",
-            scale=cfg.routed_scale, norm_eps=cfg.route_eps,
-            bias_update_rate=cfg.bias_update_rate,
-            apply_gradient=cfg.router_trains)
-    with fluid.name_scope("lfm2.moe.experts"):
-        out, counts = held_experts_ffn(flat, idx, wt, cfg.held, cfg.moe_ffn,
-                                       n + ".experts", gated=True)
+    out, counts = blocks.routed_gated_experts(
+        layers.reshape(h, [-1, cfg.hidden]), cfg.num_experts, cfg.top_k,
+        cfg.held, cfg.moe_ffn, n, "lfm2.moe", scale=cfg.routed_scale,
+        norm_eps=cfg.route_eps, bias_update_rate=cfg.bias_update_rate,
+        apply_gradient=cfg.router_trains)
     return layers.reshape(out, [-1, seq_len, cfg.hidden]), counts
 
 
@@ -206,7 +194,7 @@ def build_lfm2_pretrain(cfg, seq_len):
         h = layers.rms_norm(x, n + ".ffn_norm", epsilon=cfg.eps)
         if i < cfg.num_dense_layers:
             with fluid.name_scope("lfm2.mlp"):
-                y = _mlp(h, cfg, n + ".mlp")
+                y = blocks.swiglu(h, cfg.ffn, cfg.hidden, n + ".mlp", nfd=2)
         else:
             y, c = _experts(h, cfg, n + ".moe", seq_len)
             counts.append(c)

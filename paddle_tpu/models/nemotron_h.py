@@ -26,6 +26,8 @@ from paddle_tpu.fluid.param_attr import ParamAttr
 
 from .decode_utils import (DecodeModel, StateEntry, require_rows_only,
                            update_cache)
+from .decoder_blocks import fc as _fc
+from .decoder_blocks import greedy_head
 
 __all__ = ["NemotronHConfig", "build_prefill", "build_step", "param_shapes"]
 
@@ -144,11 +146,6 @@ class NemotronHConfig:
                 "moe_experts_touched_sum": int(aux[:, 2].sum())}
 
 
-def _fc(x, size, name, nfd=1):
-    return layers.fc(x, size, num_flatten_dims=nfd,
-                     param_attr=ParamAttr(name=name + ".w"), bias_attr=False)
-
-
 def _moe(h, cfg, n, live):
     """LatentMoE on (T, H) rows: routed path in the latent over the held
     experts, shared expert on ``h`` itself. -> (out (T, H), counts, the
@@ -188,11 +185,7 @@ def _ssm_args(cfg):
 
 def _head(x, cfg):
     """Final norm, float32 logits over the held rows, greedy token."""
-    x = layers.rms_norm(x, "nh.norm_f", epsilon=cfg.eps)
-    logits = layers.dense_acc32(x, cfg.vocab, "nh.head")
-    nxt = layers.cast(
-        layers.unsqueeze(layers.argmax(logits, axis=-1), [1]), "int64")
-    return logits, nxt
+    return greedy_head(x, cfg.vocab, cfg.eps, "nh.norm_f", "nh.head")
 
 
 def _embed(ids, cfg):

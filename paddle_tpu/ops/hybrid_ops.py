@@ -184,22 +184,65 @@ def _mamba2_scan(ctx, ins, attrs):
             "StateOut": [last.reshape(b, g * hg, p, n)]}
 
 
+def rotary_inv_freq(rot, theta, yarn=None):
+    """(rot / 2,) float32 turning rates of a rotary term over ``rot``
+    dimensions, and the factor on its cos and sin. Plain:
+    ``theta^(-2i/rot)``, factor 1. ``yarn`` ((factor, original positions,
+    beta_fast, beta_slow, attention_factor); Peng et al. 2023,
+    arXiv:2309.00071, as ``transformers`` computes it): pair i keeps its
+    rate where it turns more than ``beta_fast`` times over the original
+    positions, has it divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear blend between (the corrections
+    floored and ceiled, clipped to [0, rot - 1])."""
+    import math
+
+    import numpy as np
+
+    f = float(theta) ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if not yarn:
+        return (1.0 / f).astype(np.float32), 1.0
+    factor, original, fast, slow, attention_factor = yarn
+
+    def correction(turns):
+        return (rot * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction(fast)), 0)
+    high = min(math.ceil(correction(slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    inv = (1.0 - ramp) / f + ramp / (factor * f)
+    return inv.astype(np.float32), float(attention_factor)
+
+
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
-    """Rotary position term over whole heads, half-split pairs: X (B, T,
-    heads, dh), position t = 0..T-1 along axis 1, pair i of a head is
-    ``(x[i], x[i + dh/2])`` turned by ``t * theta^(-2i/dh)``. Computed in
-    float32, returned in X's dtype."""
+    """Rotary position term, half-split pairs: X (B, T, heads, dh); the
+    row at t along axis 1 stands at position t, or with ``Pos`` (B, 1) at
+    ``pos[b] + t`` (a decode step's row at its slot's position). Over the
+    first ``rotary_dim`` dimensions of a head (default: all) pair i is
+    ``(x[i], x[i + rotary_dim/2])``, turned by ``position * rate_i``
+    (:func:`rotary_inv_freq`; ``yarn`` blends and scales); the other
+    dimensions pass unturned. Computed in float32, returned in X's
+    dtype."""
     x = ins["X"][0]
     t, dh = x.shape[1], x.shape[-1]
-    half = dh // 2
-    freq = float(attrs["theta"]) ** (
-        -jnp.arange(half, dtype=F32) * 2.0 / dh)
-    ang = jnp.arange(t, dtype=F32)[:, None] * freq[None, :]   # (T, dh/2)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    rot = int(attrs.get("rotary_dim") or dh)
+    half = rot // 2
+    freq, factor = rotary_inv_freq(rot, float(attrs["theta"]),
+                                   attrs.get("yarn") or None)
+    at = jnp.arange(t, dtype=F32)[None, :]                    # (1, T)
+    if ins.get("Pos"):
+        at = at + ins["Pos"][0].reshape(-1, 1).astype(F32)    # (B, T)
+    ang = at[:, :, None] * jnp.asarray(freq)[None, None, :]   # (., T, rot/2)
+    cos = (jnp.cos(ang) * factor)[:, :, None, :]
+    sin = (jnp.sin(ang) * factor)[:, :, None, :]
     xf = x.astype(F32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    x1, x2 = xf[..., :half], xf[..., half:rot]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                           xf[..., rot:]], -1)
     return single(out.astype(x.dtype))
 
 
@@ -230,21 +273,71 @@ def _flash_gqa(q, k, v, nh, nkv):
     return jnp.swapaxes(out, 1, 2).reshape(b, t, nh * dh)
 
 
+def _banded_gqa(q, k, v, nh, nkv, window):
+    """Causal attention in which query i sees keys i - window < j <= i,
+    without (T, T) scores and at the window's cost: the queries in blocks
+    of ``window`` rows, each against its own block of keys and the one
+    before it (2 x window columns, masked to the band), one block a trip
+    of a loop, so the scores alive are (heads, window, 2 x window)."""
+    b, t, _ = q.shape
+    dh = q.shape[-1] // nh
+    pad = (-t) % window
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+    nb = (t + pad) // window
+    qb = q.reshape(b, nb, window, nkv, nh // nkv, dh)
+
+    def blocks(a):
+        """(nb, B, 2 x window, kv heads, dh): block c - 1 (zeros before the
+        first) and block c of the keys or values."""
+        a = a.reshape(b, nb, window, nkv, dh)
+        before = jnp.concatenate([jnp.zeros_like(a[:, :1]), a[:, :-1]], 1)
+        return jnp.moveaxis(jnp.concatenate([before, a], 2), 1, 0)
+
+    row = jnp.arange(window, dtype=jnp.int32)[:, None]
+    col = jnp.arange(2 * window, dtype=jnp.int32)[None, :] - window
+    band = (col <= row) & (row - col < window)             # (W, 2W)
+
+    def one(args):
+        c, qc, kc, vc = args
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qc, kc,
+                            preferred_element_type=F32) * dh ** -0.5
+        seen = band & ((col >= 0) | (c > 0))               # no block before 0
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(vc.dtype), vc,
+                          preferred_element_type=F32).astype(q.dtype)
+
+    out = lax.map(one, (jnp.arange(nb, dtype=jnp.int32),
+                        jnp.moveaxis(qb, 1, 0), blocks(k), blocks(v)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t + pad, nh * dh)[:, :t]
+
+
 @register_op("gqa_attention")
 def _gqa_attention(ctx, ins, attrs):
     """Softmax attention with fewer key/value heads than query heads; no
     position term of its own. Q (B, Tq, heads * dh), K/V (B, Tk,
     kv_heads * dh). With ``Pos`` (B, 1) the keys are a slot cache and row
-    b sees positions <= pos[b]; without it Tq == Tk and the mask is
-    causal. A causal call of at least FLASH_MIN_SEQ positions runs through
-    the flash kernels on the TPU (a training sequence of 4,096 would
-    otherwise hold (B, heads, T, T) float32 scores); shorter calls, other
-    platforms and a sharded program take the products below."""
+    b sees columns <= pos[b]: the rows of a sequence, or a ring of the
+    last Tk positions written at ``position mod Tk`` (every column of it
+    once ``pos >= Tk - 1``; a softmax does not mind the order). Without
+    ``Pos``, Tq == Tk and the mask is causal, with ``window`` > 0 cut to
+    the last ``window`` positions (:func:`_banded_gqa` where the sequence
+    is longer than the window). A plain causal call of at least
+    FLASH_MIN_SEQ positions runs through the flash kernels on the TPU (a
+    training sequence of 4,096 would otherwise hold (B, heads, T, T)
+    float32 scores); shorter calls, other platforms and a sharded program
+    take the products below."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     nh, nkv = int(attrs["heads"]), int(attrs["kv_heads"])
+    window = int(attrs.get("window") or 0)
     b, tq, _ = q.shape
     tk = k.shape[1]
     dh = q.shape[-1] // nh
+    if ins.get("Pos") and window:
+        raise ValueError("gqa_attention over a slot cache takes no window: "
+                         "a window layer's cache is a ring of that length")
+    if window and tq > window:
+        return single(_banded_gqa(q, k, v, nh, nkv, window))
     if (not ins.get("Pos") and tq >= FLASH_MIN_SEQ
             and getattr(ctx, "platform", None) == "tpu"
             and not getattr(ctx, "mesh_axes", None)):
@@ -266,10 +359,25 @@ def _gqa_attention(ctx, ins, attrs):
     return single(ctxv.reshape(b, tq, nh * dh).astype(q.dtype))
 
 
+@register_op("kv_ring_gather")
+def _kv_ring_gather(ctx, ins, attrs):
+    """What a prefill hands a window layer's ring: X (B, T, C) one row a
+    position, Len (B, 1) -> Out (B, window, C) whose column j is the row
+    of the last real position p < len with ``p mod window == j`` (zeros
+    where there is none yet)."""
+    x, window = ins["X"][0], int(attrs["window"])
+    last = ins["Len"][0].reshape(-1, 1).astype(jnp.int32) - 1     # (B, 1)
+    j = jnp.arange(window, dtype=jnp.int32)[None, :]
+    at = last - jnp.mod(last - j, window)                         # (B, W)
+    rows = jnp.take_along_axis(x, jnp.maximum(at, 0)[:, :, None], axis=1)
+    return single(jnp.where((at >= 0)[:, :, None], rows, 0).astype(x.dtype))
+
+
 @register_op("moe_route_topk")
 def _moe_route_topk(ctx, ins, attrs):
-    """Sigmoid top-k routing in float32 over ALL experts: scores
-    ``sigmoid(x W_g)``, the k largest of ``score + bias`` chosen, their
+    """Top-k routing in float32 over ALL experts: scores ``sigmoid(x
+    W_g)`` (``score_func`` "softmax": ``softmax(x W_g)``), the k largest of
+    ``score + bias`` chosen (of the scores alone without ``Bias``), their
     scores normalised over the k chosen (``norm_eps`` added to their sum)
     and scaled. -> Index (T, k) int32, Weight (T, k) float32. Operands
     stored in float32 (a trained router's master weights) are multiplied
@@ -288,9 +396,13 @@ def _moe_route_topk(ctx, ins, attrs):
     if attrs.get("detach_input"):
         x = lax.stop_gradient(x)
     full = x.dtype == F32 and gate.dtype == F32
-    s = jax.nn.sigmoid(_dot_f32(x, gate,
-                                lax.Precision.HIGHEST if full else None))
-    _, idx = lax.top_k(s + ins["Bias"][0].astype(F32), int(attrs["k"]))
+    logits = _dot_f32(x, gate, lax.Precision.HIGHEST if full else None)
+    if attrs.get("score_func", "sigmoid") == "softmax":
+        s = jax.nn.softmax(logits, -1)
+    else:
+        s = jax.nn.sigmoid(logits)
+    chosen_by = s + ins["Bias"][0].astype(F32) if ins.get("Bias") else s
+    _, idx = lax.top_k(chosen_by, int(attrs["k"]))
     w = jnp.take_along_axis(s, idx, axis=-1)
     w = w / (jnp.sum(w, -1, keepdims=True)
              + float(attrs.get("norm_eps", 1e-20)))
@@ -617,21 +729,25 @@ def held_experts_sum(x, idx, wt, w1, w2, first, live=None, platform=None):
 def _held_experts_ffn(ctx, ins, attrs):
     """A chip's share of a routed expert layer. X (T, D), Index/Weight
     (T, k) from the router over all experts, W1 (held, D, F), W2 (held, F,
-    D): squared-ReLU experts (:func:`held_experts_sum`), where ``Live``
-    (T, 1) masks rows that carry no token; with ``W3`` (held, D, F) gated
-    ones, ``W2 (silu(W1 x) * W3 x)`` (:func:`gated_experts_sum`), which
-    train. Counts is int32 ``[assignments held, largest count on one held
-    expert, held experts that got any]``, gated also the sorted rows the
-    loops covered."""
+    D): squared-ReLU experts (:func:`held_experts_sum`); with ``W3``
+    (held, D, F) gated ones, ``W2 (silu(W1 x) * W3 x)``
+    (:func:`gated_experts_sum`), which train. ``Live`` (T, 1) masks rows
+    that carry no token (a dead decode slot, a prompt's padding). Counts is
+    int32 ``[assignments held, largest count on one held expert, held
+    experts that got any]``, gated also the sorted rows the loops
+    covered."""
     x = ins["X"][0]
     live = ins["Live"][0] if ins.get("Live") else None
     platform = getattr(ctx, "platform", None)
     first = int(attrs["first_expert"])
     if ins.get("W3"):
+        idx = ins["Index"][0]
         if live is not None:
-            raise ValueError("gated held experts take no Live mask")
+            # a row that carries no token chooses no expert: -1 lies in no
+            # held range (a served program; nothing differentiates it)
+            idx = jnp.where(live.reshape(-1, 1).astype(bool), idx, -1)
         out, counts = gated_experts_sum(
-            x, ins["Index"][0], ins["Weight"][0], ins["W1"][0],
+            x, idx, ins["Weight"][0], ins["W1"][0],
             ins["W3"][0], ins["W2"][0], first, platform)
     else:
         out, counts = held_experts_sum(

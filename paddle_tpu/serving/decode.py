@@ -13,10 +13,13 @@ mid-generation. This engine removes the full-batch barrier:
   attention layer a ``(slots, cache_len, width)`` K and V (``rows``, one
   per position), for a state-space layer a convolution window and a
   recurrent state per slot (``fixed``, a whole value per sequence), for
-  an expert layer nothing. Every program that takes the buffers is given
-  them **donated** and updates them in place: a step writes one row per
-  slot and attention layer, overwrites each fixed state, and copies
-  nothing. A slot is a sequence's home for its whole generation;
+  a window attention layer a ``(slots, window, width)`` K and V (``ring``,
+  position p at row ``p mod window``), for an expert layer nothing. Every
+  program that takes the buffers is given them **donated** and updates
+  them in place: a step writes one row per slot and attention layer (of a
+  ring, over the row that has left the window), overwrites each fixed
+  state, and copies nothing. A slot is a sequence's home for its whole
+  generation;
   retiring frees the slot the same step.
 - **Two programs, both AOT** — a *prefill* program per declared prompt
   bucket (parallel pass over the right-padded prompt writes a slot's
@@ -146,9 +149,10 @@ class SlotCache:
     device buffer of ``entry.dtype``, kept as the flat list ``bufs`` in
     the declaration's order, which is the order of a step program's
     ``cache_feed_names``. ``rows`` entries (K and V of an attention
-    layer) and ``fixed`` entries (a convolution window, a state-space
-    state) live side by side; allocation, donation, :meth:`write_slot`
-    and :meth:`read_slot` treat them alike.
+    layer), ``ring`` entries (K and V of a window attention layer, the
+    window long) and ``fixed`` entries (a convolution window, a
+    state-space state) live side by side; allocation, donation,
+    :meth:`write_slot` and :meth:`read_slot` treat them alike.
 
     Every program that takes the buffers consumes them
     (``Predictor(donate_feeds=...)``) and hands them back updated in
@@ -395,9 +399,10 @@ class DecodeEngine:
     caller that made them on the device and hands them over (it must not
     donate or change them afterwards).
 
-    A model that carries ``fixed`` state (a state-space layer) cannot be
-    combined with what cuts, shares, quantises or ships state row by
-    row: ``prefix_pool``, ``session_tier``, ``kv_dtype="int8"``, a
+    A model that carries ``fixed`` state (a state-space layer) or a
+    ``ring`` (a window attention layer) cannot be combined with what
+    cuts, shares, quantises or ships state row by row: ``prefix_pool``,
+    ``session_tier``, ``kv_dtype="int8"``, a
     ``draft`` (block verify with roll-back), ``role="decode"`` /
     :meth:`submit_prefilled` (the KV wire). Each is refused at
     construction."""
@@ -572,7 +577,7 @@ class DecodeEngine:
 
         # -- the persistent slot cache + host-side slot state ----------
         self._cache = SlotCache(jax, model, self.slots)
-        for kind in ("rows", "fixed"):
+        for kind in ("rows", "fixed", "ring"):
             obs.set_gauge("serving.decode.state_bytes_%s.%s"
                           % (kind, self.name), self._cache.nbytes(kind))
         self._tok = np.zeros((self.slots, 1), np.int64)
@@ -1949,6 +1954,7 @@ class DecodeEngine:
         out["live_slots"] = sum(1 for s in self._slots if s is not None)
         out["state_bytes_rows"] = self._cache.nbytes("rows")
         out["state_bytes_fixed"] = self._cache.nbytes("fixed")
+        out["state_bytes_ring"] = self._cache.nbytes("ring")
         out["slots"] = self.slots
         out["kv_dtype"] = self.kv_dtype
         out["role"] = self.role

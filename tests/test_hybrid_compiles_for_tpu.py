@@ -83,6 +83,84 @@ def test_the_step_at_published_widths_aliases_all_its_state(one_chip):
     assert round(weights / 1e9, 1) == 9.3
 
 
+def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
+    """Laguna-S-2.1's cut (3,002 M parameters, 64 slots x 8,704) compiled
+    for the described chip. The step: every declared buffer, rows and rings,
+    aliased to its fetch (4.97 GB updated in place), no cache-sized scratch,
+    arguments + scratch under the chip's 16 GB. The 8,192 prefill: the
+    grouped kernels (three a sparse layer) and the flash kernel of the two
+    full layers are in it, no (T, T) array is (the window layers' scores are
+    banded blocks), and its scratch fits beside weights and state."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import laguna
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "laguna_s_2_1.json")))
+    cfg = laguna.LagunaConfig.from_hf(
+        doc, router_experts=doc["reduced_from"]["num_experts"],
+        first_expert=doc["share"]["first_expert"])
+    slots, cache_len = doc["serving"]["slots"], doc["serving"]["cache_len"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {k: sds(s, d) for k, (s, d) in laguna.param_shapes(cfg).items()}
+    weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in params.values())
+    assert round(weights / 1e9, 2) == 6.00
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = laguna.build_step(cfg, cache_len)
+        prog = fluid.default_main_program()
+    step = build_step_fn(prog, v["feed_names"],
+                         [x.name for x in v["fetch_vars"]], is_test=True,
+                         platform="tpu")
+    names = v["cache_feed_names"]
+    decl = cfg.decode_model(cache_len).state
+
+    def fwd(state, feeds, donated):
+        feeds = dict(feeds)
+        feeds.update(zip(names, donated))
+        return step(state, feeds, jax.random.PRNGKey(0))[0]
+
+    feeds = {"lg_step_tok": sds((slots, 1), "int32"),
+             "lg_step_pos": sds((slots, 1), "int32")}
+    donated = tuple(sds((slots,) + tuple(e.shape), e.dtype) for e in decl)
+    compiled = _no_cache_compile(jax.jit(fwd, donate_argnums=(2,)).lower(
+        params, feeds, donated))
+    mem = compiled.memory_analysis()
+    state_bytes = slots * sum(e.nbytes for e in decl)
+    assert round(state_bytes / 1e9, 2) == 4.97
+    assert mem.alias_size_in_bytes >= state_bytes       # all 10, in place
+    assert mem.temp_size_in_bytes < 512e6               # nothing cache-sized
+    assert 10.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    assert compiled.as_text().count("tpu_custom_call") == 4 * 3
+
+    bucket = 8192
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = laguna.build_prefill(cfg, bucket, cache_len)
+        prog = fluid.default_main_program()
+    prefill = build_step_fn(prog, v["feed_names"],
+                            [x.name for x in v["fetch_vars"]], is_test=True,
+                            platform="tpu")
+    compiled = _no_cache_compile(jax.jit(
+        lambda state, feeds: prefill(state, feeds, jax.random.PRNGKey(0))[0]
+    ).lower(params, {"lg_prefill_ids": sds((1, bucket), "int32"),
+                     "lg_prefill_len": sds((1, 1), "int32")}))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4 * 3 + 2
+    assert "flash_fwd" in text
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    mem = compiled.memory_analysis()
+    # 1.70 GB: the gated experts' buffers at the static bound of 81,920 rows
+    assert mem.temp_size_in_bytes < 2.2e9
+    assert weights + state_bytes + mem.temp_size_in_bytes < 14.5e9
+
+
 def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
     """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
     vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
