@@ -579,16 +579,25 @@ def gated_experts_sum(x, idx, wt, w1, w3, w2, first, platform=None):
     elsewhere add nothing. Sorted and grouped as :func:`held_experts_sum`,
     and differentiable in ``x``, the three matrices and ``wt`` (through
     which the router learns). The sort lists the n assignments that landed
-    here first, and nothing outside the grouped kernels touches a sorted
-    row past them: the gather of the tokens' rows, the gate and the
-    weighted sum back into the tokens are loops over chunks of
-    GATED_CHUNK_ROWS sorted rows whose trip count ``ceil(n / rows)`` the
-    device computes, and so are their gradients (a hand-written backward
-    pass of the same trips). The buffers keep the static bound of tokens x
-    experts per token, so any load is exact: nothing has a capacity. The
-    hidden activations and each expert's output are kept in ``x``'s dtype.
-    -> (out (T, D) float32, counts as :func:`held_experts_sum` and fourth
-    the sorted rows the loops covered, trips x GATED_CHUNK_ROWS)."""
+    here first, and between the sort and the sum back nothing outside the
+    grouped kernels touches a sorted row past them: the gather of the
+    tokens' rows and the gate are loops over chunks of GATED_CHUNK_ROWS
+    sorted rows whose trip count ``ceil(n / rows)`` the device computes,
+    and so are their gradients (a hand-written backward pass of the same
+    trips). ONE pass reads the static bound instead: the weighted sum back
+    into the tokens (and its mirror, ``d x``) goes token by token through
+    the sort's inverse (:func:`_sum_into_tokens`), a read for each of a
+    token's k choices, held or not, and one write of the token's row. That
+    is tokens x k reads where a scatter-add over the held rows makes n
+    read-modify-writes, and it wins although n is a quarter of the bound
+    in the cells that run it, because the chip reads a row several times
+    faster than it adds one into a row it must read and write back, one
+    row after another (PERF.md, PR 36). The buffers keep the static bound
+    of tokens x experts per token, so any load is exact: nothing has a
+    capacity. The hidden activations and each expert's output are kept in
+    ``x``'s dtype. -> (out (T, D) float32, counts as
+    :func:`held_experts_sum` and fourth the sorted rows the loops over the
+    held rows covered, trips x GATED_CHUNK_ROWS)."""
     return _gated_fwd(x, idx, wt, w1, w3, w2, first, platform)[0]
 
 
@@ -613,19 +622,33 @@ def _gate(a, b, trips, r, weight=None):
     return lax.fori_loop(0, trips, body, lax.empty(a.shape, a.dtype))
 
 
-def _add_into_tokens(terms, tok, n, trips, r, t, weight=None):
-    """(t, D) float32: the sum of ``terms`` at each of the first n sorted
-    rows (times the row's weight) added into the row's token; a token that
-    two held experts serve gets both."""
-    def body(c, out):
-        add = sum(_chunk(v, c, r).astype(F32) for v in terms)
-        if weight is not None:
-            add = add * _chunk(weight, c, r)[:, None]
-        return out.at[_chunk(tok, c, r)].add(
-            jnp.where(_live(c, r, n), add, 0.0))
+def _sum_into_tokens(rows, inv, here, weight=None):
+    """(T, D) float32: for each token the sum over its k choices of the
+    sorted row of ``rows`` that ``inv`` (T, k) names (times the choice's
+    ``weight``); a choice not ``here`` is left out by a select, since its
+    row is undefined. Reads only, and each token's row is written once: a
+    trip takes a quarter of GATED_CHUNK_ROWS tokens and gathers that many
+    rows a choice (512 at a time read fastest at both cells' shapes, one
+    gather of all k x 512 slowest: PERF.md, PR 36); the last chunk is
+    moved back to end on the last token."""
+    t, k = inv.shape
+    inv = jnp.where(here, inv, 0)
+    tc = min(t, GATED_CHUNK_ROWS // 4)
 
-    return lax.fori_loop(0, trips, body,
-                         jnp.zeros((t, terms[0].shape[1]), F32))
+    def body(c, out):
+        at = jnp.minimum(c * tc, t - tc)
+        p, h, w = (v if v is None else lax.dynamic_slice_in_dim(v, at, tc)
+                   for v in (inv, here, weight))
+        acc = jnp.zeros((tc, rows.shape[1]), F32)
+        for j in range(k):
+            term = jnp.take(rows, p[:, j], axis=0).astype(F32)
+            if w is not None:
+                term = term * w[:, j, None]
+            acc = acc + jnp.where(h[:, j, None], term, 0.0)
+        return lax.dynamic_update_slice_in_dim(out, acc, at, 0)
+
+    return lax.fori_loop(0, -(-t // tc), body,
+                         lax.empty((t, rows.shape[1]), F32))
 
 
 def _gated_fwd(x, idx, wt, w1, w3, w2, first, platform):
@@ -642,9 +665,10 @@ def _gated_fwd(x, idx, wt, w1, w3, w2, first, platform):
                       lax.empty((t * k + pad, x.shape[1]), x.dtype))
     a, b = dot(xs, w1), dot(xs, w3)
     outs = dot(_gate(a, b, trips, r), w2)
-    out = _add_into_tokens([outs], tok, n, trips, r, t, wts)
+    inv, here = jnp.argsort(order).reshape(t, k), here.reshape(t, k)
+    out = _sum_into_tokens(outs, inv, here, wt)
     counts = jnp.concatenate([_counts(here, sizes), (trips * r)[None]])
-    return (out, counts), (x, order, sizes, n, trips, tok, wts, a, b,
+    return (out, counts), (x, inv, here, sizes, n, trips, tok, wts, a, b,
                            w1, w3, w2)
 
 
@@ -658,9 +682,9 @@ def _gated_bwd(first, platform, res, cts):
     over a buffer of their shape that is dead by then where there is one.
     Rows of the last chunk past the held ones are undefined on the
     kernels' side: masked wherever they would be summed."""
-    x, order, sizes, n, trips, tok, wts, a, b, w1, w3, w2 = res
+    x, inv, here, sizes, n, trips, tok, wts, a, b, w1, w3, w2 = res
     g = cts[0]
-    r = min(GATED_CHUNK_ROWS, order.shape[0])
+    r = min(GATED_CHUNK_ROWS, inv.size)
 
     def pull(v, w, ct):
         """(d v, d w) of ``grouped_dot(v, w)``: on the TPU its own backward
@@ -693,8 +717,14 @@ def _gated_bwd(first, platform, res, cts):
     da, db, dwt = lax.fori_loop(0, trips, gate, (a, b, jnp.zeros_like(wts)))
     xs = _gather_rows(x, tok, trips, r, gs)
     (dxa, dw1), (dxb, dw3) = pull(xs, w1, da), pull(xs, w3, db)
-    dx = _add_into_tokens([dxa, dxb], tok, n, trips, r, x.shape[0])
-    dwt = jnp.take(dwt, jnp.argsort(order)).reshape(x.shape[0], -1)
+    # one read a choice, not two: the two products' rows added first, over
+    # the held rows, into the first's buffer (one more rounding to x's
+    # dtype of what both kernels had just rounded to it)
+    dxs = lax.fori_loop(0, trips, lambda c, s: _put(s, c, r, (
+        _chunk(s, c, r).astype(F32) + _chunk(dxb, c, r).astype(F32)
+    ).astype(s.dtype)), dxa)
+    dx = _sum_into_tokens(dxs, inv, here)
+    dwt = jnp.take(dwt, inv)
     return dx.astype(x.dtype), None, dwt, dw1, dw3, dw2
 
 

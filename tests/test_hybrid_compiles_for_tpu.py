@@ -90,7 +90,9 @@ def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
     arguments + scratch under the chip's 16 GB. The 8,192 prefill: the
     grouped kernels (three a sparse layer) and the flash kernel of the two
     full layers are in it, no (T, T) array is (the window layers' scores are
-    banded blocks), and its scratch fits beside weights and state."""
+    banded blocks), no scatter adds rows into the prompt's `[8192, 3072]`
+    (the gated experts' sum back is a read a token), and its scratch fits
+    beside weights and state."""
     import jax
     import jax.numpy as jnp
 
@@ -155,6 +157,8 @@ def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
     assert text.count("tpu_custom_call") == 4 * 3 + 2
     assert "flash_fwd" in text
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    # the gated experts' sum back into the tokens reads, a token at a time
+    assert not _row_scatters(text, "8192,3072")
     mem = compiled.memory_analysis()
     # 1.70 GB: the gated experts' buffers at the static bound of 81,920 rows
     assert mem.temp_size_in_bytes < 2.2e9
@@ -278,6 +282,13 @@ def _static_bound_passes(text):
     return out
 
 
+def _row_scatters(text, shape):
+    """The `scatter` instructions of an optimised HLO text whose result is
+    a float32 array of `shape` ("rows,width")."""
+    return [line.strip() for line in text.splitlines()
+            if re.search(r"= f32\[%s\]\S* scatter\(" % shape, line)]
+
+
 def test_the_lfm2_training_step_at_published_widths_fits_one_chip(one_chip):
     """The cell's step (LFM2-8B-A1B's cut: 507.8 M parameters, 4 x 4,096
     tokens, bf16 AMP, Adam) as `Executor.run` would build it, compiled for
@@ -290,7 +301,10 @@ def test_the_lfm2_training_step_at_published_widths_fits_one_chip(one_chip):
     program holds no `copy`, `gather` or `select` that makes a
     `[65536, 2048]` or `[65536, 1792]` array outside a `while` body (before
     the loops there were sixteen such gathers and sixteen selects), and a
-    `copy` of one anywhere would be a loop that lost its buffer."""
+    `copy` of one anywhere would be a loop that lost its buffer. The way
+    back into the tokens, forward and backward, is a read a token: the one
+    scatter left that adds rows into a `[16384, 2048]` array is the tied
+    embedding's gradient (16,384 held rows of the vocabulary)."""
     import jax
     import jax.numpy as jnp
 
@@ -346,9 +360,12 @@ def test_the_lfm2_training_step_at_published_widths_fits_one_chip(one_chip):
     passes = _static_bound_passes(text)
     assert not passes["outside_loops"], passes["outside_loops"][:4]
     assert not [op for op in passes["in_loops"] if op == "copy"]
-    # four expert layers x (three loops forward + five backward) + the
-    # fused head's two (+ the compiler's own)
-    assert len(re.findall(r" while\(", text)) >= 4 * 8 + 2
+    scatters = _row_scatters(text, "16384,2048")
+    assert len(scatters) == 1 and "lookup_table" in scatters[0], scatters
+    # four expert layers x (three loops forward + six backward: the two
+    # products' rows are added over the held rows before the read back) +
+    # the fused head's two (+ the compiler's own)
+    assert len(re.findall(r" while\(", text)) >= 4 * 9 + 2
     mem = compiled.memory_analysis()
     state_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
                       for s in state.values())

@@ -261,6 +261,28 @@ def _gated_by_the_book(x, idx, wt, w1, w3, w2, first):
     return jnp.sum(jnp.where(here, wt, 0.0)[:, :, None] * out, 1), None
 
 
+def _gated_operands(rng, tokens, k, held, dtype=jnp.float32, d=64, f=48):
+    """(x, wt, w1, w3, w2): rows and matrices in `dtype`, float32 weights
+    on the assignments."""
+    x = jnp.asarray(rng.normal(size=(tokens, d)), dtype)
+    wt = jnp.asarray(0.2 + rng.random((tokens, k)), jnp.float32)
+    w1, w3 = (jnp.asarray(0.1 * rng.normal(size=(held, d, f)), dtype)
+              for _ in range(2))
+    w2 = jnp.asarray(0.1 * rng.normal(size=(held, f, d)), dtype)
+    return x, wt, w1, w3, w2
+
+
+def _gated_loss(fn, idx, first, operands):
+    """((sum(sin(out)), (out, counts)), the five gradients) of `fn`, a
+    `gated_experts_sum`, over `operands`."""
+    def f_(x, wt, w1, w3, w2):
+        out, counts = fn(x, idx, wt, w1, w3, w2, first)
+        return jnp.sum(jnp.sin(out)), (out, counts)
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(f_, (0, 1, 2, 3, 4), has_aux=True)(
+            *operands)
+
+
 CHUNK, TOKENS, SLOTS = 8, 12, 2     # 24 assignments: up to three trips
 
 
@@ -275,7 +297,7 @@ def test_the_gated_loops_follow_the_held_rows_at_every_load(monkeypatch,
     are whole chunks over the held rows and no more; a token whose two
     experts are both held gets both."""
     monkeypatch.setattr(hybrid_ops, "GATED_CHUNK_ROWS", CHUNK)
-    first, held, d, f = 2, 4, 64, 48
+    first, held = 2, 4
     rng = np.random.default_rng(held_rows)
     # `held_rows` of the 24 assignments on experts 2..5, the rest elsewhere;
     # the first tokens hold both of theirs
@@ -284,22 +306,11 @@ def test_the_gated_loops_follow_the_held_rows_at_every_load(monkeypatch,
     idx[held_rows:] = np.where(np.arange(TOKENS * SLOTS - held_rows) % 2,
                                0, 7)
     idx = jnp.asarray(idx.reshape(TOKENS, SLOTS), jnp.int32)
-    x = jnp.asarray(rng.normal(size=(TOKENS, d)), jnp.float32)
-    wt = jnp.asarray(0.2 + rng.random((TOKENS, SLOTS)), jnp.float32)
-    w1, w3 = (jnp.asarray(0.1 * rng.normal(size=(held, d, f)), jnp.float32)
-              for _ in range(2))
-    w2 = jnp.asarray(0.1 * rng.normal(size=(held, f, d)), jnp.float32)
-
-    def loss(fn):
-        def f_(x, wt, w1, w3, w2):
-            out, counts = fn(x, idx, wt, w1, w3, w2, first)
-            return jnp.sum(jnp.sin(out)), (out, counts)
-        with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(f_, (0, 1, 2, 3, 4), has_aux=True)(
-                x, wt, w1, w3, w2)
-
-    (got, (out, counts)), got_g = loss(hybrid_ops.gated_experts_sum)
-    (want, (ref_out, _)), want_g = loss(_gated_by_the_book)
+    operands = x, wt, w1, w3, w2 = _gated_operands(rng, TOKENS, SLOTS, held)
+    (got, (out, counts)), got_g = _gated_loss(
+        hybrid_ops.gated_experts_sum, idx, first, operands)
+    (want, (ref_out, _)), want_g = _gated_loss(
+        _gated_by_the_book, idx, first, operands)
     close(got, want)
     close(out, ref_out)
     for a, e in zip(got_g, want_g):
@@ -311,6 +322,140 @@ def test_the_gated_loops_follow_the_held_rows_at_every_load(monkeypatch,
                                     w1, w3, w2, first)[0] for j in range(2)]
         close(out[0], (alone[0] + alone[1])[0])
         assert float(jnp.abs(alone[1]).max()) > 1e-4
+
+
+# what the sum back into the tokens must get right one token at a time, 8
+# sorted rows a chunk (two tokens a trip): tokens, k, experts, first, held
+SUM_BACK_CASES = {
+    "every_choice_held_beside_none_held": (12, 4, 8, 2, 4),
+    "ten_choices_with_dead_rows": (9, 10, 32, 8, 8),     # Laguna's, in small
+    "tokens_no_multiple_of_the_chunk": (11, 2, 8, 2, 4),
+    "three_choices_over_odd_tokens": (13, 3, 8, 2, 4),
+    "bfloat16_rows": (16, 2, 8, 2, 4),
+}
+
+
+def _batch(case):
+    """(operands, idx, first, live) of a named case: distinct experts a
+    token, drawn evenly, but token 1's, which are all held elsewhere."""
+    tokens, k, experts, first, held = SUM_BACK_CASES[case]
+    rng = np.random.default_rng(tokens)
+    idx = np.argsort(rng.random((tokens, experts)), axis=1)[:, :k]
+    idx[1] = np.delete(np.arange(experts), first + np.arange(held))[:k]
+    live = None
+    if case.startswith("every"):
+        idx[0] = first + np.arange(k)
+    elif case.startswith("ten"):
+        live = np.asarray([1, 0, 1, 1, 0, 1, 1, 1, 0], bool)
+    dtype = jnp.bfloat16 if case.startswith("bfloat16") else jnp.float32
+    return (_gated_operands(rng, tokens, k, held, dtype),
+            jnp.asarray(idx, jnp.int32), first, live)
+
+
+@pytest.mark.parametrize("case", list(SUM_BACK_CASES))
+def test_the_sum_back_reads_each_tokens_rows_through_the_sorts_inverse(
+        monkeypatch, case):
+    """`gated_experts_sum`'s way back into the tokens is a read a choice
+    through the inverse of the sort, a chunk of tokens a trip: output, the
+    five gradients and the counts against the unsorted sum where a token
+    holds all of its choices beside one that holds none, with ten choices
+    and dead rows (`Live`, as the op masks them), with token counts that
+    the chunk of tokens does not divide, and with bfloat16 rows against
+    the float32 sum within bfloat16's rounding."""
+    monkeypatch.setattr(hybrid_ops, "GATED_CHUNK_ROWS", CHUNK)
+    operands, idx, first, live = _batch(case)
+    x, wt, w1, w3, w2 = operands
+    tokens, k = idx.shape
+    if "tokens" in case:        # two tokens a trip at a chunk of 8
+        assert tokens % 2
+    masked = idx if live is None else jnp.where(live[:, None], idx, -1)
+    (_, (out, counts)), got_g = _gated_loss(
+        hybrid_ops.gated_experts_sum, masked, first, operands)
+    (_, (ref_out, _)), want_g = _gated_loss(
+        _gated_by_the_book, masked, first,
+        [v.astype(jnp.float32) for v in operands])
+    # bfloat16 keeps 8 bits: three roundings on the way (the two products,
+    # the gate, the experts' outputs) and one of each gradient
+    tol = 2e-5 if x.dtype == jnp.float32 else 3e-2
+    assert out.dtype == jnp.float32
+    close(out, ref_out, tol)
+    for a, e in zip(got_g, want_g):
+        close(a, e, tol)
+    here = np.asarray((masked >= first) & (masked < first + w1.shape[0]))
+    assert int(counts[0]) == here.sum()
+    assert int(counts[3]) == -(-here.sum() // CHUNK) * CHUNK
+    none = ~here.any(1)
+    assert none.any() and not np.asarray(out)[none].any()
+    assert not np.asarray(got_g[0], np.float32)[none].any()
+    if case.startswith("every"):
+        assert here[0].all() and none[1]
+        alone = [_gated_by_the_book(x[:1], idx[:1, j:j + 1], wt[:1, j:j + 1],
+                                    w1, w3, w2, first)[0] for j in range(k)]
+        close(out[0], sum(alone)[0])
+    if live is not None:        # the op's own mask does the same
+        op = lower("held_experts_ffn", {
+            "X": [x], "Index": [idx], "Weight": [wt], "W1": [w1],
+            "W3": [w3], "W2": [w2],
+            "Live": [jnp.asarray(live[:, None], jnp.float32)]},
+            {"first_expert": first})
+        close(op["Out"][0], out)
+        assert none[~live].all() and op["Counts"][0].tolist() == \
+            counts.tolist()
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["forward_weighted", "backward_unweighted"])
+def test_undefined_rows_past_the_held_ones_never_reach_a_token(weighted):
+    """The grouped kernels leave the sorted rows past the n held ones
+    undefined. `_sum_into_tokens` reads a row for every choice, held or
+    not, and SELECTS: with those rows all NaN (times a zero weight they
+    would stay NaN) the sums are finite and the held rows' own."""
+    rng = np.random.default_rng(int(weighted))
+    tokens, k, d, n = 10, 3, 16, 11
+    here = np.zeros((tokens * k,), bool)
+    here[rng.permutation(tokens * k)[:n]] = True
+    order = np.argsort(~here, kind="stable")       # the held rows first
+    inv = np.argsort(order).reshape(tokens, k)
+    rows = rng.normal(size=(tokens * k, d)).astype(np.float32)
+    rows[n:] = np.nan
+    here = here.reshape(tokens, k)
+    wt = np.where(here, rng.random((tokens, k)), 0.0).astype(np.float32)
+    got = hybrid_ops._sum_into_tokens(
+        jnp.asarray(rows), jnp.asarray(inv, jnp.int32), jnp.asarray(here),
+        jnp.asarray(wt) if weighted else None)
+    want = np.zeros((tokens, d), np.float32)
+    for t_, j in zip(*np.nonzero(here)):
+        want[t_] += rows[inv[t_, j]] * (wt[t_, j] if weighted else 1.0)
+    assert np.isfinite(np.asarray(got)).all()
+    close(got, want)
+
+
+def test_poisoned_products_leave_output_and_gradients_finite(monkeypatch):
+    """The whole function with every grouped product's rows past the held
+    ones set to NaN, forward and backward: what it returns is finite and
+    what it returned without the poison."""
+    monkeypatch.setattr(hybrid_ops, "GATED_CHUNK_ROWS", CHUNK)
+    operands, idx, first, _ = _batch("tokens_no_multiple_of_the_chunk")
+
+    def grads():
+        return _gated_loss(hybrid_ops.gated_experts_sum, idx, first,
+                           operands)
+
+    (_, (clean, _)), clean_g = grads()
+    plain = hybrid_ops.grouped_dot
+
+    def poisoned(xs, w, sizes, platform=None, out_dtype=jnp.float32):
+        held = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
+        out = plain(jnp.where(held, xs, 0.0), w, sizes, platform, out_dtype)
+        return jnp.where(held, out, jnp.nan)
+
+    monkeypatch.setattr(hybrid_ops, "grouped_dot", poisoned)
+    (_, (out, _)), got_g = grads()
+    assert np.isfinite(np.asarray(out)).all()
+    close(out, clean)
+    for a, e in zip(got_g, clean_g):
+        assert np.isfinite(np.asarray(a)).all()
+        close(a, e)
 
 
 def test_four_shares_of_two_experts_add_up_to_the_uncut_layer():
