@@ -63,15 +63,29 @@ hot paths:
   ``prefill_sync_seconds`` (a part of it), ``dispatch_seconds``,
   ``emit_seconds`` (``emit`` and ``decide``), ``release_seconds``,
   ``sync_seconds``, ``idle_seconds`` — the seven phases sum to the
-  thread's wall time. ``steps_ahead`` in ``stats()`` (counter
-  ``serving.decode.steps_ahead``) counts the steps dispatched while the
-  step before's tokens were still undelivered.
+  thread's wall time; ``loop_cpu_seconds`` beside them is that thread's
+  CPU (``time.thread_time()`` once a loop turn: what of the wall time it
+  was running) and ``process_cpu_seconds`` every thread's
+  (``time.process_time()`` at the call). ``steps_ahead`` in ``stats()``
+  (counter ``serving.decode.steps_ahead``) counts the steps dispatched
+  while the step before's tokens were still undelivered.
 - per request, sharing ``request=<DecodeStream.id>``: ``http.generate``
   (first body byte to terminating chunk; ``status``, ``tokens``,
-  ``first_byte_s``), ``decode.queue`` (submit to the start of its
-  prefill), ``decode.prefill`` (``slot``, ``bucket``, ``plen``,
-  ``path``), ``decode.stream`` (first emit to retire; ``tokens``,
-  ``reason``). No per-token span.
+  ``first_byte_s``, and what the socket cost the stream: ``write_s``,
+  the wall time of every chunk's write + flush, over ``chunks``),
+  ``decode.queue`` (submit to the start of its prefill),
+  ``decode.prefill`` (``slot``, ``bucket``, ``plen``, ``path``),
+  ``decode.stream`` (first emit to retire; ``tokens``, ``reason``), and
+  ``decode.stream.read``, the READER's side of the stream, recorded by
+  ``DecodeStream.tokens()`` on the thread that ran it when the
+  generator ends (first ``get`` to the end; sums over the stream:
+  ``wake_s``, a ready item waiting for its waiting reader to run, with
+  ``wake_max_s`` at ``wake_max_index``; ``consume_s``, what the consumer
+  did with each token; ``cpu_s``, the thread's CPU; ``tokens``;
+  ``end`` = ``done`` / ``err`` / ``timeout`` / ``closed``). No
+  per-token span. A reader that gives up on a stalled stream first
+  records a ``stream_stall`` event (source ``serving``: the request,
+  its tokens so far, ``active_spans()`` of every thread).
 
 Well-known executor fast-path metrics (PR 4):
 

@@ -270,7 +270,11 @@ class DecodeStream:
         self.stall_timeout_s = float(stall_timeout_s)
         self.finish_reason = None
         self.t_submit = time.monotonic()
-        self._q = queue.Queue()
+        # one producer (the dispatch thread), one consumer, unbounded:
+        # SimpleQueue's put and get are C and wake the reader straight
+        # into tokens(); queue.Queue's bounded-queue bookkeeping
+        # (not_full, unfinished_tasks) nobody read
+        self._q = queue.SimpleQueue()
         self._tokens = []
         self._done = threading.Event()
         self._cancelled = threading.Event()
@@ -293,21 +297,76 @@ class DecodeStream:
         """Generator yielding token ids as the engine produces them.
         ``timeout`` bounds the wait for EACH token (default: the
         engine's request timeout); a stalled engine raises
-        ``TimeoutError``, a failed request raises its error."""
+        ``TimeoutError``, a failed request raises its error.
+
+        However it ends, the generator records one ``decode.stream.read``
+        span from the thread that ran it (the reader's side of the
+        stream, where ``decode.stream`` is the engine's): ``t0`` its
+        first ``get``, ``t1`` its end, and as fields sums over the items
+        it took (the tokens and the one end): ``wake_s``, from the later
+        of an item's put and the reader's ``get`` to the ``get``'s
+        return (the item was ready AND its reader was waiting, and the
+        reader still did not run: scheduling and the GIL), its largest
+        term ``wake_max_s`` at item ``wake_max_index``; ``consume_s``,
+        from a token's yield to the consumer's next ``next()`` (or its
+        ``close()``); ``cpu_s``, this thread's CPU over the span;
+        ``tokens``; ``end`` (``done`` / ``err`` / ``timeout`` /
+        ``closed``, the consumer left). ``wake_s + consume_s`` + the
+        reader's waits on an empty queue = ``t1 - t0``. With telemetry
+        off no clock is read here and nothing is recorded."""
         wait = self.stall_timeout_s if timeout is None else float(timeout)
-        while True:
-            try:
-                kind, val = self._q.get(timeout=wait)
-            except queue.Empty:
-                raise TimeoutError(
-                    "no token for %.1fs (generated %d so far)"
-                    % (wait, len(self._tokens)))
-            if kind == "tok":
-                yield val
-            elif kind == "err":
-                raise val
-            else:  # done
-                return
+        timed = obs.enabled()
+        clock = time.monotonic
+        n, end = 0, "closed"
+        wake = wake_max = consume = 0.0
+        wake_at = None
+        if timed:
+            cpu0 = time.thread_time()
+            t0 = t_wait = t_got = clock()
+        try:
+            while True:
+                try:
+                    kind, val, t_put = self._q.get(timeout=wait)
+                except queue.Empty:
+                    end = "timeout"
+                    # what every thread was inside when the reader gave
+                    # up: the engine's phase, or no engine span at all
+                    obs.event("stream_stall", source="serving",
+                              request=self.id, tokens=len(self._tokens),
+                              waited_s=wait, active=obs.active_spans())
+                    raise TimeoutError(
+                        "no token for %.1fs (generated %d so far)"
+                        % (wait, len(self._tokens)))
+                if timed:
+                    t_got = clock()
+                    w = t_got - (t_put if t_put > t_wait else t_wait)
+                    wake += w
+                    if w > wake_max:
+                        wake_max, wake_at = w, n
+                if kind == "tok":
+                    n += 1
+                    yield val
+                    if timed:
+                        t_wait = clock()
+                        consume += t_wait - t_got
+                elif kind == "err":
+                    end = "err"
+                    raise val
+                else:  # done
+                    end = "done"
+                    return
+        finally:
+            if timed:
+                t1 = clock()
+                if end == "closed":
+                    # left at a yield: the consumer held the last token
+                    # until it closed the generator
+                    consume += t1 - t_got
+                obs.record_span(
+                    "decode.stream.read", t0, t1, request=self.id,
+                    tokens=n, end=end, wake_s=wake, wake_max_s=wake_max,
+                    wake_max_index=wake_at, consume_s=consume,
+                    cpu_s=time.thread_time() - cpu0)
 
     def result(self, timeout=None):
         """Block until generation finishes; returns the full token
@@ -327,18 +386,20 @@ class DecodeStream:
     # -- engine surface --------------------------------------------------
     def _emit(self, tok):
         self._tokens.append(tok)
-        self._q.put(("tok", tok))
+        # beside each item the time of its put: the reader's side of
+        # ``decode.stream.read`` (see tokens())
+        self._q.put(("tok", tok, time.monotonic()))
 
     def _finish(self, reason):
         self.finish_reason = reason
         self._done.set()
-        self._q.put(("done", reason))
+        self._q.put(("done", reason, time.monotonic()))
 
     def _fail(self, exc):
         self._error = exc
         self.finish_reason = "error"
         self._done.set()
-        self._q.put(("err", exc))
+        self._q.put(("err", exc, time.monotonic()))
 
 
 class _Request:
@@ -600,11 +661,14 @@ class DecodeEngine:
         # the exits of its phase spans (one writer; stats() copies).
         # admit + prefill_total + dispatch + emit + release + sync + idle
         # is the loop's wall time; prefill_sync is the part of
-        # prefill_total spent waiting for the device
+        # prefill_total spent waiting for the device. loop_cpu is the
+        # thread's CPU (time.thread_time(), read once a loop turn): what
+        # of that wall time it was RUNNING
         self._phase_s = dict.fromkeys(
             ("admit_seconds", "prefill_seconds_total",
              "prefill_sync_seconds", "dispatch_seconds", "sync_seconds",
-             "emit_seconds", "release_seconds", "idle_seconds"), 0.0)
+             "emit_seconds", "release_seconds", "idle_seconds",
+             "loop_cpu_seconds"), 0.0)
         self._proc = "decode:%s" % self.name  # track of its trace spans
         self._rate = collections.deque(maxlen=64)  # (t_done, 1) retires
         self._thread = None
@@ -1095,7 +1159,11 @@ class DecodeEngine:
         or returns, flushes the outbox first: a stream sees its tokens in
         order, then exactly one end."""
         phase = self._phase_s
+        cpu = time.thread_time()
         while True:
+            now = time.thread_time()
+            phase["loop_cpu_seconds"] += now - cpu
+            cpu = now
             filled = phase["prefill_seconds_total"]
             with obs.span("decode.loop.admit") as sp:
                 self._sweep_cancelled()
@@ -1934,10 +2002,17 @@ class DecodeEngine:
         waits for the GIL behind the streams it just woke, while the
         device runs step n+1), ``sync_seconds`` (the wait for step n+1's
         tokens), ``idle_seconds`` — the seven sum to the thread's wall
-        time."""
+        time; ``loop_cpu_seconds``, that thread's CPU time up to its last
+        loop turn (what of the seven it was running, the rest it waited:
+        for the device, the GIL, a request), and
+        ``process_cpu_seconds``, every thread's (``time.process_time()``
+        at this call): less the loop's and the stream readers'
+        (``decode.stream.read``'s ``cpu_s``) it is whatever else shares
+        the process and its GIL."""
         with self._stats_lock:
             out = dict(self._stats)
         out.update(self._phase_s)
+        out["process_cpu_seconds"] = time.process_time()
         for k in ("requests", "tokens", "prefills", "adopts", "steps",
                   "retired", "shed", "deadline_miss", "cancelled",
                   "prefill_errors", "adopt_errors", "step_errors",

@@ -181,12 +181,17 @@ class ServingHandler(BaseHTTPRequestHandler):
             self._send_json(404, {"error": "not found: %s" % self.path})
 
     # -- decode streaming (:generate) -----------------------------------
-    def _chunk(self, doc):
+    def _chunk(self, doc, wrote):
         """One chunked-transfer frame holding a JSON line, flushed so
-        the client sees each token as the step loop emits it."""
+        the client sees each token as the step loop emits it. Adds to
+        ``wrote`` (``[seconds, chunks]``) what the write and the flush
+        took: the system call and the wait for the GIL after it."""
         data = (json.dumps(doc) + "\n").encode("utf-8")
+        t = time.monotonic()
         self.wfile.write(b"%X\r\n" % len(data) + data + b"\r\n")
         self.wfile.flush()
+        wrote[0] += time.monotonic() - t
+        wrote[1] += 1
 
     def _generate_errdoc(self, exc, name, engine):
         """(status, doc, headers) for a pre-stream generate failure.
@@ -329,21 +334,22 @@ class ServingHandler(BaseHTTPRequestHandler):
         self.send_header("Content-Type", "application/jsonl")
         self.send_header("Transfer-Encoding", "chunked")
         self.end_headers()
+        wrote = [0.0, 0]    # seconds in write + flush, chunks written
         try:
             try:
                 if first is not None:
-                    self._chunk({"token": first, "index": 0})
+                    self._chunk({"token": first, "index": 0}, wrote)
                     # the first token is on the wire
                     sp.note(first_byte_s=sp.elapsed())
                     for i, tok in enumerate(gen, start=1):
-                        self._chunk({"token": tok, "index": i})
+                        self._chunk({"token": tok, "index": i}, wrote)
                 toks = handle.so_far()
                 done = {"done": True,
                         "finish_reason": handle.finish_reason,
                         "tokens": toks, "n_tokens": len(toks)}
                 if tctx is not None:
                     done["trace_id"] = tctx.trace_id
-                self._chunk(done)
+                self._chunk(done, wrote)
             except (BrokenPipeError, ConnectionResetError):
                 # client went away: free the slot at the next dispatch
                 # iteration instead of decoding to nobody
@@ -354,12 +360,16 @@ class ServingHandler(BaseHTTPRequestHandler):
                 return 200
             except Exception as e:  # noqa: BLE001 — mid-stream engine error
                 self._chunk({"error": "%s: %s" % (type(e).__name__, e),
-                             "done": True, "finish_reason": "error"})
+                             "done": True, "finish_reason": "error"},
+                            wrote)
                 return 200
         finally:
             if not handle.done:
                 handle.cancel()
-            sp.note(tokens=len(handle.so_far()))
+            # what the socket cost the stream: decode.stream.read's
+            # consume_s less write_s is the encoding and the loop
+            sp.note(tokens=len(handle.so_far()), write_s=wrote[0],
+                    chunks=wrote[1])
             try:
                 self.wfile.write(b"0\r\n\r\n")
                 self.wfile.flush()

@@ -67,11 +67,12 @@ def prompt(n, vocab=97, seed=3):
 
 
 def drain(handle):
-    """What the stream was handed, in order: [(kind, value), ...]."""
+    """What the stream was handed, in order: [(kind, value), ...] (the
+    time of each put, which rides beside them, dropped)."""
     got = []
     while True:
         try:
-            got.append(handle._q.get_nowait())
+            got.append(handle._q.get_nowait()[:2])
         except queue.Empty:
             return got
 

@@ -16,7 +16,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import observability as obs
 from paddle_tpu.models import gpt
 from paddle_tpu.observability import tracing
-from paddle_tpu.serving import DecodeEngine, ModelRegistry, ServingServer
+from paddle_tpu.serving import (DecodeEngine, DecodeStream, ModelRegistry,
+                                ServingServer)
 
 PHASES = ("admit_seconds", "prefill_seconds_total", "dispatch_seconds",
           "sync_seconds", "emit_seconds", "release_seconds", "idle_seconds")
@@ -370,6 +371,212 @@ def test_generate_over_http_has_one_span_with_the_engines_id(tiny):
     assert f["first_byte_s"] >= engine_ttft - (prefill["t1"] - stream["t0"])
     assert refused["fields"]["status"] == 400
     assert "request" not in refused["fields"]
+
+
+# -- stream delivery: the reader's side of a stream (ISSUE 37) ----------------
+
+def hand_fed(tokens=(), end=None, **kw):
+    """A stream fed by hand, as the engine thread feeds one."""
+    st = DecodeStream(4, 64, **kw)
+    for tok in tokens:
+        st._emit(tok)
+    if end == "done":
+        st._finish("length")
+    elif end == "err":
+        st._fail(RuntimeError("step failed"))
+    return st
+
+
+def the_read(request):
+    (s,) = [s for s in obs.spans("decode.stream.read")
+            if s["fields"]["request"] == request]
+    return s, s["fields"]
+
+
+def test_a_finished_streams_read_span_is_its_readers_account(tiny):
+    eng = make_engine(tiny, auto_start=True)
+    try:
+        h = eng.submit(prompt(5), max_new=9)
+        got = list(h.tokens(timeout=60))
+    finally:
+        eng.stop()
+    s, f = the_read(h.id)
+    wall = s["t1"] - s["t0"]
+    assert got == h.so_far() and f["tokens"] == len(got) == 9
+    assert f["end"] == "done"
+    assert s["thread"] == threading.current_thread().name
+    assert h.t_submit <= s["t0"] < s["t1"]
+    assert 0 <= f["wake_max_s"] <= f["wake_s"]
+    assert 0 <= f["wake_max_index"] <= 9    # the end is item 9
+    assert f["consume_s"] >= 0
+    assert f["wake_s"] + f["consume_s"] <= wall
+    assert 0 <= f["cpu_s"] <= wall
+    # the same id as the engine's side of the stream
+    (stream,) = obs.spans("decode.stream")
+    assert stream["fields"]["request"] == h.id
+
+
+def test_a_slow_consumer_is_in_consume_s_and_the_three_parts_are_the_whole():
+    """Every item is there before the reader starts, so it never waits on
+    an empty queue: what is left of `t1 - t0` is wake + consume."""
+    st = hand_fed(range(10), "done")
+    for _ in st.tokens(timeout=5):
+        time.sleep(0.01)
+    s, f = the_read(st.id)
+    wall = s["t1"] - s["t0"]
+    assert f["tokens"] == 10 and f["end"] == "done"
+    assert f["consume_s"] >= 10 * 0.01
+    assert f["wake_s"] < 0.1 * f["consume_s"]
+    assert f["wake_s"] + f["consume_s"] == pytest.approx(wall, rel=0.01)
+
+
+def test_waiting_on_an_empty_queue_is_neither_wake_nor_consume():
+    st = hand_fed()
+
+    def feed():
+        for tok in range(5):
+            time.sleep(0.03)
+            st._emit(tok)
+        st._finish("length")
+
+    t = threading.Thread(target=feed)
+    t.start()
+    assert list(st.tokens(timeout=5)) == list(range(5))
+    t.join(10)
+    assert not t.is_alive()
+    s, f = the_read(st.id)
+    wall = s["t1"] - s["t0"]
+    assert wall >= 5 * 0.03
+    assert f["wake_s"] + f["consume_s"] <= wall - 5 * 0.03 * 0.5
+
+
+@pytest.mark.parametrize("end, yielded", [
+    ("done", 3), ("err", 3), ("timeout", 3), ("closed", 2)])
+def test_the_read_span_says_how_the_stream_ended(end, yielded):
+    st = hand_fed(range(3), end if end in ("done", "err") else None)
+    gen = st.tokens(timeout=0.05)
+    got = []
+    if end == "closed":
+        got = [next(gen), next(gen)]
+        time.sleep(0.02)
+        gen.close()                      # the consumer leaves
+    elif end == "done":
+        got = list(gen)
+    else:
+        with pytest.raises(RuntimeError if end == "err" else TimeoutError):
+            for tok in gen:
+                got.append(tok)
+    s, f = the_read(st.id)
+    assert len(got) == yielded == f["tokens"] and f["end"] == end
+    wall = s["t1"] - s["t0"]
+    assert f["wake_s"] + f["consume_s"] <= wall
+    if end == "closed":                  # it held the last token till then
+        assert f["consume_s"] >= 0.02
+        assert f["wake_s"] + f["consume_s"] == pytest.approx(wall, rel=0.01)
+    if end == "timeout":                 # the wait that gave up is neither
+        assert wall >= 0.05 > f["wake_s"] + f["consume_s"]
+    assert len(obs.spans("decode.stream.read")) == 1
+
+
+def test_a_stalled_stream_leaves_a_flight_recorder_event_with_every_threads_spans():
+    st = hand_fed([7])
+    inside, leave = threading.Event(), threading.Event()
+
+    def engine_thread():
+        with obs.span("decode.step.sync"):
+            inside.set()
+            leave.wait(10)
+
+    t = threading.Thread(target=engine_thread, name="decode-stalled")
+    t.start()
+    try:
+        assert inside.wait(10)
+        with pytest.raises(TimeoutError, match="generated 1 so far"):
+            list(st.tokens(timeout=0.05))
+    finally:
+        leave.set()
+        t.join(10)
+    (ev,) = obs.get_recorder().of("stream_stall")
+    assert ev["request"] == st.id and ev["tokens"] == 1
+    assert ev["source"] == "serving" and ev["waited_s"] == 0.05
+    (frame,) = ev["active"]["decode-stalled"]
+    assert frame[0] == "decode.step.sync" and frame[1] >= 0
+    assert obs.counter("serving.stream_stall") == 1
+
+
+def test_off_mode_streams_the_same_tokens_and_reads_no_clock(monkeypatch):
+    want = list(hand_fed(range(6), "done").tokens(timeout=5))
+    obs.reset()
+    monkeypatch.setenv(obs.TELEMETRY_ENV, "off")
+    st = hand_fed(range(6), "done")
+    clock, me, reads = time.monotonic, threading.current_thread(), []
+
+    def counted():
+        if threading.current_thread() is me:
+            reads.append(1)
+        return clock()
+
+    monkeypatch.setattr(time, "monotonic", counted)
+    got = list(st.tokens(timeout=5))
+    n_reads = len(reads)
+    with pytest.raises(TimeoutError):
+        list(hand_fed().tokens(timeout=0.01))
+    monkeypatch.undo()
+    assert got == want == list(range(6))
+    assert n_reads == 0
+    assert obs.spans() == [] and obs.get_recorder().of("stream_stall") == []
+
+
+def test_http_generate_notes_what_the_socket_cost(tiny):
+    eng = make_engine(tiny, auto_start=True)
+    reg = ModelRegistry()
+    reg.publish("gpt", eng)
+    srv = ServingServer(reg).start()
+    try:
+        body = json.dumps({"prompt": prompt(6).tolist(),
+                           "max_new_tokens": 7}).encode()
+        with urllib.request.urlopen(urllib.request.Request(
+                srv.url + "/v1/models/gpt:generate", data=body),
+                timeout=60) as r:
+            lines = [json.loads(ln) for ln in r.read().splitlines()]
+        time.sleep(0.05)
+    finally:
+        srv.stop()
+        eng.stop()
+    assert lines[-1]["n_tokens"] == 7
+    (http,) = obs.spans("http.generate")
+    (read,) = obs.spans("decode.stream.read")
+    f, r = http["fields"], read["fields"]
+    assert f["chunks"] == f["tokens"] + 1 == 8
+    assert 0 < f["write_s"] <= http["t1"] - http["t0"]
+    # the handler's thread read the stream, under the request's one id
+    assert r["request"] == f["request"] and read["thread"] == http["thread"]
+    assert r["tokens"] == 7 and r["end"] == "done"
+    assert http["t0"] <= read["t0"] and read["t1"] <= http["t1"]
+    # the writes of the seven tokens lie inside what the consumer did
+    # with them (the closing chunk's does not: the stream has ended)
+    assert r["consume_s"] > 0 and f["write_s"] > 0
+
+
+def test_stats_has_the_loop_threads_and_the_processs_cpu_both_growing(tiny):
+    eng = make_engine(tiny, auto_start=True)
+    try:
+        eng.submit(prompt(4), max_new=3).result(60)
+        before = eng.stats()
+        t_a = time.monotonic()
+        for h in [eng.submit(prompt(3 + i), max_new=20) for i in range(4)]:
+            h.result(60)
+        wall = time.monotonic() - t_a
+        after = eng.stats()
+    finally:
+        eng.stop()
+    loop = after["loop_cpu_seconds"] - before["loop_cpu_seconds"]
+    proc = after["process_cpu_seconds"] - before["process_cpu_seconds"]
+    assert 0 < loop <= wall * 1.05
+    assert 0 < proc and loop <= proc * 1.05
+    # the thread runs for less than its seven phases' wall time
+    phases = sum(after[k] - before[k] for k in PHASES)
+    assert loop <= phases * 1.05
 
 
 # -- names a device trace is read by ------------------------------------------
