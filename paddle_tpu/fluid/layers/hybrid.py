@@ -158,9 +158,14 @@ def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None):
     """Softmax attention of ``heads`` query heads over ``kv_heads``
     key/value heads, no position term of its own. Causal over (B, T, .)
     inputs, with ``window`` each query sees the last ``window`` positions
-    only (banded blocks: neither (T, T) scores nor a full causal call's
-    cost); or with ``pos`` (B, 1) over a slot cache of which row b sees
-    columns <= pos[b], be it a sequence's rows or a window layer's ring.
+    only (a block of ``window`` queries against its own keys and the block
+    before: neither (T, T) scores nor a full causal call's cost; on an
+    unsharded TPU program, with a head size and a window that are
+    multiples of 128, one Pallas kernel whose scores stay on the chip,
+    everywhere else the same blocks through XLA: the op chooses,
+    ``ops.hybrid_ops._gqa_attention``); or with ``pos`` (B, 1) over a slot
+    cache of which row b sees columns <= pos[b], be it a sequence's rows or
+    a window layer's ring.
     A plain causal call of 1,024 positions or more runs as the Pallas flash
     kernels on an unsharded TPU program (``ops.hybrid_ops.FLASH_MIN_SEQ``),
     so no (T, T) scores are held for the backward pass."""

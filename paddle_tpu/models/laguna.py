@@ -249,7 +249,10 @@ def build_prefill(cfg, prompt_len, cache_len):
     ``(B, cache_len, kv width)``, zero past ``len``; per window layer K and
     V ``(B, window, kv width)``, the last ``window`` real positions' rows at
     ``position mod window``. A window layer's scores are banded: neither a
-    (T, T) array nor a full causal call's cost. ``moe_routed`` names, per
+    (T, T) array nor a full causal call's cost (on the chip one kernel a
+    layer, ``window_attn_fwd``, the scores never in HBM; on the CPU or
+    under a mesh the banded blocks through XLA: ``gqa_attention`` chooses
+    from the call it sees). ``moe_routed`` names, per
     sparse layer, the held experts' part ``(B * prompt_len, hidden)``;
     ``attn_in`` / ``attn_out``, per layer, the stream before the layer and
     what its attention block adds to it ``(B, prompt_len, hidden)``: for

@@ -278,7 +278,10 @@ def _banded_gqa(q, k, v, nh, nkv, window):
     without (T, T) scores and at the window's cost: the queries in blocks
     of ``window`` rows, each against its own block of keys and the one
     before it (2 x window columns, masked to the band), one block a trip
-    of a loop, so the scores alive are (heads, window, 2 x window)."""
+    of a loop, so the scores alive are (heads, window, 2 x window). The
+    plain path: float32 scores through HBM, what the CPU, a sharded
+    program and a window or head that does not tile take; the Pallas
+    kernel's comparison and its gradient (:func:`_window_gqa`)."""
     b, t, _ = q.shape
     dh = q.shape[-1] // nh
     pad = (-t) % window
@@ -312,6 +315,26 @@ def _banded_gqa(q, k, v, nh, nkv, window):
     return jnp.moveaxis(out, 0, 1).reshape(b, t + pad, nh * dh)[:, :t]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _window_gqa(q, k, v, nh, nkv, window, interpret=False):
+    """:func:`_banded_gqa`'s result from the Pallas kernel for the band
+    (``ops/pallas_attention.py`` ``window_attention``): one call, the
+    op's own layout, the scores never in HBM. Forward only; its gradient
+    is the banded blocks' own."""
+    from .pallas_attention import window_attention
+
+    return window_attention(q, k, v, nh, nkv, window, interpret=interpret)
+
+
+def _window_gqa_bwd(nh, nkv, window, interpret, res, g):
+    return jax.vjp(lambda q, k, v: _banded_gqa(q, k, v, nh, nkv, window),
+                   *res)[1](g)
+
+
+_window_gqa.defvjp(lambda q, k, v, *a: (_window_gqa(q, k, v, *a), (q, k, v)),
+                   _window_gqa_bwd)
+
+
 @register_op("gqa_attention")
 def _gqa_attention(ctx, ins, attrs):
     """Softmax attention with fewer key/value heads than query heads; no
@@ -321,12 +344,19 @@ def _gqa_attention(ctx, ins, attrs):
     last Tk positions written at ``position mod Tk`` (every column of it
     once ``pos >= Tk - 1``; a softmax does not mind the order). Without
     ``Pos``, Tq == Tk and the mask is causal, with ``window`` > 0 cut to
-    the last ``window`` positions (:func:`_banded_gqa` where the sequence
-    is longer than the window). A plain causal call of at least
-    FLASH_MIN_SEQ positions runs through the flash kernels on the TPU (a
-    training sequence of 4,096 would otherwise hold (B, heads, T, T)
-    float32 scores); shorter calls, other platforms and a sharded program
-    take the products below."""
+    the last ``window`` positions. The op chooses from what it sees, no
+    caller sets anything. A sequence longer than its window, on the TPU,
+    in an unsharded program, with a head size and a window that tile
+    (multiples of 128): the Pallas kernel for the band
+    (:func:`_window_gqa`); any other sequence longer than its window
+    (the CPU, a mesh, small windows): the banded blocks through XLA
+    (:func:`_banded_gqa`), which are also the kernel's gradient. A plain
+    causal call of at least FLASH_MIN_SEQ positions runs through the
+    flash kernels on the TPU (a training sequence of 4,096 would
+    otherwise hold (B, heads, T, T) float32 scores); shorter calls, other
+    platforms and a sharded program take the products below. Which of the
+    two window paths a lowering took is counted:
+    ``ops.gqa_attention.window_kernel`` / ``.window_banded``."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     nh, nkv = int(attrs["heads"]), int(attrs["kv_heads"])
     window = int(attrs.get("window") or 0)
@@ -336,11 +366,17 @@ def _gqa_attention(ctx, ins, attrs):
     if ins.get("Pos") and window:
         raise ValueError("gqa_attention over a slot cache takes no window: "
                          "a window layer's cache is a ring of that length")
+    unsharded_tpu = (getattr(ctx, "platform", None) == "tpu"
+                     and not getattr(ctx, "mesh_axes", None))
     if window and tq > window:
+        from .. import observability as obs
+
+        if unsharded_tpu and dh % 128 == 0 and window % 128 == 0:
+            obs.inc("ops.gqa_attention.window_kernel")
+            return single(_window_gqa(q, k, v, nh, nkv, window))
+        obs.inc("ops.gqa_attention.window_banded")
         return single(_banded_gqa(q, k, v, nh, nkv, window))
-    if (not ins.get("Pos") and tq >= FLASH_MIN_SEQ
-            and getattr(ctx, "platform", None) == "tpu"
-            and not getattr(ctx, "mesh_axes", None)):
+    if not ins.get("Pos") and tq >= FLASH_MIN_SEQ and unsharded_tpu:
         return single(_flash_gqa(q, k, v, nh, nkv))
     qg = q.reshape(b, tq, nkv, nh // nkv, dh)
     kg = k.reshape(b, tk, nkv, dh)

@@ -34,7 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "reference_attention"]
+__all__ = ["flash_attention", "reference_attention", "window_attention"]
 
 _NEG_INF = -1e30
 
@@ -590,6 +590,85 @@ def reference_attention(q, k, v, key_padding_mask=None, sm_scale=None,
         keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_p, p.shape)
         p = jnp.where(keep, p, 0.0) / (1.0 - dropout_p)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+# ---------------------------------------------------------------------------
+# causal attention over a band (a window layer's prefill), forward only
+# ---------------------------------------------------------------------------
+def _window_kernel(q_ref, kp_ref, k_ref, vp_ref, v_ref, o_ref, *, sm_scale):
+    """One (block of ``window`` queries, query head): the block's rows
+    against the block of keys before it and its own, 2 x window columns. A
+    row's whole band is here at once, so the softmax is one pass with no
+    running maximum; query i sees column j of the block before where
+    ``j > i`` (i - (j - window) < window) and of its own where ``j <= i``.
+    Block 0 has no block before (its index is clamped to 0 and masked)."""
+    c = pl.program_id(1)
+    w = q_ref.shape[1]
+    q = q_ref[0]                                              # (W, dh)
+
+    def scores(k_ref):
+        return lax.dot_general(
+            q, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale    # (W, W)
+
+    rows = lax.broadcasted_iota(jnp.int32, (w, w), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (w, w), 1)
+    sp = jnp.where((cols > rows) & (c > 0), scores(kp_ref), _NEG_INF)
+    so = jnp.where(cols <= rows, scores(k_ref), _NEG_INF)
+    m = jnp.maximum(jnp.max(sp, axis=1, keepdims=True),
+                    jnp.max(so, axis=1, keepdims=True))
+    pp = jnp.exp(sp - m)
+    po = jnp.exp(so - m)
+    l = (jnp.sum(pp, axis=1, keepdims=True)
+         + jnp.sum(po, axis=1, keepdims=True))                # > 0: j == i
+
+    def context(p, v_ref):
+        return lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (W, dh)
+
+    o_ref[0] = ((context(pp, vp_ref) + context(po, v_ref)) / l
+                ).astype(o_ref.dtype)
+
+
+def window_attention(q, k, v, heads, kv_heads, window, interpret=False):
+    """Causal attention in which query i sees keys ``i - window < j <= i``,
+    in the layout the ops hold: q (B, T, heads * dh), k / v (B, T,
+    kv_heads * dh) -> (B, T, heads * dh). One call: grid (batch, block of
+    ``window`` queries, query head), the head innermost, so the
+    ``heads // kv_heads`` consecutive query heads of a group find their key
+    and value blocks already in fast memory. No head is repeated and
+    nothing is transposed: a block is ``(window, dh)`` columns
+    ``[h * dh, (h + 1) * dh)`` of the array as it stands, so ``dh`` and
+    ``window`` are multiples of 128. The scores (window, 2 x window)
+    float32 never leave the chip. A length the window does not divide is
+    padded with zero rows (behind every real query, so none sees them).
+    Operands in their own dtype, float32 accumulation and softmax, the
+    probabilities cast to the values' dtype for the second product.
+    Forward only: the caller differentiates the plain blocks."""
+    b, t, _ = q.shape
+    dh = q.shape[-1] // heads
+    group = heads // kv_heads
+    pad = (-t) % window
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+
+    def spec(index):
+        return pl.BlockSpec((1, window, dh), index)
+
+    mine = spec(lambda i, c, h: (i, c, h))
+    own = spec(lambda i, c, h: (i, c, h // group))
+    before = spec(lambda i, c, h: (i, jnp.maximum(c - 1, 0), h // group))
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, sm_scale=dh ** -0.5),
+        grid=(b, (t + pad) // window, heads),
+        in_specs=[mine, before, own, before, own],
+        out_specs=mine,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="window_attn_fwd",
+    )(q, k, k, v, v)
+    return out[:, :t] if pad else out
 
 
 # ---------------------------------------------------------------------------

@@ -2008,11 +2008,19 @@ class DecodeEngine:
         ``process_cpu_seconds``, every thread's (``time.process_time()``
         at this call): less the loop's and the stream readers'
         (``decode.stream.read``'s ``cpu_s``) it is whatever else shares
-        the process and its GIL."""
+        the process and its GIL. ``window_kernel_lowerings`` /
+        ``window_banded_lowerings``: the process's counts (the hub's
+        ``ops.gqa_attention.window_kernel`` / ``.window_banded``) of
+        window-attention calls over a sequence longer than the window that
+        lowered to the Pallas kernel for the band / to the banded blocks
+        through XLA: one a window layer of each prefill program compiled."""
         with self._stats_lock:
             out = dict(self._stats)
         out.update(self._phase_s)
         out["process_cpu_seconds"] = time.process_time()
+        for path in ("kernel", "banded"):
+            out["window_%s_lowerings" % path] = obs.counter(
+                "ops.gqa_attention.window_" + path)
         for k in ("requests", "tokens", "prefills", "adopts", "steps",
                   "retired", "shed", "deadline_miss", "cancelled",
                   "prefill_errors", "adopt_errors", "step_errors",

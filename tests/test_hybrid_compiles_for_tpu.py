@@ -88,9 +88,10 @@ def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
     for the described chip. The step: every declared buffer, rows and rings,
     aliased to its fetch (4.97 GB updated in place), no cache-sized scratch,
     arguments + scratch under the chip's 16 GB. The 8,192 prefill: the
-    grouped kernels (three a sparse layer) and the flash kernel of the two
-    full layers are in it, no (T, T) array is (the window layers' scores are
-    banded blocks), no scatter adds rows into the prompt's `[8192, 3072]`
+    grouped kernels (three a sparse layer), the flash kernel of the two
+    full layers and the band's kernel of the three window layers are in it,
+    no (T, T) array is and no block of float32 scores (a window layer's
+    stay in the kernel), no scatter adds rows into the prompt's `[8192, 3072]`
     (the gated experts' sum back is a read a token), and its scratch fits
     beside weights and state."""
     import jax
@@ -154,9 +155,10 @@ def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
     ).lower(params, {"lg_prefill_ids": sds((1, bucket), "int32"),
                      "lg_prefill_len": sds((1, 1), "int32")}))
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 4 * 3 + 2
-    assert "flash_fwd" in text
+    assert text.count("tpu_custom_call") == 4 * 3 + 2 + 3
+    assert "flash_fwd" in text and "window_attn_fwd" in text
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    assert not re.search(r"f32\[(?:\d+,)*512,1024\]", text)
     # the gated experts' sum back into the tokens reads, a token at a time
     assert not _row_scatters(text, "8192,3072")
     mem = compiled.memory_analysis()

@@ -33,6 +33,7 @@ from paddle_tpu.fluid.inference import Predictor
 from paddle_tpu.fluid.lowering import build_step_fn
 from paddle_tpu.models import gpt, laguna, nemotron_h
 from paddle_tpu.ops import LOWERINGS, hybrid_ops
+from paddle_tpu.ops.registry import LowerContext
 from paddle_tpu.serving.decode import SlotCache, kv_slot_bytes
 
 from benchmark.reference import laguna_lm as ref
@@ -77,6 +78,24 @@ def close(got, want, tol=1e-4):
 
 def gap(got, want):
     return float(np.abs(got - want).max() / want.std())
+
+
+def digest(text):
+    """sha256 of a lowered program's StableHLO, source locations (and a
+    kernel's serialised body, which carries them) taken out."""
+    text = re.sub(r"loc\(.*?\)", "", text)
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    text = re.sub(r"#loc\d* = .*\n", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def window_lowerings():
+    """[kernel, banded]: the process's counts of window calls longer than
+    their window that lowered to the Pallas kernel / the banded blocks."""
+    from paddle_tpu import observability as obs
+
+    return [obs.counter("ops.gqa_attention.window_" + path)
+            for path in ("kernel", "banded")]
 
 
 @pytest.fixture(scope="module")
@@ -211,6 +230,132 @@ def test_a_window_over_a_slot_cache_is_refused():
         lower("gqa_attention", {"Q": x, "K": x, "V": x,
                                 "Pos": np.zeros((1, 1), np.int64)},
               heads=2, kv_heads=2, window=8)
+
+
+def _bf16_exact(a):
+    return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("t", [40, 37, 16, 9])
+def test_the_window_kernel_against_the_equations_and_the_banded_blocks(
+        t, dtype, tol):
+    """The path the TPU takes for a sequence longer than its window
+    (`window_attn_fwd`, one call, the op's own layout), interpreted: a
+    length the window divides, one it does not, one block more than the
+    window (whole and padded), 6 query heads over 2 key/value heads.
+    bfloat16: the operands are bfloat16 values, the kernel rounds its
+    probabilities (before their sum divides) and its output, the blocks
+    theirs (after): one rounding of 2**-8 apart."""
+    q, k, v = (_bf16_exact(RNG.normal(size=(2, t, n * 16)))
+               for n in (6, 2, 2))
+    args = [jnp.asarray(a, dtype) for a in (q, k, v)]
+    got = hybrid_ops._window_gqa(*args, 6, 2, WINDOW, True)
+    assert got.dtype == args[0].dtype and got.shape == q.shape
+    got = np.asarray(got.astype(jnp.float32))
+    close(got, hybrid_ops._banded_gqa(*args, 6, 2, WINDOW).astype(
+        jnp.float32), tol)
+    for b in range(2):
+        close(got[b], attention_written_out(
+            q[b].astype(np.float64), k[b].astype(np.float64),
+            v[b].astype(np.float64), 6, 2, WINDOW), max(tol, 1e-4))
+
+
+@pytest.mark.parametrize("t", [32, 21])
+def test_the_window_kernels_gradients_are_the_banded_blocks_own(t):
+    """Forward only: under `append_backward` a window layer behaves as the
+    plain blocks do, the kernel's `custom_vjp` hands the cotangent to
+    `jax.vjp` of them."""
+    q, k, v = (jnp.asarray(RNG.normal(size=(2, t, n * 16)), jnp.float32)
+               for n in (6, 2, 2))
+    w = jnp.asarray(RNG.normal(size=q.shape), jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(w * hybrid_ops._window_gqa(
+        *a, 6, 2, WINDOW, True)), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(w * hybrid_ops._banded_gqa(
+        *a, 6, 2, WINDOW)), (0, 1, 2))(q, k, v)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+
+
+def _attention_text(platform, t, heads=4, kv_heads=2, dh=128, window=0,
+                    mesh_axes=None, pos=False, batch=1):
+    """The StableHLO of one `gqa_attention` call for `platform`."""
+    q = jax.ShapeDtypeStruct((batch, t, heads * dh), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((batch, t, kv_heads * dh), jnp.bfloat16)
+    p = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    ctx = LowerContext(platform=platform)
+    ctx.mesh_axes = mesh_axes
+    attrs = {"heads": heads, "kv_heads": kv_heads}
+    if window:
+        attrs["window"] = window
+
+    def f(q, k, v, p):
+        ins = {"Q": [q[:, :1] if pos else q], "K": [k], "V": [v]}
+        if pos:
+            ins["Pos"] = [p]
+        return LOWERINGS["gqa_attention"](ctx, ins, attrs)["Out"][0]
+    return jax.jit(f).trace(q, kv, kv, p).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+WINDOW_PATHS = {
+    # what of a call decides, and which of the two paths it lowers
+    "the cell's window on the chip": (dict(platform="tpu"), "kernel"),
+    "a length the window does not divide": (dict(platform="tpu", t=1100),
+                                            "kernel"),
+    "the CPU": (dict(platform="cpu"), "banded"),
+    "a sharded program": (dict(platform="tpu", mesh_axes={"dp": "dp"}),
+                          "banded"),
+    "a window that does not tile": (dict(platform="tpu", window=200),
+                                    "banded"),
+    "a head that does not tile": (dict(platform="tpu", dh=64), "banded"),
+    "no longer than the window": (dict(platform="tpu", t=512), None),
+    "a step over a ring": (dict(platform="tpu", t=512, window=0, pos=True),
+                           None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_PATHS))
+def test_the_window_kernel_is_taken_from_what_the_op_sees(case):
+    """A window, a sequence longer than it, no `Pos`, a TPU, no mesh, a
+    head size and a window that are multiples of 128: the kernel; any
+    other sequence longer than its window the banded blocks; no caller
+    sets anything. The two lowering counters count what was taken."""
+    call, path = WINDOW_PATHS[case]
+    before = window_lowerings()
+    text = _attention_text(**dict(dict(t=1024, window=512), **call))
+    assert [n - b for n, b in zip(window_lowerings(), before)] == [
+        int(path == "kernel"), int(path == "banded")]
+    assert ("window_attn_fwd" in text) == (path == "kernel")
+    assert ("tpu_custom_call" in text) == (path == "kernel")
+
+
+ATTENTION_DIGESTS = {
+    # read on the parent commit (PR 37's tree) with `_attention_text`:
+    # LFM2's causal call of the training cell (flash from 1,024 on) and the
+    # hybrid's prefill call at its longest bucket, on both platforms
+    ("lfm2", "tpu"): "7aaa81309855852c",
+    ("lfm2", "cpu"): "8d41e7a61ea22f96",
+    ("nemotron_h", "tpu"): "0a219c6d3ba28eb3",
+    ("nemotron_h", "cpu"): "0a219c6d3ba28eb3",
+}
+ATTENTION_CALLS = {"lfm2": dict(t=4096, heads=32, kv_heads=8, dh=64, batch=4),
+                   "nemotron_h": dict(t=512, heads=32, kv_heads=2, dh=128)}
+
+
+@pytest.mark.parametrize("family,platform", sorted(ATTENTION_DIGESTS))
+def test_the_causal_calls_without_a_window_lower_unchanged(family, platform):
+    """What the branch for the band must not move: a causal call without a
+    window lowers to the text it had on the parent commit (LFM2's through
+    the flash kernels on the TPU, the hybrid's short one through XLA), and
+    counts on neither window counter."""
+    before = window_lowerings()
+    text = _attention_text(platform, **ATTENTION_CALLS[family])
+    assert window_lowerings() == before
+    assert "window_attn_fwd" not in text
+    assert ("flash_fwd" in text) == ((family, platform) == ("lfm2", "tpu"))
+    assert ("tpu_custom_call" in text) == ("flash_fwd" in text)
+    assert digest(text) == ATTENTION_DIGESTS[(family, platform)]
 
 
 @pytest.mark.parametrize("plen", [3, 8, 9, 21])
@@ -390,7 +535,10 @@ def test_through_the_engine_tokens_counters_and_reused_slots(model):
     """Served through DecodeEngine with fewer slots than requests: every
     served token lies within LIMIT of the reference's best at its position,
     the step's counts arrive, nothing is copied."""
+    from paddle_tpu import observability as obs
+
     cfg, w = model
+    kernel, banded = window_lowerings()
     eng = serving.DecodeEngine(cfg, w, slots=2, cache_len=CACHE_LEN,
                                prompt_buckets=[16, 32], name="lg-test",
                                adopt_params=True)
@@ -412,6 +560,11 @@ def test_through_the_engine_tokens_counters_and_reused_slots(model):
         assert st["state_bytes_ring"] == 2 * 6 * WINDOW * 32 * 2
         assert st["state_bytes_rows"] == 2 * 4 * CACHE_LEN * 32 * 2
         assert st["state_bytes_fixed"] == 0
+        # three window layers in each of the two prefill programs, on the
+        # CPU through the banded blocks; the step's rings are no window call
+        assert st["window_banded_lowerings"] - banded == 6
+        assert st["window_kernel_lowerings"] == kernel
+        assert "ops_gqa_attention_window_banded" in obs.render_prom()
     finally:
         eng.stop(drain=False, timeout=5)
 
@@ -494,9 +647,7 @@ def test_what_the_config_names_and_the_file_does_not_build_is_refused():
 
 # -- what must not move for the other served programs ------------------------
 def _step_digest(cfg, builder, cache_len, platform):
-    """sha256 of the step program's StableHLO for `platform`, source
-    locations (and a kernel's serialised body, which carries them) taken
-    out."""
+    """:func:`digest` of the step program's StableHLO for `platform`."""
     with fluid.program_guard(fluid.Program(), fluid.Program()):
         v = builder(cfg, cache_len)
         prog = fluid.default_main_program()
@@ -514,10 +665,7 @@ def _step_digest(cfg, builder, cache_len, platform):
         for n in v["feed_names"]}
     text = jax.jit(lambda p, f: step(p, f, jax.random.PRNGKey(0))[0]).trace(
         params, feeds).lower(lowering_platforms=(platform,)).as_text()
-    text = re.sub(r"loc\(.*?\)", "", text)
-    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
-    text = re.sub(r"#loc\d* = .*\n", "", text)
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    return digest(text)
 
 
 NH = dict(hybrid_override_pattern="ME*EM", vocab_size=211, hidden_size=64,
