@@ -1,10 +1,13 @@
 """Layers of hybrid state-space / attention / sparse-expert decoders: RMS
 norm (plain, grouped, gated), squared ReLU, a causal depthwise convolution
 with carried window, the Mamba-2 recurrence (chunked scan and one step),
-a rotary position term (whole or part of a head, plain or YaRN, from the row
-index or from a slot's position), grouped-query attention (causal, windowed,
-over a slot cache or ring), top-k routing (sigmoid or softmax scores) and a
-product that keeps its float32 accumulator. The lowerings are
+a rotary position term (whole or part of a head, half-split or interleaved
+pairs, plain or YaRN, from the row index or from a slot's position),
+grouped-query attention (causal, windowed, over a slot cache or ring), a
+learned selection of keys and latent attention over it (a prompt's expanded
+path, a step's absorbed path over gathered cache rows), top-k routing
+(sigmoid or softmax scores) and a product that keeps its float32
+accumulator. The lowerings are
 ``paddle_tpu/ops/hybrid_ops.py``; the held-experts layer built on the router is
 ``paddle_tpu.parallel.moe.held_experts_ffn``.
 """
@@ -13,7 +16,7 @@ from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "relu_squared", "dense_acc32", "causal_conv1d",
            "mamba2_scan", "mamba2_step", "rotary_embedding", "gqa_attention",
-           "kv_ring_gather", "moe_route_topk"]
+           "kv_ring_gather", "dsa_select", "mla_attention", "moe_route_topk"]
 
 
 def _out(helper, dtype, shape):
@@ -129,13 +132,16 @@ def mamba2_step(xbc, dt, state, name, heads, head_dim, groups, state_size):
                 (xbc.shape[0], heads * head_dim), state.shape, {})
 
 
-def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None):
+def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None,
+                     interleaved=False):
     """Rotary position term over ``x`` (B, T, heads, head_dim): the row at
     t along axis 1 stands at position t, or with ``pos`` (B, 1) at
     ``pos[b] + t`` (a decode step's one row at its slot's position). Pair
     ``(x[i], x[i + rotary_dim/2])`` of every head is turned by ``position
-    * theta^(-2i/rotary_dim)``; dimensions past ``rotary_dim`` (default:
-    the whole head) pass unturned. ``yarn`` = ``(factor, original
+    * theta^(-2i/rotary_dim)``, with ``interleaved`` pair ``(x[2i], x[2i +
+    1])``, which comes out de-interleaved (at i and i + rotary_dim/2);
+    dimensions past ``rotary_dim`` (default: the whole head) pass
+    unturned. ``yarn`` = ``(factor, original
     positions, beta_fast, beta_slow, attention_factor)`` blends the rates
     and multiplies cos and sin by the last
     (``ops.hybrid_ops.rotary_inv_freq``). No parameter."""
@@ -149,6 +155,8 @@ def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None):
         attrs["rotary_dim"] = int(rotary_dim)
     if yarn is not None:
         attrs["yarn"] = [float(v) for v in yarn]
+    if interleaved:
+        attrs["interleaved"] = True
     helper.append_op(type="rotary_embedding", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out
@@ -192,6 +200,58 @@ def kv_ring_gather(x, length, window):
     helper.append_op(type="kv_ring_gather",
                      inputs={"X": [x], "Len": [length]},
                      outputs={"Out": [out]}, attrs={"window": int(window)})
+    return out
+
+
+def dsa_select(q, k, w, heads, topk, pos=None):
+    """The keys a sparse-attention layer's indexer keeps for each query:
+    ``q`` (B, Tq, heads * d) and ``k`` (B, Tk, d) the indexer's queries and
+    keys, turned by their positions, ``w`` (B, Tq, heads) float32 the
+    heads' weights; key s scores ``sum_j w[t, j] relu(q[t, j] . k[s])`` and
+    the ``topk`` visible keys of largest score are kept (all, while there
+    are no more), exactly. Over a prompt (causal) -> (B, T, T) int8, 1
+    where query t keeps key s; with ``pos`` (B, 1) over the slots' indexer
+    rows, row b seeing columns <= pos[b] -> (B, min(topk, Tk)) int32 kept
+    columns, -1 for none. What :func:`mla_attention` takes as
+    ``selected``. No parameter."""
+    helper = LayerHelper("dsa_select")
+    inputs = {"Q": [q], "K": [k], "W": [w]}
+    if pos is None:
+        out = _out(helper, "int8", (q.shape[0], q.shape[1], k.shape[1]))
+    else:
+        inputs["Pos"] = [pos]
+        out = _out(helper, "int32", (q.shape[0], min(int(topk), k.shape[1])))
+    helper.append_op(type="dsa_select", inputs=inputs,
+                     outputs={"Selected": [out]},
+                     attrs={"heads": int(heads), "topk": int(topk)})
+    return out
+
+
+def mla_attention(q, latent, selected, name, heads, rank, nope_dim, rope_dim,
+                  v_dim, pos=None):
+    """Multi-head latent attention over the keys ``selected``
+    (:func:`dsa_select`): ``q`` (B, Tq, heads * (nope_dim + rope_dim)) per
+    head ``[q_nope | q_rope]``, ``latent`` (B, Tk, >= rank + rope_dim) a
+    position's ``[ckv | k_rope]`` of latent rank ``rank``, zeros after it up
+    to the cache's width -> (B, Tq, heads * v_dim). A prompt takes
+    the expanded path (keys and values of every head made from the
+    latents); with ``pos`` (B, 1) a step takes the absorbed path over the
+    kept rows of the slots' cache ``latent``, gathered. Parameters
+    ``<name>.uk.w`` (rank, heads * nope_dim) and ``<name>.uv.w`` (rank,
+    heads * v_dim)."""
+    helper = LayerHelper("mla_attention")
+    inputs = {"Q": [q], "Latent": [latent], "Selected": [selected],
+              "Wuk": [_param(helper, name + ".uk.w",
+                             [rank, heads * nope_dim], q.dtype)],
+              "Wuv": [_param(helper, name + ".uv.w",
+                             [rank, heads * v_dim], q.dtype)]}
+    if pos is not None:
+        inputs["Pos"] = [pos]
+    out = _out(helper, q.dtype, (q.shape[0], q.shape[1], heads * v_dim))
+    helper.append_op(type="mla_attention", inputs=inputs,
+                     outputs={"Out": [out]},
+                     attrs={"heads": int(heads), "nope_dim": int(nope_dim),
+                            "rope_dim": int(rope_dim), "v_dim": int(v_dim)})
     return out
 
 

@@ -57,13 +57,20 @@ class DecodeModel:
     (both are traced inside one jitted call; identity by default).
     ``step_counters(aux, live)`` maps the step program's trailing fetch
     (after the state) to lifetime counters of ``DecodeEngine.stats()``.
+    ``rows_are_kv`` says that the ``rows`` entries are the K and the V
+    of attention layers, all of one width (with their scales, if
+    quantised): the geometry the prefix pool, the session tier, the wire
+    format, the int8 step and the delta / verify builders know. The one
+    model whose rows are of another make (a latent row and an indexer's
+    row of unequal widths) sets it False.
     """
 
     def __init__(self, cfg, state, build_prefill, build_step,
                  build_delta=None, build_verify=None, unpack=None,
-                 pack=None, step_counters=None):
+                 pack=None, step_counters=None, rows_are_kv=True):
         self.cfg = cfg
         self.state = list(state)
+        self.rows_are_kv = bool(rows_are_kv)
         self.build_prefill = build_prefill
         self.build_step = build_step
         self.build_delta = build_delta
@@ -80,8 +87,9 @@ class DecodeModel:
 
 def require_rows_only(model, feature):
     """Features that cut, share, quantise or ship a sequence's state row
-    by row cannot hold a ``fixed`` or a ``ring`` entry: refuse, do not
-    emulate."""
+    by row cannot hold a ``fixed`` or a ``ring`` entry, and they know rows
+    only as the K and the V of attention layers, all of one width
+    (``DecodeModel.rows_are_kv``): refuse anything else, do not emulate."""
     other = [e for e in model.state if e.kind != "rows"]
     if other:
         names = [e.name for e in other]
@@ -94,6 +102,14 @@ def require_rows_only(model, feature):
             "back" % (feature, " and ".join(what.get(k, k) for k in kinds),
                       ", ".join(names[:3]),
                       ", ..." if len(names) > 3 else ""))
+    if not model.rows_are_kv:
+        shapes = sorted({(e.name.rsplit("_", 1)[0], e.shape[-1])
+                         for e in model.state})
+        raise ValueError(
+            "%s needs rows that are the K and the V of attention layers, "
+            "all of one width; this model's rows are not K and V of one "
+            "width (%s): nothing here cuts, packs, quantises or ships them"
+            % (feature, ", ".join("%s %d wide" % s for s in shapes)))
 
 
 def split_heads(t, heads, dh):

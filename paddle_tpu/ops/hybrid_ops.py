@@ -219,13 +219,17 @@ def rotary_inv_freq(rot, theta, yarn=None):
 
 @register_op("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
-    """Rotary position term, half-split pairs: X (B, T, heads, dh); the
-    row at t along axis 1 stands at position t, or with ``Pos`` (B, 1) at
-    ``pos[b] + t`` (a decode step's row at its slot's position). Over the
-    first ``rotary_dim`` dimensions of a head (default: all) pair i is
-    ``(x[i], x[i + rotary_dim/2])``, turned by ``position * rate_i``
+    """Rotary position term: X (B, T, heads, dh); the row at t along axis
+    1 stands at position t, or with ``Pos`` (B, 1) at ``pos[b] + t`` (a
+    decode step's row at its slot's position). Over the first
+    ``rotary_dim`` dimensions of a head (default: all) pair i is ``(x[i],
+    x[i + rotary_dim/2])`` (half-split) or, with ``interleaved``,
+    ``(x[2i], x[2i + 1])``, turned by ``position * rate_i``
     (:func:`rotary_inv_freq`; ``yarn`` blends and scales); the other
-    dimensions pass unturned. Computed in float32, returned in X's
+    dimensions pass unturned. Either way the turned pair i comes out at
+    ``(i, i + rotary_dim/2)``: an interleaved head leaves de-interleaved,
+    as ``transformers`` hands it on (queries and keys alike, so their
+    products do not see it). Computed in float32, returned in X's
     dtype."""
     x = ins["X"][0]
     t, dh = x.shape[1], x.shape[-1]
@@ -240,7 +244,11 @@ def _rotary_embedding(ctx, ins, attrs):
     cos = (jnp.cos(ang) * factor)[:, :, None, :]
     sin = (jnp.sin(ang) * factor)[:, :, None, :]
     xf = x.astype(F32)
-    x1, x2 = xf[..., :half], xf[..., half:rot]
+    if attrs.get("interleaved"):
+        pairs = xf[..., :rot].reshape(xf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:rot]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
                            xf[..., rot:]], -1)
     return single(out.astype(x.dtype))
@@ -407,6 +415,223 @@ def _kv_ring_gather(ctx, ins, attrs):
     at = last - jnp.mod(last - j, window)                         # (B, W)
     rows = jnp.take_along_axis(x, jnp.maximum(at, 0)[:, :, None], axis=1)
     return single(jnp.where((at >= 0)[:, :, None], rows, 0).astype(x.dtype))
+
+
+DSA_QUERY_BLOCK = 128   # queries a trip of the selection's and the masked
+# attention's loops over a prompt: the float32 scores alive are (index heads,
+# 128, keys) and (heads, 128, keys), 0.27 and 0.54 GB at 16,384 keys
+KEPT_BLOCK = 512        # query and key tile of the kept-keys kernel
+DSA_TIERS = 4           # a prompt's queries in this many runs, run j against
+# the keys [0, (j + 1) T / tiers): 0.625 of the square where one run is all
+# of it and the causal half is 0.5
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 in the same order (no NaN; -0.0 counts as 0.0)."""
+    b = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _kth_largest(keys, k):
+    """keys (..., n) uint32 -> (...,) the k-th largest of each row, exact:
+    the largest v with at least k keys >= v, built bit by bit from the top
+    in 32 passes of compare and count; no sort. 0 where a row has fewer
+    than k keys."""
+    def bit(i, ans):
+        cand = ans | jnp.uint32(1 << 31) >> i.astype(jnp.uint32)
+        enough = jnp.sum(keys >= cand[..., None], -1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, ans)
+
+    return lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def _index_scores(qi, ki, w, heads):
+    """The indexer's score of every key for every query: qi (B, Tq, heads *
+    d) and ki (B, Tk, d) as stored, w (B, Tq, heads) float32 -> (B, Tq, Tk)
+    float32 ``sum_j w_j relu(qi_j . ki)``; the products accumulate in
+    float32 and the weighted sum over the heads is float32 arithmetic, not
+    a product the chip would round."""
+    b, tq, _ = qi.shape
+    per_head = jnp.einsum("bqjd,bkd->bqjk", qi.reshape(b, tq, heads, -1), ki,
+                          preferred_element_type=F32)
+    return jnp.sum(jnp.maximum(per_head, 0.0) * w[..., None], axis=2)
+
+
+def _tiers(t):
+    """(block, tiers) of a prompt of t rows: blocks of DSA_QUERY_BLOCK
+    queries (t itself where it is shorter or no multiple), in DSA_TIERS
+    runs of equal length where each holds at least two blocks."""
+    blk = DSA_QUERY_BLOCK if t % DSA_QUERY_BLOCK == 0 else t
+    tiers = DSA_TIERS if t % (2 * DSA_TIERS * blk) == 0 else 1
+    return blk, tiers
+
+
+def _over_query_blocks(one, t, per_query, per_key):
+    """``one(rows (blk,) int32, *blocks, *keys)`` over a prompt's queries, a
+    block a trip, tier by tier. ``per_query`` arrays (B, T, ...) are handed
+    over a block at a time (B, blk, ...); ``per_key`` pairs (array, axis)
+    are cut to the tier's keys ``[0, tk)`` along ``axis`` ONCE a tier,
+    outside its loop (a slice inside the body is copied every trip: 0.5 GB
+    of keys a block at 16,384). -> the tiers' results (B, queries of the
+    tier, ...), in order."""
+    blk, tiers = _tiers(t)
+    nq = t // tiers
+    outs = []
+    for j in range(tiers):
+        tk = (j + 1) * nq
+        rows = (j * nq + jnp.arange(nq, dtype=jnp.int32)).reshape(-1, blk)
+        parts = [jnp.moveaxis(
+            a[:, j * nq:tk].reshape((a.shape[0], nq // blk, blk)
+                                    + a.shape[2:]), 1, 0) for a in per_query]
+        keys = [lax.slice_in_dim(a, 0, tk, axis=axis) for a, axis in per_key]
+        out = lax.map(lambda args, keys=keys: one(*args, *keys),
+                      (rows, *parts))
+        out = jnp.moveaxis(out, 0, 1)
+        outs.append(out.reshape((out.shape[0], nq) + out.shape[3:]))
+    return outs
+
+
+@register_op("dsa_select")
+def _dsa_select(ctx, ins, attrs):
+    """The learned selection of keys of a sparse-attention layer (DeepSeek
+    sparse attention's lightning indexer): Q (B, Tq, heads * d) the
+    indexer's queries, K (B, Tk, d) its keys (one for all heads), both
+    already turned by their positions, W (B, Tq, heads) float32 the heads'
+    weights; query t scores key s ``I[t, s] = sum_j W[t, j] relu(Q[t, j] .
+    K[s])`` and keeps the ``topk`` visible keys of largest score, all of
+    them while it sees no more than that. Exact: no approximate top-k.
+
+    Without ``Pos`` a prompt (Tq == Tk, key s visible to query t iff s <=
+    t): Selected (B, T, T) int8, 1 where query t keeps key s. The row's
+    threshold is the ``topk``-th largest score (:func:`_kth_largest`, 32
+    counting passes, no sort and no gather), over blocks of queries so that
+    the scores alive are (heads, DSA_QUERY_BLOCK, keys), and in DSA_TIERS
+    runs of queries each against the keys up to its own end.
+
+    With ``Pos`` (B, 1) a decode step (Tq == 1) over the slots' indexer
+    rows K (B, cache_len, d), row b seeing columns <= pos[b]: Selected (B,
+    min(topk, cache_len)) int32, the kept columns in the order of their
+    scores, -1 where the slot has fewer (``lax.top_k``: exact)."""
+    q, k, w = ins["Q"][0], ins["K"][0], ins["W"][0].astype(F32)
+    heads, topk = int(attrs["heads"]), int(attrs["topk"])
+    b, tq, _ = q.shape
+    tk = k.shape[1]
+    if ins.get("Pos"):
+        at = jnp.arange(tk, dtype=jnp.int32)[None, :]
+        seen = at <= ins["Pos"][0].reshape(b, 1).astype(jnp.int32)
+        scores = jnp.where(seen, _index_scores(q, k, w, heads)[:, 0],
+                           -jnp.inf)
+        best, idx = lax.top_k(scores, min(topk, tk))
+        return {"Selected": [jnp.where(best > -jnp.inf, idx, -1)
+                             .astype(jnp.int32)]}
+
+    def one(rows, qb, wb, kb):
+        tkeys = kb.shape[1]
+        seen = jnp.arange(tkeys, dtype=jnp.int32)[None, :] <= rows[:, None]
+        scores = jnp.where(seen, _index_scores(qb, kb, wb, heads), -jnp.inf)
+        keys = _ordered_bits(scores)
+        kept = (keys >= _kth_largest(keys, topk)[..., None]) & seen
+        return jnp.pad(kept.astype(jnp.int8),
+                       ((0, 0), (0, 0), (0, tk - tkeys)))
+
+    return {"Selected": [jnp.concatenate(
+        _over_query_blocks(one, tq, (q, w), [(k, 1)]), axis=1)]}
+
+
+@register_op("mla_attention")
+def _mla_attention(ctx, ins, attrs):
+    """Multi-head latent attention over a selection of keys. Q (B, Tq,
+    heads * (nope + rope)) per head ``[q_nope | q_rope]``, the second part
+    turned by its position; Latent (B, Tk, >= rank + rope) a position's row
+    ``[ckv | k_rope | zeros]`` (the normed latent and the one turned key
+    part all heads share, then whatever padding the cache's width carries);
+    Wuk (rank, heads * nope) and Wuv (rank, heads * v) expand
+    a latent to a head's key and value; scale ``(nope + rope)^-1/2``;
+    Selected from ``dsa_select``. Out (B, Tq, heads * v). Two paths, the
+    same numbers:
+
+    a prompt (no ``Pos``; Selected (B, T, T) int8): expanded. ``k_nope =
+    ckv Wuk`` and ``v = ckv Wuv`` for every position and head, score
+    ``q_nope . k_nope + q_rope . k_rope``, softmax over the kept keys,
+    ``sum p v``. On an unsharded TPU program whose length KEPT_BLOCK
+    divides and whose head widths are multiples of 128, one Pallas kernel
+    (``pallas_attention.kept_keys_attention``: the scores stay on the
+    chip, the work is the causal half); everywhere else blocks of
+    DSA_QUERY_BLOCK queries x all heads through XLA, run j of DSA_TIERS
+    against the keys up to its end, so no (heads, T, T) array exists
+    either way. The op chooses from what it sees; which path a lowering
+    took is counted (``ops.mla_attention.kept_kernel`` / ``.kept_blocks``).
+
+    a decode step (``Pos`` given, Tq == 1; Latent the slots' rows (B,
+    cache_len, .), Selected (B, k) int32 columns, -1 for none): absorbed.
+    The kept rows are gathered, ``q~ = q_nope Wuk_h^T`` (rank) scores a row
+    as ``q~ . ckv + q_rope . k_rope``, and ``(sum p ckv) Wuv_h`` is the
+    head's output: no key or value is expanded, the cache is read at the
+    kept rows alone."""
+    q, lat = ins["Q"][0], ins["Latent"][0]
+    wuk, wuv, sel = ins["Wuk"][0], ins["Wuv"][0], ins["Selected"][0]
+    heads, nope, rope, vd = (int(attrs[k]) for k in
+                             ("heads", "nope_dim", "rope_dim", "v_dim"))
+    b, tq, _ = q.shape
+    rank, width = wuk.shape[0], lat.shape[-1]
+    scale = (nope + rope) ** -0.5
+    if ins.get("Pos"):
+        rows = jax.vmap(lambda c, i: jnp.take(c, i, axis=0))(
+            lat, jnp.maximum(sel, 0))                     # (B, k, width)
+        qh = q.reshape(b, heads, nope + rope)
+        absorbed = jnp.einsum("bhd,chd->bhc", qh[..., :nope],
+                              wuk.reshape(rank, heads, nope),
+                              preferred_element_type=F32).astype(q.dtype)
+        # the query in the row's own layout, zeros against its padding: the
+        # gathered rows are read where they lie, not cut to size first
+        ql = jnp.concatenate(
+            [absorbed, qh[..., nope:],
+             jnp.zeros((b, heads, width - rank - rope), q.dtype)], -1)
+        scores = jnp.einsum("bhc,bkc->bhk", ql, rows,
+                            preferred_element_type=F32) * scale
+        probs = jax.nn.softmax(
+            jnp.where((sel >= 0)[:, None, :], scores, -1e30), -1)
+        mixed = jnp.einsum("bhk,bkc->bhc", probs.astype(q.dtype),
+                           rows[..., :rank],
+                           preferred_element_type=F32).astype(q.dtype)
+        out = jnp.einsum("bhc,chv->bhv", mixed, wuv.reshape(rank, heads, vd),
+                         preferred_element_type=F32)
+        return single(out.reshape(b, 1, heads * vd).astype(q.dtype))
+
+    from .. import observability as obs
+
+    ckv, k_rope = lat[..., :rank], lat[..., rank:rank + rope]
+    k_nope = _dot_f32(ckv, wuk).astype(q.dtype).reshape(b, tq, heads, nope)
+    keys = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (b, tq, heads, rope))], -1)
+    values = _dot_f32(ckv, wuv).astype(q.dtype)
+    if (getattr(ctx, "platform", None) == "tpu"
+            and not getattr(ctx, "mesh_axes", None)
+            and tq % KEPT_BLOCK == 0 and (nope + rope) % 128 == 0
+            and vd % 128 == 0):
+        from .pallas_attention import kept_keys_attention
+
+        obs.inc("ops.mla_attention.kept_kernel")
+        return single(kept_keys_attention(
+            q, keys.reshape(b, tq, -1), values, sel, heads, scale,
+            block=KEPT_BLOCK))
+    obs.inc("ops.mla_attention.kept_blocks")
+    kh = jnp.swapaxes(keys, 1, 2)
+    vh = jnp.swapaxes(values.reshape(b, tq, heads, vd), 1, 2)
+
+    def one(rows, qb, kept, kb, vb):
+        scores = jnp.einsum("bqhd,bhkd->bhqk",
+                            qb.reshape(b, -1, heads, nope + rope), kb,
+                            preferred_element_type=F32) * scale
+        probs = jax.nn.softmax(
+            jnp.where(kept[:, None, :, :kb.shape[2]] > 0, scores, -1e30), -1)
+        return jnp.einsum("bhqk,bhkd->bqhd", probs.astype(q.dtype), vb,
+                          preferred_element_type=F32
+                          ).astype(q.dtype).reshape(b, -1, heads * vd)
+
+    return single(jnp.concatenate(
+        _over_query_blocks(one, tq, (q, sel), [(kh, 2), (vh, 2)]), axis=1))
 
 
 @register_op("moe_route_topk")

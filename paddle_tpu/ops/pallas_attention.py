@@ -34,7 +34,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "reference_attention", "window_attention"]
+__all__ = ["flash_attention", "reference_attention", "window_attention",
+           "kept_keys_attention"]
 
 _NEG_INF = -1e30
 
@@ -669,6 +670,102 @@ def window_attention(q, k, v, heads, kv_heads, window, interpret=False):
         name="window_attn_fwd",
     )(q, k, k, v, v)
     return out[:, :t] if pad else out
+
+
+def _kept_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_ref, l_ref, acc_ref,
+                 *, sm_scale):
+    """One (block of queries, head, block of keys) of causal attention over
+    the keys a (T, T) int8 selection keeps: the scores of the tile, masked
+    to the selection, join the head's running maximum, sum and weighted
+    values (online softmax) in fast memory; the last tile of keys a query
+    block may see writes the block's output. A tile of keys wholly beyond
+    the queries' positions is skipped (its index is clamped, so nothing is
+    fetched for it either). Every query keeps at least one key (its
+    selection is never empty), so the sum is positive once its tile with a
+    kept key has been met, and what a row gathered before that (tiles in
+    which it keeps nothing) is wiped by the rescaling."""
+    i, j = pl.program_id(1), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(keep_ref[0] != 0, s, _NEG_INF)          # (bq, bk)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == i)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def kept_keys_attention(q, k, v, keep, heads, sm_scale, block=512,
+                        interpret=False):
+    """Causal attention of ``heads`` heads in which query t sees only the
+    keys s <= t with ``keep[t, s] != 0`` (a learned selection, the same for
+    every head), in the layout the ops hold: q and k (B, T, heads * dqk), v
+    (B, T, heads * dv), keep (B, T, T) int8 -> (B, T, heads * dv). One
+    call: grid (batch, block of queries, head, block of keys), the keys
+    innermost and sequential; a head's running maximum, sum and weighted
+    values stay in fast memory, so neither the (heads, T, T) scores nor a
+    block of them goes through HBM. Nothing is transposed: a tile is
+    ``block`` rows of the columns ``[h * d, (h + 1) * d)`` of the array as
+    it stands, so ``dqk``, ``dv`` and ``block`` are multiples of 128 and T
+    of ``block``. Tiles of keys beyond a query block's positions are
+    skipped: the work is the causal half. The selection saves no work here
+    (a tile of 512 x 512 that keeps nothing is rare): it is a mask.
+    Operands in their own dtype, float32 accumulation and softmax, the
+    probabilities cast to the values' dtype for the second product.
+    Forward only."""
+    b, t, _ = q.shape
+    dqk, dv = q.shape[-1] // heads, v.shape[-1] // heads
+    if t % block or dqk % _LANES or dv % _LANES or block % _LANES:
+        raise ValueError(
+            "kept_keys_attention tiles %d positions by blocks of %d and "
+            "heads of %d / %d by 128 lanes" % (t, block, dqk, dv))
+    n = t // block
+
+    def rows(width, index):
+        return pl.BlockSpec((1, block, width), index)
+
+    def seen(i, j):
+        """The tile of keys step j fetches: a skipped one's is the last
+        fetched again, so nothing moves for it."""
+        return jnp.minimum(j, i)
+
+    return pl.pallas_call(
+        functools.partial(_kept_kernel, sm_scale=sm_scale),
+        grid=(b, n, heads, n),
+        in_specs=[rows(dqk, lambda a, i, h, j: (a, i, h)),
+                  rows(dqk, lambda a, i, h, j: (a, seen(i, j), h)),
+                  rows(dv, lambda a, i, h, j: (a, seen(i, j), h)),
+                  pl.BlockSpec((1, block, block),
+                               lambda a, i, h, j: (a, i, seen(i, j)))],
+        out_specs=rows(dv, lambda a, i, h, j: (a, i, h)),
+        out_shape=jax.ShapeDtypeStruct((b, t, heads * dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="kept_keys_attn_fwd",
+    )(q, k, v, keep)
 
 
 # ---------------------------------------------------------------------------

@@ -197,6 +197,11 @@ class SlotCache:
         zeros = self._jax.numpy.zeros
         self.bufs = [zeros(sp.shape, sp.dtype) for sp in self.specs]
 
+    def release(self):
+        """Drop the buffers for good (a stopped engine's: whoever still
+        holds the engine then holds none of its device state)."""
+        self.bufs = None
+
     def feeds(self, names):
         """The buffers under a program's cache feed names."""
         return dict(zip(names, self.bufs))
@@ -751,7 +756,8 @@ class DecodeEngine:
     def stop(self, drain=True, timeout=30.0):
         """Stop admitting work. ``drain=True`` finishes every live slot
         and queued request first; ``drain=False`` fails them with
-        :class:`EngineClosedError`. Idempotent."""
+        :class:`EngineClosedError`. Once the dispatch thread has ended the
+        slots' state is freed on the device. Idempotent."""
         with self._admit_lock:
             self._closed = True
         if not drain:
@@ -761,6 +767,7 @@ class DecodeEngine:
             self._thread.join(timeout=max(0.1, float(timeout)))
         if self._thread is None or not self._thread.is_alive():
             self._flush()  # what a thread that died still owed
+            self._cache.release()  # nothing runs on the slots' state now
         while True:  # no thread (or it died): fail leftovers loudly
             try:
                 req = self._q.get_nowait()

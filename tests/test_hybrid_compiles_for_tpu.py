@@ -167,6 +167,97 @@ def test_lagunas_step_and_longest_prefill_at_published_widths(one_chip):
     assert weights + state_bytes + mem.temp_size_in_bytes < 14.5e9
 
 
+def test_glm5s_step_and_longest_prefill_at_published_widths(one_chip):
+    """GLM-5's cut (3,910 M parameters, 16 slots x 17,408) compiled for the
+    described chip. The step: both declared buffers of every layer, the
+    latent rows (640 wide) and the indexer's rows, aliased to their fetches
+    (2.14 GB updated in place), no cache-sized scratch (a 576-wide cache is
+    laid out with positions minor and copied into row order and back every
+    step: 3.2 GB of scratch), arguments + scratch under the chip's 16 GB,
+    the three grouped kernels of each of the four sparse layers in it. The
+    16,384 prefill: those kernels once a call of the routed layer (four
+    calls of 4,096 tokens a layer), the kept-keys kernel once a layer, no
+    (heads, T, T) array (the selection is int8, a block of queries a row),
+    and its scratch fits beside weights and state."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import costs_glm5
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import glm_moe_dsa as glm
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "glm_5.json")))
+    doc["model"] = {k: v for k, v in doc.items()
+                    if not isinstance(v, (dict, list))}
+    cfg = glm.GlmMoeDsaConfig.from_hf(
+        costs_glm5.sizes(doc),
+        router_experts=doc["reduced_from"]["n_routed_experts"],
+        first_expert=doc["share"]["first_expert"])
+    slots, cache_len = doc["serving"]["slots"], doc["serving"]["cache_len"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {k: sds(s, d) for k, (s, d) in glm.param_shapes(cfg).items()}
+    weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in params.values())
+    assert round(weights / 1e9, 2) == 7.82
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = glm.build_step(cfg, cache_len)
+        prog = fluid.default_main_program()
+    step = build_step_fn(prog, v["feed_names"],
+                         [x.name for x in v["fetch_vars"]], is_test=True,
+                         platform="tpu")
+    names = v["cache_feed_names"]
+    decl = cfg.decode_model(cache_len).state
+
+    def fwd(state, feeds, donated):
+        feeds = dict(feeds)
+        feeds.update(zip(names, donated))
+        return step(state, feeds, jax.random.PRNGKey(0))[0]
+
+    feeds = {"glm_step_tok": sds((slots, 1), "int32"),
+             "glm_step_pos": sds((slots, 1), "int32")}
+    donated = tuple(sds((slots,) + tuple(e.shape), e.dtype) for e in decl)
+    compiled = _no_cache_compile(jax.jit(fwd, donate_argnums=(2,)).lower(
+        params, feeds, donated))
+    mem = compiled.memory_analysis()
+    state_bytes = slots * sum(e.nbytes for e in decl)
+    assert round(state_bytes / 1e9, 2) == 2.14
+    assert mem.alias_size_in_bytes >= state_bytes       # all 10, in place
+    assert mem.temp_size_in_bytes < 256e6               # nothing cache-sized
+    assert 9.5e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11e9
+    assert compiled.as_text().count("tpu_custom_call") == 4 * 3
+
+    bucket = 16384
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = glm.build_prefill(cfg, bucket, cache_len)
+        prog = fluid.default_main_program()
+    prefill = build_step_fn(prog, v["feed_names"],
+                            [x.name for x in v["fetch_vars"]], is_test=True,
+                            platform="tpu")
+    compiled = _no_cache_compile(jax.jit(
+        lambda state, feeds: prefill(state, feeds, jax.random.PRNGKey(0))[0]
+    ).lower(params, {"glm_prefill_ids": sds((1, bucket), "int32"),
+                     "glm_prefill_len": sds((1, 1), "int32")}))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4 * 4 * 3 + 5
+    assert text.count("kept_keys_attn_fwd") >= 5     # a layer's attention
+    # (T, heads x 256) is 16,384 square at these sizes, so q is: no array
+    # has heads or index heads before such a square, and the selection is
+    # held as the tiers' blocks of int8
+    assert not re.search(r"\[(?:\d+,)*(?:64|32),16384,16384\]", text)
+    assert re.search(r"s8\[32,1,128,16384\]", text)
+    mem = compiled.memory_analysis()
+    # the keys and values of 64 heads expanded (1.1 GB), q, the int8
+    # selection (0.27 GB), the routed layer's sorted buffers
+    assert mem.temp_size_in_bytes < 5e9
+    assert weights + state_bytes + mem.temp_size_in_bytes < 14.6e9
+
+
 def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
     """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
     vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
