@@ -7,6 +7,7 @@ lookup_table_op, interpolate_op (ref: paddle/fluid/operators/...). Convs and
 matmuls lower to lax.conv_general_dilated / dot_general so XLA tiles them on
 the MXU; norms/activations are elementwise chains XLA fuses around them.
 """
+import math
 import os
 
 import numpy as np
@@ -175,13 +176,39 @@ def _dropout_keep_mask(ctx, p, shape):
     generation cost about a third of a BERT-base train step on the v5e.
     The rbg key derives from the deterministic per-(op, draw) step key,
     so masks are reproducible and identical between the forward pass
-    and its vjp replay."""
+    and its vjp replay.
+
+    One uint32 generator word serves two elements: each takes a 16-bit
+    field of it and is kept iff the field is below
+    ``round(keep_prob * 65536)``, so the realised keep rate is
+    ``round(keep_prob * 65536) / 65536``, within 2**-17 of ``keep_prob``.
+    The words lie twice along the first axis of even size, shifted by
+    0 in the first half and by 16 in the second: a concatenation, a
+    shift and a compare that fuse into one pass from the half-size word
+    array to the mask.  A shape with no even axis draws ``ceil(n / 2)``
+    words flat and cuts the mask to ``n``."""
     kd = jax.random.key_data(ctx.next_rng()).astype(jnp.uint32).reshape(-1)
     if kd.size < 4:
         kd = jnp.concatenate([kd, kd])
     key = jax.random.wrap_key_data(kd[:4], impl="rbg")
     keep_prob = 1.0 - p
-    return jax.random.bernoulli(key, keep_prob, shape), keep_prob
+    shape = tuple(shape)
+    n = math.prod(shape)
+    axis = next((i for i, s in enumerate(shape) if s % 2 == 0), None)
+    flat = axis is None
+    if flat:
+        axis, word_shape = 0, (-(-n // 2),)
+    else:
+        word_shape = shape[:axis] + (shape[axis] // 2,) + shape[axis + 1:]
+    words = jax.random.bits(key, word_shape, jnp.uint32)
+    twice = jnp.concatenate([words, words], axis=axis)
+    row = lax.broadcasted_iota(jnp.int32, twice.shape, axis)
+    shift = jnp.where(row < word_shape[axis], jnp.uint32(0), jnp.uint32(16))
+    fields = (twice >> shift) & jnp.uint32(0xFFFF)
+    keep = fields < jnp.uint32(round(keep_prob * 65536))
+    if flat:
+        keep = keep[:n].reshape(shape)
+    return keep, keep_prob
 
 
 @register_op("dropout")

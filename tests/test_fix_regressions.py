@@ -2,6 +2,7 @@
 sharding-rule anchoring, density priors, nms_top_k, box_clip rank, stable
 endpoint hashing, NMT pad/eos separation."""
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
@@ -266,3 +267,96 @@ def test_dropout_tiny_rate_is_honoured_exactly():
     drop_rate = (yv == 0).mean()
     assert abs(drop_rate - 0.002) < 0.0008, drop_rate
     np.testing.assert_allclose(yv[yv != 0], 1.0 / 0.998, rtol=1e-6)
+
+
+def _dropout_program(shape, p, seed, name):
+    """A program of one `upscale_in_train` dropout over a fed tensor of
+    `shape`, with the gradient of its sum; returns (prog, startup, y, grad)."""
+    prog, startup = fluid.Program(), fluid.Program()
+    prog.random_seed = seed
+    with fluid.program_guard(prog, startup):
+        x = fluid.data(name, shape, "float32")
+        x.stop_gradient = False
+        y = layers.dropout(x, dropout_prob=p,
+                           dropout_implementation="upscale_in_train")
+        grad = fluid.backward.gradients([layers.reduce_sum(y)], [x])[0]
+    return prog, startup, y, grad
+
+
+def _word_mates(keep):
+    """The two halves of a mask whose elements share a generator word, by
+    `_dropout_keep_mask`'s rule: the halves of the first axis of even size,
+    else of the flat mask (whose last word serves one element alone)."""
+    axis = next((i for i, s in enumerate(keep.shape) if s % 2 == 0), None)
+    if axis is None:
+        flat = keep.reshape(-1)
+        half = (flat.size + 1) // 2
+        return flat[:flat.size - half], flat[half:]
+    lo, hi = np.split(keep, 2, axis=axis)
+    return lo.reshape(-1), hi.reshape(-1)
+
+
+@pytest.mark.parametrize("p", [0.0, 0.1, 0.5, 0.9, 1.0])
+@pytest.mark.parametrize("shape,steps", [
+    ((1024, 1024), 2),       # even: 2**20 elements a step
+    ((1023, 1025), 2),       # no even axis: the flat draw, cut by one
+    ((1, 2048), 2),          # the leading axis is odd, the next one is split
+    ((1,), 512),             # one element a step: the word's other field is cut
+])
+def test_dropout_sixteen_bit_fields(p, shape, steps):
+    """`_dropout_keep_mask` gives each element a 16-bit field of a word that
+    it shares with one other element: (a) the keep rate is 1-p within four
+    standard errors, exact at 0 and 1; (b) two elements of one word are
+    kept together at the product of their rates; (c) the gradient's mask is
+    the forward pass's; (d) a step's mask is not the last step's."""
+    prog, startup, y, grad = _dropout_program(shape, p, seed=41, name="d16x")
+    exe = fluid.Executor()
+    exe.run(startup)
+    xv = np.ones(shape, np.float32)
+    keeps = []
+    for _ in range(steps):
+        yv, gv = (np.asarray(v) for v in exe.run(
+            prog, feed={"d16x": xv}, fetch_list=[y, grad]))
+        assert yv.shape == shape
+        keep = yv != 0
+        np.testing.assert_array_equal(keep, gv != 0)                  # (c)
+        if keep.any():
+            np.testing.assert_allclose(yv[keep], 1.0 / (1.0 - p), rtol=1e-6)
+        keeps.append(keep)
+    keeps = np.stack(keeps)
+    q = 1.0 - p
+    if p in (0.0, 1.0):
+        assert keeps.all() if p == 0.0 else not keeps.any()           # (a)
+        return
+    n = keeps.size
+    assert abs(keeps.mean() - q) <= 4 * np.sqrt(q * (1 - q) / n)      # (a)
+    if keeps[0].size > 1:
+        assert (keeps[0] != keeps[1]).any()                           # (d)
+    else:
+        assert keeps.any() and not keeps.all()                        # (d)
+    lo, hi = _word_mates(keeps[0])
+    if lo.size:
+        both = q * q
+        assert abs((lo & hi).mean() - both) <= 4 * np.sqrt(
+            both * (1 - both) / lo.size)                              # (b)
+
+
+def test_dropout_draws_half_a_word_an_element():
+    """The jitted step of a [64, 768] dropout holds one generator
+    operation, of at most ceil(n / 2) uint32 words."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fluid.lowering import build_step_fn
+
+    prog, _, y, _ = _dropout_program((64, 768), 0.1, seed=7, name="d16_hlo")
+    step = build_step_fn(prog, ["d16_hlo"], [y.name])
+    hlo = jax.jit(step).lower(
+        {}, {"d16_hlo": jax.ShapeDtypeStruct((64, 768), jnp.float32)},
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    ).compiler_ir(dialect="hlo").as_hlo_text()
+    draws = re.findall(r"u32\[([\d,]*)\][^=]*\) rng-bit-generator\(", hlo)
+    assert len(draws) == 1, hlo
+    words = int(np.prod([int(d) for d in draws[0].split(",") if d]))
+    assert words <= (64 * 768 + 1) // 2, draws
