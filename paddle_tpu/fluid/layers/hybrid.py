@@ -1,7 +1,7 @@
 """Layers of hybrid state-space / attention / sparse-expert decoders: RMS
 norm (plain, grouped, gated), squared ReLU, a causal depthwise convolution
 with carried window, the Mamba-2 recurrence (chunked scan and one step),
-a rotary position term (whole or part of a head, half-split or interleaved
+the gated delta rule with a decay a channel (the same two forms), a rotary position term (whole or part of a head, half-split or interleaved
 pairs, plain or YaRN, from the row index or from a slot's position),
 grouped-query attention (causal, windowed, over a slot cache or ring), a
 learned selection of keys and latent attention over it (a prompt's expanded
@@ -15,7 +15,8 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["rms_norm", "relu_squared", "dense_acc32", "causal_conv1d",
-           "mamba2_scan", "mamba2_step", "rotary_embedding", "gqa_attention",
+           "mamba2_scan", "mamba2_step", "kda_scan", "kda_step",
+           "rotary_embedding", "gqa_attention",
            "kv_ring_gather", "dsa_select", "mla_attention", "moe_route_topk"]
 
 
@@ -130,6 +131,60 @@ def mamba2_step(xbc, dt, state, name, heads, head_dim, groups, state_size):
     return _ssm("mamba2_step", xbc, dt, name, heads, head_dim, groups,
                 state_size, {"State": [state]},
                 (xbc.shape[0], heads * head_dim), state.shape, {})
+
+
+def _kda(op, q, k, v, g, beta, name, heads, head_dim, extra, state_shape,
+         attrs):
+    helper = LayerHelper(op)
+    inputs = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+              "ALog": [_param(helper, name + ".A_log", [heads], "float32")],
+              "DtBias": [_param(helper, name + ".dt_bias",
+                                [heads * head_dim], "float32")]}
+    inputs.update(extra)
+    out = _out(helper, q.dtype, q.shape)
+    state_out = _out(helper, "float32", state_shape)
+    attrs = dict(attrs, heads=int(heads), head_dim=int(head_dim))
+    helper.append_op(type=op, inputs=inputs,
+                     outputs={"O": [out], "StateOut": [state_out]},
+                     attrs=attrs)
+    return out, state_out
+
+
+def kda_scan(q, k, v, g, beta, name, heads, head_dim, length=None,
+             state=None, chunk=None, beta_scale=1.0):
+    """The gated delta rule with a decay a channel (Kimi Delta Attention)
+    over a whole right-padded sequence, chunked: ``S_t = (I - beta_t k_t
+    k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T
+    q_t``. ``q``, ``k``, ``v`` (B, T, heads * head_dim) after their
+    convolutions (the op L2-norms q and k a head and scales q), ``g`` (B,
+    T, heads * head_dim) and ``beta`` (B, T, heads) raw: ``g_t =
+    -exp(A_log) softplus(g + dt_bias)``, ``beta_t = beta_scale
+    sigmoid(beta)``. ``state`` (B, heads, head_dim, head_dim) float32 is
+    the state before the first position (zeros without it); positions >=
+    ``length`` (B, 1) leave it alone. -> ``(o (B, T, heads * head_dim),
+    state (B, heads, head_dim, head_dim) float32)``. Parameters
+    ``<name>.A_log`` (heads) and ``<name>.dt_bias`` (heads * head_dim),
+    float32."""
+    extra = {}
+    if length is not None:
+        extra["Len"] = [length]
+    if state is not None:
+        extra["State"] = [state]
+    attrs = {"beta_scale": float(beta_scale)}
+    if chunk:
+        attrs["chunk"] = int(chunk)
+    return _kda("kda_scan", q, k, v, g, beta, name, heads, head_dim, extra,
+                (q.shape[0], heads, head_dim, head_dim), attrs)
+
+
+def kda_step(q, k, v, g, beta, state, name, heads, head_dim, beta_scale=1.0):
+    """One position of the same recurrence for every row: ``q``, ``k``,
+    ``v``, ``g`` (S, heads * head_dim), ``beta`` (S, heads), ``state`` (S,
+    heads, head_dim, head_dim) float32 -> ``(o (S, heads * head_dim),
+    state_out)``."""
+    return _kda("kda_step", q, k, v, g, beta, name, heads, head_dim,
+                {"State": [state]}, state.shape,
+                {"beta_scale": float(beta_scale)})
 
 
 def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None,

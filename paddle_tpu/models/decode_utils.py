@@ -87,7 +87,8 @@ class DecodeModel:
 
 def require_rows_only(model, feature):
     """Features that cut, share, quantise or ship a sequence's state row
-    by row cannot hold a ``fixed`` or a ``ring`` entry, and they know rows
+    by row cannot hold a ``fixed`` or a ``ring`` entry (the message says
+    what each kind would need of them), and they know rows
     only as the K and the V of attention layers, all of one width
     (``DecodeModel.rows_are_kv``): refuse anything else, do not emulate."""
     other = [e for e in model.state if e.kind != "rows"]
@@ -96,12 +97,21 @@ def require_rows_only(model, feature):
         kinds = sorted({e.kind for e in other})
         what = {"fixed": "a fixed-size state per sequence",
                 "ring": "a ring of a window layer's last positions"}
+        need = {"fixed": "a recurrent state (a convolution window, a "
+                         "state-space or a delta-rule state) can be cut or "
+                         "shared only where a fill took a snapshot of it, "
+                         "at a position chosen beforehand, and rolled back "
+                         "only to a copy kept from before the steps to undo",
+                "ring": "a ring has written over what it held of the "
+                        "earlier positions"}
         raise ValueError(
             "%s needs state with one row per position; this model also "
             "carries %s (%s%s), which has no prefix to cut, share or roll "
-            "back" % (feature, " and ".join(what.get(k, k) for k in kinds),
+            "back: %s; nothing here takes such a snapshot or keeps such a "
+            "copy" % (feature, " and ".join(what.get(k, k) for k in kinds),
                       ", ".join(names[:3]),
-                      ", ..." if len(names) > 3 else ""))
+                      ", ..." if len(names) > 3 else "",
+                      "; ".join(need[k] for k in kinds if k in need)))
     if not model.rows_are_kv:
         shapes = sorted({(e.name.rsplit("_", 1)[0], e.shape[-1])
                          for e in model.state})

@@ -2,14 +2,19 @@
 layers: a bias-free projection, a SwiGLU feed-forward (a dense MLP, a
 shared expert), a routed layer of gated experts of which this chip holds a
 range, and the greedy head of a served step. ``models/lfm2.py`` (trained),
-``models/nemotron_h.py`` and ``models/laguna.py`` (served) take them from
-here; each keeps what only it has (its mixers, its state, its programs).
+``models/nemotron_h.py``, ``models/laguna.py``, ``models/glm_moe_dsa.py`` and
+``models/solar_open2.py`` (served) take them from here; each keeps what only it has (its mixers, its state, its programs).
 """
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
-__all__ = ["fc", "swiglu", "routed_gated_experts", "greedy_head"]
+__all__ = ["fc", "swiglu", "routed_gated_experts", "routed_in_calls",
+           "greedy_head"]
+
+MOE_PROMPT_ROWS = 4096     # tokens of a prompt a call of the routed layer
+# takes: its sorted buffers hold tokens x experts per token rows of the
+# hidden width whatever lands here (1.6 GB each at 16,384 tokens x 8 x 6,144)
 
 
 def fc(x, size, name, nfd=1):
@@ -43,6 +48,29 @@ def routed_gated_experts(flat, num_experts, top_k, held, width, name, scope,
     with fluid.name_scope(scope + ".experts"):
         return held_experts_ffn(flat, idx, wt, held, width,
                                 name + ".experts", live=live, gated=True)
+
+
+def routed_in_calls(flat, live, counts, *args, **router):
+    """:func:`routed_gated_experts` over ``flat`` (T, H), a long prompt's
+    rows in calls of MOE_PROMPT_ROWS tokens (where that divides T; else one
+    call): the router chooses a token at a time, so the cuts change no
+    number. ``live`` (T, 1) or None. Each call's counts are appended to
+    ``counts``. -> the held experts' part (T, H)."""
+    t = flat.shape[0]
+    cuts = (range(0, t, MOE_PROMPT_ROWS)
+            if t and t > MOE_PROMPT_ROWS and t % MOE_PROMPT_ROWS == 0
+            else [None])
+    parts = []
+    for at in cuts:
+        rows, alive = flat, live
+        if at is not None:
+            rows = layers.slice(flat, [0], [at], [at + MOE_PROMPT_ROWS])
+            alive = live if live is None else layers.slice(
+                live, [0], [at], [at + MOE_PROMPT_ROWS])
+        part, c = routed_gated_experts(rows, *args, live=alive, **router)
+        parts.append(part)
+        counts.append(c)
+    return parts[0] if len(parts) == 1 else layers.concat(parts, axis=0)
 
 
 def greedy_head(x, vocab, eps, norm_name, head_name):

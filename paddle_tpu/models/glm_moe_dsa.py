@@ -59,9 +59,6 @@ from .decode_utils import (DecodeModel, StateEntry, require_rows_only,
 __all__ = ["GlmMoeDsaConfig", "build_prefill", "build_step", "param_shapes"]
 
 DTYPE = "bfloat16"
-MOE_PROMPT_ROWS = 4096     # tokens of a prompt a call of the routed layer
-# takes: its sorted buffers hold tokens x experts per token rows of the
-# hidden width whatever lands here (1.6 GB each at 16,384 tokens x 8 x 6,144)
 
 
 class GlmMoeDsaConfig:
@@ -266,28 +263,14 @@ def _attend(q, lat, qi, ki, wi, cfg, n, pos=None):
 
 def _feed_forward(w, cfg, i, live, counts, routed):
     """The layer's second half on flat rows (T, H); a prompt's routed layer
-    in calls of MOE_PROMPT_ROWS tokens."""
+    in calls of ``blocks.MOE_PROMPT_ROWS`` tokens."""
     n = "glm%d" % i
     if i < cfg.first_dense:
         with fluid.name_scope("glm.mlp"):
             return blocks.swiglu(w, cfg.ffn, cfg.hidden, n + ".mlp")
-    t = w.shape[0]
-    cuts = (range(0, t, MOE_PROMPT_ROWS)
-            if t and t > MOE_PROMPT_ROWS and t % MOE_PROMPT_ROWS == 0
-            else [None])
-    parts = []
-    for at in cuts:
-        rows, alive = w, live
-        if at is not None:
-            rows = layers.slice(w, [0], [at], [at + MOE_PROMPT_ROWS])
-            alive = live if live is None else layers.slice(
-                live, [0], [at], [at + MOE_PROMPT_ROWS])
-        part, c = blocks.routed_gated_experts(
-            rows, cfg.num_experts, cfg.top_k, cfg.held, cfg.moe_ffn,
-            n + ".moe", "glm.experts", live=alive, scale=cfg.routed_scale)
-        parts.append(part)
-        counts.append(c)
-    part = parts[0] if len(parts) == 1 else layers.concat(parts, axis=0)
+    part = blocks.routed_in_calls(
+        w, live, counts, cfg.num_experts, cfg.top_k, cfg.held, cfg.moe_ffn,
+        n + ".moe", "glm.experts", scale=cfg.routed_scale)
     routed.append(part)
     with fluid.name_scope("glm.experts.shared"):
         shared = blocks.swiglu(w, cfg.shared_ffn, cfg.hidden,

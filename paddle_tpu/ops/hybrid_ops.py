@@ -184,6 +184,209 @@ def _mamba2_scan(ctx, ins, attrs):
             "StateOut": [last.reshape(b, g * hg, p, n)]}
 
 
+KDA_CHUNK = 64          # positions of a chunk of the delta rule's scan (the
+# published kernels' choice); attr ``chunk`` overrides it
+KDA_SUB = 16            # a chunk's decays are taken relative to the start of
+# a sub-chunk of this many rows; inside one every pair has its own factor
+KDA_GROUP = 8           # chunks whose triangular systems are solved together
+# (their float32 pairs alive are heads x 8 x 4 x 16 x 16 x 128: 268 MB)
+KDA_PRECISION = lax.Precision.HIGHEST   # the scan's float32 products
+_kda_dot = functools.partial(jnp.einsum, precision=KDA_PRECISION)
+
+
+def _kda_inputs(q, k, v, g_raw, beta_raw, a_log, dt_bias, attrs):
+    """The delta rule's operands in float32, heads split off: q and k
+    (after their convolutions) L2-normed a head (eps 1e-6), q times
+    ``head_dim^-1/2``; v; the log decay a channel ``g = -exp(A_log[h]) *
+    softplus(g_raw + dt_bias)`` (<= 0); ``beta = beta_scale *
+    sigmoid(beta_raw)`` a head. Leading axes are kept."""
+    h, d = int(attrs["heads"]), int(attrs["head_dim"])
+    lead = q.shape[:-1]
+
+    def heads(x):
+        return x.astype(F32).reshape(lead + (h, d))
+
+    def l2(x):
+        return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    g = -jnp.exp(a_log.astype(F32))[:, None] * jax.nn.softplus(
+        heads(g_raw) + dt_bias.astype(F32).reshape(h, d))
+    beta = float(attrs.get("beta_scale", 1.0)) * jax.nn.sigmoid(
+        beta_raw.astype(F32))
+    return l2(heads(q)) * d ** -0.5, l2(heads(k)), heads(v), g, beta
+
+
+@register_op("kda_step")
+def _kda_step(ctx, ins, attrs):
+    """One position of the gated delta rule with a decay a channel (Kimi
+    Delta Attention) for every slot: ``S' = diag(exp(g)) S``, ``S <- S' +
+    beta k (v - S'^T k)^T``, ``o = S^T q``. Q, K, V (S, heads * head_dim)
+    after their convolutions, G (S, heads * head_dim) and Beta (S, heads)
+    raw (:func:`_kda_inputs`), State (S, heads, head_dim, head_dim)
+    float32, keys down and values across -> O (S, heads * head_dim),
+    StateOut. Float32 multiplies and sums, no product the chip would
+    round."""
+    q, state = ins["Q"][0], ins["State"][0]
+    qf, kf, vf, g, beta = _kda_inputs(
+        q, ins["K"][0], ins["V"][0], ins["G"][0], ins["Beta"][0],
+        ins["ALog"][0], ins["DtBias"][0], attrs)
+    sp = state.astype(F32) * jnp.exp(g)[..., None]          # (S, H, Dk, Dv)
+    r = vf - jnp.sum(sp * kf[..., None], -2)                # (S, H, Dv)
+    sn = sp + (beta[..., None] * kf)[..., None] * r[..., None, :]
+    o = jnp.sum(sn * qf[..., None], -2)
+    return {"O": [o.reshape(q.shape).astype(q.dtype)],
+            "StateOut": [sn.astype(state.dtype)]}
+
+
+def _unit_lower_inverse(low):
+    """(..., n, n) strictly lower triangular L -> ``(I + L)^-1`` by forward
+    substitution, row by row (n - 1 dependent rows; a series in powers of L
+    cancels catastrophically once ``beta`` nears 2)."""
+    n = low.shape[-1]
+    eye = jnp.eye(n, dtype=low.dtype)
+    x = jnp.broadcast_to(eye, low.shape)
+    for t in range(1, n):
+        row = eye[t] - jnp.sum(low[..., t, :t, None] * x[..., :t, :], -2)
+        x = x.at[..., t, :].set(row)
+    return x
+
+
+def _kda_chunks(qf, kf, vf, g, beta, sub):
+    """The part of the chunked delta rule that needs no state, for a batch
+    of chunks at once. qf, kf, vf, g (..., C, D) float32, beta (..., C) ->
+    ``w`` (..., C, D), ``u`` (..., C, D), ``qk`` (..., C, C), ``qe``, ``ke``
+    (..., C, D), ``ge`` (..., D) with which a chunk that starts from the
+    state S gives ``U = u - w S``, ``O = qe S + qk U``, ``S <- exp(ge) S +
+    ke^T U``.
+
+    With G the running sum of g inside the chunk, ``kk[t, s] = sum_d k_t
+    k_s exp(G_t - G_s)`` (s < t) and ``qk[t, s]`` the same with q_t (s <=
+    t): ``exp(-G)`` alone overflows float32 after a dozen strong decays, so
+    rows and columns of different sub-chunks take their decays relative to
+    the start of the row's sub-chunk (both exponents <= 0) and pairs inside
+    a sub-chunk take ``exp(G_t - G_s)`` pair by pair. ``[w | u] = (I +
+    diag(beta) kk)^-1 diag(beta) [k exp(G) | v]`` by forward substitution:
+    inside the sub-chunks row by row, between them block by block."""
+    c, d = qf.shape[-2:]
+    nb, dot = c // sub, _kda_dot
+    gs = jnp.cumsum(g, axis=-2)                              # G, inclusive
+
+    def blocks(x):
+        return x.reshape(x.shape[:-2] + (nb, sub, x.shape[-1]))
+
+    qb, kb, gb = blocks(qf), blocks(kf), blocks(gs)
+    diff = gb[..., :, None, :] - gb[..., None, :, :]         # [t, s, d]
+    seen = jnp.tril(jnp.ones((sub, sub), bool))[..., None]
+    pair = jnp.exp(jnp.where(seen, diff, -jnp.inf)) * kb[..., None, :, :]
+    kk_in = jnp.sum(pair * kb[..., :, None, :], -1)          # (.., nb, s, s)
+    qk_in = jnp.sum(pair * qb[..., :, None, :], -1)
+    kk_rows, qk_rows = [], []
+    for i in range(nb):
+        ref = gs[..., i * sub - 1, :][..., None, :] if i else None
+        parts_k, parts_q = [], []
+        if i:
+            rows = jnp.exp(gb[..., i, :, :] - ref)           # <= 1
+            cols = kf[..., :i * sub, :] * jnp.exp(ref - gs[..., :i * sub, :])
+            parts_k.append(dot("...td,...sd->...ts", kb[..., i, :, :] * rows,
+                               cols))
+            parts_q.append(dot("...td,...sd->...ts", qb[..., i, :, :] * rows,
+                               cols))
+        zeros = jnp.zeros(kk_in.shape[:-3] + (sub, c - (i + 1) * sub), F32)
+        kk_rows.append(jnp.concatenate(
+            parts_k + [kk_in[..., i, :, :], zeros], -1))
+        qk_rows.append(jnp.concatenate(
+            parts_q + [qk_in[..., i, :, :], zeros], -1))
+    qk = jnp.concatenate(qk_rows, -2)                        # s <= t
+    low = jnp.concatenate(kk_rows, -2) * beta[..., None]     # diag(beta) kk
+    low = jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), low, 0.0)
+    rhs = jnp.concatenate([kf * jnp.exp(gs), vf], -1) * beta[..., None]
+    inv = _unit_lower_inverse(jnp.stack(
+        [low[..., i * sub:(i + 1) * sub, i * sub:(i + 1) * sub]
+         for i in range(nb)], -3))                           # (.., nb, s, s)
+    solved = []
+    for i in range(nb):
+        r = rhs[..., i * sub:(i + 1) * sub, :]
+        if i:
+            r = r - dot("...ts,...sd->...td",
+                        low[..., i * sub:(i + 1) * sub, :i * sub],
+                        jnp.concatenate(solved, -2))
+        solved.append(dot("...ts,...sd->...td", inv[..., i, :, :], r))
+    wu = jnp.concatenate(solved, -2)
+    ge = gs[..., -1, :]
+    return (wu[..., :d], wu[..., d:], qk, qf * jnp.exp(gs),
+            kf * jnp.exp(ge[..., None, :] - gs), ge)
+
+
+@register_op("kda_scan")
+def _kda_scan(ctx, ins, attrs):
+    """:func:`_kda_step`'s recurrence over a whole right-padded sequence, in
+    the chunked form. Q, K, V (B, T, heads * head_dim) after their
+    convolutions, G (B, T, heads * head_dim) and Beta (B, T, heads) raw;
+    ``State`` (B, heads, head_dim, head_dim) float32 the state before the
+    first position (zeros without it); ``Len`` (B, 1): positions at or past
+    ``len`` get ``beta = 0`` and ``g = 0``, so the state stops at the last
+    real token. -> O (B, T, heads * head_dim), StateOut.
+
+    KDA_GROUP chunks at a time: what of a chunk needs no state
+    (:func:`_kda_chunks`: the pairs' decays, the triangular system) for the
+    whole group at once, then a scan over the group's chunks that carries
+    the state and makes each chunk's output inside the carry. Nothing of
+    the sequence's length is held in float32 but the op's own inputs: a
+    group's operands are cut from them where they lie."""
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    g_raw, beta_raw = ins["G"][0], ins["Beta"][0]
+    h, d = int(attrs["heads"]), int(attrs["head_dim"])
+    b, t, _ = q.shape
+    c = min(int(attrs.get("chunk") or KDA_CHUNK), t)
+    sub = KDA_SUB if c % KDA_SUB == 0 else c
+    nc = min(KDA_GROUP, -(-t // c))
+    span = nc * c                                            # a group's rows
+    groups = -(-t // span)
+    real = jnp.arange(groups * span, dtype=jnp.int32)[None, :] < (
+        ins["Len"][0].reshape(b, 1).astype(jnp.int32) if ins.get("Len")
+        else t)
+    pad = groups * span - t
+    if pad:
+        q, k, v, g_raw, beta_raw = (
+            jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+            for a in (q, k, v, g_raw, beta_raw))
+    dot = _kda_dot
+    state = (ins["State"][0].astype(F32) if ins.get("State")
+             else jnp.zeros((b, h, d, d), F32))
+
+    def chunked(x):
+        """(B, span, H, .) -> (B, H, nc, C, .)"""
+        x = x.reshape((b, nc, c, h) + x.shape[3:])
+        return jnp.moveaxis(x, 3, 1)
+
+    def group(s, at):
+        cut = [lax.dynamic_slice_in_dim(a, at * span, span, axis=1)
+               for a in (q, k, v, g_raw, beta_raw)]
+        live = lax.dynamic_slice_in_dim(real, at * span, span, axis=1)
+        qf, kf, vf, g, beta = _kda_inputs(
+            *cut, ins["ALog"][0], ins["DtBias"][0], attrs)
+        g = jnp.where(live[:, :, None, None], g, 0.0)
+        beta = jnp.where(live[:, :, None], beta, 0.0)
+        parts = _kda_chunks(chunked(qf), chunked(kf), chunked(vf),
+                            chunked(g), chunked(beta), sub)
+
+        def chunk(s, p):
+            w, u, qk, qe, ke, ge = p                         # (B, H, C, .)
+            u = u - dot("bhck,bhkv->bhcv", w, s)
+            o = dot("bhck,bhkv->bhcv", qe, s) + dot("bhcs,bhsv->bhcv", qk, u)
+            s = s * jnp.exp(ge)[..., None] + dot("bhck,bhcv->bhkv", ke, u)
+            return s, o
+
+        s, o = lax.scan(chunk, s, tuple(jnp.moveaxis(p, 2, 0) for p in parts))
+        o = jnp.moveaxis(o, 0, 2).reshape(b, h, span, d)     # (B, H, span, D)
+        return s, jnp.swapaxes(o, 1, 2).reshape(b, span, h * d).astype(
+            q.dtype)
+
+    state, out = lax.scan(group, state, jnp.arange(groups, dtype=jnp.int32))
+    out = jnp.moveaxis(out, 0, 1).reshape(b, groups * span, h * d)[:, :t]
+    return {"O": [out], "StateOut": [state]}
+
+
 def rotary_inv_freq(rot, theta, yarn=None):
     """(rot / 2,) float32 turning rates of a rotary term over ``rot``
     dimensions, and the factor on its cos and sin. Plain:

@@ -10,8 +10,10 @@ log-sum-exp, so attention memory is O(T·D) instead of O(T²).
 
 Design notes (TPU):
 - grid = (B*H, Tq/block_q); K and V for one (batch, head) ride whole in VMEM
-  (T·D ≤ ~1M elements covers T=16k at D=64 — beyond that, sequence
-  parallelism via parallel/ring_attention.py splits T across chips anyway).
+  (T·D ≤ ~1M elements covers T=16k at D=64 under the default scoped limit;
+  the forward call asks for a larger limit where they need it, as 16k at
+  D=128 does — beyond that, sequence parallelism via
+  parallel/ring_attention.py splits T across chips anyway).
 - QK^T and P·V hit the MXU via dot_general with f32 accumulation; the
   running max/sum rescale is VPU work fused around them.
 - dropout uses a counter-based hash PRNG written in plain integer jnp ops
@@ -46,6 +48,7 @@ _NEG_INF = -1e30
 # and the kernels slice lane/sublane 0.
 _LANES = 128
 _SUBLANES = 8
+_SCOPED_VMEM = 16 << 20    # what a kernel may hold unless it asks for more
 
 
 # ---------------------------------------------------------------------------
@@ -314,6 +317,15 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
         args.append(_kpm3(kpm))
     in_specs += [q_spec, kv_spec, kv_spec]
     args += [q, k, v]
+    # K and V of one head ride whole in VMEM, each double-buffered: past
+    # the compiler's default scoped limit (16,384 positions of 128: 16 MiB
+    # for them alone) the call asks for what it holds and as much again for
+    # its tiles; below it nothing is passed and the call is as it was
+    held = 4 * tk * d * k.dtype.itemsize
+    params = {}
+    if held > _SCOPED_VMEM - (4 << 20):
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=held + _SCOPED_VMEM)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq),
@@ -328,6 +340,7 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
         ),
         interpret=interpret,
         name="flash_fwd",
+        **params,
     )(*args)
     return out, lse
 

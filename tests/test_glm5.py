@@ -567,7 +567,7 @@ def test_a_long_prompts_routed_layer_runs_in_calls_of_a_few_rows(
             scope=w, name=name).run(feeds)
 
     calls, one = routed_parts("glm_prefill_32_one_call")
-    monkeypatch.setattr(glm, "MOE_PROMPT_ROWS", 8)
+    monkeypatch.setattr(glm.blocks, "MOE_PROMPT_ROWS", 8)
     cut_calls, cut = routed_parts("glm_prefill_32_four_calls")
     assert (calls, cut_calls) == (LAYERS - 1, 4 * (LAYERS - 1))
     for a, b in zip(one, cut):

@@ -258,6 +258,91 @@ def test_glm5s_step_and_longest_prefill_at_published_widths(one_chip):
     assert weights + state_bytes + mem.temp_size_in_bytes < 14.6e9
 
 
+def test_solar_open2s_programs_lower_at_published_widths(one_chip):
+    """Solar-Open2's cut (3,308 M parameters, 64 slots x 16,896) lowered
+    for the described chip, both programs: the step with all fourteen state
+    buffers donated (5.26 GB), the 16,384 prefill with the delta-rule
+    layers' mixers in four runs of 4,096 positions from a carried state.
+    Lowered, not compiled: the chip's compiler takes 40 s over the step and
+    100 s over the prefill here (read once, by hand: 0.33 GB and 3.1 GB of
+    scratch beside 11.9 GB of arrays; PERF.md). What is compiled is the one
+    call the default limits refuse: the flash kernel over 16,384 positions
+    of 128 holds K and V of a head whole in fast memory, 16 MiB
+    double-buffered, and asks for the room."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import costs_solar
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import solar_open2 as solar
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "solar_open2_250b.json")))
+    doc["model"] = {k: v for k, v in doc.items()
+                    if not isinstance(v, (dict, list))}
+    m = costs_solar.sizes(doc)
+    cfg = solar.SolarOpen2Config.from_hf(
+        m, router_experts=m["router_experts"], first_expert=m["first_expert"])
+    slots, cache_len = doc["serving"]["slots"], doc["serving"]["cache_len"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {k: sds(s, d) for k, (s, d) in solar.param_shapes(cfg).items()}
+    weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in params.values())
+    assert round(weights / 1e9, 2) == 6.62
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = solar.build_step(cfg, cache_len)
+        prog = fluid.default_main_program()
+    step = build_step_fn(prog, v["feed_names"],
+                         [x.name for x in v["fetch_vars"]], is_test=True,
+                         platform="tpu")
+    names = v["cache_feed_names"]
+    decl = cfg.decode_model(cache_len).state
+    assert round(slots * sum(e.nbytes for e in decl) / 1e9, 2) == 5.26
+
+    def fwd(state, feeds, donated):
+        feeds = dict(feeds)
+        feeds.update(zip(names, donated))
+        return step(state, feeds, jax.random.PRNGKey(0))[0]
+
+    feeds = {"so_step_tok": sds((slots, 1), "int32"),
+             "so_step_pos": sds((slots, 1), "int32")}
+    donated = tuple(sds((slots,) + tuple(e.shape), e.dtype) for e in decl)
+    text = jax.jit(fwd, donate_argnums=(2,)).lower(
+        params, feeds, donated).as_text()
+    assert "tpu_custom_call" in text                    # the experts' gmm
+    assert text.count("tf.aliasing_output") == len(decl) == 14
+
+    bucket = 16384
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = solar.build_prefill(cfg, bucket, cache_len)
+        prog = fluid.default_main_program()
+    prefill = build_step_fn(prog, v["feed_names"],
+                            [x.name for x in v["fetch_vars"]], is_test=True,
+                            platform="tpu")
+    text = jax.jit(
+        lambda state, feeds: prefill(state, feeds, jax.random.PRNGKey(0))[0]
+    ).lower(params, {"so_prefill_ids": sds((1, bucket), "int32"),
+                     "so_prefill_len": sds((1, 1), "int32")}).as_text()
+    # the softmax layer through the flash kernel; no float32 array of the
+    # delta-rule layers is the prompt's length, only a run's
+    assert "tpu_custom_call" in text and "flash_fwd" in text
+    assert "tensor<1x16384x8192xf32>" not in text
+    assert "tensor<1x4096x8192xf32>" in text
+
+    qkv = sds((64, bucket, 128), "bfloat16")
+    compiled = _no_cache_compile(jax.jit(
+        lambda q, k, v: flash_attention(q[None], k[None], v[None],
+                                        causal=True, block_q=512,
+                                        block_k=512)).lower(qkv, qkv, qkv))
+    assert "flash_fwd" in compiled.as_text()
+
+
 def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
     """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
     vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
