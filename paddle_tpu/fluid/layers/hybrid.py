@@ -217,7 +217,8 @@ def rotary_embedding(x, theta, pos=None, rotary_dim=None, yarn=None,
     return out
 
 
-def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None):
+def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None,
+                  offset=None):
     """Softmax attention of ``heads`` query heads over ``kv_heads``
     key/value heads, no position term of its own. Causal over (B, T, .)
     inputs, with ``window`` each query sees the last ``window`` positions
@@ -228,7 +229,10 @@ def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None):
     everywhere else the same blocks through XLA: the op chooses,
     ``ops.hybrid_ops._gqa_attention``); or with ``pos`` (B, 1) over a slot
     cache of which row b sees columns <= pos[b], be it a sequence's rows or
-    a window layer's ring.
+    a window layer's ring; or with ``offset`` (1, 1) a chunk of queries that
+    stand ``offset`` rows into the keys (the rows of the sequence so far):
+    query i sees the columns <= offset + i, and a chunk of 1,024 positions
+    or more takes the flash forward kernel with that offset.
     A plain causal call of 1,024 positions or more runs as the Pallas flash
     kernels on an unsharded TPU program (``ops.hybrid_ops.FLASH_MIN_SEQ``),
     so no (T, T) scores are held for the backward pass."""
@@ -236,6 +240,8 @@ def gqa_attention(q, k, v, heads, kv_heads, pos=None, window=None):
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if pos is not None:
         inputs["Pos"] = [pos]
+    if offset is not None:
+        inputs["Offset"] = [offset]
     attrs = {"heads": int(heads), "kv_heads": int(kv_heads)}
     if window:
         attrs["window"] = int(window)

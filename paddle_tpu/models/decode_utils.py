@@ -55,6 +55,20 @@ class DecodeModel:
     ``(1,) + entry.shape`` array per entry and ``pack(rows)`` turns one
     slot's per-entry arrays into the stacked groups of the wire format
     (both are traced inside one jitted call; identity by default).
+    ``build_chunk(cfg, rows, cache_len)``, with ``chunk_rows`` the rows it
+    wants, is the builder of a model whose prefill can CONTINUE: a program
+    over ``rows`` positions of a prompt that feeds the ids ``(1, rows)``,
+    the real tokens of this chunk ``(1, 1)``, the row of its first position
+    ``(1, 1)`` (``feed_names[:3]``) and the sequence's state so far, one
+    ``(1,) + entry.shape`` array per entry in the declaration's order
+    (``cache_feed_names``, donated; zeros before the first chunk), and
+    fetches the greedy token after the chunk's last real position and the
+    state carried on, in the same order: after the last chunk, what
+    ``build_prefill`` fetches. The engine then fills a long prompt beside
+    live streams a chunk a turn, with a decode step between two chunks
+    (``DecodeEngine._loop``); ``chunk_rows`` is the run length the model's
+    own programs already cut a prompt by, not a knob. A model without one
+    (the default) is filled by its bucket programs alone.
     ``step_counters(aux, live)`` maps the step program's trailing fetch
     (after the state) to lifetime counters of ``DecodeEngine.stats()``.
     ``rows_are_kv`` says that the ``rows`` entries are the K and the V
@@ -67,7 +81,8 @@ class DecodeModel:
 
     def __init__(self, cfg, state, build_prefill, build_step,
                  build_delta=None, build_verify=None, unpack=None,
-                 pack=None, step_counters=None, rows_are_kv=True):
+                 pack=None, step_counters=None, rows_are_kv=True,
+                 build_chunk=None, chunk_rows=None):
         self.cfg = cfg
         self.state = list(state)
         self.rows_are_kv = bool(rows_are_kv)
@@ -75,6 +90,10 @@ class DecodeModel:
         self.build_step = build_step
         self.build_delta = build_delta
         self.build_verify = build_verify
+        if (build_chunk is None) != (chunk_rows is None):
+            raise ValueError("build_chunk and chunk_rows come together")
+        self.build_chunk = build_chunk
+        self.chunk_rows = chunk_rows and int(chunk_rows)
         self.unpack = unpack or (lambda *vals: list(vals))
         self.pack = pack or (lambda rows: list(rows))
         self.step_counters = step_counters

@@ -57,13 +57,16 @@ from . import decoder_blocks as blocks
 from .decode_utils import (DecodeModel, StateEntry, require_rows_only,
                            update_cache)
 
-__all__ = ["SolarOpen2Config", "build_prefill", "build_step", "param_shapes"]
+__all__ = ["SolarOpen2Config", "build_prefill", "build_chunk", "build_step",
+           "param_shapes"]
 
 DTYPE = "bfloat16"
 GQA, KDA = "gqa", "kda"
 KDA_PROMPT_ROWS = 4096     # positions of a prompt a run of a delta-rule
 # layer's mixer takes: q, k, v, the decay and the convolutions' float32
-# copies are runs x 8,192 wide (0.13 GB each, not 0.54 at 16,384)
+# copies are runs x 8,192 wide (0.13 GB each, not 0.54 at 16,384). Also the
+# rows of a chunk (:func:`build_chunk`, ``DecodeModel.chunk_rows``): a chunk
+# is one such run, and one call of the routed layer (MOE_PROMPT_ROWS)
 
 
 class SolarOpen2Config:
@@ -176,7 +179,9 @@ class SolarOpen2Config:
                 "kda_%d" % i, (self.kda_heads, self.kda_head_dim,
                                self.kda_head_dim), np.float32, "fixed"))
         model = DecodeModel(self, state, build_prefill, build_step,
-                            step_counters=self._step_counters)
+                            step_counters=self._step_counters,
+                            build_chunk=build_chunk,
+                            chunk_rows=KDA_PROMPT_ROWS)
         if kv_dtype != "fp32":
             require_rows_only(model, "kv_dtype=%r" % (kv_dtype,))
         return model
@@ -245,17 +250,20 @@ def _kda_args(cfg):
                 beta_scale=cfg.beta_scale)
 
 
-def _kda_prompt(u, plen, prompt_len, cfg, n):
+def _kda_prompt(u, plen, prompt_len, cfg, n, carried=None):
     """A delta-rule layer's mixer over a right-padded prompt u (1, P, H) of
     ``plen`` (1, 1) real tokens, in runs of KDA_PROMPT_ROWS positions, each
     from the windows and the state the run before it hands on (``layers.
     causal_conv1d`` and ``layers.kda_scan`` both take what they carry), so
-    that the float32 copies alive are a run's and not the prompt's. -> (what
-    the mixer adds (1, P, H), [the three windows and the state at the last
-    real token])."""
+    that the float32 copies alive are a run's and not the prompt's; the
+    first from ``carried`` (the three windows and the state an earlier
+    program handed on; zeros without). -> (what the mixer adds (1, P, H),
+    [the three windows and the state at the last real token])."""
     run = (KDA_PROMPT_ROWS if prompt_len > KDA_PROMPT_ROWS
            and prompt_len % KDA_PROMPT_ROWS == 0 else prompt_len)
     windows, s, outs = [None] * 3, None, []
+    if carried:
+        *windows, s = carried
     for at in range(0, prompt_len, run):
         part, left = u, plen
         if run < prompt_len:
@@ -370,6 +378,81 @@ def build_prefill(cfg, prompt_len, cache_len):
             "attn_in": attn_in, "attn_out": attn_out,
             "feed_names": ["so_prefill_ids", "so_prefill_len"],
             "fetch_vars": [nxt] + state}
+
+
+def build_chunk(cfg, rows, cache_len):
+    """A prefill that CONTINUES: ``rows`` positions of a prompt from the
+    state the chunks before handed on (zeros before the first), so that the
+    engine can run a decode step between two chunks of a long prompt. Feeds
+    ``so_chunk_ids`` (1, rows) int64, ``so_chunk_len`` (1, 1) the real
+    tokens of THIS chunk (right-padded), ``so_chunk_start`` (1, 1) the row
+    of its first position (a multiple of ``rows``; ``start + rows <=
+    cache_len``), and the sequence's state, one feed ``(1,) + entry.shape``
+    per declared entry (``cache_feed_names``), all donated. Fetches the
+    greedy token after the chunk's last real position and the state carried
+    on, in the same order (what :func:`build_prefill` fetches once the last
+    chunk has run): K and V with the chunk's rows written at ``start``
+    (zeros past ``len``) and the chunk's queries against the rows ``[0,
+    start + len)`` (``layers.gqa_attention(offset=start)``); each delta-rule
+    layer one run of :func:`_kda_prompt` from the carried windows and state;
+    the routed layer one call. The same runs at the same sizes as a
+    ``rows``-long part of the one-shot program, in the same precisions."""
+    from .gpt import _row_coords
+
+    if not 1 <= rows <= cache_len:
+        raise ValueError("need 1 <= rows (%d) <= cache_len (%d)"
+                         % (rows, cache_len))
+    ids = fluid.data("so_chunk_ids", shape=[1, rows], dtype="int64")
+    clen = fluid.data("so_chunk_len", shape=[1, 1], dtype="int64")
+    start = fluid.data("so_chunk_start", shape=[1, 1], dtype="int64")
+    decl = cfg.decode_model(cache_len).state
+    feeds = [fluid.data("so_chunk_" + e.name, shape=[1] + list(e.shape),
+                        dtype=str(np.dtype(e.dtype))) for e in decl]
+    by_name = {e.name: f for e, f in zip(decl, feeds)}
+    x = layers.reshape(_embed(ids, cfg), [1, rows, cfg.hidden])
+    steps = layers.unsqueeze(layers.range(0, rows, 1, "int64"), [0])
+    valid = layers.cast(layers.less_than(steps, clen), DTYPE)   # (1, rows)
+    valid3 = layers.unsqueeze(valid, [2])
+    live = layers.reshape(valid, [rows, 1])
+    state, counts, routed = [], [], []
+    for i, kind in enumerate(cfg.kinds):
+        n = "so%d" % i
+        u = layers.rms_norm(x, n + ".attn_norm", epsilon=cfg.eps)
+        if kind == GQA:
+            with fluid.name_scope("solar.gqa"):
+                q, k, v = _gqa_inputs(u, cfg, n)
+                k, v = (update_cache(by_name["%s_%d" % (part, i)],
+                                     layers.elementwise_mul(new, valid3),
+                                     pos=start)
+                        for part, new in (("k", k), ("v", v)))
+                y = _gqa_out(layers.gqa_attention(
+                    q, k, v, cfg.heads, cfg.kv_heads, offset=start), u, cfg, n)
+                state += [k, v]
+        else:
+            with fluid.name_scope("solar.kda"):
+                y, handed = _kda_prompt(
+                    u, clen, rows, cfg, n,
+                    carried=[by_name["conv_%s_%d" % (part, i)]
+                             for part in "qkv"] + [by_name["kda_%d" % i]])
+                state += handed
+        x = layers.elementwise_add(x, y)
+        w = layers.reshape(
+            layers.rms_norm(x, n + ".mlp_norm", epsilon=cfg.eps),
+            [rows, cfg.hidden])
+        y = _feed_forward(w, cfg, i, live, counts, routed)
+        x = layers.elementwise_add(
+            x, layers.reshape(y, [1, rows, cfg.hidden]))
+    # a chunk with no real token (never dispatched by the engine) reads row 0
+    last = layers.elementwise_max(
+        layers.elementwise_sub(clen, layers.fill_constant([1], "int64", 1)),
+        layers.fill_constant([1], "int64", 0))
+    logits, nxt = _head(layers.gather_nd(x, _row_coords(last)), cfg)
+    names = [f.name for f in feeds]
+    return {"ids": ids, "len": clen, "start": start, "next": nxt,
+            "logits": logits, "state": state,
+            "feed_names": ["so_chunk_ids", "so_chunk_len", "so_chunk_start"]
+            + names,
+            "cache_feed_names": names, "fetch_vars": [nxt] + state}
 
 
 def build_step(cfg, cache_len):

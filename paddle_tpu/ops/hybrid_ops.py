@@ -463,24 +463,27 @@ FLASH_BLOCK = 512       # query and key tile of the flash kernels here:
 # 41.7 and 128 reads 81.5; 1,024 runs out of fast memory (PERF.md)
 
 
-def _flash_gqa(q, k, v, nh, nkv):
+def _flash_gqa(q, k, v, nh, nkv, offset=None):
     """Causal attention through the Pallas flash kernels (forward, dq,
     dk/dv; ``ops/pallas_attention.py``): no (T, T) score array exists in
     either pass. The kernels take one key/value head per query head, so
     the key/value heads are repeated; the sum over a group in the
-    backward pass is the repeat's own transpose."""
+    backward pass is the repeat's own transpose. With ``offset`` (a traced
+    int32 scalar) the queries stand that many rows into the keys
+    (``flash_attention``'s ``q_offset``; forward only)."""
     from .pallas_attention import flash_attention
 
     b, t, _ = q.shape
     dh = q.shape[-1] // nh
 
     def heads_first(x, n):
-        return jnp.swapaxes(x.reshape(b, t, n, dh), 1, 2)   # (B, n, T, dh)
+        return jnp.swapaxes(x.reshape(b, -1, n, dh), 1, 2)  # (B, n, T, dh)
 
     kh = jnp.repeat(heads_first(k, nkv), nh // nkv, axis=1)
     vh = jnp.repeat(heads_first(v, nkv), nh // nkv, axis=1)
     out = flash_attention(heads_first(q, nh), kh, vh, causal=True,
-                          block_q=FLASH_BLOCK, block_k=FLASH_BLOCK)
+                          block_q=FLASH_BLOCK, block_k=FLASH_BLOCK,
+                          q_offset=offset)
     return jnp.swapaxes(out, 1, 2).reshape(b, t, nh * dh)
 
 
@@ -553,17 +556,20 @@ def _gqa_attention(ctx, ins, attrs):
     kv_heads * dh). With ``Pos`` (B, 1) the keys are a slot cache and row
     b sees columns <= pos[b]: the rows of a sequence, or a ring of the
     last Tk positions written at ``position mod Tk`` (every column of it
-    once ``pos >= Tk - 1``; a softmax does not mind the order). Without
-    ``Pos``, Tq == Tk and the mask is causal, with ``window`` > 0 cut to
-    the last ``window`` positions. The op chooses from what it sees, no
-    caller sets anything. A sequence longer than its window, on the TPU,
-    in an unsharded program, with a head size and a window that tile
-    (multiples of 128): the Pallas kernel for the band
+    once ``pos >= Tk - 1``; a softmax does not mind the order). With
+    ``Offset`` (1, 1) the queries are a chunk of a longer sequence whose rows
+    so far are the keys: query i stands at row ``offset + i`` and sees the
+    columns up to it (Tk >= offset + Tq). With neither, Tq == Tk and the
+    mask is causal, with ``window`` > 0 cut to the last ``window`` positions.
+    The op chooses from what it sees, no caller sets anything. A sequence
+    longer than its window, on the TPU, in an unsharded program, with a
+    head size and a window that tile (multiples of 128): the Pallas kernel
+    for the band
     (:func:`_window_gqa`); any other sequence longer than its window
     (the CPU, a mesh, small windows): the banded blocks through XLA
     (:func:`_banded_gqa`), which are also the kernel's gradient. A plain
-    causal call of at least FLASH_MIN_SEQ positions runs through the
-    flash kernels on the TPU (a training sequence of 4,096 would
+    causal call of at least FLASH_MIN_SEQ positions, a chunk's too, runs
+    through the flash kernels on the TPU (a training sequence of 4,096 would
     otherwise hold (B, heads, T, T) float32 scores); shorter calls, other
     platforms and a sharded program take the products below. Which of the
     two window paths a lowering took is counted:
@@ -577,6 +583,12 @@ def _gqa_attention(ctx, ins, attrs):
     if ins.get("Pos") and window:
         raise ValueError("gqa_attention over a slot cache takes no window: "
                          "a window layer's cache is a ring of that length")
+    offset = None
+    if ins.get("Offset"):
+        if ins.get("Pos") or window:
+            raise ValueError("gqa_attention of a chunk (Offset) takes "
+                             "neither Pos nor a window")
+        offset = ins["Offset"][0].reshape(()).astype(jnp.int32)
     unsharded_tpu = (getattr(ctx, "platform", None) == "tpu"
                      and not getattr(ctx, "mesh_axes", None))
     if window and tq > window:
@@ -588,7 +600,7 @@ def _gqa_attention(ctx, ins, attrs):
         obs.inc("ops.gqa_attention.window_banded")
         return single(_banded_gqa(q, k, v, nh, nkv, window))
     if not ins.get("Pos") and tq >= FLASH_MIN_SEQ and unsharded_tpu:
-        return single(_flash_gqa(q, k, v, nh, nkv))
+        return single(_flash_gqa(q, k, v, nh, nkv, offset))
     qg = q.reshape(b, tq, nkv, nh // nkv, dh)
     kg = k.reshape(b, tk, nkv, dh)
     vg = v.reshape(b, tk, nkv, dh)
@@ -599,7 +611,9 @@ def _gqa_attention(ctx, ins, attrs):
         seen = at[None, :] <= ins["Pos"][0].reshape(b, 1).astype(jnp.int32)
         seen = seen[:, None, None, None, :]
     else:
-        seen = (at[None, :] <= at[:, None])[None, None, None]
+        rows = (at if offset is None
+                else jnp.arange(tq, dtype=jnp.int32) + offset)
+        seen = (at[None, :] <= rows[:, None])[None, None, None]
     probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
     ctxv = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v.dtype), vg,
                       preferred_element_type=F32)

@@ -85,9 +85,13 @@ def _keep_mask(seed, qi, kj, bq, bk, dropout_p):
     return bits >= threshold
 
 
-def _causal_mask_tile(qi, kj, bq, bk):
+def _causal_mask_tile(qi, kj, bq, bk, offset=None):
+    """Query row r of tile qi sees key column c of tile kj where ``r >= c``;
+    with ``offset`` the queries stand ``offset`` positions into the keys."""
     rows = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     cols = kj * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if offset is not None:
+        rows = rows + offset
     return rows >= cols
 
 
@@ -95,12 +99,16 @@ def _causal_mask_tile(qi, kj, bq, bk):
 # forward kernel
 # ---------------------------------------------------------------------------
 def _fwd_kernel(seed_ref, kpm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                sm_scale, causal, dropout_p, block_k, nk):
+                sm_scale, causal, dropout_p, block_k, nk, off_ref=None):
     qi = pl.program_id(1)
     bq = q_ref.shape[1]
     d = q_ref.shape[2]
     q = q_ref[0]                                     # (bq, D)
     seed = fold_bh_seed(seed_ref[0, 0], pl.program_id(0))
+    # the row of the keys at which query 0 stands (a chunk of a longer
+    # sequence against the rows written so far); None: row 0, and nothing
+    # of it in the lowering
+    offset = None if off_ref is None else off_ref[0, 0]
 
     def body(j, carry):
         m, l, acc = carry
@@ -113,7 +121,8 @@ def _fwd_kernel(seed_ref, kpm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
             # kpm block is (1, SUBLANES, tk) broadcast rows; take row 0
             s = s + kpm_ref[0, 0:1, pl.ds(j * block_k, block_k)]
         if causal:
-            s = jnp.where(_causal_mask_tile(qi, j, bq, block_k), s, _NEG_INF)
+            s = jnp.where(_causal_mask_tile(qi, j, bq, block_k, offset), s,
+                          _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                                # (bq, bk)
         alpha = jnp.exp(m - m_new)
@@ -136,7 +145,8 @@ def _fwd_kernel(seed_ref, kpm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     acc0 = jnp.zeros((bq, d), jnp.float32)
     if causal:
         # only tiles that intersect the lower triangle of this q block
-        upper = ((qi + 1) * bq + block_k - 1) // block_k
+        last = (qi + 1) * bq if offset is None else offset + (qi + 1) * bq
+        upper = (last + block_k - 1) // block_k
         upper = jnp.minimum(upper, nk)
     else:
         upper = nk
@@ -297,7 +307,10 @@ def _kpm3(kpm):
 
 
 def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
-              block_k, heads, interpret):
+              block_k, heads, interpret, offset=None):
+    """``offset`` (1, 1) int32 or None: the row of the keys at which query 0
+    stands (:func:`flash_attention`'s ``q_offset``), read from SMEM beside
+    the seed; None adds no operand and the call is as it was."""
     bh, tq, d = q.shape
     tk = k.shape[1]
     nq = tq // block_q
@@ -312,6 +325,10 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
     )
     in_specs = [seed_spec]
     args = [seed]
+    if offset is not None:
+        kernel = functools.partial(_fwd_kernel_offset, kernel)
+        in_specs.append(seed_spec)
+        args.append(offset)
     if kpm is not None:
         in_specs.append(kpm_spec)
         args.append(_kpm3(kpm))
@@ -339,7 +356,7 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if offset is None else "flash_fwd_offset",
         **params,
     )(*args)
     return out, lse
@@ -347,6 +364,10 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
 
 def _fwd_kernel_nokpm(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw):
     _fwd_kernel(seed_ref, None, q_ref, k_ref, v_ref, o_ref, lse_ref, **kw)
+
+
+def _fwd_kernel_offset(kernel, seed_ref, off_ref, *refs, **kw):
+    kernel(seed_ref, *refs, off_ref=off_ref, **kw)
 
 
 def _dq_kernel_nokpm(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -526,14 +547,25 @@ _flash.defvjp(
 
 def flash_attention(q, k, v, key_padding_mask=None, seed=None, sm_scale=None,
                     causal=False, dropout_p=0.0, block_q=128, block_k=128,
-                    interpret=False):
+                    interpret=False, q_offset=None):
     """Flash multi-head attention.
 
     q: (B, H, Tq, D); k, v: (B, H, Tk, D).
     key_padding_mask: optional additive f32 (B, Tk) (-inf/-1e30 at pads).
     seed: int32 scalar array driving dropout bits (ignored if dropout_p=0).
+    q_offset: optional int32 scalar array (traced: one program serves every
+    value), the row of the keys at which query 0 stands: query i sees the
+    keys ``j <= q_offset + i``. A chunk of a longer sequence against the
+    rows written so far (``Tk >= q_offset + Tq``); key tiles past the
+    chunk's last query are never visited, so the chunks of a sequence cost
+    its causal half between them. Causal and forward only (the call goes
+    round the custom vjp). Without it the kernel has no such operand and
+    lowers as it did.
     Returns (B, H, Tq, D) in q.dtype.
     """
+    if q_offset is not None and not causal:
+        raise ValueError("flash_attention(q_offset=...) is a causal call: "
+                         "the offset places the queries among the keys")
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     tq, tk = q.shape[2], k.shape[2]
@@ -570,10 +602,19 @@ def flash_attention(q, k, v, key_padding_mask=None, seed=None, sm_scale=None,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    out = _flash(
-        q, k, v, kpm, seed, float(sm_scale), bool(causal), float(dropout_p),
-        bq, bk, interpret,
-    )
+    if q_offset is not None:
+        b, h, _, d = q.shape
+        out, _ = _fwd_call(
+            q.reshape(b * h, -1, d), k.reshape(b * h, -1, d),
+            v.reshape(b * h, -1, d), kpm, seed, float(sm_scale), True,
+            float(dropout_p), bq, bk, h, interpret,
+            offset=jnp.asarray(q_offset, jnp.int32).reshape((1, 1)))
+        out = out.reshape(q.shape)
+    else:
+        out = _flash(
+            q, k, v, kpm, seed, float(sm_scale), bool(causal),
+            float(dropout_p), bq, bk, interpret,
+        )
     if pad_q:
         out = out[:, :, :tq, :]
     return out

@@ -441,6 +441,31 @@ class _Slot:
         self.hist = hist
 
 
+class _Fill:
+    """A fill in progress: a long prompt that goes into its slot a chunk a
+    turn of the dispatch loop (``DecodeModel.build_chunk``), so that the
+    live streams get a step between two chunks. ``at`` is the row the next
+    chunk starts at; ``state`` the sequence's state so far, device arrays
+    that the chunk program consumes and hands back, OUTSIDE the
+    :class:`SlotCache` (the step program reads and writes every slot's
+    buffers, live or not: a half-built state must not sit in one); ``nxt``
+    the last dispatched chunk's token, still on the device. ``ctx`` is the
+    context of the request's ``decode.prefill`` span, which runs from the
+    first chunk to the seat, and ``elapsed`` its time so far: what
+    ``_seat`` and ``_observe_prefill`` ask of a span."""
+
+    __slots__ = ("req", "slot", "at", "state", "nxt", "t0", "ctx")
+
+    def __init__(self, req, slot, state, ctx):
+        self.req, self.slot, self.state, self.ctx = req, slot, state, ctx
+        self.at = 0
+        self.nxt = None
+        self.t0 = time.monotonic()
+
+    def elapsed(self):
+        return time.monotonic() - self.t0
+
+
 class DecodeEngine:
     """Continuous-batching decode engine over a prefill/step program
     pair. The model hands over its builders and the declaration of the
@@ -555,6 +580,16 @@ class DecodeEngine:
                 with fluid.program_guard(fluid.Program(), fluid.Program()):
                     pv = model.build_prefill(cfg, b, self.cache_len)
                     prefill[b] = (fluid.default_main_program(), pv)
+        # the chunk program of a model whose prefill can continue: a
+        # long prompt then fills its slot a chunk a turn beside live
+        # streams (_chunked); the bucket programs stay for the rest. No
+        # bucket longer than a chunk: nothing would ever be cut
+        chunk = None
+        if (prefill and model.build_chunk is not None
+                and self.prompt_buckets[-1] > model.chunk_rows):
+            with fluid.program_guard(fluid.Program(), fluid.Program()):
+                cv = model.build_chunk(cfg, model.chunk_rows, self.cache_len)
+                chunk = (fluid.default_main_program(), cv)
         # delta-prefill ladder (prefix-pool hits + session resumes):
         # same bucket widths as cold prefill, suffix-sized at use
         delta = {}
@@ -573,7 +608,7 @@ class DecodeEngine:
         persist = {}
         all_progs = ([step_prog] + [p for p, _ in prefill.values()]
                      + [p for p, _ in delta.values()]
-                     + ([verify[0]] if verify is not None else []))
+                     + [pv[0] for pv in (chunk, verify) if pv is not None])
         for prog in all_progs:
             for v in prog.list_vars():
                 if not getattr(v, "persistable", False):
@@ -624,6 +659,21 @@ class DecodeEngine:
             self._prefill_preds[b].ledger_tag = (
                 "decode.prefill:%s" % self.name)
             self._prefill_vars[b] = pv
+        # jit_fwd_chunk_<rows>: not a name that holds "fwd_prefill_"
+        self._chunk_pred = self._chunk_vars = self._chunk_zeros = None
+        if chunk is not None:
+            prog, self._chunk_vars = chunk
+            self._chunk_pred = Predictor(
+                prog, self._chunk_vars["feed_names"],
+                self._chunk_vars["fetch_vars"], scope=persist,
+                name="chunk_%d" % model.chunk_rows,
+                donate_feeds=self._chunk_vars["cache_feed_names"])
+            self._chunk_pred.ledger_tag = (
+                "decode.prefill.chunk:%s" % self.name)
+            # what a sequence carries before its first chunk: one dispatch
+            self._chunk_zeros = jax.jit(lambda: [
+                jax.numpy.zeros((1,) + tuple(e.shape), e.dtype)
+                for e in model.state])
         self._delta_preds = {}
         for b, (prog, dv) in delta.items():
             self._delta_preds[b] = Predictor(
@@ -654,6 +704,9 @@ class DecodeEngine:
         # error); and what is left of the step that produced them
         self._outbox = []
         self._spent = None
+        # the fill in progress (_Fill), at most one: its slot is held,
+        # neither free nor live, and nothing else is admitted meanwhile
+        self._fill = None
 
         self._q = queue.Queue(maxsize=int(queue_capacity))
         self._stop_event = threading.Event()
@@ -754,10 +807,11 @@ class DecodeEngine:
         return self
 
     def stop(self, drain=True, timeout=30.0):
-        """Stop admitting work. ``drain=True`` finishes every live slot
-        and queued request first; ``drain=False`` fails them with
-        :class:`EngineClosedError`. Once the dispatch thread has ended the
-        slots' state is freed on the device. Idempotent."""
+        """Stop admitting work. ``drain=True`` finishes every live slot,
+        the fill in progress and every queued request first;
+        ``drain=False`` fails them with :class:`EngineClosedError`. Once
+        the dispatch thread has ended the slots' state is freed on the
+        device. Idempotent."""
         with self._admit_lock:
             self._closed = True
         if not drain:
@@ -775,6 +829,9 @@ class DecodeEngine:
                 break
             req.handle._fail(EngineClosedError(
                 "engine %r stopped before prefill" % self.name))
+        if self._fill is not None:
+            self._end_fill(EngineClosedError(
+                "engine %r stopped mid-prefill" % self.name))
         for i, s in enumerate(self._slots):
             if s is not None:
                 self._slots[i] = None
@@ -1109,9 +1166,10 @@ class DecodeEngine:
         return report.findings
 
     def warmup(self, check_hbm=True):
-        """Pre-build the step program and every prompt-bucket prefill
-        through the compile-cache disk tier (zero ``compile_start`` on
-        a restarted server). Returns the per-program report."""
+        """Pre-build the step program, every prompt-bucket prefill and the
+        chunk program of a model that has one through the compile-cache
+        disk tier (zero ``compile_start`` on a restarted server). Returns
+        the per-program report."""
         if check_hbm:
             self.check_hbm_budget()
         self.check_ladder()
@@ -1126,6 +1184,13 @@ class DecodeEngine:
                 np.zeros((1, b), np.int64), 1, b))
             report.append({"program": "prefill", "bucket": b,
                            "source": source})
+        if self._chunk_pred is not None:
+            source = self._chunk_pred.warm(self._chunk_feeds(
+                np.zeros((1, self._model.chunk_rows), np.int64), 1, 0,
+                self._jax.eval_shape(self._chunk_zeros)))
+            self._jax.block_until_ready(self._chunk_zeros())
+            report.append({"program": "chunk",
+                           "rows": self._model.chunk_rows, "source": source})
         for b in sorted(self._delta_preds):
             cache1 = (1, self.cfg.num_layers, self.cache_len,
                       self.cfg.hidden)
@@ -1164,7 +1229,15 @@ class DecodeEngine:
         delivery wakes run while the device runs step n+1. Whatever
         fails, retires or abandons streams, and the loop before it idles
         or returns, flushes the outbox first: a stream sees its tokens in
-        order, then exactly one end."""
+        order, then exactly one end.
+
+        While a fill is in progress (:class:`_Fill`: a long prompt of a
+        model that declares a chunk program, admitted beside live streams)
+        a turn is: ONE chunk dispatched with no host sync, then the step,
+        then the delivery as above, so the live streams wait a chunk and
+        not the whole prompt for their next token. The turn after the last
+        chunk seats the request (its state into the slot, its first
+        token) before anything else is admitted."""
         phase = self._phase_s
         cpu = time.thread_time()
         while True:
@@ -1178,12 +1251,16 @@ class DecodeEngine:
             # self time: the slot fills it ran have their own total
             phase["admit_seconds"] += sp.seconds - (
                 phase["prefill_seconds_total"] - filled)
-            live = sum(1 for s in self._slots if s is not None)
             if self._abort:
                 self._fail_all()
                 return
+            if self._fill is not None:
+                self._fill_chunk()
+            live = sum(1 for s in self._slots if s is not None)
             if live == 0:
                 self._flush()
+                if self._fill is not None:
+                    continue  # nobody to step for: the next chunk, or the seat
                 if self._stop_event.is_set() and self._q.empty():
                     return
                 self._idle()
@@ -1217,6 +1294,9 @@ class DecodeEngine:
                 break
             req.handle._fail(EngineClosedError(
                 "engine %r stopped before prefill" % self.name))
+        if self._fill is not None:
+            self._end_fill(EngineClosedError(
+                "engine %r stopped mid-prefill" % self.name))
         self._fail_live(EngineClosedError(
             "engine %r stopped mid-generation" % self.name))
 
@@ -1224,9 +1304,44 @@ class DecodeEngine:
         for i, s in enumerate(self._slots):
             if s is not None and s.handle.cancelled:
                 self._retire(i, "cancelled")
+        f = self._fill
+        if f is None:
+            return
+        # a fill in progress is looked at between its chunks, as a queued
+        # request is before its prefill: no chip time for an answer nobody
+        # is waiting for
+        if f.req.handle.cancelled:
+            self._end_fill()
+            return
+        late = self._deadline_error(f.req, "between chunks of its prefill")
+        if late is not None:
+            self._end_fill(late)
+
+    def _deadline_error(self, req, where):
+        """The error ``req`` fails with if its deadline has passed (counted
+        and reported here: it is shed BEFORE more chip time goes into an
+        answer nobody is waiting for), else None."""
+        now = time.monotonic()
+        if req.deadline is None or now <= req.deadline:
+            return None
+        self._bump("deadline_miss")
+        waited_ms = round(1000 * (now - req.handle.t_submit), 3)
+        obs.event("deadline_miss", source="serving", model=self.name,
+                  engine="decode", waited_ms=waited_ms)
+        return DeadlineExceededError(
+            "deadline expired after %s ms %s (model %r)"
+            % (waited_ms, where, self.name))
 
     def _admit(self):
-        """Prefill queued requests into free slots."""
+        """Prefill queued requests into free slots. A fill in progress
+        (:class:`_Fill`) is seated first, once its last chunk is out;
+        while it has chunks to go nothing else is admitted, and the
+        request that opens one ends the round: further free slots wait
+        their turn, as they wait behind one another's whole programs."""
+        if self._fill is not None:
+            if self._fill.at < self._fill.req.plen:
+                return
+            self._seat_fill()
         for i in range(self.slots):
             if self._slots[i] is not None:
                 continue
@@ -1244,21 +1359,13 @@ class DecodeEngine:
                     self._bump("cancelled")
                     req = None
                     continue
-                now = time.monotonic()
-                if req.deadline is not None and now > req.deadline:
-                    # shed BEFORE prefill: no chip time for an answer
-                    # nobody is waiting for
-                    self._bump("deadline_miss")
-                    waited_ms = round(
-                        1000 * (now - req.handle.t_submit), 3)
-                    obs.event("deadline_miss", source="serving",
-                              model=self.name, engine="decode",
-                              waited_ms=waited_ms)
-                    req.handle._fail(DeadlineExceededError(
-                        "deadline expired after %s ms in decode queue "
-                        "(model %r)" % (waited_ms, self.name)))
+                late = self._deadline_error(req, "in decode queue")
+                if late is not None:
+                    req.handle._fail(late)
                     req = None
             self._fill_slot(i, req)
+            if self._fill is not None:
+                break
         obs.set_gauge("serving.queue_depth.%s" % self.name,
                       self._q.qsize())
 
@@ -1266,7 +1373,9 @@ class DecodeEngine:
         """Route one admitted request onto its cheapest fill path (remote
         handoff adopt, session-resume delta, prefix-pool full-hit adopt,
         prefix-pool delta, or cold prefill) and run it inside the
-        request's fill span. The finished ``decode.queue`` wait
+        request's fill span; a cold prefill that goes in chunks
+        (:meth:`_chunked`) is only opened here, and runs a chunk a turn of
+        the loop. The finished ``decode.queue`` wait
         (``t_submit`` to now) is recorded where the request leaves the
         queue; a sampled request's spans also go to its JSONL trace,
         from the same exits."""
@@ -1298,6 +1407,11 @@ class DecodeEngine:
             tenant=req.tenant)
         if ctx is None or not ctx.sampled:
             ctx = qctx
+        if path == "cold" and self._chunked(req):
+            if ctx is not None and ctx.sampled:
+                ctx = ctx.child()  # as a span's entry does
+            self._fill = _Fill(req, slot, self._chunk_zeros(), ctx)
+            return
         fields = {}
         if ctx is not None and path == "cold":
             # cost-model annotation of a sampled request's trace only: an
@@ -1355,6 +1469,12 @@ class DecodeEngine:
         names = self._prefill_vars[bucket]["feed_names"]
         return {names[0]: ids, names[1]: np.asarray([[plen]], np.int64)}
 
+    def _prefill_error(self, e):
+        """Count and report a prefill program's dispatch that raised."""
+        self._bump("prefill_errors")
+        obs.event("prefill_error", source="serving", model=self.name,
+                  error="%s: %s" % (type(e).__name__, str(e)[:200]))
+
     def _prefill(self, slot, req, sp):
         ids = np.zeros((1, req.bucket), np.int64)
         ids[0, :req.plen] = req.prompt
@@ -1366,11 +1486,17 @@ class DecodeEngine:
                 return_numpy=False)
         except Exception as e:  # noqa: BLE001 — fail the request, not the loop
             sp.note(error=type(e).__name__)
-            self._bump("prefill_errors")
-            obs.event("prefill_error", source="serving", model=self.name,
-                      error="%s: %s" % (type(e).__name__, str(e)[:200]))
+            self._prefill_error(e)
             req.handle._fail(e)
             return
+        self._bump("prefill_rows_computed", req.bucket)
+        self._bump("prefills")
+        self._seat_prefilled(slot, req, sp, nxt, state)
+
+    def _seat_prefilled(self, slot, req, sp, nxt, state):
+        """What every cold fill ends in, the bucket program's and the last
+        chunk's alike: the state into the slot, the wait for the first
+        token, the prompt's rows banked, the seat."""
         if self.kv_dtype == "int8":
             # the prefill program stays fp32; quantize per row on the
             # way into the resident buffers (same codec as the wire)
@@ -1384,7 +1510,6 @@ class DecodeEngine:
         else:
             self._cache.write_slot(slot, *state)
         tok = self._first_token(nxt)
-        self._bump("prefill_rows_computed", req.bucket)
         if self._prefix_pool is not None:
             # bank this prompt's rows (fp32, pre-residency) so the
             # next shared-prefix request adopts instead of recomputing
@@ -1395,8 +1520,97 @@ class DecodeEngine:
             except Exception:  # noqa: BLE001 — caching is best-effort
                 self._bump("prefix_insert_errors")
         self._observe_prefill(req, sp)
-        self._bump("prefills")
         self._seat(slot, req, sp, tok, req.plen)
+
+    # -- a fill in chunks --------------------------------------------------
+    def _chunked(self, req):
+        """Whether a cold fill goes in chunks, from what the engine sees:
+        the model declared a chunk program; a slot is live (with none there
+        is nobody to stall, and the one-shot bucket program is the cheaper
+        fill: no carried rows in and out); the prompt's bucket is longer
+        than a chunk (else there is nothing to cut); every chunk's rows lie
+        inside the cache (a block write past its end would be clamped onto
+        earlier rows)."""
+        rows = self._model.chunk_rows
+        return (self._chunk_pred is not None
+                and any(s is not None for s in self._slots)
+                and req.bucket > rows
+                and -(-req.plen // rows) * rows <= self.cache_len)
+
+    def _chunk_feeds(self, ids, n, start, state):
+        """The chunk program's feeds under its own names: the ids, the
+        chunk's real tokens, the row of its first position, the state."""
+        names = self._chunk_vars["feed_names"]
+        feeds = {names[0]: ids, names[1]: np.asarray([[n]], np.int64),
+                 names[2]: np.asarray([[start]], np.int64)}
+        feeds.update(zip(self._chunk_vars["cache_feed_names"], state))
+        return feeds
+
+    def _fill_chunk(self):
+        """Dispatch the next chunk of the fill in progress and do not wait
+        for it: the step that follows queues behind it on the device. A
+        dispatch that raises ends the request as a failed prefill does."""
+        f = self._fill
+        req, rows = f.req, self._model.chunk_rows
+        n = min(rows, req.plen - f.at)
+        ids = np.zeros((1, rows), np.int64)
+        ids[0, :n] = req.prompt[f.at:f.at + n]
+        feeds = self._chunk_feeds(ids, n, f.at, f.state)
+        f.state = None  # consumed by the dispatch, whatever comes of it
+        sp = obs.span("decode.prefill.chunk", ctx=f.ctx, proc=self._proc,
+                      request=req.handle.id, slot=f.slot, start=f.at, rows=n)
+        try:
+            with sp:
+                if _conc._on:
+                    _conc.note_blocking("device.dispatch")
+                f.nxt, *f.state = self._chunk_pred.run(
+                    feeds, return_numpy=False)
+        except Exception as e:  # noqa: BLE001 — fail the request, not the loop
+            self._prefill_error(e)
+            self._end_fill(e)
+            return
+        finally:
+            self._phase_s["prefill_seconds_total"] += sp.seconds
+        f.at += n
+        self._bump("fill_chunks")
+        self._bump("prefill_rows_chunked", rows)
+
+    def _fill_span(self, f, **fields):
+        """The request's ``decode.prefill`` span, from the record's opening
+        to now: into the ring and, for a sampled request, its trace, under
+        the context the chunks' spans were children of."""
+        t1 = time.monotonic()
+        fields.update(proc=self._proc, request=f.req.handle.id, slot=f.slot,
+                      bucket=f.req.bucket, plen=f.req.plen, path="chunked",
+                      chunks=-(-f.at // self._model.chunk_rows))
+        obs.record_span("decode.prefill", f.t0, t1, **fields)
+        if f.ctx is not None and f.ctx.sampled:
+            obs.export_span("decode.prefill", f.ctx,
+                            time.time() - (t1 - f.t0), t1 - f.t0, fields)
+
+    def _seat_fill(self):
+        """The last chunk is out: close the record and seat its request.
+        The step that went out after that chunk has been waited for, so the
+        first token is on its way to the host already."""
+        f, self._fill = self._fill, None
+        with obs.span("decode.prefill.seat") as sp:
+            self._bump("chunked_fills")
+            self._seat_prefilled(f.slot, f.req, f, f.nxt, f.state)
+        self._phase_s["prefill_seconds_total"] += sp.seconds
+        self._fill_span(f)
+
+    def _end_fill(self, error=None):
+        """Close the record with no seat: its carried arrays are dropped,
+        its slot is free again, and its request ends once: cancelled, or
+        failed with ``error``."""
+        f, self._fill = self._fill, None
+        if error is None:
+            f.req.handle._finish("cancelled")
+            self._bump("cancelled")
+            self._fill_span(f, end="cancelled")
+        else:
+            f.req.handle._fail(error)
+            self._fill_span(f, error=type(error).__name__)
 
     def _adopt_prefix(self, slot, req, sp):
         """FULL prefix-pool hit: the pool holds rows for the whole
@@ -1999,12 +2213,19 @@ class DecodeEngine:
         ``steps_ahead``, the steps dispatched while the step before's
         tokens were still undelivered (all but the first after an idle
         stretch or a failure: the loop is pipelined by one step);
+        ``chunked_fills``, the cold fills that went into their slot in
+        chunks beside live streams (``prefills`` counts the bucket
+        programs' alone), ``fill_chunks`` the chunk programs dispatched for
+        them and ``prefill_rows_chunked`` the rows those computed
+        (``prefill_rows_computed``: the bucket programs' rows);
         and where the dispatch thread's time went, in seconds, in the
         loop's order: ``admit_seconds`` (self time),
         ``prefill_seconds_total`` (of it ``prefill_sync_seconds`` waiting
-        for the device), ``dispatch_seconds`` (step n+1 goes out),
-        ``emit_seconds`` (step n's tokens handed to their streams, and
-        the decide after each sync: next feeds, which slots finish),
+        for the device; a chunked fill's share is its chunks' dispatches
+        and its seat, not the steps between), ``dispatch_seconds`` (step
+        n+1 goes out), ``emit_seconds`` (step n's tokens handed to their
+        streams, and the decide after each sync: next feeds, which slots
+        finish),
         ``release_seconds`` (what is left of step n dropped; the thread
         waits for the GIL behind the streams it just woke, while the
         device runs step n+1), ``sync_seconds`` (the wait for step n+1's
@@ -2033,6 +2254,7 @@ class DecodeEngine:
                   "prefill_errors", "adopt_errors", "step_errors",
                   "cache_copy_steps", "steps_ahead",
                   "prefill_rows_computed", "prefill_rows_saved",
+                  "chunked_fills", "fill_chunks", "prefill_rows_chunked",
                   "prefix_full_hits", "delta_prefills", "delta_errors",
                   "spec_rounds", "spec_proposed", "spec_accepted",
                   "spec_fallback_steps", "hibernated", "resumed"):

@@ -343,6 +343,77 @@ def test_solar_open2s_programs_lower_at_published_widths(one_chip):
     assert "flash_fwd" in compiled.as_text()
 
 
+def test_solar_open2s_chunk_program_at_published_widths(one_chip):
+    """The program a long prompt fills its slot by, a chunk a turn
+    (`solar.build_chunk`: 4,096 positions from the carried state), lowered
+    for the described chip: all fourteen carried arrays donated and aliased
+    (82 MB in, the same buffers out), the softmax layer through the flash
+    forward kernel with a query offset, no float32 array of a delta-rule
+    layer longer than the chunk. Compiled by hand once (30 s here: 1.67 GB
+    of scratch where the 16,384 program holds 3.08; PERF.md); what is
+    compiled here is the kernel's call alone at the chunk's shapes: 4,096
+    queries of 64 heads against the 16,896 rows of the cache, K and V of a
+    head whole in fast memory, the offset a scalar beside the seed."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from benchmark import costs_solar
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import solar_open2 as solar
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "solar_open2_250b.json")))
+    doc["model"] = {k: v for k, v in doc.items()
+                    if not isinstance(v, (dict, list))}
+    m = costs_solar.sizes(doc)
+    cfg = solar.SolarOpen2Config.from_hf(
+        m, router_experts=m["router_experts"], first_expert=m["first_expert"])
+    cache_len = doc["serving"]["cache_len"]
+    model = cfg.decode_model(cache_len)
+    rows = model.chunk_rows
+    assert rows == 4096 and model.build_chunk is solar.build_chunk
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {k: sds(s, d) for k, (s, d) in solar.param_shapes(cfg).items()}
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = solar.build_chunk(cfg, rows, cache_len)
+        prog = fluid.default_main_program()
+    chunk = build_step_fn(prog, v["feed_names"],
+                          [x.name for x in v["fetch_vars"]], is_test=True,
+                          platform="tpu")
+    names = v["cache_feed_names"]
+    assert v["feed_names"][3:] == names and len(names) == len(model.state)
+
+    def fwd(state, feeds, donated):
+        feeds = dict(feeds)
+        feeds.update(zip(names, donated))
+        return chunk(state, feeds, jax.random.PRNGKey(0))[0]
+
+    feeds = {"so_chunk_ids": sds((1, rows), "int32"),
+             "so_chunk_len": sds((1, 1), "int32"),
+             "so_chunk_start": sds((1, 1), "int32")}
+    donated = tuple(sds((1,) + tuple(e.shape), e.dtype) for e in model.state)
+    text = jax.jit(fwd, donate_argnums=(2,)).lower(
+        params, feeds, donated).as_text()
+    assert text.count("tf.aliasing_output") == len(model.state) == 14
+    assert "flash_fwd_offset" in text
+    assert "tensor<1x4096x8192xf32>" in text
+    assert "tensor<1x16896x8192xf32>" not in text
+
+    q = sds((64, rows, 128), "bfloat16")
+    kv = sds((64, cache_len, 128), "bfloat16")
+    compiled = _no_cache_compile(jax.jit(
+        lambda q, k, v, at: flash_attention(
+            q[None], k[None], v[None], causal=True, block_q=512,
+            block_k=512, q_offset=at)).lower(q, kv, kv, sds((), "int32")))
+    assert "flash_fwd_offset" in compiled.as_text()
+
+
 def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
     """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
     vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
