@@ -164,7 +164,14 @@ def kda_scan(q, k, v, g, beta, name, heads, head_dim, length=None,
     ``length`` (B, 1) leave it alone. -> ``(o (B, T, heads * head_dim),
     state (B, heads, head_dim, head_dim) float32)``. Parameters
     ``<name>.A_log`` (heads) and ``<name>.dt_bias`` (heads * head_dim),
-    float32."""
+    float32. On the TPU, in an unsharded program, with ``head_dim`` a
+    multiple of 128, T whole chunks of 64 positions and ``chunk`` left
+    alone (a run of 4,096, a bucket of 8,192 or 16,384), the lowering is
+    one Pallas kernel that keeps a head's chunk and its float32 state in
+    fast memory (``ops/pallas_kda.py``); every other call (a CPU, a mesh, a
+    narrower head, a ragged T, another ``chunk``) takes the same float32
+    arithmetic through XLA, which is also the kernel's gradient. The op
+    chooses from what it sees; nothing here selects."""
     extra = {}
     if length is not None:
         extra["Len"] = [length]

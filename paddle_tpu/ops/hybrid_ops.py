@@ -188,8 +188,10 @@ KDA_CHUNK = 64          # positions of a chunk of the delta rule's scan (the
 # published kernels' choice); attr ``chunk`` overrides it
 KDA_SUB = 16            # a chunk's decays are taken relative to the start of
 # a sub-chunk of this many rows; inside one every pair has its own factor
-KDA_GROUP = 8           # chunks whose triangular systems are solved together
-# (their float32 pairs alive are heads x 8 x 4 x 16 x 16 x 128: 268 MB)
+# (the XLA form's; the Pallas kernel takes its pairs one by one in blocks of 8)
+KDA_GROUP = 8           # the XLA form's: chunks whose triangular systems are
+# solved together (its float32 pairs alive are heads x 8 x 4 x 16 x 16 x 128:
+# 268 MB; the Pallas kernel holds a head's chunk in fast memory and has none)
 KDA_PRECISION = lax.Precision.HIGHEST   # the scan's float32 products
 _kda_dot = functools.partial(jnp.einsum, precision=KDA_PRECISION)
 
@@ -317,15 +319,12 @@ def _kda_chunks(qf, kf, vf, g, beta, sub):
             kf * jnp.exp(ge[..., None, :] - gs), ge)
 
 
-@register_op("kda_scan")
-def _kda_scan(ctx, ins, attrs):
-    """:func:`_kda_step`'s recurrence over a whole right-padded sequence, in
-    the chunked form. Q, K, V (B, T, heads * head_dim) after their
-    convolutions, G (B, T, heads * head_dim) and Beta (B, T, heads) raw;
-    ``State`` (B, heads, head_dim, head_dim) float32 the state before the
-    first position (zeros without it); ``Len`` (B, 1): positions at or past
-    ``len`` get ``beta = 0`` and ``g = 0``, so the state stops at the last
-    real token. -> O (B, T, heads * head_dim), StateOut.
+def _kda_scan_xla(q, k, v, g_raw, beta_raw, a_log, dt_bias, state, length,
+                  attrs):
+    """:func:`_kda_scan` through XLA, what every platform can run and the
+    Pallas kernel's comparison and gradient. ``state`` (B, heads, head_dim,
+    head_dim) float32, ``length`` (B, 1) or None (every position real) ->
+    (o, state after the last real position).
 
     KDA_GROUP chunks at a time: what of a chunk needs no state
     (:func:`_kda_chunks`: the pairs' decays, the triangular system) for the
@@ -333,8 +332,6 @@ def _kda_scan(ctx, ins, attrs):
     the state and makes each chunk's output inside the carry. Nothing of
     the sequence's length is held in float32 but the op's own inputs: a
     group's operands are cut from them where they lie."""
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    g_raw, beta_raw = ins["G"][0], ins["Beta"][0]
     h, d = int(attrs["heads"]), int(attrs["head_dim"])
     b, t, _ = q.shape
     c = min(int(attrs.get("chunk") or KDA_CHUNK), t)
@@ -343,16 +340,13 @@ def _kda_scan(ctx, ins, attrs):
     span = nc * c                                            # a group's rows
     groups = -(-t // span)
     real = jnp.arange(groups * span, dtype=jnp.int32)[None, :] < (
-        ins["Len"][0].reshape(b, 1).astype(jnp.int32) if ins.get("Len")
-        else t)
+        length.reshape(b, 1).astype(jnp.int32) if length is not None else t)
     pad = groups * span - t
     if pad:
         q, k, v, g_raw, beta_raw = (
             jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
             for a in (q, k, v, g_raw, beta_raw))
     dot = _kda_dot
-    state = (ins["State"][0].astype(F32) if ins.get("State")
-             else jnp.zeros((b, h, d, d), F32))
 
     def chunked(x):
         """(B, span, H, .) -> (B, H, nc, C, .)"""
@@ -363,8 +357,7 @@ def _kda_scan(ctx, ins, attrs):
         cut = [lax.dynamic_slice_in_dim(a, at * span, span, axis=1)
                for a in (q, k, v, g_raw, beta_raw)]
         live = lax.dynamic_slice_in_dim(real, at * span, span, axis=1)
-        qf, kf, vf, g, beta = _kda_inputs(
-            *cut, ins["ALog"][0], ins["DtBias"][0], attrs)
+        qf, kf, vf, g, beta = _kda_inputs(*cut, a_log, dt_bias, attrs)
         g = jnp.where(live[:, :, None, None], g, 0.0)
         beta = jnp.where(live[:, :, None], beta, 0.0)
         parts = _kda_chunks(chunked(qf), chunked(kf), chunked(vf),
@@ -384,6 +377,84 @@ def _kda_scan(ctx, ins, attrs):
 
     state, out = lax.scan(group, state, jnp.arange(groups, dtype=jnp.int32))
     out = jnp.moveaxis(out, 0, 1).reshape(b, groups * span, h * d)[:, :t]
+    return out, state
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
+def _kda_scan_call(q, k, v, g_raw, beta_raw, a_log, dt_bias, state, length,
+                   heads, head_dim, beta_scale, interpret):
+    """The kernel's call as one jitted function: the call sites of a program
+    (twelve in Solar's 16,384 prefill) and the programs of a process share
+    ONE trace of the kernel's body and one lowering a module, where each
+    site alone costs most of a second of a serving process's set-up."""
+    from .pallas_kda import kda_scan_fwd
+
+    return kda_scan_fwd(q, k, v, g_raw, beta_raw, a_log, dt_bias, state,
+                        length, heads, head_dim, beta_scale, chunk=KDA_CHUNK,
+                        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12))
+def _kda_scan_kernel(q, k, v, g_raw, beta_raw, a_log, dt_bias, state, length,
+                     heads, head_dim, beta_scale, interpret=False):
+    """:func:`_kda_scan_xla`'s result from the Pallas kernel
+    (``ops/pallas_kda.py`` ``kda_scan_fwd``: a head's chunk and its float32
+    state in fast memory, the same float32 products at ``highest``).
+    Forward only; its gradient is the XLA form's."""
+    return _kda_scan_call(q, k, v, g_raw, beta_raw, a_log, dt_bias, state,
+                          length, heads, head_dim, beta_scale, interpret)
+
+
+def _kda_scan_kernel_bwd(heads, head_dim, beta_scale, interpret, res, g):
+    *given, length = res
+    attrs = {"heads": heads, "head_dim": head_dim, "beta_scale": beta_scale}
+    return jax.vjp(lambda *a: _kda_scan_xla(*a, length, attrs),
+                   *given)[1](g) + (None,)
+
+
+_kda_scan_kernel.defvjp(
+    lambda *a: (_kda_scan_kernel(*a), a[:9]), _kda_scan_kernel_bwd)
+
+
+@register_op("kda_scan")
+def _kda_scan(ctx, ins, attrs):
+    """:func:`_kda_step`'s recurrence over a whole right-padded sequence, in
+    the chunked form. Q, K, V (B, T, heads * head_dim) after their
+    convolutions, G (B, T, heads * head_dim) and Beta (B, T, heads) raw;
+    ``State`` (B, heads, head_dim, head_dim) float32 the state before the
+    first position (zeros without it); ``Len`` (B, 1): positions at or past
+    ``len`` get ``beta = 0`` and ``g = 0``, so the state stops at the last
+    real token. -> O (B, T, heads * head_dim), StateOut.
+
+    The op chooses from what it sees: on the TPU, in an unsharded program,
+    with heads of a multiple of 128 channels and T whole chunks of the
+    published 64 positions, the Pallas kernel (:func:`_kda_scan_kernel`);
+    anything else (a CPU, a mesh, a narrow head, a ragged T, another
+    ``chunk``) the same arithmetic through XLA (:func:`_kda_scan_xla`),
+    which is also the kernel's gradient. Which one a lowering took is
+    counted: ``ops.kda_scan.kernel`` / ``ops.kda_scan.xla``."""
+    from .. import observability as obs
+
+    q = ins["Q"][0]
+    h, d = int(attrs["heads"]), int(attrs["head_dim"])
+    b, t, _ = q.shape
+    state = (ins["State"][0].astype(F32) if ins.get("State")
+             else jnp.zeros((b, h, d, d), F32))
+    length = ins["Len"][0] if ins.get("Len") else None
+    given = (q, ins["K"][0], ins["V"][0], ins["G"][0], ins["Beta"][0],
+             ins["ALog"][0], ins["DtBias"][0], state)
+    if (getattr(ctx, "platform", None) == "tpu"
+            and not getattr(ctx, "mesh_axes", None) and d % 128 == 0
+            and int(attrs.get("chunk") or KDA_CHUNK) == KDA_CHUNK
+            and t % KDA_CHUNK == 0):
+        obs.inc("ops.kda_scan.kernel")
+        if length is None:
+            length = jnp.full((b, 1), t, jnp.int32)
+        out, state = _kda_scan_kernel(
+            *given, length, h, d, float(attrs.get("beta_scale", 1.0)))
+    else:
+        obs.inc("ops.kda_scan.xla")
+        out, state = _kda_scan_xla(*given, length, attrs)
     return {"O": [out], "StateOut": [state]}
 
 

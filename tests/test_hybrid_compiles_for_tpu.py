@@ -414,6 +414,47 @@ def test_solar_open2s_chunk_program_at_published_widths(one_chip):
     assert "flash_fwd_offset" in compiled.as_text()
 
 
+def test_the_delta_rules_scan_kernel_at_solar_open2s_widths(one_chip):
+    """The Pallas kernel of the delta rule's chunked scan
+    (`ops/pallas_kda.py` `kda_scan_fwd`) compiled for the described chip at
+    the shapes of one run of a Solar-Open2 layer: 4,096 positions of 64
+    heads of 128 channels from a carried float32 state. The chip's compiler
+    takes the lanes' rolls, the transposes and the float32 products at
+    `highest` (interpret mode shows none of that), no float32 copy of the
+    operands goes through HBM beside the call, and the op takes the kernel
+    from what it sees."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import LOWERINGS
+
+    heads, dim, rows = 64, 128, 4096
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    class Ctx:
+        platform, mesh_axes = "tpu", None
+
+    wide = sds((1, rows, heads * dim), "bfloat16")
+    ins = {"Q": [wide], "K": [wide], "V": [wide],
+           "G": [sds((1, rows, heads * dim), "float32")],
+           "Beta": [sds((1, rows, heads), "float32")],
+           "ALog": [sds((heads,), "float32")],
+           "DtBias": [sds((heads * dim,), "float32")],
+           "State": [sds((1, heads, dim, dim), "float32")],
+           "Len": [sds((1, 1), "int32")]}
+    compiled = _no_cache_compile(jax.jit(
+        lambda ins: LOWERINGS["kda_scan"](
+            Ctx(), ins, dict(heads=heads, head_dim=dim, beta_scale=2.0))
+    ).lower(ins))
+    assert "kda_scan_fwd" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the raw beta transposed (1 MB) and the decays' sign, nothing a run long
+    assert mem.temp_size_in_bytes < 8e6
+
+
 def test_fused_vocabulary_head_at_berts_widths_holds_one_chunk(one_chip):
     """BERT-base's head and its gradient (256 x 128 rows, hidden 768,
     vocabulary 30,522, bfloat16 operands as under AMP), compiled for the
