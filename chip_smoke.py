@@ -12,8 +12,9 @@ the models (depth as published too; weights random from a seed):
   server    GPT (hidden 768 x 12 layers) behind DecodeEngine -> ModelRegistry
             -> ServingServer, concurrent HTTP :generate; every emitted
             token's logit within a stated tolerance of a float32 reference
-  kernels   every Pallas kernel in the tree compiled by Mosaic (flash
-            attention fwd+bwd, fused layer norm fwd+bwd) against references
+  kernels   flash attention fwd+bwd compiled by Mosaic, with and without
+            dropout and a key-padding mask, against references (the
+            kernels a cell runs are held to their XLA twins by that cell)
   callback  one py_func program (host callback) trains on the chip
   multichip only when several chips are visible: BERT-base data-parallel
             (CompiledProgram) and dp x tp (DistributedProgram) over all of
@@ -48,14 +49,12 @@ CHIP = dict(
     gpt_prompt_lens=(5, 12, 16, 30, 50, 64), gpt_max_new=16,
     # BERT-base head shapes: 12 heads of 64
     flash_shapes=((8, 12, 128, 64), (8, 12, 512, 64)), flash_block=128,
-    ln_shape=(48 * 128, 768),
 )
 REHEARSAL = dict(
     bert_batch=8, bert_seq=32, bert_steps=4,
     gpt_cache_len=48, gpt_buckets=(8, 16), gpt_slots=2,
     gpt_prompt_lens=(3, 6, 10, 14), gpt_max_new=4,
     flash_shapes=((1, 2, 32, 16), (1, 2, 64, 16)), flash_block=16,
-    ln_shape=(40, 32),
 )
 
 # Tolerances, each with its reason.
@@ -65,8 +64,6 @@ REHEARSAL = dict(
 # largest reference element the chip runs of PR 21 measured 3.6e-3 .. 9.7e-3
 # over output and gradients.
 FLASH_RTOL = 2e-2
-# Fused layer norm has no matmul: float32 VPU arithmetic on both sides.
-LN_RTOL = 1e-4
 # Serving: the engine's programs run float32 weights at the TPU's default
 # matmul precision (bf16 passes); the teacher-forced reference runs at
 # "highest". An emitted token may differ from the reference argmax only
@@ -443,11 +440,11 @@ def phase_kernels(sz, cache):
     import numpy as np
 
     from paddle_tpu.ops import pallas_attention as pa
-    from paddle_tpu.ops.pallas_layernorm import fused_layer_norm
 
     interpret = sz is not CHIP   # Mosaic on the chip, interpreter in rehearsal
     block, seed, drop = sz["flash_block"], 11, 0.1
     rng = np.random.default_rng(0)
+    cases = []
 
     def masked_reference(q, k, v, kpm, keep, p):
         """reference_attention with the kernel's own dropout mask."""
@@ -486,36 +483,13 @@ def phase_kernels(sz, cache):
                     "flash_attention %s kpm=%s dropout=%s: rel err "
                     "(out, dq, dk, dv) = %s > %s"
                     % (shape, kpm is not None, p, errs, FLASH_RTOL))
-                note("kernels", kernel="flash_attention", shape=shape,
-                     key_padding_mask=kpm is not None, dropout=p,
-                     compiled_by="interpreter" if interpret else "mosaic",
-                     rel_err_out_dq_dk_dv=[round(e, 6) for e in errs],
-                     rtol=FLASH_RTOL)
-
-    n, hdim = sz["ln_shape"]
-    x, w = (jnp.asarray(rng.normal(size=(n, hdim)), jnp.float32)
-            for _ in range(2))
-    g, bta = (jnp.asarray(rng.normal(size=(hdim,)), jnp.float32)
-              for _ in range(2))
-
-    def ln_reference(x, g, b):
-        mean = jnp.mean(x, -1, keepdims=True)
-        var = jnp.var(x, -1, keepdims=True)
-        return (x - mean) * jax.lax.rsqrt(var + 1e-5) * g + b
-
-    def fused(x, g, b):
-        return fused_layer_norm(x, g, b, interpret=interpret)
-
-    errs = [rel_err(a, b) for a, b in zip(
-        out_and_grads(fused, w, x, g, bta),
-        out_and_grads(ln_reference, w, x, g, bta))]
-    assert all(np.isfinite(errs)) and max(errs) <= LN_RTOL, (
-        "fused_layer_norm %s: rel err (y, dx, dgamma, dbeta) = %s > %s"
-        % ((n, hdim), errs, LN_RTOL))
-    note("kernels", kernel="fused_layer_norm", shape=(n, hdim),
+                cases.append(dict(
+                    shape=shape, key_padding_mask=kpm is not None,
+                    dropout=p,
+                    rel_err_out_dq_dk_dv=[round(e, 6) for e in errs]))
+    note("kernels", kernel="flash_attention",
          compiled_by="interpreter" if interpret else "mosaic",
-         rel_err_y_dx_dgamma_dbeta=[float("%.3g" % e) for e in errs],
-         rtol=LN_RTOL, **cache.take())
+         rtol=FLASH_RTOL, cases=cases, **cache.take())
 
 
 # ---------------------------------------------------------------------------

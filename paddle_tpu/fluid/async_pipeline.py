@@ -40,7 +40,6 @@ stale staging (TrainGuard restarts readers, which bumps the reader
 generation and drops reader-level staging the same way).
 """
 import collections
-import os
 import queue as _queue_mod
 import threading
 import time
@@ -52,9 +51,10 @@ from .. import observability as obs
 from ..observability import runhealth as _runhealth
 from ..analysis import concurrency as _conc
 
-__all__ = ["PipelinedRunner", "ASYNC_DEPTH_ENV"]
+__all__ = ["PipelinedRunner"]
 
-ASYNC_DEPTH_ENV = "PADDLE_TPU_ASYNC_DEPTH"
+# classic double buffering: stage batch N+1 while the device computes N
+DEFAULT_DEPTH = 2
 
 _END = object()
 
@@ -80,7 +80,7 @@ class PipelinedRunner:
         self._scope = scope
         self._return_numpy = return_numpy
         if depth is None:
-            depth = int(os.environ.get(ASYNC_DEPTH_ENV, "2"))
+            depth = DEFAULT_DEPTH
         self._depth = max(1, int(depth))
         self._window = max(1, int(window if window is not None else depth))
         self._q = _queue_mod.Queue(self._depth)

@@ -11,12 +11,11 @@ TPU-native design notes:
   fixed-length masked scan: TPU wants static shapes, so decoding runs
   `max_step_num + 1` steps with finished beams frozen (mathematically
   identical output, lengths reported exactly). When `max_step_num` is
-  None the bound comes from PADDLE_TPU_MAX_DECODE_LEN (default 256).
+  None the bound is MAX_DECODE_LEN (256).
 - `dynamic_lstmp` lowers to the `lstmp` scan op (ops/rnn_ops.py), the
   projected-LSTM of Sak et al. 2014 (ref rnn.py:1512).
 """
 import collections
-import os
 
 import numpy as np
 
@@ -28,6 +27,9 @@ __all__ = [
     "RNNCell", "GRUCell", "LSTMCell", "rnn", "Decoder",
     "BeamSearchDecoder", "dynamic_decode", "dynamic_lstmp",
 ]
+
+# steps `dynamic_decode` scans when its caller names no `max_step_num`
+MAX_DECODE_LEN = 256
 
 
 def _lay():
@@ -440,14 +442,13 @@ def dynamic_decode(decoder, inits=None, max_step_num=None,
     delta: a fixed-length masked scan instead of a While/TensorArray
     loop — finished beams are frozen by the decoder itself, so outputs
     match the reference's early-exit loop wherever it would have stopped;
-    the bound is max_step_num (or PADDLE_TPU_MAX_DECODE_LEN, default 256,
-    when None)."""
+    the bound is max_step_num (MAX_DECODE_LEN when None)."""
     from . import control_flow
 
     L = T = _lay()
 
     if max_step_num is None:
-        tmax = int(os.environ.get("PADDLE_TPU_MAX_DECODE_LEN", 256))
+        tmax = MAX_DECODE_LEN
     else:
         tmax = int(max_step_num) + 1
 

@@ -8,7 +8,6 @@ matmuls lower to lax.conv_general_dilated / dot_general so XLA tiles them on
 the MXU; norms/activations are elementwise chains XLA fuses around them.
 """
 import math
-import os
 
 import numpy as np
 import jax
@@ -499,37 +498,10 @@ def _batch_norm(ctx, ins, attrs):
 
 @register_op("layer_norm")
 def _layer_norm(ctx, ins, attrs):
-    import os
-
     x = ins["X"][0]
     eps = attrs.get("epsilon", 1e-5)
     begin = attrs.get("begin_norm_axis", 1)
     axes = tuple(range(begin, x.ndim))
-    platform = ctx.platform or jax.default_backend()
-    if (
-        os.environ.get("PADDLE_TPU_PALLAS_LN")
-        and platform == "tpu"
-        and not ctx.mesh_axes
-        and begin == x.ndim - 1
-    ):
-        # fused pallas kernel (opt-in; see ops/pallas_layernorm.py). The
-        # kernel's own mean/rstd become Mean/Variance (no extra passes),
-        # squeezed exactly like the default path squeezes its keepdims stats
-        from .pallas_layernorm import fused_layer_norm
-
-        scale = ins["Scale"][0] if ins.get("Scale") else None
-        bias = ins["Bias"][0] if ins.get("Bias") else None
-        out, mean, rstd = fused_layer_norm(x, scale, bias, eps,
-                                           return_stats=True)
-        var = 1.0 / (rstd * rstd) - eps
-        lead = x.shape[:begin]
-        mean_kd = mean.reshape(lead + (1,) * (x.ndim - begin))
-        var_kd = var.reshape(lead + (1,) * (x.ndim - begin))
-        return {
-            "Y": [out],
-            "Mean": [jnp.squeeze(mean_kd)],
-            "Variance": [jnp.squeeze(var_kd)],
-        }
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axes, keepdims=True)
     var = jnp.var(xf, axis=axes, keepdims=True)

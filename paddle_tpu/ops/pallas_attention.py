@@ -24,7 +24,8 @@ Design notes (TPU):
   over k-tiles, both re-forming P from the saved lse.
 
 `flash_attention` carries a custom_vjp; `reference_attention` is the plain
-jax oracle used by tests and by the CPU lowering fallback.
+jax oracle used by tests and by the `fused_multihead_attention` lowering
+(`flash_attention` itself is called by `hybrid_ops._flash_gqa`).
 """
 import functools
 
@@ -830,10 +831,10 @@ from .registry import register_op, single  # noqa: E402
 
 @register_op("fused_multihead_attention")
 def _fused_mha_lowering(ctx, ins, attrs):
-    """Q/K/V: (B, H, T, D). Pallas flash kernels on a single TPU device;
-    the plain-jax path otherwise (CPU, and under a device mesh — a
-    pallas_call is an opaque custom call the SPMD partitioner can't split,
-    while the einsum formulation partitions over (dp, tp) for free)."""
+    """Q/K/V: (B, H, T, D). Ring attention under an 'sp'-sharded mesh,
+    the plain-jax einsum formulation otherwise: XLA fuses it on one chip
+    (faster there than the flash kernel at every length measured, PERF.md
+    §7) and partitions it over (dp, tp) for free."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     kpm = ins["KeyPaddingMask"][0] if ins.get("KeyPaddingMask") else None
     causal = bool(attrs.get("causal", False))
@@ -841,33 +842,6 @@ def _fused_mha_lowering(ctx, ins, attrs):
     if attrs.get("is_test", False) or ctx.is_test:
         p = 0.0
     key = ctx.next_rng() if p > 0.0 else None
-    import os
-    platform = ctx.platform or jax.default_backend()
-    # measured on v5e (BERT-base): XLA's own attention fusion beats the
-    # pallas flash kernel at EVERY length tried — T=128: 104k vs 80k,
-    # T=512: 91k vs 69k, T=1024: 68k vs 51k, T=2048: 42k vs 34k tok/s —
-    # so auto-engage is off by default; set PADDLE_TPU_FLASH_MIN_SEQ to a
-    # threshold to opt in (the kernel is correctness-tested and remains
-    # the basis for the masked/dropout ring-attention block path).
-    _flash_env = os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ")
-    min_t = int(_flash_env) if _flash_env else (1 << 30)
-    use_pallas = (
-        platform == "tpu"
-        and not ctx.mesh_axes
-        and not os.environ.get("PADDLE_TPU_DISABLE_PALLAS")
-        and q.shape[2] >= min_t
-    )
-    if use_pallas:
-        seed = None
-        if key is not None:
-            seed = jax.random.randint(
-                key, (), 0, 2 ** 31 - 1, dtype=jnp.int32
-            )
-        out = flash_attention(
-            q, k, v, kpm, seed=seed, causal=causal, dropout_p=p
-        )
-        return single(out)
-
     # Under an 'sp'-sharded mesh, exact RING attention keeps every chip
     # holding only its sequence shard of K/V (rotated over ICI via
     # ppermute) instead of the all-gather the einsum formulation would

@@ -18,6 +18,7 @@ of surfacing raw orbax internals.
 """
 import json
 import os
+import threading
 import time
 import warnings
 
@@ -33,6 +34,18 @@ __all__ = ["save_checkpoint", "load_checkpoint", "latest_step",
 # managers kept open across saves so async writes can complete in the
 # background; finalize()/Executor.close()/process exit flushes them
 _managers = {}
+
+# orbax numbers every save with ONE process-wide "current operation id"
+# (orbax ..._src/futures/synchronization.OperationIdGenerator): a save
+# advances it and then reads it back, several times, while it enqueues —
+# the step- and item-directory creation signals are keyed by what those
+# reads return. Two saves that enqueue at the same moment from two
+# threads (two managers, two directories: the elastic fleet's workers)
+# read each other's id and then create, await and remove each other's
+# temporary directories. Only the enqueue reads the global; the
+# background write carries the id it captured, so background writes of
+# different managers still overlap, and wait=False keeps its meaning.
+_enqueue_lock = threading.Lock()
 
 # digest-manifest finisher threads for wait=False saves (dir -> list);
 # finalize() joins them so a flushed directory always has its manifests
@@ -149,8 +162,6 @@ def save_checkpoint(dirname, state, step=0, max_to_keep=None, wait=True):
     digests = None
     digest_box = None
     if _digests_enabled():
-        import threading
-
         from ..integrity.digest import digest_state
 
         digest_box = {}
@@ -166,16 +177,18 @@ def save_checkpoint(dirname, state, step=0, max_to_keep=None, wait=True):
 
         digest_thread = threading.Thread(
             target=_digest, daemon=True, name="checkpoint-digest")
-    mgr = _manager(dirname, max_to_keep)
-    saved = mgr.save(int(step), args=ocp.args.StandardSave(dict(state)))
-    if not saved:
-        # orbax skips steps that already exist — delete and rewrite
-        mgr.delete(int(step))
-        saved = mgr.save(
-            int(step), args=ocp.args.StandardSave(dict(state)))
+    with _enqueue_lock:
+        mgr = _manager(dirname, max_to_keep)
+        saved = mgr.save(int(step), args=ocp.args.StandardSave(dict(state)))
         if not saved:
-            raise RuntimeError(
-                "orbax refused to save step %s under %r" % (step, dirname))
+            # orbax skips steps that already exist — delete and rewrite
+            mgr.delete(int(step))
+            saved = mgr.save(
+                int(step), args=ocp.args.StandardSave(dict(state)))
+            if not saved:
+                raise RuntimeError(
+                    "orbax refused to save step %s under %r"
+                    % (step, dirname))
     if digest_box is not None:
         from ..integrity import envelope
 
@@ -207,8 +220,6 @@ def save_checkpoint(dirname, state, step=0, max_to_keep=None, wait=True):
             # background write; finalize()/the next blocking call joins
             # it. The trainer-facing call returns at enqueue cost — the
             # digest never extends the hot path.
-            import threading
-
             fin = threading.Thread(
                 target=_finish_manifest, args=(False,), daemon=True,
                 name="checkpoint-manifest")

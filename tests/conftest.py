@@ -94,6 +94,10 @@ def armed_sanitizers():
     from paddle_tpu.analysis import concurrency, sanitizer
 
     was_conc, was_scope = concurrency.armed(), sanitizer.armed()
+    # the thread registry outlives a test: a thread an EARLIER test of
+    # this worker left running (slow to exit on a shared CPU) is that
+    # test's, and is not charged to the drill that happens to run next
+    before = set(concurrency.live_threads())
     concurrency.arm()
     concurrency.reset()
     sanitizer.arm()
@@ -102,7 +106,8 @@ def armed_sanitizers():
         yield
         conc_v = concurrency.violations()
         scope_v = sanitizer.violations()
-        leaked = [t.name for t in concurrency.live_threads()]
+        leaked = [t.name for t in concurrency.live_threads()
+                  if t not in before]
     finally:
         if not was_conc:
             concurrency.disarm()

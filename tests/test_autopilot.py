@@ -444,11 +444,12 @@ def test_autopilot_chaos_drill_detect_remediate_trace(
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "1e14")
     monkeypatch.setenv("PADDLE_TPU_HBM_BW", "1e12")
     # per-token SLO generous enough that clean CPU decode (plus the
-    # occasional compile-boundary gap) does not burn, while the seeded
-    # 2s stall blows it by >10x on every token
+    # occasional compile-boundary gap, on a CPU that six test workers
+    # share) does not burn, while the seeded 2s stall blows it 4x on
+    # every token: the healthy phase must not depend on the machine
     tenants = TenantTable(
         [TenantSpec("batch", priority=1)],
-        default_spec=TenantSpec("default", per_token_slo_ms=100.0))
+        default_spec=TenantSpec("default", per_token_slo_ms=500.0))
     router = disagg_fleet(
         m["cfg"], m["scope"], n_prefill=1, n_decode=2, slots=2,
         cache_len=64, kv_dtype="fp32", wire_dtype="fp32",

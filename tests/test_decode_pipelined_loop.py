@@ -310,7 +310,11 @@ def test_hibernation_hands_over_what_a_lone_stream_hands_over(models):
 def test_phase_totals_sum_to_the_threads_wall_time(models):
     """With a step of a chip's length (every dispatch stalled 5 ms at the
     chaos site): what no phase holds is the spans' own cost, about 60 us
-    a turn."""
+    a turn. Held as what a shared CPU cannot bend: the loop's spans lie one
+    after another on its thread and the seven totals are their sum, every
+    second counted once. What lies BETWEEN two spans stretches when six
+    test workers share the machine, so the share of the wall time that no
+    phase holds (under 2% on an idle machine) has a loose bound."""
     obs.reset()
     eng = engine(models, "gpt", "phases", auto_start=False)
     eng.warmup(check_hbm=False)
@@ -330,8 +334,12 @@ def test_phase_totals_sum_to_the_threads_wall_time(models):
     after = eng.stats()
     delta = {k: after[k] - before[k] for k in PHASES}
     assert all(v > 0 for v in delta.values()), delta
-    assert delta["idle_seconds"] >= 0.2 > 0.5 * delta["dispatch_seconds"]
-    assert sum(delta.values()) == pytest.approx(wall, rel=0.02)
+    assert delta["idle_seconds"] >= 0.2                  # the sleep, at least
+    loop = obs.spans(LOOP_SPANS)
+    assert all(a["t1"] <= b["t0"] for a, b in zip(loop, loop[1:]))
+    assert sum(after[k] for k in PHASES) == pytest.approx(
+        sum(s["t1"] - s["t0"] for s in loop))
+    assert 0.75 * wall <= sum(delta.values())
     # emit is the deliveries and the decides: the ring holds the same
     ring = sum(s["t1"] - s["t0"] for s in obs.spans(
         ("decode.step.emit", "decode.step.decide")))

@@ -702,12 +702,29 @@ def batches(n=3, rows=4, t=32):
     return out
 
 
+@pytest.fixture(scope="module")
+def followed():
+    """The reference's three steps over `batches()`, computed once a
+    configuration: the float32, recompute and AMP programs are held to the
+    same reference run (nothing of the program under test enters it)."""
+    done = {}
+
+    def follow(m):
+        if id(m) not in done:
+            done[id(m)] = ref.follow(
+                m, 7, batches(), dict(OPT, expert_bias_update_rate=RATE),
+                block_rows=2)
+        return done[id(m)]
+
+    return follow
+
+
 @pytest.mark.parametrize("amp,recompute,tol,m", [
     (False, (), 2e-4, M), (False, (0, 1), 2e-4, M),
     (True, (), 0.05, M), (False, (), 2e-4, WHOLE)],
     ids=["float32", "recompute", "amp", "every_expert_held"])
 def test_three_steps_of_the_program_against_the_reference(
-        fresh_programs, amp, recompute, tol, m):
+        fresh_programs, followed, amp, recompute, tol, m):
     """Loss, per-leaf gradient norm and per-leaf change over three steps
     through `minimize` and `Executor.run`: to rounding in float32 (with and
     without recomputation), to bfloat16's in the AMP program. On one chip's
@@ -730,8 +747,7 @@ def test_three_steps_of_the_program_against_the_reference(
             grad = {n: float(jnp.linalg.norm(scope.find_value(
                 n + "_moment1_0"))) / (1 - OPT["beta1"])
                     for n in ref.trained(m)}
-    want = ref.follow(m, 7, data, dict(OPT, expert_bias_update_rate=RATE),
-                      block_rows=2)
+    want = followed(m)
     change = {n: float(jnp.linalg.norm(scope.find_value(n) - w0[n]))
               for n in ref.trained(m)}
     floor_g = float(np.median(list(want["grad_norm"].values())))

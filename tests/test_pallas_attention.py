@@ -3,7 +3,13 @@
 the fused_multihead_attention op against the unfused matmul/softmax graph.
 
 Kernels run in pallas interpret mode on the CPU test mesh; on real TPU the
-same code path compiles via Mosaic (exercised by chip_smoke.py)."""
+same code path compiles via Mosaic (exercised by chip_smoke.py).
+
+Which kernel a call takes is decided by what the call shows (platform, mesh,
+shape: ops/hybrid_ops.py), never by the environment: the last two tests hold
+`paddle_tpu/ops/` to that."""
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -160,24 +166,31 @@ def test_prime_length_pads_not_degrades():
     assert float(jnp.max(jnp.abs(g - gr))) < 5e-4
 
 
-def test_fused_layer_norm_matches_jnp():
-    from paddle_tpu.ops.pallas_layernorm import fused_layer_norm
+# ---------------------------------------------------------------------------
+# no lowering asks the environment which kernel to take
+# ---------------------------------------------------------------------------
+PACKAGE = pathlib.Path(pa.__file__).resolve().parents[1]
 
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(6, 7, 32)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(32,)), jnp.float32)
-    b = jnp.asarray(rng.normal(size=(32,)), jnp.float32)
 
-    def ref(x, g, b):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        return (x - mean) * jax.lax.rsqrt(var + 1e-5) * g + b
+def _sources(root):
+    return {str(p.relative_to(PACKAGE)): p.read_text(encoding="utf-8")
+            for p in sorted(root.rglob("*.py"))}
 
-    y = fused_layer_norm(x, g, b, interpret=True)
-    assert float(jnp.max(jnp.abs(y - ref(x, g, b)))) < 1e-5
 
-    gf = jax.grad(lambda *a: jnp.sum(fused_layer_norm(
-        *a, interpret=True) ** 2), argnums=(0, 1, 2))(x, g, b)
-    gr = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(x, g, b)
-    for a_, b_ in zip(gf, gr):
-        assert float(jnp.max(jnp.abs(a_ - b_))) < 1e-3
+def test_no_module_under_ops_reads_the_environment():
+    readers = sorted(name for name, text in _sources(PACKAGE / "ops").items()
+                     if "os.environ" in text or "getenv" in text)
+    assert readers == []
+
+
+@pytest.mark.parametrize("option", [
+    "PADDLE_TPU_FLASH_MIN_SEQ", "PADDLE_TPU_DISABLE_PALLAS",
+    "PADDLE_TPU_PALLAS_LN", "PADDLE_TPU_ASYNC_DEPTH",
+    "PADDLE_TPU_MAX_DECODE_LEN"])
+def test_an_option_nobody_set_is_gone_from_the_package(option):
+    """Each was a user-set switch that no cell, test, example or script
+    set (PR 46): two chose Pallas paths that lost on the chip, one turned
+    every Pallas path off, two were the defaults of arguments."""
+    assert sorted(name for name, text in _sources(PACKAGE).items()
+                  if option in text) == []
+    assert not (PACKAGE / "ops" / "pallas_layernorm.py").exists()
