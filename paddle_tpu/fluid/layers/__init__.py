@@ -29,6 +29,8 @@ from .distributions import *  # noqa: F401,F403
 from . import device  # noqa: F401
 from . import hybrid
 from .hybrid import *  # noqa: F401,F403
+from . import vision_tower
+from .vision_tower import *  # noqa: F401,F403
 from . import math_op_patch
 
 math_op_patch.monkey_patch_variable()
@@ -47,3 +49,4 @@ __all__ += _rnn_module.__all__
 __all__ += detection.__all__
 __all__ += distributions.__all__
 __all__ += hybrid.__all__
+__all__ += vision_tower.__all__

@@ -296,25 +296,32 @@ def dsa_select(q, k, w, heads, topk, pos=None):
 
 
 def mla_attention(q, latent, selected, name, heads, rank, nope_dim, rope_dim,
-                  v_dim, pos=None):
+                  v_dim, pos=None, offset=None):
     """Multi-head latent attention over the keys ``selected``
-    (:func:`dsa_select`): ``q`` (B, Tq, heads * (nope_dim + rope_dim)) per
-    head ``[q_nope | q_rope]``, ``latent`` (B, Tk, >= rank + rope_dim) a
+    (:func:`dsa_select`), or with ``selected`` None over every earlier
+    position: ``q`` (B, Tq, heads * (nope_dim + rope_dim)) per head
+    ``[q_nope | q_rope]``, ``latent`` (B, Tk, >= rank + rope_dim) a
     position's ``[ckv | k_rope]`` of latent rank ``rank``, zeros after it up
     to the cache's width -> (B, Tq, heads * v_dim). A prompt takes
     the expanded path (keys and values of every head made from the
-    latents); with ``pos`` (B, 1) a step takes the absorbed path over the
-    kept rows of the slots' cache ``latent``, gathered. Parameters
-    ``<name>.uk.w`` (rank, heads * nope_dim) and ``<name>.uv.w`` (rank,
-    heads * v_dim)."""
+    latents); with ``offset`` (1, 1) and no selection the queries are a
+    chunk that stands ``offset`` rows into ``latent``, the sequence's rows
+    so far; with ``pos`` (B, 1) a step takes the absorbed path over the
+    slots' cache ``latent``: its kept rows gathered, or without a selection
+    its rows ``<= pos`` where they lie. Parameters ``<name>.uk.w`` (rank,
+    heads * nope_dim) and ``<name>.uv.w`` (rank, heads * v_dim)."""
     helper = LayerHelper("mla_attention")
-    inputs = {"Q": [q], "Latent": [latent], "Selected": [selected],
+    inputs = {"Q": [q], "Latent": [latent],
               "Wuk": [_param(helper, name + ".uk.w",
                              [rank, heads * nope_dim], q.dtype)],
               "Wuv": [_param(helper, name + ".uv.w",
                              [rank, heads * v_dim], q.dtype)]}
+    if selected is not None:
+        inputs["Selected"] = [selected]
     if pos is not None:
         inputs["Pos"] = [pos]
+    if offset is not None:
+        inputs["Offset"] = [offset]
     out = _out(helper, q.dtype, (q.shape[0], q.shape[1], heads * v_dim))
     helper.append_op(type="mla_attention", inputs=inputs,
                      outputs={"Out": [out]},

@@ -1936,7 +1936,8 @@ def where(condition, x=None, y=None):
         )
         return out
     return _layer(
-        "where", {"Condition": condition, "X": x, "Y": y}, out_shape=x.shape
+        "where", {"Condition": condition, "X": x, "Y": y},
+        out_dtype=x.dtype, out_shape=x.shape
     )
 
 

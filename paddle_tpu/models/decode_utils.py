@@ -14,7 +14,8 @@ import numpy as np
 from paddle_tpu.fluid import layers
 
 __all__ = ["attend", "attend_cached", "split_heads", "step_masks",
-           "update_cache", "StateEntry", "DecodeModel", "require_rows_only"]
+           "update_cache", "StateEntry", "DecodeModel", "MediaEncoder",
+           "require_rows_only"]
 
 
 class StateEntry(collections.namedtuple(
@@ -38,6 +39,68 @@ class StateEntry(collections.namedtuple(
     @property
     def nbytes(self):
         return int(np.prod(self.shape)) * np.dtype(self.dtype).itemsize
+
+
+class MediaEncoder:
+    """The encoder of a model whose prompts may hold MEDIA rows: positions
+    that take, in place of their token's embedding, a row an encoder made
+    from an image the request carries.
+
+    ``build(cfg, patches)`` builds the encoder's program over ONE image
+    padded to ``patches`` rows of ``patch_width`` uint8 values (one program a
+    bucket of ``buckets``, ascending; the largest is the most patches an
+    image may hold): it feeds the patches ``(1, patches, patch_width)`` and
+    the image's grid ``(1, 2)`` int64 ``[h, w]`` (``feed_names``) and fetches
+    the image's rows ``(patches / (merge[0] merge[1]), width)``, the real ones
+    first: ``merge`` is the block of patches one row is made of, and a grid's
+    sides are whole numbers of it. ``max_grid`` is the most patches a side
+    may hold (the sides of the encoder's learned position table: its program
+    resizes the table DOWN to a grid and has no row or column past them).
+    :meth:`check_grid` is the one place that says which grids the programs
+    take. ``media_id`` is the token id that marks a position taking a media
+    row; a prompt holds as many of them as its images give rows, and takes
+    the rows in order. ``patchify(pixels)`` (host code) turns one image's
+    uint8 pixels ``(patch h, patch w, 3)`` into the ``(h w, patch_width)``
+    uint8 rows the program is fed, ``patch`` the pixels a patch's side holds.
+    The model's fill programs (prefill and chunk) then feed, beside ids and
+    lengths, the request's rows ``(buffer_rows, width)`` (every image's one
+    after the other, then padding) and per position the row it takes or -1
+    (``media_feed_names``: the buffer, the index)."""
+
+    def __init__(self, build, buckets, media_id, width, merge, max_grid,
+                 buffer_rows, max_images, patch_width, patch, patchify):
+        self.build, self.patchify, self.patch = build, patchify, int(patch)
+        self.buckets = tuple(sorted(int(b) for b in buckets))
+        self.media_id, self.width = int(media_id), int(width)
+        self.merge = (int(merge[0]), int(merge[1]))
+        self.max_grid = (int(max_grid[0]), int(max_grid[1]))
+        self.buffer_rows, self.max_images = int(buffer_rows), int(max_images)
+        self.patch_width = int(patch_width)
+
+    def rows_of(self, patches):
+        """Media rows an image of ``patches`` patches gives."""
+        return int(patches) // (self.merge[0] * self.merge[1])
+
+    def bucket_for(self, patches):
+        return next((b for b in self.buckets if b >= patches), None)
+
+    def check_grid(self, h, w):
+        """``ValueError`` in words unless the encoder's programs take an
+        image of ``h x w`` patches."""
+        (mh, mw), (top, wide) = self.merge, self.max_grid
+        if h < mh or w < mw or h % mh or w % mw:
+            raise ValueError(
+                "a grid of %d x %d patches: a side is a whole number of the "
+                "%d x %d patches a media row is made of" % (h, w, mh, mw))
+        if h > top or w > wide:
+            raise ValueError(
+                "a grid of %d x %d patches: a side holds at most %d x %d "
+                "(the sides of the encoder's position table)"
+                % (h, w, top, wide))
+        if self.bucket_for(h * w) is None:
+            raise ValueError(
+                "a grid of %d x %d holds %d patches; at most %d"
+                % (h, w, h * w, self.buckets[-1]))
 
 
 class DecodeModel:
@@ -69,6 +132,12 @@ class DecodeModel:
     (``DecodeEngine._loop``); ``chunk_rows`` is the run length the model's
     own programs already cut a prompt by, not a knob. A model without one
     (the default) is filled by its bucket programs alone.
+    ``encoder`` (a :class:`MediaEncoder`) is declared by a model whose
+    requests may carry images: the engine runs its program on the device, an
+    image a turn, and the fill programs take the rows at the marked
+    positions. Such a request is refused, in words, by everything that
+    re-runs or ships a prompt as ids alone (the prefix pool, the session
+    tier, a draft, the wire).
     ``step_counters(aux, live)`` maps the step program's trailing fetch
     (after the state) to lifetime counters of ``DecodeEngine.stats()``.
     ``rows_are_kv`` says that the ``rows`` entries are the K and the V
@@ -82,8 +151,9 @@ class DecodeModel:
     def __init__(self, cfg, state, build_prefill, build_step,
                  build_delta=None, build_verify=None, unpack=None,
                  pack=None, step_counters=None, rows_are_kv=True,
-                 build_chunk=None, chunk_rows=None):
+                 build_chunk=None, chunk_rows=None, encoder=None):
         self.cfg = cfg
+        self.encoder = encoder
         self.state = list(state)
         self.rows_are_kv = bool(rows_are_kv)
         self.build_prefill = build_prefill

@@ -2,15 +2,18 @@
 layers: a bias-free projection, a SwiGLU feed-forward (a dense MLP, a
 shared expert), a routed layer of gated experts of which this chip holds a
 range, and the greedy head of a served step. ``models/lfm2.py`` (trained),
-``models/nemotron_h.py``, ``models/laguna.py``, ``models/glm_moe_dsa.py`` and
-``models/solar_open2.py`` (served) take them from here; each keeps what only it has (its mixers, its state, its programs).
+``models/nemotron_h.py``, ``models/laguna.py``, ``models/glm_moe_dsa.py``,
+``models/solar_open2.py`` and ``models/kimi_vl.py`` (served) take them from here; each keeps what only it has (its mixers, its state, its programs). The two latent-attention files
+(``glm_moe_dsa.py``, ``kimi_vl.py``) also share a head's rotary part turned in
+interleaved pairs (:func:`turned`) and the make of a latent row
+(:func:`latent_rows`).
 """
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.param_attr import ParamAttr
 
 __all__ = ["fc", "swiglu", "routed_gated_experts", "routed_in_calls",
-           "greedy_head"]
+           "greedy_head", "turned", "latent_rows"]
 
 MOE_PROMPT_ROWS = 4096     # tokens of a prompt a call of the routed layer
 # takes: its sorted buffers hold tokens x experts per token rows of the
@@ -81,3 +84,38 @@ def greedy_head(x, vocab, eps, norm_name, head_name):
     nxt = layers.cast(
         layers.unsqueeze(layers.argmax(logits, axis=-1), [1]), "int64")
     return logits, nxt
+
+
+def turned(x, lead, count, width, cfg, first, pos=None):
+    """(B, lead, count * width) -> the same, of every head of ``width`` the
+    first (``first``) or the last ``cfg.rope_dim`` dimensions turned by the
+    row's position (``cfg.theta``), interleaved pairs."""
+    x = layers.reshape(x, [-1, lead, count, width])
+    if first:
+        x = layers.rotary_embedding(x, cfg.theta, pos=pos,
+                                    rotary_dim=cfg.rope_dim, interleaved=True)
+    else:
+        keep = width - cfg.rope_dim
+        x = layers.concat([
+            layers.slice(x, [3], [0], [keep]),
+            layers.rotary_embedding(layers.slice(x, [3], [keep], [width]),
+                                    cfg.theta, pos=pos, interleaved=True)],
+            axis=3)
+    return layers.reshape(x, [-1, lead, count * width])
+
+
+def latent_rows(u, lead, cfg, n, pos=None):
+    """The latent rows of a latent-attention layer over u (B, lead, H) ->
+    (B, lead, cfg.latent_width): ``[RMSNorm(ckv) | k_rope turned | zeros]``
+    from ``u <n>.mla.kv_a`` (``cfg.kv_rank`` + ``cfg.rope_dim`` wide), what
+    the layer's cache holds of a position."""
+    held = cfg.kv_rank + cfg.rope_dim
+    kv = fc(u, held, n + ".mla.kv_a", 2)
+    return layers.concat([
+        layers.rms_norm(layers.slice(kv, [2], [0], [cfg.kv_rank]),
+                        n + ".mla.kv_norm", epsilon=cfg.eps),
+        turned(layers.slice(kv, [2], [cfg.kv_rank], [held]),
+               lead, 1, cfg.rope_dim, cfg, True, pos),
+        layers.fill_constant_batch_size_like(
+            kv, shape=[-1, lead, cfg.latent_width - held], dtype=kv.dtype,
+            value=0.0)], axis=2)

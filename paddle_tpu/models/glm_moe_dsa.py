@@ -194,22 +194,10 @@ class GlmMoeDsaConfig:
                 "latent_rows_read": int(aux[-1])}
 
 
-def _turned(x, lead, count, width, cfg, first, pos=None):
-    """(B, lead, count * width) -> the same, of every head of ``width`` the
-    first (``first``) or the last ``rope_dim`` dimensions turned by the
-    row's position, interleaved pairs."""
-    x = layers.reshape(x, [-1, lead, count, width])
-    if first:
-        x = layers.rotary_embedding(x, cfg.theta, pos=pos,
-                                    rotary_dim=cfg.rope_dim, interleaved=True)
-    else:
-        keep = width - cfg.rope_dim
-        x = layers.concat([
-            layers.slice(x, [3], [0], [keep]),
-            layers.rotary_embedding(layers.slice(x, [3], [keep], [width]),
-                                    cfg.theta, pos=pos, interleaved=True)],
-            axis=3)
-    return layers.reshape(x, [-1, lead, count * width])
+# under this module's own name: the GLM-5 cell's planted fault
+# ``selection_before_the_rotary_term`` (tests/benchmark_tests/test_glm5_cell.py)
+# replaces ``glm_moe_dsa._turned`` to leave the indexer's rows unturned
+_turned = blocks.turned
 
 
 def _attention_inputs(u, lead, cfg, n, pos=None):
@@ -220,24 +208,15 @@ def _attention_inputs(u, lead, cfg, n, pos=None):
     with fluid.name_scope("glm.mla"):
         cq = layers.rms_norm(blocks.fc(u, cfg.q_rank, n + ".mla.q_a", 2),
                              n + ".mla.q_norm", epsilon=cfg.eps)
-        q = _turned(blocks.fc(cq, cfg.heads * (cfg.nope_dim + cfg.rope_dim),
-                              n + ".mla.q_b", 2),
-                    lead, cfg.heads, cfg.nope_dim + cfg.rope_dim, cfg, False,
-                    pos)
-        held = cfg.kv_rank + cfg.rope_dim
-        kv = blocks.fc(u, held, n + ".mla.kv_a", 2)
-        lat = layers.concat([
-            layers.rms_norm(layers.slice(kv, [2], [0], [cfg.kv_rank]),
-                            n + ".mla.kv_norm", epsilon=cfg.eps),
-            _turned(layers.slice(kv, [2], [cfg.kv_rank], [held]),
-                    lead, 1, cfg.rope_dim, cfg, True, pos),
-            layers.fill_constant_batch_size_like(
-                kv, shape=[-1, lead, cfg.latent_width - held], dtype=DTYPE,
-                value=0.0)], axis=2)
+        q = _turned(
+            blocks.fc(cq, cfg.heads * (cfg.nope_dim + cfg.rope_dim),
+                      n + ".mla.q_b", 2),
+            lead, cfg.heads, cfg.nope_dim + cfg.rope_dim, cfg, False, pos)
+        lat = blocks.latent_rows(u, lead, cfg, n, pos)
     with fluid.name_scope("glm.indexer"):
-        qi = _turned(blocks.fc(cq, cfg.index_heads * cfg.index_dim,
-                               n + ".idx.q", 2),
-                     lead, cfg.index_heads, cfg.index_dim, cfg, True, pos)
+        qi = _turned(
+            blocks.fc(cq, cfg.index_heads * cfg.index_dim, n + ".idx.q", 2),
+            lead, cfg.index_heads, cfg.index_dim, cfg, True, pos)
         ki = layers.layer_norm(
             blocks.fc(u, cfg.index_dim, n + ".idx.k", 2), begin_norm_axis=2,
             epsilon=1e-6, param_attr=ParamAttr(name=n + ".idx.k_norm.w"),

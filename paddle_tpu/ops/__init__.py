@@ -24,6 +24,7 @@ from . import rcnn_ops  # noqa: F401
 from . import rnn_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import hybrid_ops  # noqa: F401
+from . import vision_ops  # noqa: F401
 
 
 def _register_late_modules():

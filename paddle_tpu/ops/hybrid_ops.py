@@ -826,46 +826,123 @@ def _dsa_select(ctx, ins, attrs):
         _over_query_blocks(one, tq, (q, w), [(k, 1)]), axis=1)]}
 
 
+def _mla_causal(ctx, q, lat, wuk, wuv, heads, nope, rope, vd, offset):
+    """Latent attention with no selection, a prompt or a chunk of one:
+    expanded. Query i stands at row ``offset + i`` (row i without an offset)
+    of the latent rows ``lat`` (B, Tk, .) and sees every row up to its own.
+    Keys ``[ckv Wuk_h | k_rope]`` and values ``ckv Wuv_h`` are made for all
+    Tk rows. On an unsharded TPU program whose lengths FLASH_BLOCK divides,
+    the flash forward kernel with a query offset (``pallas_attention.
+    flash_attention(q_offset=)``): queries and keys padded with zeros to a
+    whole number of 128 lanes (192 -> 256: the MXU contracts 128 at a time,
+    so the zeros cost fast memory and no pass), the values at their own
+    width; everywhere else blocks of DSA_QUERY_BLOCK queries through XLA
+    against all Tk keys. Counted: ``ops.mla_attention.causal_kernel`` /
+    ``.causal_blocks``."""
+    from .. import observability as obs
+
+    b, tq, _ = q.shape
+    tk, rank = lat.shape[1], wuk.shape[0]
+    dqk, scale = nope + rope, (nope + rope) ** -0.5
+    ckv, k_rope = lat[..., :rank], lat[..., rank:rank + rope]
+    k_nope = _dot_f32(ckv, wuk).astype(q.dtype).reshape(b, tk, heads, nope)
+    keys = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (b, tk, heads, rope))], -1)
+    values = _dot_f32(ckv, wuv).astype(q.dtype).reshape(b, tk, heads, vd)
+    qh = q.reshape(b, tq, heads, dqk)
+    if (getattr(ctx, "platform", None) == "tpu"
+            and not getattr(ctx, "mesh_axes", None)
+            and tq >= FLASH_MIN_SEQ and tq % FLASH_BLOCK == 0
+            and tk % FLASH_BLOCK == 0 and vd % 128 == 0):
+        from .pallas_attention import flash_attention
+
+        obs.inc("ops.mla_attention.causal_kernel")
+        pad = (-dqk) % 128
+        if pad:
+            qh = jnp.pad(qh, ((0, 0), (0, 0), (0, 0), (0, pad)))
+            keys = jnp.pad(keys, ((0, 0), (0, 0), (0, 0), (0, pad)))
+        out = flash_attention(
+            jnp.swapaxes(qh, 1, 2), jnp.swapaxes(keys, 1, 2),
+            jnp.swapaxes(values, 1, 2), causal=True, sm_scale=scale,
+            block_q=FLASH_BLOCK, block_k=FLASH_BLOCK,
+            q_offset=jnp.zeros((), jnp.int32) if offset is None else offset)
+        return jnp.swapaxes(out, 1, 2).reshape(b, tq, heads * vd)
+    obs.inc("ops.mla_attention.causal_blocks")
+    blk = DSA_QUERY_BLOCK if tq % DSA_QUERY_BLOCK == 0 else tq
+    first = jnp.zeros((), jnp.int32) if offset is None else offset
+    col = jnp.arange(tk, dtype=jnp.int32)[None, :]
+
+    def one(args):
+        rows, qb = args                              # (blk,), (B, blk, h, d)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", qb, keys,
+                            preferred_element_type=F32) * scale
+        seen = col <= (first + rows)[:, None]
+        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30), -1)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), values,
+                          preferred_element_type=F32
+                          ).astype(q.dtype).reshape(b, blk, heads * vd)
+
+    out = lax.map(one, (jnp.arange(tq, dtype=jnp.int32).reshape(-1, blk),
+                        jnp.moveaxis(qh.reshape(b, -1, blk, heads, dqk),
+                                     1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, tq, heads * vd)
+
+
 @register_op("mla_attention")
 def _mla_attention(ctx, ins, attrs):
-    """Multi-head latent attention over a selection of keys. Q (B, Tq,
-    heads * (nope + rope)) per head ``[q_nope | q_rope]``, the second part
-    turned by its position; Latent (B, Tk, >= rank + rope) a position's row
-    ``[ckv | k_rope | zeros]`` (the normed latent and the one turned key
-    part all heads share, then whatever padding the cache's width carries);
-    Wuk (rank, heads * nope) and Wuv (rank, heads * v) expand
+    """Multi-head latent attention, over a selection of keys or over all of
+    them. Q (B, Tq, heads * (nope + rope)) per head ``[q_nope | q_rope]``,
+    the second part turned by its position; Latent (B, Tk, >= rank + rope) a
+    position's row ``[ckv | k_rope | zeros]`` (the normed latent and the one
+    turned key part all heads share, then whatever padding the cache's width
+    carries); Wuk (rank, heads * nope) and Wuv (rank, heads * v) expand
     a latent to a head's key and value; scale ``(nope + rope)^-1/2``;
-    Selected from ``dsa_select``. Out (B, Tq, heads * v). Two paths, the
-    same numbers:
+    Selected from ``dsa_select``, or absent: every earlier position is seen.
+    Out (B, Tq, heads * v). The paths, the same numbers:
 
-    a prompt (no ``Pos``; Selected (B, T, T) int8): expanded. ``k_nope =
-    ckv Wuk`` and ``v = ckv Wuv`` for every position and head, score
-    ``q_nope . k_nope + q_rope . k_rope``, softmax over the kept keys,
-    ``sum p v``. On an unsharded TPU program whose length KEPT_BLOCK
-    divides and whose head widths are multiples of 128, one Pallas kernel
-    (``pallas_attention.kept_keys_attention``: the scores stay on the
-    chip, the work is the causal half); everywhere else blocks of
+    a prompt over a selection (no ``Pos``; Selected (B, T, T) int8):
+    expanded. ``k_nope = ckv Wuk`` and ``v = ckv Wuv`` for every position
+    and head, score ``q_nope . k_nope + q_rope . k_rope``, softmax over the
+    kept keys, ``sum p v``. On an unsharded TPU program whose length
+    KEPT_BLOCK divides and whose head widths are multiples of 128, one
+    Pallas kernel (``pallas_attention.kept_keys_attention``: the scores stay
+    on the chip, the work is the causal half); everywhere else blocks of
     DSA_QUERY_BLOCK queries x all heads through XLA, run j of DSA_TIERS
-    against the keys up to its end, so no (heads, T, T) array exists
-    either way. The op chooses from what it sees; which path a lowering
-    took is counted (``ops.mla_attention.kept_kernel`` / ``.kept_blocks``).
+    against the keys up to its end, so no (heads, T, T) array exists either
+    way. The op chooses from what it sees; which path a lowering took is
+    counted (``ops.mla_attention.kept_kernel`` / ``.kept_blocks``).
+
+    a prompt or a chunk of one with no selection (no ``Pos``, no Selected;
+    with ``Offset`` (1, 1) the queries stand that many rows into Latent, the
+    sequence's rows so far, Tk >= offset + Tq): expanded and plainly causal
+    (:func:`_mla_causal`).
 
     a decode step (``Pos`` given, Tq == 1; Latent the slots' rows (B,
-    cache_len, .), Selected (B, k) int32 columns, -1 for none): absorbed.
-    The kept rows are gathered, ``q~ = q_nope Wuk_h^T`` (rank) scores a row
-    as ``q~ . ckv + q_rope . k_rope``, and ``(sum p ckv) Wuv_h`` is the
-    head's output: no key or value is expanded, the cache is read at the
-    kept rows alone."""
+    cache_len, .)): absorbed. ``q~ = q_nope Wuk_h^T`` (rank) scores a row as
+    ``q~ . ckv + q_rope . k_rope``, and ``(sum p ckv) Wuv_h`` is the head's
+    output: no key or value is expanded. With Selected (B, k) int32 columns,
+    -1 for none, the kept rows are gathered and the cache is read at them
+    alone; without, the rows are the slot's whole cache where it lies, no
+    gather, row b seeing columns <= pos[b] (counted:
+    ``ops.mla_attention.dense_step``)."""
     q, lat = ins["Q"][0], ins["Latent"][0]
-    wuk, wuv, sel = ins["Wuk"][0], ins["Wuv"][0], ins["Selected"][0]
+    wuk, wuv = ins["Wuk"][0], ins["Wuv"][0]
+    sel = ins["Selected"][0] if ins.get("Selected") else None
     heads, nope, rope, vd = (int(attrs[k]) for k in
                              ("heads", "nope_dim", "rope_dim", "v_dim"))
     b, tq, _ = q.shape
     rank, width = wuk.shape[0], lat.shape[-1]
     scale = (nope + rope) ** -0.5
     if ins.get("Pos"):
-        rows = jax.vmap(lambda c, i: jnp.take(c, i, axis=0))(
-            lat, jnp.maximum(sel, 0))                     # (B, k, width)
+        if sel is None:
+            from .. import observability as obs
+
+            obs.inc("ops.mla_attention.dense_step")
+            rows = lat                                    # (B, cache, width)
+        else:
+            rows = jax.vmap(lambda c, i: jnp.take(c, i, axis=0))(
+                lat, jnp.maximum(sel, 0))                 # (B, k, width)
         qh = q.reshape(b, heads, nope + rope)
         absorbed = jnp.einsum("bhd,chd->bhc", qh[..., :nope],
                               wuk.reshape(rank, heads, nope),
@@ -877,14 +954,25 @@ def _mla_attention(ctx, ins, attrs):
              jnp.zeros((b, heads, width - rank - rope), q.dtype)], -1)
         scores = jnp.einsum("bhc,bkc->bhk", ql, rows,
                             preferred_element_type=F32) * scale
+        seen = (sel >= 0) if sel is not None else (
+            jnp.arange(lat.shape[1], dtype=jnp.int32)[None, :]
+            <= ins["Pos"][0].reshape(b, 1).astype(jnp.int32))
         probs = jax.nn.softmax(
-            jnp.where((sel >= 0)[:, None, :], scores, -1e30), -1)
+            jnp.where(seen[:, None, :], scores, -1e30), -1)
         mixed = jnp.einsum("bhk,bkc->bhc", probs.astype(q.dtype),
                            rows[..., :rank],
                            preferred_element_type=F32).astype(q.dtype)
         out = jnp.einsum("bhc,chv->bhv", mixed, wuv.reshape(rank, heads, vd),
                          preferred_element_type=F32)
         return single(out.reshape(b, 1, heads * vd).astype(q.dtype))
+    if sel is None:
+        offset = (ins["Offset"][0].reshape(()).astype(jnp.int32)
+                  if ins.get("Offset") else None)
+        return single(_mla_causal(ctx, q, lat, wuk, wuv, heads, nope, rope,
+                                  vd, offset))
+    if ins.get("Offset"):
+        raise ValueError("mla_attention of a chunk (Offset) takes no "
+                         "selection: a selection's prompt is filled whole")
 
     from .. import observability as obs
 

@@ -103,7 +103,7 @@ def _fwd_kernel(seed_ref, kpm_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                 sm_scale, causal, dropout_p, block_k, nk, off_ref=None):
     qi = pl.program_id(1)
     bq = q_ref.shape[1]
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]       # the values' width (the keys' may differ)
     q = q_ref[0]                                     # (bq, D)
     seed = fold_bh_seed(seed_ref[0, 0], pl.program_id(0))
     # the row of the keys at which query 0 stands (a chunk of a longer
@@ -311,9 +311,11 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
               block_k, heads, interpret, offset=None):
     """``offset`` (1, 1) int32 or None: the row of the keys at which query 0
     stands (:func:`flash_attention`'s ``q_offset``), read from SMEM beside
-    the seed; None adds no operand and the call is as it was."""
+    the seed; None adds no operand and the call is as it was. The values
+    may be of another width than the queries and keys (latent attention: 192
+    against 128); the output then has the values'."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     nq = tq // block_q
     nk = tk // block_k
     seed_spec, kpm_spec, q_spec, kv_spec = _specs(
@@ -333,13 +335,14 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
     if kpm is not None:
         in_specs.append(kpm_spec)
         args.append(_kpm3(kpm))
-    in_specs += [q_spec, kv_spec, kv_spec]
+    in_specs += [q_spec, kv_spec,
+                 pl.BlockSpec((1, tk, dv), lambda b, i: (b, 0, 0))]
     args += [q, k, v]
     # K and V of one head ride whole in VMEM, each double-buffered: past
     # the compiler's default scoped limit (16,384 positions of 128: 16 MiB
     # for them alone) the call asks for what it holds and as much again for
     # its tiles; below it nothing is passed and the call is as it was
-    held = 4 * tk * d * k.dtype.itemsize
+    held = 2 * tk * (d + dv) * k.dtype.itemsize
     params = {}
     if held > _SCOPED_VMEM - (4 << 20):
         params["compiler_params"] = pltpu.CompilerParams(
@@ -349,11 +352,11 @@ def _fwd_call(q, k, v, kpm, seed, sm_scale, causal, dropout_p, block_q,
         grid=(bh, nq),
         in_specs=in_specs,
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i: (b, i, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
         ),
         interpret=interpret,
@@ -560,8 +563,9 @@ def flash_attention(q, k, v, key_padding_mask=None, seed=None, sm_scale=None,
     rows written so far (``Tk >= q_offset + Tq``); key tiles past the
     chunk's last query are never visited, so the chunks of a sequence cost
     its causal half between them. Causal and forward only (the call goes
-    round the custom vjp). Without it the kernel has no such operand and
-    lowers as it did.
+    round the custom vjp), and on this path v may be of another width than q
+    and k (the output then has v's). Without it the kernel has no such
+    operand and lowers as it did.
     Returns (B, H, Tq, D) in q.dtype.
     """
     if q_offset is not None and not causal:
@@ -605,12 +609,13 @@ def flash_attention(q, k, v, key_padding_mask=None, seed=None, sm_scale=None,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
     if q_offset is not None:
         b, h, _, d = q.shape
+        dv = v.shape[-1]     # may differ from d on this path alone
         out, _ = _fwd_call(
             q.reshape(b * h, -1, d), k.reshape(b * h, -1, d),
-            v.reshape(b * h, -1, d), kpm, seed, float(sm_scale), True,
+            v.reshape(b * h, -1, dv), kpm, seed, float(sm_scale), True,
             float(dropout_p), bq, bk, h, interpret,
             offset=jnp.asarray(q_offset, jnp.int32).reshape((1, 1)))
-        out = out.reshape(q.shape)
+        out = out.reshape(q.shape[:-1] + (dv,))
     else:
         out = _flash(
             q, k, v, kpm, seed, float(sm_scale), bool(causal),

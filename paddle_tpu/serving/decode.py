@@ -417,7 +417,11 @@ class _Request:
                  # "suffix"/"sbucket" the delta-prefill tail, "hist"
                  # the token-per-written-row history
                  "session", "base", "start", "suffix", "sbucket",
-                 "hist")
+                 "hist",
+                 # a request that carries images: "media" a list of (uint8
+                 # patches padded to their bucket, (h, w)), "media_index"
+                 # per prompt position the media row it takes or -1
+                 "media", "media_index")
 
 
 class _Slot:
@@ -442,10 +446,16 @@ class _Slot:
 
 
 class _Fill:
-    """A fill in progress: a long prompt that goes into its slot a chunk a
-    turn of the dispatch loop (``DecodeModel.build_chunk``), so that the
-    live streams get a step between two chunks. ``at`` is the row the next
-    chunk starts at; ``state`` the sequence's state so far, device arrays
+    """A fill in progress: a prompt that goes into its slot a UNIT a turn of
+    the dispatch loop, so that the live streams get a step between two
+    units: a long prompt a chunk a turn (``DecodeModel.build_chunk``;
+    ``chunked``), and before its rows, for a request that carries images,
+    the encoder's program an image a turn (``DecodeModel.encoder``;
+    ``image`` the next one to run, ``media`` the device buffer their rows are
+    gathered in, ``media_at`` the row the next image's go to). A fill that is
+    not chunked ends in ONE unit of its bucket's program. ``at`` is the row
+    the next chunk starts at (the prompt's length once every row is out);
+    ``state`` the sequence's state so far, device arrays
     that the chunk program consumes and hands back, OUTSIDE the
     :class:`SlotCache` (the step program reads and writes every slot's
     buffers, live or not: a half-built state must not sit in one); ``nxt``
@@ -454,11 +464,13 @@ class _Fill:
     first chunk to the seat, and ``elapsed`` its time so far: what
     ``_seat`` and ``_observe_prefill`` ask of a span."""
 
-    __slots__ = ("req", "slot", "at", "state", "nxt", "t0", "ctx")
+    __slots__ = ("req", "slot", "at", "state", "nxt", "t0", "ctx",
+                 "chunked", "media", "media_at", "image")
 
-    def __init__(self, req, slot, state, ctx):
+    def __init__(self, req, slot, state, ctx, chunked=True, media=None):
         self.req, self.slot, self.state, self.ctx = req, slot, state, ctx
-        self.at = 0
+        self.chunked, self.media = chunked, media
+        self.at = self.media_at = self.image = 0
         self.nxt = None
         self.t0 = time.monotonic()
 
@@ -590,6 +602,14 @@ class DecodeEngine:
             with fluid.program_guard(fluid.Program(), fluid.Program()):
                 cv = model.build_chunk(cfg, model.chunk_rows, self.cache_len)
                 chunk = (fluid.default_main_program(), cv)
+        # the encoder's programs of a model whose requests may carry
+        # images, one a patch bucket (DecodeModel.encoder)
+        towers = {}
+        if prefill and model.encoder is not None:
+            for b in model.encoder.buckets:
+                with fluid.program_guard(fluid.Program(), fluid.Program()):
+                    tv = model.encoder.build(cfg, b)
+                    towers[b] = (fluid.default_main_program(), tv)
         # delta-prefill ladder (prefix-pool hits + session resumes):
         # same bucket widths as cold prefill, suffix-sized at use
         delta = {}
@@ -608,6 +628,7 @@ class DecodeEngine:
         persist = {}
         all_progs = ([step_prog] + [p for p, _ in prefill.values()]
                      + [p for p, _ in delta.values()]
+                     + [p for p, _ in towers.values()]
                      + [pv[0] for pv in (chunk, verify) if pv is not None])
         for prog in all_progs:
             for v in prog.list_vars():
@@ -674,6 +695,27 @@ class DecodeEngine:
             self._chunk_zeros = jax.jit(lambda: [
                 jax.numpy.zeros((1,) + tuple(e.shape), e.dtype)
                 for e in model.state])
+        # jit_fwd_tower_<patches>; the rows of a request's images are
+        # gathered in one device buffer (a fresh one a request: the write
+        # donates it), a text-only request of such a model feeds a blank
+        # one that is never written
+        self._tower_preds, self._tower_vars = {}, {}
+        self._media_blank = self._media_zeros = self._media_write = None
+        for b, (prog, tv) in towers.items():
+            self._tower_preds[b] = Predictor(
+                prog, tv["feed_names"], tv["fetch_vars"], scope=persist,
+                name="tower_%d" % b)
+            self._tower_preds[b].ledger_tag = "decode.tower:%s" % self.name
+            self._tower_vars[b] = tv
+        if towers:
+            enc, jnp = model.encoder, jax.numpy
+            dtype = towers[enc.buckets[0]][1]["fetch_vars"][0].dtype
+            self._media_zeros = jax.jit(lambda: jnp.zeros(
+                (enc.buffer_rows, enc.width), dtype))
+            self._media_blank = self._media_zeros()
+            self._media_write = jax.jit(
+                lambda buf, rows, at: jax.lax.dynamic_update_slice(
+                    buf, rows.astype(buf.dtype), (at, 0)), donate_argnums=0)
         self._delta_preds = {}
         for b, (prog, dv) in delta.items():
             self._delta_preds[b] = Predictor(
@@ -847,12 +889,73 @@ class DecodeEngine:
         obs.event("engine_stop", source="serving", count=False,
                   model=self.name, engine="decode", drained=bool(drain))
 
+    @property
+    def media_encoder(self):
+        """The model's :class:`~paddle_tpu.models.decode_utils.MediaEncoder`
+        (None: requests carry token ids alone)."""
+        return self._model.encoder
+
     # -- admission -------------------------------------------------------
     def _bucket_for(self, plen):
         for b in self.prompt_buckets:
             if b >= plen:
                 return b
         return None
+
+    def _check_media(self, prompt, media, session):
+        """A request's images against the model's encoder and the prompt:
+        -> (media as a list of (patches padded to their bucket (1, bucket,
+        patch width), (h, w)), per position the media row it takes or -1).
+        Refused in words: images for a model with no encoder, or together
+        with what re-runs or ships a prompt as ids alone; a grid the encoder
+        does not take (``MediaEncoder.check_grid``) or a patch array that is
+        not the grid's; a prompt whose marked positions are not as many as
+        the rows the images give."""
+        enc, media = self._model.encoder, list(media or ())
+        if enc is None:
+            raise ValueError(
+                "model %r takes no images: it declares no encoder"
+                % self.name)
+        for feature, on in (
+                ("a prefix pool", self._prefix_pool is not None),
+                ("a session", session is not None
+                 and self._session_tier is not None),
+                ("a draft", self._draft is not None)):
+            if media and on:
+                raise ValueError(
+                    "a request with images cannot go through %s: it keeps or "
+                    "re-runs a prompt as token ids alone, and a media "
+                    "position's row is no token's" % feature)
+        if len(media) > enc.max_images:
+            raise ValueError("%d images in one request; at most %d"
+                             % (len(media), enc.max_images))
+        rows = 0
+        for k, (patches, (h, w)) in enumerate(media):
+            try:
+                enc.check_grid(int(h), int(w))
+            except ValueError as e:
+                raise ValueError("image %d: %s" % (k, e))
+            n = int(h) * int(w)
+            if (tuple(patches.shape) != (n, enc.patch_width)
+                    or patches.dtype != np.uint8):
+                raise ValueError(
+                    "image %d: patches %s %s are not uint8 (%d, %d)"
+                    % (k, patches.dtype, tuple(patches.shape), n,
+                       enc.patch_width))
+            # padded to its patch bucket here, on the caller's thread: the
+            # dispatch loop only hands it over
+            fed = np.zeros((1, enc.bucket_for(n), enc.patch_width), np.uint8)
+            fed[0, :n] = patches
+            media[k] = (fed, (int(h), int(w)))
+            rows += enc.rows_of(n)
+        marked = prompt == enc.media_id
+        if int(marked.sum()) != rows:
+            raise ValueError(
+                "the prompt marks %d media positions (token id %d) and the "
+                "%d images give %d rows" % (int(marked.sum()), enc.media_id,
+                                            len(media), rows))
+        index = np.where(marked, np.cumsum(marked) - 1, -1).astype(np.int32)
+        return media, index
 
     def _route_request(self, prompt, plen, h):
         """Build a partially-filled :class:`_Request` routed either
@@ -902,7 +1005,8 @@ class DecodeEngine:
         return req
 
     def submit(self, prompt, max_new=None, eos_id=None, deadline_ms=None,
-               tenant=None, priority=None, trace_ctx=None, session=None):
+               tenant=None, priority=None, trace_ctx=None, session=None,
+               media=None):
         """Enqueue one generation request; returns a
         :class:`DecodeStream`. Raises :class:`ShedError` when the queue
         is full, :class:`EngineClosedError` after ``stop()``, and
@@ -935,6 +1039,9 @@ class DecodeEngine:
             raise ValueError(
                 "prompt token out of range [0, %d)" % self.cfg.vocab)
         session = None if session is None else str(session)
+        media_index = None
+        if media or self._model.encoder is not None:
+            media, media_index = self._check_media(prompt, media, session)
         h = None
         if session is not None and self._session_tier is not None:
             h = self._session_tier.resume(session)
@@ -956,6 +1063,7 @@ class DecodeEngine:
                 self._session_tier.hibernate(session, h)
             raise
         req.session = session
+        req.media, req.media_index = media or None, media_index
         req.max_new = max_new
         req.eos_id = self.eos_id if eos_id is None else eos_id
         req.handoff = None
@@ -1045,6 +1153,7 @@ class DecodeEngine:
         req.max_new = max_new
         req.eos_id = self.eos_id if eos_id is None else eos_id
         req.handoff = handoff
+        req.media = req.media_index = None
         req.session = None if session is None else str(session)
         req.base = None
         req.start = 0
@@ -1191,6 +1300,19 @@ class DecodeEngine:
             self._jax.block_until_ready(self._chunk_zeros())
             report.append({"program": "chunk",
                            "rows": self._model.chunk_rows, "source": source})
+        enc = self._model.encoder
+        for b in sorted(self._tower_preds):
+            names = self._tower_vars[b]["feed_names"]
+            source = self._tower_preds[b].warm({
+                names[0]: np.zeros((1, b, enc.patch_width), np.uint8),
+                names[1]: np.ones((1, 2), np.int64)})
+            # the write of a bucket's rows into a request's buffer
+            self._jax.block_until_ready(self._media_write(
+                self._media_zeros(), self._jax.numpy.zeros(
+                    (enc.rows_of(b), enc.width), self._media_blank.dtype),
+                np.int32(0)))
+            report.append({"program": "tower", "patches": b,
+                           "source": source})
         for b in sorted(self._delta_preds):
             cache1 = (1, self.cfg.num_layers, self.cache_len,
                       self.cfg.hidden)
@@ -1232,11 +1354,13 @@ class DecodeEngine:
         order, then exactly one end.
 
         While a fill is in progress (:class:`_Fill`: a long prompt of a
-        model that declares a chunk program, admitted beside live streams)
-        a turn is: ONE chunk dispatched with no host sync, then the step,
-        then the delivery as above, so the live streams wait a chunk and
+        model that declares a chunk program, admitted beside live streams;
+        or a request that carries images) a turn is: ONE unit dispatched
+        with no host sync (an image through the encoder, or a chunk, or
+        the bucket's whole program), then the step,
+        then the delivery as above, so the live streams wait a unit and
         not the whole prompt for their next token. The turn after the last
-        chunk seats the request (its state into the slot, its first
+        unit seats the request (its state into the slot, its first
         token) before anything else is admitted."""
         phase = self._phase_s
         cpu = time.thread_time()
@@ -1255,7 +1379,7 @@ class DecodeEngine:
                 self._fail_all()
                 return
             if self._fill is not None:
-                self._fill_chunk()
+                self._fill_unit()
             live = sum(1 for s in self._slots if s is not None)
             if live == 0:
                 self._flush()
@@ -1407,10 +1531,14 @@ class DecodeEngine:
             tenant=req.tenant)
         if ctx is None or not ctx.sampled:
             ctx = qctx
-        if path == "cold" and self._chunked(req):
+        chunked = path == "cold" and self._chunked(req)
+        if chunked or (path == "cold" and req.media):
             if ctx is not None and ctx.sampled:
                 ctx = ctx.child()  # as a span's entry does
-            self._fill = _Fill(req, slot, self._chunk_zeros(), ctx)
+            self._fill = _Fill(
+                req, slot, self._chunk_zeros() if chunked else None, ctx,
+                chunked=chunked,
+                media=self._media_zeros() if req.media else None)
             return
         fields = {}
         if ctx is not None and path == "cold":
@@ -1464,10 +1592,25 @@ class DecodeEngine:
         obs.observe("serving.decode.ttft_seconds",
                     time.monotonic() - req.handle.t_submit)
 
-    def _prefill_feeds(self, ids, plen, bucket):
-        """A prefill program's two feeds under its own names."""
-        names = self._prefill_vars[bucket]["feed_names"]
-        return {names[0]: ids, names[1]: np.asarray([[plen]], np.int64)}
+    def _prefill_feeds(self, ids, plen, bucket, media=None, index=None):
+        """A prefill program's two feeds under its own names; and, for a
+        model with an encoder, the media buffer and per position the row it
+        takes (a text-only request: the blank buffer, -1 everywhere)."""
+        pv = self._prefill_vars[bucket]
+        names = pv["feed_names"]
+        feeds = {names[0]: ids, names[1]: np.asarray([[plen]], np.int64)}
+        self._media_feeds(feeds, pv, ids.shape[1], media, index)
+        return feeds
+
+    def _media_feeds(self, feeds, pv, rows, media, index):
+        if not pv.get("media_feed_names"):
+            return
+        at = np.full((1, rows), -1, np.int32)
+        if index is not None:
+            at[0, :len(index)] = index
+        buf, ix = pv["media_feed_names"]
+        feeds[buf] = self._media_blank if media is None else media
+        feeds[ix] = at
 
     def _prefill_error(self, e):
         """Count and report a prefill program's dispatch that raised."""
@@ -1475,9 +1618,15 @@ class DecodeEngine:
         obs.event("prefill_error", source="serving", model=self.name,
                   error="%s: %s" % (type(e).__name__, str(e)[:200]))
 
-    def _prefill(self, slot, req, sp):
+    @staticmethod
+    def _bucket_ids(req):
+        """The prompt right-padded to its bucket, (1, bucket) int64."""
         ids = np.zeros((1, req.bucket), np.int64)
         ids[0, :req.plen] = req.prompt
+        return ids
+
+    def _prefill(self, slot, req, sp):
+        ids = self._bucket_ids(req)
         try:
             if _conc._on:
                 _conc.note_blocking("device.dispatch")
@@ -1520,6 +1669,10 @@ class DecodeEngine:
             except Exception:  # noqa: BLE001 — caching is best-effort
                 self._bump("prefix_insert_errors")
         self._observe_prefill(req, sp)
+        # the prompt's real rows, and those of them an encoder made
+        self._bump("fill_rows", req.plen)
+        if req.media_index is not None:
+            self._bump("media_rows", int((req.media_index >= 0).sum()))
         self._seat(slot, req, sp, tok, req.plen)
 
     # -- a fill in chunks --------------------------------------------------
@@ -1537,14 +1690,94 @@ class DecodeEngine:
                 and req.bucket > rows
                 and -(-req.plen // rows) * rows <= self.cache_len)
 
-    def _chunk_feeds(self, ids, n, start, state):
+    def _chunk_feeds(self, ids, n, start, state, media=None, index=None):
         """The chunk program's feeds under its own names: the ids, the
-        chunk's real tokens, the row of its first position, the state."""
+        chunk's real tokens, the row of its first position, the state (and
+        :meth:`_media_feeds`, ``index`` the chunk's own positions')."""
         names = self._chunk_vars["feed_names"]
         feeds = {names[0]: ids, names[1]: np.asarray([[n]], np.int64),
                  names[2]: np.asarray([[start]], np.int64)}
         feeds.update(zip(self._chunk_vars["cache_feed_names"], state))
+        self._media_feeds(feeds, self._chunk_vars, ids.shape[1], media, index)
         return feeds
+
+    def _fill_unit(self):
+        """The next unit of the fill in progress: its next image through the
+        encoder, then its rows a chunk a turn, or (a fill that is not
+        chunked) its bucket's program once."""
+        f = self._fill
+        if f.image < len(f.req.media or ()):
+            self._fill_tower()
+        elif f.chunked:
+            self._fill_chunk()
+        else:
+            self._fill_bucket()
+
+    def _dispatch_unit(self, sp, dispatch):
+        """One unit of the fill in progress: ``dispatch()`` inside its span
+        ``sp``, not waited for. A dispatch that raises ends the request as a
+        failed prefill does. -> whether it went out."""
+        try:
+            with sp:
+                if _conc._on:
+                    _conc.note_blocking("device.dispatch")
+                dispatch()
+        except Exception as e:  # noqa: BLE001 — fail the request, not the loop
+            self._prefill_error(e)
+            self._end_fill(e)
+            return False
+        finally:
+            self._phase_s["prefill_seconds_total"] += sp.seconds
+        return True
+
+    def _fill_tower(self):
+        """Dispatch the encoder over the fill's next image (padded to its
+        patch bucket at submit) and the write of its rows into the request's
+        buffer; wait for neither."""
+        f, enc = self._fill, self._model.encoder
+        fed, (h, w) = f.req.media[f.image]
+        n, b = h * w, fed.shape[1]
+        names = self._tower_vars[b]["feed_names"]
+
+        def dispatch():
+            rows, = self._tower_preds[b].run(
+                {names[0]: fed, names[1]: np.asarray([[h, w]], np.int64)},
+                return_numpy=False)
+            f.media = self._media_write(f.media, rows, np.int32(f.media_at))
+
+        if not self._dispatch_unit(obs.span(
+                "serving.decode.tower", ctx=f.ctx, proc=self._proc,
+                request=f.req.handle.id, image=f.image, patches=n, bucket=b),
+                dispatch):
+            return
+        f.media_at += enc.rows_of(n)
+        f.image += 1
+        self._bump("tower_runs")
+        self._bump("media_images")
+        self._bump("media_patches", n)
+        self._bump("tower_pad_patches", b - n)
+
+    def _fill_bucket(self):
+        """The whole of a fill that is not chunked, as one unit: its
+        bucket's program dispatched with the request's media rows, not
+        waited for; the next turn seats it."""
+        f = self._fill
+        req = f.req
+
+        def dispatch():
+            f.nxt, *f.state = self._prefill_preds[req.bucket].run(
+                self._prefill_feeds(self._bucket_ids(req), req.plen,
+                                    req.bucket, f.media, req.media_index),
+                return_numpy=False)
+
+        if not self._dispatch_unit(obs.span(
+                "decode.prefill.bucket", ctx=f.ctx, proc=self._proc,
+                request=req.handle.id, slot=f.slot, bucket=req.bucket),
+                dispatch):
+            return
+        f.at = req.plen
+        self._bump("prefill_rows_computed", req.bucket)
+        self._bump("prefills")
 
     def _fill_chunk(self):
         """Dispatch the next chunk of the fill in progress and do not wait
@@ -1555,22 +1788,20 @@ class DecodeEngine:
         n = min(rows, req.plen - f.at)
         ids = np.zeros((1, rows), np.int64)
         ids[0, :n] = req.prompt[f.at:f.at + n]
-        feeds = self._chunk_feeds(ids, n, f.at, f.state)
+        feeds = self._chunk_feeds(
+            ids, n, f.at, f.state, f.media,
+            None if req.media_index is None
+            else req.media_index[f.at:f.at + n])
         f.state = None  # consumed by the dispatch, whatever comes of it
-        sp = obs.span("decode.prefill.chunk", ctx=f.ctx, proc=self._proc,
-                      request=req.handle.id, slot=f.slot, start=f.at, rows=n)
-        try:
-            with sp:
-                if _conc._on:
-                    _conc.note_blocking("device.dispatch")
-                f.nxt, *f.state = self._chunk_pred.run(
-                    feeds, return_numpy=False)
-        except Exception as e:  # noqa: BLE001 — fail the request, not the loop
-            self._prefill_error(e)
-            self._end_fill(e)
+
+        def dispatch():
+            f.nxt, *f.state = self._chunk_pred.run(feeds, return_numpy=False)
+
+        if not self._dispatch_unit(obs.span(
+                "decode.prefill.chunk", ctx=f.ctx, proc=self._proc,
+                request=req.handle.id, slot=f.slot, start=f.at, rows=n),
+                dispatch):
             return
-        finally:
-            self._phase_s["prefill_seconds_total"] += sp.seconds
         f.at += n
         self._bump("fill_chunks")
         self._bump("prefill_rows_chunked", rows)
@@ -1581,8 +1812,10 @@ class DecodeEngine:
         the context the chunks' spans were children of."""
         t1 = time.monotonic()
         fields.update(proc=self._proc, request=f.req.handle.id, slot=f.slot,
-                      bucket=f.req.bucket, plen=f.req.plen, path="chunked",
-                      chunks=-(-f.at // self._model.chunk_rows))
+                      bucket=f.req.bucket, plen=f.req.plen,
+                      path="chunked" if f.chunked else "media",
+                      chunks=(-(-f.at // self._model.chunk_rows)
+                              if f.chunked else 0), images=f.image)
         obs.record_span("decode.prefill", f.t0, t1, **fields)
         if f.ctx is not None and f.ctx.sampled:
             obs.export_span("decode.prefill", f.ctx,
@@ -1594,7 +1827,8 @@ class DecodeEngine:
         first token is on its way to the host already."""
         f, self._fill = self._fill, None
         with obs.span("decode.prefill.seat") as sp:
-            self._bump("chunked_fills")
+            if f.chunked:
+                self._bump("chunked_fills")
             self._seat_prefilled(f.slot, f.req, f, f.nxt, f.state)
         self._phase_s["prefill_seconds_total"] += sp.seconds
         self._fill_span(f)

@@ -16,7 +16,18 @@ thread per model does the batching):
   :class:`~paddle_tpu.serving.disagg.DisaggRouter` published into the
   registry). Body ``{"prompt": [ids], "max_new_tokens": 32?,
   "eos_id": 2?, "deadline_ms": 50?, "timeout_s": 10?, "stream": true?,
-  "tenant": "chat"?, "priority": "interactive"|0..2?}`` — ``tenant``
+  "tenant": "chat"?, "priority": "interactive"|0..2?, "images": [{"grid":
+  [h, w], "pixels": "<base64 of uint8 (14 h, 14 w, 3)>"}]?}`` — ``images``
+  (a model whose ``DecodeModel`` declares an encoder; absent: today's
+  request) are page images or screenshots as RAW pixels, no codec: ``h x
+  w`` patches each (what ``MediaEncoder.check_grid`` takes: both even, each
+  within the encoder's position table's side, ``h w`` within its largest
+  bucket), row-major rows of ``14 w`` RGB pixels; the prompt holds the
+  model's media placeholder id once for every row the images give (``h w /
+  4`` each), where the rows go, in order. A grid the encoder does not take, a pixel
+  count that is not the grid's, a placeholder count that is not the rows',
+  images for a model without an encoder or together with a session, a prefix
+  pool or a draft: 400, in words. ``tenant``
   must be a non-empty string and ``priority`` an int 0..2 or a named
   class (400 otherwise); both feed the disagg fleet's multi-tenant
   admission and are harmless on a lone engine.
@@ -266,6 +277,21 @@ class ServingHandler(BaseHTTPRequestHandler):
         self._send_json(code, doc, headers)
         return code
 
+    @staticmethod
+    def _media(name, engine, images):
+        """The body's ``images`` -> what ``submit(media=)`` takes: decoded,
+        checked and cut into patches here, on the handler's thread, outside
+        the engine's lock (span ``serving.decode.media_prepare``)."""
+        from .media import decode_images
+
+        encoder = getattr(engine, "media_encoder", None)
+        if encoder is None:
+            raise ValueError("model %r takes no images" % name)
+        with obs.span("serving.decode.media_prepare", proc="http",
+                      model=name, images=len(images)
+                      if isinstance(images, list) else 0):
+            return decode_images(images, encoder)
+
     def _generate(self, name, engine, sp):
         """Serve one ``:generate`` request inside its span `sp`; returns
         the HTTP status it answered with."""
@@ -287,6 +313,8 @@ class ServingHandler(BaseHTTPRequestHandler):
                         % (session,))
                 kw["session"] = session.strip()
             kw.update(self._parse_tenant_priority(body))
+            if body.get("images") is not None:
+                kw["media"] = self._media(name, engine, body["images"])
             timeout_s = body.get("timeout_s")
             stream = bool(body.get("stream", True))
         except (ValueError, KeyError, TypeError) as e:

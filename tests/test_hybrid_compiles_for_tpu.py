@@ -457,6 +457,245 @@ def test_solar_open2s_chunk_program_at_published_widths(one_chip):
     assert "flash_fwd_offset" in compiled.as_text()
 
 
+def _kimi_vl_at_published_widths():
+    from benchmark import costs_kimi_vl
+    from paddle_tpu.models import kimi_vl as kimi
+
+    doc = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "kimi_vl_a3b_instruct.json")))
+    doc["model"] = {k: v for k, v in doc.items()
+                    if not isinstance(v, (dict, list))}
+    return (kimi.KimiVlConfig.from_hf(costs_kimi_vl.sizes(doc)),
+            doc["serving"]["slots"], doc["serving"]["cache_len"])
+
+
+def test_kimi_vls_step_chunk_and_tower_at_published_widths(one_chip):
+    """Kimi-VL-A3B's cut (3,541 M parameters: five decoder layers whole
+    with all 64 experts, the whole vocabulary, the tower and the projector;
+    48 slots x 17,408) for the described chip. The step, compiled whole: the
+    latent rows of every layer (640 wide) aliased to their fetches (5.35 GB
+    updated in place), no cache-sized scratch (the absorbed path reads the
+    rows where they lie: no gather, no copy), the three grouped kernels of
+    each of the four sparse layers in it, the decoder's weights 6.19 GB of
+    the 7.08. The chunk and the largest tower, lowered: five carried arrays
+    donated and aliased, the flash forward kernel with a query offset once a
+    layer (queries and keys padded 192 -> 256, the values at 128), the
+    tower's flash kernel once a block, no (.., T, T) array in either. The
+    chip's compiler is handed the two attention calls alone at their
+    operand shapes. Compiled whole by hand (PR 47): the step 11 s, 0.02 GB
+    of scratch; the chunk 26 s, 0.53 GB; the 16,384 prefill 34 s, 0.89 GB;
+    the 4,096 tower 26 s, 0.69 GB."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.lowering import build_step_fn
+    from paddle_tpu.models import kimi_vl as kimi
+    from paddle_tpu.ops import LOWERINGS
+    from paddle_tpu.ops.pallas_attention import flash_attention
+
+    cfg, slots, cache_len = _kimi_vl_at_published_widths()
+    model = cfg.decode_model(cache_len)
+    decl, rows = model.state, model.chunk_rows
+    assert rows == 4096 and model.encoder.buckets == (1024, 2048, 4096)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = {k: sds(s, d) for k, (s, d) in kimi.param_shapes(cfg).items()}
+    weights = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in params.values())
+    assert round(weights / 1e9, 2) == 7.08
+
+    def lowered(build, args, feeds, donated):
+        with fluid.program_guard(fluid.Program(), fluid.Program()):
+            v = build(cfg, *args)
+            prog = fluid.default_main_program()
+        step = build_step_fn(prog, v["feed_names"],
+                             [x.name for x in v["fetch_vars"]], is_test=True,
+                             platform="tpu")
+        names = v.get("cache_feed_names", [])
+        used = {n: params[n] for n in params
+                if prog.global_block().has_var(n)}
+
+        def fwd(state, feeds, donated):
+            feeds = dict(feeds)
+            feeds.update(zip(names, donated))
+            return step(state, feeds, jax.random.PRNGKey(0))[0]
+
+        return used, jax.jit(fwd, donate_argnums=(2,)).lower(
+            used, feeds, donated)
+
+    used, step = lowered(
+        kimi.build_step, (cache_len,),
+        {"kimi_step_tok": sds((slots, 1), "int32"),
+         "kimi_step_pos": sds((slots, 1), "int32")},
+        tuple(sds((slots,) + tuple(e.shape), e.dtype) for e in decl))
+    compiled = _no_cache_compile(step)
+    mem = compiled.memory_analysis()
+    state_bytes = slots * sum(e.nbytes for e in decl)
+    assert round(state_bytes / 1e9, 2) == 5.35
+    assert mem.alias_size_in_bytes >= state_bytes          # all five, in place
+    assert mem.temp_size_in_bytes < 256e6
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 4 * 3
+    for scope in ("kimi.mla", "kimi.mlp", "kimi.experts.route",
+                  "kimi.experts.experts", "kimi.experts.shared", "kimi.head"):
+        assert scope + "/" in hlo, scope          # in the HLO's op_names
+    held = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in used.values())
+    assert round(held / 1e9, 2) == 6.19
+
+    _, chunk = lowered(
+        kimi.build_chunk, (rows, cache_len),
+        {"kimi_chunk_ids": sds((1, rows), "int32"),
+         "kimi_chunk_len": sds((1, 1), "int32"),
+         "kimi_chunk_start": sds((1, 1), "int32"),
+         "kimi_chunk_media": sds((cfg.media_rows, cfg.hidden), "bfloat16"),
+         "kimi_chunk_media_index": sds((1, rows), "int32")},
+        tuple(sds((1,) + tuple(e.shape), e.dtype) for e in decl))
+    assert "kimi.splice/" in chunk.as_text(debug_info=True)
+    text = chunk.as_text()
+    assert text.count("tf.aliasing_output") == len(decl) == 5
+    assert text.count("flash_fwd_offset") >= 5
+    assert not re.search(r"tensor<(\d+x)*4096x(4096|%d)xf32>" % cache_len,
+                         text)
+    heads = cfg.heads
+    q = sds((heads, rows, 256), "bfloat16")
+    k = sds((heads, cache_len, 256), "bfloat16")
+    v = sds((heads, cache_len, 128), "bfloat16")
+    compiled = _no_cache_compile(jax.jit(
+        lambda q, k, v, at: flash_attention(
+            q[None], k[None], v[None], causal=True, sm_scale=192 ** -0.5,
+            block_q=512, block_k=512, q_offset=at)).lower(
+        q, k, v, sds((), "int32")))
+    assert "flash_fwd_offset" in compiled.as_text()
+
+    patches = model.encoder.buckets[-1]
+    _, tower = lowered(
+        kimi.build_tower, (patches,),
+        {"kimi_tower_patches": sds((1, patches, 588), "uint8"),
+         "kimi_tower_grid": sds((1, 2), "int32")}, ())
+    named = tower.as_text(debug_info=True)
+    for scope in ("kimi.tower.patch", "kimi.tower.attn", "kimi.tower.mlp",
+                  "kimi.tower.merge", "kimi.project"):
+        assert scope + "/" in named, scope
+    text = tower.as_text()
+    assert text.count("flash_fwd") >= cfg.vision.layers
+    assert not re.search(r"tensor<(\d+x)*4096x4096x(f32|bf16)>", text)
+
+    class Ctx:
+        platform, mesh_axes = "tpu", None
+
+    wide = sds((1, patches, cfg.vision.hidden), "bfloat16")
+    compiled = _no_cache_compile(jax.jit(
+        lambda ins: LOWERINGS["tower_attention"](
+            Ctx(), ins, dict(heads=cfg.vision.heads))).lower(
+        {"Q": [wide], "K": [wide], "V": [wide],
+         "Grid": [sds((1, 2), "int32")]}))
+    assert "flash_fwd" in compiled.as_text()
+
+
+def _lowered_digest(build, cfg, args, batch, one_chip):
+    """sha256 (16 hex digits) of a program's lowered text for the described
+    chip, outside file paths: the checkout's root replaced, a Pallas call's
+    payload (the serialised kernel, source locations and all) blanked."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid.lowering import build_step_fn
+
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        v = build(cfg, *args)
+        prog = fluid.default_main_program()
+    fn = build_step_fn(prog, v["feed_names"],
+                       [x.name for x in v["fetch_vars"]], is_test=True,
+                       platform="tpu")
+    block = prog.global_block()
+
+    def sds(var):
+        shape = [batch if (d is None or d < 0) else d for d in var.shape]
+        dtype = {"int64": "int32"}.get(str(var.dtype), str(var.dtype))
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    state = {n: sds(x) for n, x in block.vars.items()
+             if getattr(x, "persistable", False)}
+    feeds = {n: sds(block.vars[n]) for n in v["feed_names"]}
+    text = jax.jit(lambda s, f, k: fn(s, f, k)[0]).lower(
+        state, feeds, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                           sharding=one_chip)).as_text()
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = "<kernel>"',
+                  text.replace(ROOT, "<root>"))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_text_only_models_programs_lower_as_before_images(one_chip):
+    """What ISSUE 47 (requests that carry images; `mla_attention` without a
+    selection; the flash forward kernel's second width; the latent row's
+    make moved into `decoder_blocks.py`) must NOT move: the step and fill
+    programs of GPT, GLM-5 and Solar-Open2 at their cells' sizes, lowered for
+    the described chip, are line for line what the parent commit lowers
+    (digests taken from `git archive 90eb6e1` by the same code, PR 47). A
+    later PR that changes one of these programs on purpose takes its new
+    digest the same way and says so."""
+    from benchmark import costs_glm5, costs_solar
+    from paddle_tpu.models import glm_moe_dsa as glm
+    from paddle_tpu.models import gpt
+    from paddle_tpu.models import solar_open2 as solar
+
+    def doc_of(name):
+        doc = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                          name + ".json")))
+        doc["model"] = {k: v for k, v in doc.items()
+                        if not isinstance(v, (dict, list))}
+        return doc
+
+    got = {}
+    doc = doc_of("glm_5")
+    m = costs_glm5.sizes(doc)
+    cfg = glm.GlmMoeDsaConfig.from_hf(
+        m, router_experts=m["router_experts"], first_expert=m["first_expert"])
+    sv = doc["serving"]
+    got["glm5.step"] = _lowered_digest(glm.build_step, cfg,
+                                       (sv["cache_len"],), sv["slots"],
+                                       one_chip)
+    got["glm5.prefill_8192"] = _lowered_digest(
+        glm.build_prefill, cfg, (8192, sv["cache_len"]), 1, one_chip)
+    doc = doc_of("solar_open2_250b")
+    m = costs_solar.sizes(doc)
+    cfg = solar.SolarOpen2Config.from_hf(
+        m, router_experts=m["router_experts"], first_expert=m["first_expert"])
+    sv = doc["serving"]
+    got["solar.step"] = _lowered_digest(solar.build_step, cfg,
+                                        (sv["cache_len"],), sv["slots"],
+                                        one_chip)
+    got["solar.chunk_4096"] = _lowered_digest(
+        solar.build_chunk, cfg, (4096, sv["cache_len"]), 1, one_chip)
+    doc = doc_of("openai_gpt")
+    m, sv = doc["model"], doc["serving"]
+    cfg = gpt.GPTConfig(vocab=m["vocab_size"], hidden=m["n_embd"],
+                        num_layers=m["n_layer"], heads=m["n_head"],
+                        ffn=m["n_inner"], max_len=m["n_positions"],
+                        dropout=0.0)
+    model = cfg.decode_model(sv["cache_len"], sv.get("kv_dtype", "fp32"))
+    got["gpt.step"] = _lowered_digest(model.build_step, cfg,
+                                      (sv["cache_len"],), sv["slots"],
+                                      one_chip)
+    got["gpt.prefill_128"] = _lowered_digest(
+        model.build_prefill, cfg, (128, sv["cache_len"]), 1, one_chip)
+    assert got == PINNED_AT_PR_47, got
+
+
+PINNED_AT_PR_47 = {
+    "glm5.step": "9414e6d20bf22bc1", "glm5.prefill_8192": "27c7f756360afdd1",
+    "solar.step": "2efa83de882fceb5", "solar.chunk_4096": "375f95600c0187ed",
+    "gpt.step": "65d582511be71238", "gpt.prefill_128": "7f3da6e0d33c6c86"}
+
+
 def test_the_delta_rules_scan_kernel_at_solar_open2s_widths(one_chip):
     """The Pallas kernel of the delta rule's chunked scan
     (`ops/pallas_kda.py` `kda_scan_fwd`) compiled for the described chip at
